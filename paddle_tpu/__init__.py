@@ -13,37 +13,6 @@ from __future__ import annotations
 
 __version__ = "0.1.0"
 
-# jax version compat (same spirit as ops/pallas/_compat.py): jax <= 0.4.x
-# ships shard_map under jax.experimental only; alias it so the parallel /
-# distributed layers' `from jax import shard_map` works on the container's
-# jax_graft toolchain.
-import jax as _jax_mod
-if not hasattr(_jax_mod, "shard_map"):
-    try:
-        from jax.experimental.shard_map import shard_map as _shard_map_impl
-        import functools as _functools_mod
-
-        @_functools_mod.wraps(_shard_map_impl)
-        def _shard_map_compat(*args, **kwargs):
-            # newer jax renamed check_rep -> check_vma
-            if "check_vma" in kwargs:
-                kwargs["check_rep"] = kwargs.pop("check_vma")
-            return _shard_map_impl(*args, **kwargs)
-
-        _jax_mod.shard_map = _shard_map_compat
-    except ImportError:  # very old jax: leave the original ImportError path
-        pass
-if not hasattr(_jax_mod.lax, "pcast"):
-    # pcast is a varying-axis TYPE cast (data identity); pre-varying-types
-    # jax (check_rep era) needs no cast at all
-    _jax_mod.lax.pcast = lambda x, axes, to="varying": x
-if not hasattr(_jax_mod.lax, "axis_size"):
-    def _axis_size_compat(axis_name):
-        from jax._src import core as _core
-        frame = _core.axis_frame(axis_name)  # older jax returns the int
-        return getattr(frame, "size", frame)
-    _jax_mod.lax.axis_size = _axis_size_compat
-
 from . import flags as _flags_mod
 from .flags import get_flags, set_flags
 

@@ -7,9 +7,12 @@ synchronization mapped to ``jax.block_until_ready``.
 """
 from __future__ import annotations
 
+import os
+
 import jax
 
-__all__ = ["set_device", "get_device", "device_count", "get_all_device_type",
+__all__ = ["setup_compile_cache", "set_device", "get_device",
+           "device_count", "get_all_device_type",
            "is_compiled_with_cuda", "is_compiled_with_tpu", "synchronize",
            "Stream", "Event", "current_stream"]
 
@@ -17,10 +20,26 @@ _current = ["tpu:0"]
 
 
 def _platform():
-    try:
-        return jax.default_backend()
-    except Exception:
-        return "cpu"
+    return jax.default_backend()
+
+
+def setup_compile_cache() -> str:
+    """Place JAX's persistent compilation cache and return its directory.
+
+    The location is decided from OUTSIDE the program: when
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it and nothing
+    is set in code; otherwise the cache lives at ``<checkout>/.jax_cache``
+    (git-ignored).  The directory is part of every cache key, so it must
+    not move between runs — never a temp dir.  Entry points call this once
+    before their first compile (chip_smoke.py, bench.py, serving/worker.py);
+    nothing else in the repo names a cache path.  Touches no backend."""
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+        cache_dir = os.path.join(checkout, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    return cache_dir
 
 
 def set_device(device: str):

@@ -13,6 +13,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 import jax
+from jax.experimental import mesh_utils
 from jax.sharding import Mesh
 
 _DEFAULT_MESH: Optional[Mesh] = None
@@ -28,11 +29,10 @@ def build_mesh(axes: Dict[str, int], devices=None) -> Mesh:
     total = int(np.prod(sizes))
     if total != len(devices):
         raise ValueError(f"mesh {axes} needs {total} devices, have {len(devices)}")
-    try:
-        from jax.experimental import mesh_utils
-        grid = mesh_utils.create_device_mesh(sizes, devices=devices)
-    except Exception:
-        grid = np.asarray(devices).reshape(sizes)
+    # no reshape fallback: on a TPU a device set mesh_utils cannot lay out
+    # (not a sub-box of the physical topology) would otherwise become a
+    # mesh whose "neighbours" are not ICI neighbours, silently
+    grid = mesh_utils.create_device_mesh(sizes, devices=devices)
     return Mesh(grid, axis_names=tuple(names))
 
 

@@ -873,10 +873,7 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
     sin_t, cos_t = _rope_tables(c.max_position_embeddings, head_dim,
                                 c.rope_theta, d)
     if attention_impl == "auto":
-        try:
-            use_kernel = any(dev.platform == "tpu" for dev in jax.devices())
-        except Exception:
-            use_kernel = False
+        use_kernel = any(dev.platform == "tpu" for dev in jax.devices())
     else:
         use_kernel = attention_impl == "pallas"
 
@@ -1397,10 +1394,9 @@ def llama_generate_fused(params, config: LlamaConfig, input_ids,
     into ONE executable, so serving pays a single dispatch per request
     instead of one per token.
 
-    Measured r5 (271M, B=1, v5e over the remote transport): the per-token
-    python loop runs ~48 tok/s — ~20 ms/token of dispatch round-trips
-    against ~2 ms of model math; the fused loop removes that overhead
-    entirely.  Trade-off vs llama_generate: always runs max_new_tokens
+    The per-token python loop pays one host dispatch per token; the fused
+    loop pays one per request (speeds: not measured on this code).
+    Trade-off vs llama_generate: always runs max_new_tokens
     steps (no early exit when every sequence hits EOS — EOS tails are
     masked to eos_token_id, same output contract)."""
     c = config

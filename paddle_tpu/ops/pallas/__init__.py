@@ -1,10 +1,15 @@
 """Pallas TPU kernel overrides.
 
-The PD_REGISTER_KERNEL(..., GPU, ...) analog: importing this module registers
+The PD_REGISTER_KERNEL(..., GPU, ...) analog: `register_all()` registers
 Pallas implementations for hot ops under the same op names the functional API
-dispatches through (kernel_registry.h:196 → core/dispatch.py registry).
-Registration is TPU-only; on CPU the jnp defaults run (tests exercise the
-kernels via interpret=True).
+dispatches through (kernel_registry.h:196 → core/dispatch.py registry).  It
+runs lazily, on the first kernel lookup (probing the platform initialises a
+backend, which must not happen at import), registers only when a TPU is
+among `jax.devices()`, and lets a failing device probe raise: on the CPU the
+jnp defaults run.  Off the chip the kernels are covered twice — numerics via
+`interpret=True` parity tests, and the Mosaic lowering itself by compiling
+for a described v5e (tests/test_chip_compile.py); interpret mode alone
+cannot see a block shape or a VMEM budget the chip's compiler refuses.
 """
 from __future__ import annotations
 
@@ -12,7 +17,6 @@ import jax
 import jax.numpy as jnp
 
 from ...core.dispatch import register_kernel
-from . import _compat  # noqa: F401  (pltpu.CompilerParams alias, jax<=0.4)
 from . import flash_attention as fa_mod
 from . import paged_attention as pa_mod
 
@@ -106,11 +110,7 @@ def register_all(force=False):
     """Register Pallas overrides (TPU backend only unless force)."""
     if _registered[0]:
         return
-    try:
-        on_tpu = any(d.platform == "tpu" for d in jax.devices())
-    except Exception:
-        on_tpu = False
-    if not (on_tpu or force):
+    if not (force or any(d.platform == "tpu" for d in jax.devices())):
         return
     register_kernel("flash_attention", impl="pallas")(_fa_plain)
     register_kernel("flash_attention_causal", impl="pallas")(_fa_causal)

@@ -88,7 +88,13 @@ def _rms_bwd_kernel(x_ref, w_ref, inv_ref, g_ref, dx_ref, dw_ref, dw_scr, *,
 
 
 def _rms_block_rows(n, h):
-    bn = max(8, min(256, n))
+    # a (rows, h) tile of 256K elements is what the row-wise backward
+    # kernels fit into scoped VMEM with their f32 temporaries and double
+    # buffers (256 rows at h=1024, 64 at h=4096; a fixed 256 rows at h=4096
+    # asks Mosaic for 18 MB against its 16 MB limit)
+    cap = max(8, (256 * 1024) // h)
+    cap = 1 << (cap.bit_length() - 1)
+    bn = max(8, min(cap, n))
     while n % bn != 0:
         bn //= 2
     return max(bn, 1)

@@ -19,11 +19,17 @@ so decode, verify, and chunked prefill score through the SAME kernel body
 (and, off-TPU, the same `*_ref`) — the impl-uniformity the speculative
 losslessness guarantee rests on.
 
-Layout (lane-tiled — no 128x padding cliffs like PERF.md §7.2):
+Layout (what Mosaic lowers for the v5e — compile-tested at Llama-7B widths in
+tests/test_chip_compile.py; every block spans the last two dims of its array
+whole, the one shape rule the lowering never refuses):
 
-  q          [S, Qmax, Hq, D]    ragged query segments, right-padded to Qmax
+  q          [S, Qmax, Hq, D]    ragged query segments, right-padded to Qmax;
+                                 relaid OUTSIDE the kernel to kv-head-major
+                                 rows [S, Hkv, R, D] (R = Qmax*rep rounded up
+                                 to 8 sublanes), and the output laid back
   k_pages    [Hkv, NP, ps, D]    page-pooled keys; last two dims are the
   v_pages    [Hkv, NP, ps, D]    (sublane, lane) tile => D=128-friendly
+  k/v_scales [Hkv, NP, ps]       passed as [Hkv, NP, 1, ps]: a (1, ps) tile
   page_table [S, P] int32        physical page of each logical page slot
   q_start    [S]   int32         absolute position of query 0 per slot
   q_len      [S]   int32         valid queries per slot (0 = inactive)
@@ -35,8 +41,10 @@ The page table and segment descriptors ride scalar prefetch
 (`pltpu.PrefetchScalarGridSpec`), so the K/V BlockSpec index maps resolve
 the PHYSICAL page to DMA before the kernel body runs — the indirection
 costs no kernel time.  GQA is native: the q block for grid step (s, h) is
-the `Hq // Hkv` query heads sharing kv head h, and K/V pages are fetched
-once per kv head, never materialized per q head.
+the R rows (every query of the segment x the `Hq // Hkv` heads sharing kv
+head h), and K/V pages are fetched once per kv head, never materialized per
+q head.  At q_len = 1 with MHA that block is ONE real row padded to 8 — it
+compiles and is correct; how well it feeds the MXU has not been measured.
 
 Pages past a slot's `kv_len` are skipped via `pl.when` (their table entries
 are clamped to a valid page id by the cache manager, so the speculative DMA
@@ -46,7 +54,8 @@ inside the kernel.  Padding query rows (>= q_len) and inactive slots
 
 int8/fp8 pages (`k_scales`/`v_scales`) dequantize INSIDE the kernel for
 every path — the per-(page, head, token-row) scale pages ride the same
-page-table indirection, and the f32 K/V never exist outside VMEM.
+page-table indirection, applied to the scores and probabilities rather than
+to K and V, so a dequantized K/V tile never exists at all.
 """
 from __future__ import annotations
 
@@ -58,28 +67,36 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import _compat  # noqa: F401  (pltpu.CompilerParams alias, jax<=0.4)
-
 __all__ = ["ragged_paged_attention", "ragged_paged_attention_ref",
            "ragged_paged_attention_decode", "paged_attention_decode_ref",
            "paged_gather_kv", "paged_gather_scales"]
 
 NEG_INF = -1e30
+_SUBLANES = 8      # f32 sublane count: query-row blocks pad to this
 
 
-def _attend_page(q, k, v, mask, sm_scale, m_scr, l_scr, acc_scr):
-    """One online-softmax update over one (already dequantized, f32) K/V
-    page — shared by the plain and fused-dequant kernel bodies so the
-    accumulator math can never drift between them.  ``q`` is the flattened
-    [Qmax*rep, D] query block, ``mask`` the [Qmax*rep, ps] validity of each
-    (query row, kv position) pair; a row with no valid position EVER (a
-    padding query) keeps m = NEG_INF and l = 0, so the finalizer emits
-    exact zeros for it."""
+def _attend_page(q, k, v, mask, sm_scale, m_scr, l_scr, acc_scr,
+                 k_scale=None, v_scale=None):
+    """One online-softmax update over one f32 K/V page — shared by the
+    plain and fused-dequant kernel bodies so the accumulator math can never
+    drift between them.  ``q`` is the [R, D] query-row block (row r = query
+    r // rep, head r % rep of the kv group), ``mask`` the [R, ps] validity
+    of each (query row, kv position) pair; a row with no valid position
+    EVER (a padding query or a sublane-padding row) keeps m = NEG_INF and
+    l = 0, so the finalizer emits exact zeros for it.
+
+    ``k_scale``/``v_scale`` ([1, ps], quantized pages only) are the page's
+    per-row dequant scales, applied on the [R, ps] score side — q·(k_t·s_t)
+    == (q·k_t)·s_t and Σ p_t·(v_t·s_t) == Σ (p_t·s_t)·v_t — so the scale
+    row broadcasts along sublanes in the layout it was DMA'd in and the
+    dequantized K/V tile never exists, not even in VMEM."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * sm_scale     # [Qmax*rep, ps]
+        preferred_element_type=jnp.float32) * sm_scale     # [R, ps]
+    if k_scale is not None:
+        s = s * k_scale
     s = jnp.where(mask, s, NEG_INF)
-    m_prev = m_scr[:]                                      # [Qmax*rep, 1]
+    m_prev = m_scr[:]                                      # [R, 1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     # re-mask p explicitly: on a row whose every position is masked,
     # exp(NEG_INF - NEG_INF) would be 1, silently averaging garbage V rows
@@ -87,6 +104,8 @@ def _attend_page(q, k, v, mask, sm_scale, m_scr, l_scr, acc_scr):
     p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
     alpha = jnp.exp(m_prev - m_new)
     l_scr[:] = alpha * l_scr[:] + jnp.sum(p, axis=1, keepdims=True)
+    if v_scale is not None:
+        p = p * v_scale
     acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -94,7 +113,7 @@ def _attend_page(q, k, v, mask, sm_scale, m_scr, l_scr, acc_scr):
 
 
 def _segment_mask(shape, i, page_size, rep, q_start, q_len, kv_len):
-    """[Qmax*rep, ps] validity of page i's positions against the slot's
+    """[R, ps] validity of page i's positions against the slot's
     ragged segment: kv position `col` is visible to query row `r` (query
     index r // rep) iff it is causally before-or-at that query's absolute
     position, the query is real, and the position holds valid KV."""
@@ -109,8 +128,7 @@ def _finalize_out(i, n_pages, o_ref, m_scr, l_scr, acc_scr):
     def _finalize():
         l = l_scr[:]
         inv = jnp.where(l > 0.0, 1.0 / jnp.where(l > 0.0, l, 1.0), 0.0)
-        o_ref[0] = (acc_scr[:] * inv).reshape(o_ref.shape[1:]) \
-            .astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[:] * inv).astype(o_ref.dtype)
 
 
 def _init_scratch(i, m_scr, l_scr, acc_scr):
@@ -132,12 +150,10 @@ def _ragged_kernel(pt_ref, qs_ref, ql_ref, kl_ref, q_ref, k_ref, v_ref,
 
     @pl.when(i * page_size < kv_len)
     def _body():
-        q = q_ref[0].astype(jnp.float32)
-        qmax = q.shape[0]
-        q2 = q.reshape(qmax * rep, q.shape[-1])
-        mask = _segment_mask((qmax * rep, page_size), i, page_size, rep,
+        q = q_ref[0, 0].astype(jnp.float32)
+        mask = _segment_mask((q.shape[0], page_size), i, page_size, rep,
                              q_start, q_len, kv_len)
-        _attend_page(q2, k_ref[0, 0].astype(jnp.float32),
+        _attend_page(q, k_ref[0, 0].astype(jnp.float32),
                      v_ref[0, 0].astype(jnp.float32),
                      mask, sm_scale, m_scr, l_scr, acc_scr)
 
@@ -148,13 +164,14 @@ def _ragged_kernel_quant(pt_ref, qs_ref, ql_ref, kl_ref, q_ref, k_ref,
                          ks_ref, v_ref, vs_ref, o_ref, m_scr, l_scr,
                          acc_scr, *, page_size, sm_scale, rep):
     """Fused-dequant variant: K/V pages arrive in their int8/fp8 STORAGE
-    dtype plus a per-row f32 absmax scale page, and the dequant happens
-    here, on the page tile already resident in VMEM — quantized K/V never
-    materialize as an f32 tensor anywhere (DTYPE001 polices the host-side
-    paths).  The dequant expression mirrors ``serving.quant.dequantize_kv``
-    exactly (astype f32, multiply by the broadcast row scale) so the kernel
-    and every jnp gather path see identical values for identical stored
-    rows — on EVERY dispatch path, not just decode."""
+    dtype plus a per-row f32 absmax scale page ([1, ps] per page), and the
+    dequant happens here, on the page tile already resident in VMEM —
+    quantized K/V never materialize as an f32 tensor anywhere (DTYPE001
+    polices the host-side paths).  The row scales are applied on the score
+    side (see ``_attend_page``) — the same products as
+    ``serving.quant.dequantize_kv``'s astype-f32-times-row-scale, in another
+    association order, so the kernel matches the jnp gather paths to f32
+    rounding — on EVERY dispatch path, not just decode."""
     b = pl.program_id(0)
     i = pl.program_id(2)
     n_pages = pl.num_programs(2)
@@ -163,16 +180,14 @@ def _ragged_kernel_quant(pt_ref, qs_ref, ql_ref, kl_ref, q_ref, k_ref,
 
     @pl.when(i * page_size < kv_len)
     def _body():
-        k = k_ref[0, 0].astype(jnp.float32) \
-            * ks_ref[0, 0].astype(jnp.float32)[:, None]        # [ps, D]
-        v = v_ref[0, 0].astype(jnp.float32) \
-            * vs_ref[0, 0].astype(jnp.float32)[:, None]
-        q = q_ref[0].astype(jnp.float32)
-        qmax = q.shape[0]
-        q2 = q.reshape(qmax * rep, q.shape[-1])
-        mask = _segment_mask((qmax * rep, page_size), i, page_size, rep,
+        q = q_ref[0, 0].astype(jnp.float32)
+        mask = _segment_mask((q.shape[0], page_size), i, page_size, rep,
                              q_start, q_len, kv_len)
-        _attend_page(q2, k, v, mask, sm_scale, m_scr, l_scr, acc_scr)
+        _attend_page(q, k_ref[0, 0].astype(jnp.float32),
+                     v_ref[0, 0].astype(jnp.float32),
+                     mask, sm_scale, m_scr, l_scr, acc_scr,
+                     k_scale=ks_ref[0, 0].astype(jnp.float32),
+                     v_scale=vs_ref[0, 0].astype(jnp.float32))
 
     _finalize_out(i, n_pages, o_ref, m_scr, l_scr, acc_scr)
 
@@ -225,28 +240,42 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start, q_len,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
 
+    # kv-head-major query rows: [S, Qmax, Hq, D] -> [S, Hkv, R, D] with row
+    # r = query r // rep, head r % rep of the group, zero-padded to a
+    # sublane multiple.  The (1, 1, R, D) block then spans the array's last
+    # two dims whole, which is what the Mosaic lowering accepts for any
+    # rep/qmax (a (qmax, rep, D) block out of [.., Hq, D] is refused: rep
+    # is neither a multiple of 8 nor Hq), and the kernel body needs no
+    # relayout.  Padding rows have query index >= qmax >= q_len, so the
+    # segment mask zeroes them like any padding query.
+    rows = qmax * rep
+    rows_pad = -(-rows // _SUBLANES) * _SUBLANES
+    qr = q.reshape(s_slots, qmax, hkv, rep, d).transpose(0, 2, 1, 3, 4) \
+        .reshape(s_slots, hkv, rows, d)
+    qr = jnp.pad(qr, ((0, 0), (0, 0), (0, rows_pad - rows), (0, 0)))
+
     grid = (s_slots, hkv, n_ptab)
 
     def q_idx(b, h, i, pt, qs, ql, kl):
-        return (b, 0, h, 0)
+        return (b, h, 0, 0)
 
     def kv_idx(b, h, i, pt, qs, ql, kl):
         return (h, pt[b, i], 0, 0)
 
-    def sc_idx(b, h, i, pt, qs, ql, kl):
-        return (h, pt[b, i], 0)
-
-    q_spec = pl.BlockSpec((1, qmax, rep, d), q_idx)
+    q_spec = pl.BlockSpec((1, 1, rows_pad, d), q_idx)
     kv_spec = pl.BlockSpec((1, 1, page_size, d), kv_idx)
-    sc_spec = pl.BlockSpec((1, 1, page_size), sc_idx)
     quant = k_scales is not None
     if quant:
+        # scale pages ride as [Hkv, NP, 1, ps]: a (1, ps) tile per page
+        # (again whole last-two dims), lane-major like the scores it scales
+        sc_spec = pl.BlockSpec((1, 1, 1, page_size), kv_idx)
         in_specs = [q_spec, kv_spec, sc_spec, kv_spec, sc_spec]
-        inputs = (q, k_pages, k_scales, v_pages, v_scales)
+        inputs = (qr, k_pages, k_scales[:, :, None, :],
+                  v_pages, v_scales[:, :, None, :])
         body = _ragged_kernel_quant
     else:
         in_specs = [q_spec, kv_spec, kv_spec]
-        inputs = (q, k_pages, v_pages)
+        inputs = (qr, k_pages, v_pages)
         body = _ragged_kernel
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
@@ -254,23 +283,25 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start, q_len,
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((qmax * rep, 1), jnp.float32),
-            pltpu.VMEM((qmax * rep, 1), jnp.float32),
-            pltpu.VMEM((qmax * rep, d), jnp.float32),
+            pltpu.VMEM((rows_pad, 1), jnp.float32),
+            pltpu.VMEM((rows_pad, 1), jnp.float32),
+            pltpu.VMEM((rows_pad, d), jnp.float32),
         ],
     )
     kernel = functools.partial(body, page_size=page_size,
                                sm_scale=sm_scale, rep=rep)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_slots, qmax, hq, d),
+        out_shape=jax.ShapeDtypeStruct((s_slots, hkv, rows_pad, d),
                                        out_dtype or q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(page_table.astype(jnp.int32), q_start.astype(jnp.int32),
       q_len.astype(jnp.int32), kv_len.astype(jnp.int32), *inputs)
+    return out[:, :, :rows].reshape(s_slots, hkv, qmax, rep, d) \
+        .transpose(0, 2, 1, 3, 4).reshape(s_slots, qmax, hq, d)
 
 
 def paged_gather_kv(pages, page_table):

@@ -100,6 +100,7 @@ class _Worker:
     client: RpcClient | None = None
     port: int = 0
     pid: int = 0
+    platform: str = ""               # JAX backend the worker's hello reported
     alive: bool = False
     routable: bool = False
     missed: int = 0                  # consecutive health-probe timeouts
@@ -250,7 +251,6 @@ class ProcessFleet:
         env = _rank_env(os.environ, rank=idx, local_rank=idx,
                         world=len(names), master=f"127.0.0.1:{port}",
                         endpoints=endpoints, nnodes=1, node_rank=0)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         # the worker must import the same paddle_tpu tree regardless of
         # the supervisor's cwd
         pkg_root = os.path.dirname(os.path.dirname(
@@ -291,9 +291,12 @@ class ProcessFleet:
             "hello", deadline_s=max(5.0, deadline - time.monotonic()))
         w.alive = True
         w.routable = True
+        # the platform the worker actually GOT (it inherits JAX_PLATFORMS
+        # from this process's environment, never a default from here)
+        w.platform = hello["platform"]
         self.elastic.register(w.key())
         self.tracer.engine_event("spawn", worker=w.name, generation=gen,
-                                 pid=w.pid)
+                                 pid=w.pid, platform=w.platform)
         return hello
 
     # -- request surface ---------------------------------------------------
@@ -865,6 +868,7 @@ class ProcessFleet:
                          "max_ms": round(self._h_recovery.max * 1e3, 3)
                          if self._h_recovery.count else 0.0},
             "per_worker": {w.name: {"pid": w.pid, "generation": w.generation,
+                                    "platform": w.platform,
                                     "alive": w.alive,
                                     "routable": w.routable,
                                     "load": w.load, "hb": w.hb,

@@ -162,7 +162,9 @@ class _WorkerHost:
         import numpy as np
         eng = self.engine
         if method == "hello":
+            import jax
             return {"name": self.name, "pid": os.getpid(),
+                    "platform": jax.default_backend(),
                     "restored": self.restored is not None,
                     "restored_path": None if self.restored is None
                     else self.restored[0],
@@ -295,11 +297,13 @@ def main(argv=None) -> int:
     # Heavy imports AFTER argparse so --help stays fast.
     import time as _time  # noqa: F401 — clock domain note below
 
+    from ..core.device import setup_compile_cache
     from ..inference.paged import ServingEngine
     from ..observability.telemetry import Telemetry
     from .rpc import RpcServer
     from .snapshot import EngineSnapshotManager
 
+    setup_compile_cache()
     params, cfg, engine_kw = build_from_spec(spec)
     # One clock domain fleet-wide: the supervisor stitches worker spans
     # with its own, so both must stamp wall-clock time.time.

@@ -1,0 +1,185 @@
+"""Size chip_smoke.py's executables for a chip that is described, not attached.
+
+On-chip-measurement guide §2, rehearsal 3, for WHOLE programs: compile the
+engine's four paged executables (dense prefill, prefill chunk, the decode
+horizon, speculative verify), the donated train step and the TP=4 decode
+horizon at ``llama_config_7b()`` widths for ``v5e:2x2`` from shapes alone
+(``jax.eval_shape``; nothing runs, no array exists), and print what
+``memory_analysis()`` says each needs on a device.  This is how the depth
+cuts in ``chip_smoke.SIZES`` were chosen — the largest depth whose programs
+stay inside the chip's 15.75 GiB with margin — and how to choose them again
+when an executable changes.  The compiler refuses here what it would refuse
+on the chip (a kernel it cannot lower, a program that does not fit), at no
+chip time.  A compile that passes is not a chip run.
+
+    JAX_PLATFORMS=cpu python perf/chip_fit.py                 # the SIZES table
+    JAX_PLATFORMS=cpu python perf/chip_fit.py serve:14 train:3 tp:8 one:4:float32:highest
+
+Arguments are ``phase:layers[:dtype[:matmul_precision]]`` overrides (phases:
+serve, train, and tp / one — the --multichip engines on four devices / one).
+"""
+from __future__ import annotations
+
+import os
+import sys
+import time
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                ".."))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec,  # noqa: E402
+                          SingleDeviceSharding)
+
+import chip_smoke  # noqa: E402
+
+KIND = "TPU v5 lite"
+GIB = float(1 << 30)
+
+
+def report(name, compiled, t0):
+    m = compiled.memory_analysis()
+    text = compiled.as_text()
+    need = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    print(f"{name}: compile {time.time() - t0:.1f}s  "
+          f"args {m.argument_size_in_bytes / GIB:.2f}  "
+          f"temp {m.temp_size_in_bytes / GIB:.2f}  "
+          f"out-alias {(m.output_size_in_bytes - m.alias_size_in_bytes) / GIB:.2f}  "
+          f"=> {need / GIB:.2f} GiB/device  "
+          f"tpu_custom_call x{text.count('tpu_custom_call')}  "
+          f"all-reduce {'all-reduce' in text}", flush=True)
+
+
+def placed_on(sharding):
+    """tree of arrays/shapes -> the same shapes placed on ``sharding``."""
+    return lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def paged_programs(cfg, sizes, place_params, place_pages, rep, mesh=None,
+                   dtype="bfloat16"):
+    """Lower the engine's executables the way ServingEngine jits them."""
+    from paddle_tpu.models.llama import (build_functional_llama,
+                                         build_llama_paged_decode,
+                                         make_paged_decode_horizon)
+    S, P = sizes["num_slots"], sizes["max_pages_per_seq"]
+    params = place_params(jax.eval_shape(
+        lambda: build_functional_llama(cfg, dtype=dtype)[:3]))
+    init_pages, prefill, chunk, decode_step, verify = \
+        build_llama_paged_decode(cfg, page_size=sizes["page_size"],
+                                 num_pages=S * P, dtype=dtype,
+                                 attention_impl="pallas", mesh=mesh)
+    pages = place_pages(jax.eval_shape(init_pages))
+    pk, pv = pages["k"], pages["v"]
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=rep)
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=rep)
+    flag = lambda *s: jax.ShapeDtypeStruct(s, jnp.bool_, sharding=rep)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)
+    horizon = make_paged_decode_horizon(decode_step)
+    K = sizes["decode_horizon"]
+    bucket = max(t for t in sizes["prompt_lens"]
+                 if t <= sizes["prefill_chunk"])
+    bucket = -(-bucket // sizes["prompt_bucket"]) * sizes["prompt_bucket"]
+    return {
+        f"decode horizon K={K}": (
+            jax.jit(lambda *a: horizon(*a, K=K, greedy=True),
+                    donate_argnums=(4, 5)),
+            (params, i32(S), i32(S), i32(S, P), pk, pv, flag(S), key, f32(S),
+             f32(S), i32(S), i32(S), flag(S))),
+        f"prefill chunk C={sizes['prefill_chunk']}": (
+            jax.jit(chunk, donate_argnums=(5, 6)),
+            (params, i32(1, sizes["prefill_chunk"]), i32(), i32(), i32(P),
+             pk, pv)),
+        f"dense prefill T={bucket}": (
+            jax.jit(prefill, donate_argnums=(4, 5)),
+            (params, i32(1, bucket), i32(), i32(P), pk, pv)),
+        f"verify Q={chip_smoke.VERIFY_Q}": (
+            jax.jit(verify, donate_argnums=(4, 5)),
+            (params, i32(S, chip_smoke.VERIFY_Q), i32(S), i32(S, P), pk, pv,
+             i32(S))),
+    }
+
+
+def fit_one_chip(topo, layers, dtype, phase="serve"):
+    sizes = chip_smoke.SIZES[KIND]["serve" if phase == "serve"
+                                  else "multichip"]
+    one = SingleDeviceSharding(topo.devices[0])
+    place = placed_on(one)
+    cfg = chip_smoke.cut_config(layers)
+    for name, (fn, args) in paged_programs(cfg, sizes, place, place, one,
+                                           dtype=dtype).items():
+        t0 = time.time()
+        report(f"{phase} {dtype} L={layers} {name}",
+               fn.lower(*args).compile(), t0)
+
+
+def fit_tp(topo, layers, dtype):
+    from paddle_tpu.models.llama import (llama_paged_page_spec,
+                                         llama_paged_param_specs)
+    sizes = chip_smoke.SIZES[KIND]["multichip"]
+    mesh = Mesh(topo.devices, ("mp",))
+    ns = lambda spec: NamedSharding(mesh, spec)
+
+    def place_params(tree):
+        return jax.tree_util.tree_map(
+            lambda spec, a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                 sharding=ns(spec)),
+            llama_paged_param_specs("mp"), tree,
+            is_leaf=lambda s: isinstance(s, PartitionSpec))
+
+    cfg = chip_smoke.cut_config(layers)
+    for name, (fn, args) in paged_programs(cfg, sizes, place_params,
+                                           placed_on(ns(
+                                               llama_paged_page_spec("mp"))),
+                                           ns(PartitionSpec()),
+                                           mesh=mesh, dtype=dtype).items():
+        t0 = time.time()
+        report(f"tp=4 {dtype} L={layers} {name}",
+               fn.lower(*args).compile(), t0)
+
+
+def fit_train(topo, layers, dtype):
+    from paddle_tpu.ops.pallas import register_all
+    register_all(force=True)       # what a TPU process registers by itself
+    chip_smoke.train_kernels()
+    sizes = chip_smoke.SIZES[KIND]["train"]
+    one = SingleDeviceSharding(topo.devices[0])
+    init_state, step = chip_smoke.build_train_step(
+        chip_smoke.cut_config(layers))
+    state = placed_on(one)(jax.eval_shape(init_state))
+    ids = jax.ShapeDtypeStruct((sizes["batch"], sizes["seq"]), jnp.int32,
+                               sharding=one)
+    t0 = time.time()
+    compiled = jax.jit(step, donate_argnums=tuple(range(6))) \
+        .lower(*state, (ids, ids)).compile()
+    report(f"train L={layers} B={sizes['batch']} S={sizes['seq']}",
+           compiled, t0)
+
+
+def main(argv):
+    from jax.experimental import topologies
+    jax.config.update("jax_enable_compilation_cache", False)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    sizes = chip_smoke.SIZES[KIND]
+    todo = argv or [f"serve:{sizes['serve']['layers']}",
+                    f"train:{sizes['train']['layers']}"] + [
+        f"{phase}:{arm['layers']}:{arm['dtype']}:"
+        f"{arm.get('matmul_precision') or ''}"
+        for arm in sizes["multichip"]["arms"] for phase in ("tp", "one")]
+    fits = {"serve": fit_one_chip, "train": fit_train, "tp": fit_tp,
+            "one": lambda *a: fit_one_chip(*a, phase="one")}
+    for item in todo:
+        phase, layers, dtype, precision = (item.split(":") + ["", ""])[:4]
+        with jax.default_matmul_precision(precision or None):
+            if precision:
+                print(f"# matmul precision {precision}:")
+            fits[phase](topo, int(layers), dtype or "bfloat16")
+
+
+if __name__ == "__main__":
+    main(sys.argv[1:])

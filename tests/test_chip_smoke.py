@@ -1,0 +1,178 @@
+"""CPU rehearsal of chip_smoke.py (on-chip-measurement guide §2, rehearsals
+1 and 2): the script's phases, called as the plain functions they are, at a
+tiny size on the forced-host CPU mesh with the Pallas kernels in interpret
+mode — the four-chip phase on four of the virtual devices.  Finds wrong
+paths, arguments and control flow before a chip call does, and stays as the
+guard.  ALL steering lives here: chip_smoke.main() has no CPU branch, and
+run plainly with no TPU it exits non-zero before any phase.
+"""
+import dataclasses
+import json
+
+import numpy as np
+import pytest
+import jax
+
+import chip_smoke
+from paddle_tpu.models.llama import LlamaConfig
+
+SERVE = dict(num_slots=3, page_size=8, max_pages_per_seq=8, prefill_chunk=16,
+             prompt_bucket=8, decode_horizon=4, prompt_lens=(5, 20, 12, 37),
+             max_new_tokens=6, compare_tokens=3)
+
+
+def _cfg(heads=4, kv_heads=2, layers=2, hidden=64):
+    return LlamaConfig(vocab_size=96, hidden_size=hidden,
+                       intermediate_size=128,
+                       num_hidden_layers=layers, num_attention_heads=heads,
+                       num_key_value_heads=kv_heads,
+                       max_position_embeddings=128)
+
+
+def test_main_without_a_tpu_exits_nonzero_before_any_phase(capsys):
+    assert jax.devices()[0].platform == "cpu"
+    assert chip_smoke.main([]) != 0
+    assert chip_smoke.main(["--multichip"]) != 0
+    out = capsys.readouterr()
+    assert out.out == ""                      # no phase line, no result line
+    assert "no TPU" in out.err
+
+
+def test_sizes_are_7b_widths_cut_by_depth_only():
+    from paddle_tpu.models.llama import llama_config_7b
+    full = llama_config_7b()
+    for kind, sizes in chip_smoke.SIZES.items():
+        depths = [sizes["serve"]["layers"], sizes["train"]["layers"]] \
+            + [arm["layers"] for arm in sizes["multichip"]["arms"]]
+        for layers in depths:
+            cut = chip_smoke.cut_config(layers)
+            assert dataclasses.replace(
+                cut, num_hidden_layers=full.num_hidden_layers) == full, kind
+        # the f32 arm is where the repo's TP contract (tokens equal) binds
+        assert [(a["dtype"], a.get("matmul_precision"), a["min_agreement"])
+                for a in sizes["multichip"]["arms"]][-1] == \
+            ("float32", "highest", 1.0)
+        assert sizes["train"]["seq"] == 2048
+        assert sizes["serve"]["page_size"] * \
+            sizes["serve"]["max_pages_per_seq"] >= max(
+                sizes["serve"]["prompt_lens"]) \
+            + sizes["serve"]["max_new_tokens"]
+
+
+def test_kernel_parity_phase_interpret():
+    facts = chip_smoke.kernel_parity_phase(_cfg(), SERVE, interpret=True)
+    assert set(facts["cases"]) == {"decode", "chunk", "verify"}
+    assert facts["cases"]["chunk"]["q"] == [1, SERVE["prefill_chunk"], 4, 16]
+    json.dumps(facts)                          # every fact is printable
+
+
+def test_serve_phase_interpret():
+    lines = []
+    facts = chip_smoke.serve_phase(
+        _cfg(), SERVE, attention_impl="pallas", interpret=True,
+        report=lambda phase, **f: lines.append(phase))
+    assert lines == ["serve", "serve_vs_ref"]  # facts first, verdict after
+    ran = facts["executables"]
+    assert ran["prefill"] and ran["prefill_chunk"] and ran["decode_step"]
+    assert facts["tokens_generated"] == \
+        len(SERVE["prompt_lens"]) * SERVE["max_new_tokens"]
+    assert facts["depth"] == 2 and facts["tp_degree"] == 1
+    # interpret mode lowers the kernel to plain HLO: main() fails the run on
+    # exactly this fact when it is False on the chip
+    assert facts["decode_has_tpu_custom_call"] is False
+    assert facts["vs_ref_engine"]["agreement"] >= \
+        chip_smoke.KERNEL_VS_REF_FLOOR
+    json.dumps(facts)
+
+
+def test_train_phase_with_interpret_kernels(monkeypatch):
+    from paddle_tpu.core import dispatch
+    from paddle_tpu.ops.pallas import fused
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+    def _fa_causal(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=True)
+
+    def _rms(x, w, epsilon=1e-6):
+        return fused.rms_norm(x, w, eps=epsilon, interpret=True)
+
+    # what register_all() does on a TPU, with the kernels interpreted
+    for name, fn in (("flash_attention_causal", _fa_causal),
+                     ("rms_norm", _rms)):
+        fn.__module__ = "paddle_tpu.ops.pallas.interpreted_for_rehearsal"
+        monkeypatch.setitem(dispatch._KERNELS, name,
+                            {**dispatch._KERNELS.get(name, {}), "pallas": fn})
+    kernels = chip_smoke.train_kernels()
+    assert set(kernels) == {"flash_attention_causal", "rms_norm"}
+    # hidden 128, seq 128: the smallest shapes both kernels tile
+    facts = chip_smoke.train_phase(
+        _cfg(hidden=128), dict(batch=1, seq=128, steps=3))
+    assert len(facts["losses"]) == 3
+    assert facts["losses"][-1] < facts["losses"][0]
+    assert facts["tpu_custom_calls"] == 0      # interpreted here
+    json.dumps(facts)
+
+
+def test_train_kernels_refuses_the_jnp_defaults():
+    # on the CPU the registry holds no Pallas override: the check that
+    # guards the chip run must say so, not pass
+    with pytest.raises(RuntimeError, match="Pallas"):
+        chip_smoke.train_kernels()
+
+
+def test_multichip_phase_on_four_virtual_devices():
+    sizes = dict(SERVE, tp=4, prompt_lens=(5, 20, 12), max_new_tokens=5,
+                 arms=(dict(dtype="bfloat16", layers=2, min_agreement=0.25),
+                       dict(dtype="float32", layers=1,
+                            matmul_precision="highest", min_agreement=1.0)))
+    lines = []
+    arms = chip_smoke.multichip_phase(
+        lambda layers: _cfg(heads=4, kv_heads=4, layers=layers), sizes,
+        jax.devices()[:4], attention_impl="pallas", interpret=True,
+        report=lambda phase, **f: lines.append(phase))
+    assert lines == [f"multichip/{d}/{part}"
+                     for d in ("bfloat16", "float32")
+                     for part in ("tp_engine", "one_chip_engine",
+                                  "tp_vs_one_chip")]
+    for dtype, layers in (("bfloat16", 2), ("float32", 1)):
+        tp = arms[dtype]["tp_engine"]
+        assert tp["tp_degree"] == 4 and tp["decode_has_all_reduce"]
+        assert tp["dtype"] == dtype and tp["depth"] == layers
+        assert tp["matmul_precision"] == \
+            ("highest" if dtype == "float32" else "default")
+        for name, axis in (("pages_k", 1), ("wq", 2), ("wgate", 2),
+                           ("wdown", 1)):
+            placed = tp["placed"][name]
+            assert len(placed["devices"]) == 4
+            assert placed["shard"][axis] * 4 == placed["global"][axis]
+        assert arms[dtype]["one_chip_engine"]["tp_degree"] == 1
+    assert arms["float32"]["tp_vs_one_chip"]["tokens_equal"]
+    json.dumps(arms)
+
+
+def test_multichip_phase_fails_an_arm_below_its_floor(monkeypatch):
+    # the verdict binds: an arm whose engines disagree ends the run
+    monkeypatch.setattr(chip_smoke, "token_agreement", lambda a, b: {
+        "tokens_equal": False, "first_break": [0], "agreement": 0.0})
+    sizes = dict(SERVE, tp=2, prompt_lens=(5,), max_new_tokens=2,
+                 arms=(dict(dtype="float32", layers=1, min_agreement=1.0),))
+    with pytest.raises(RuntimeError, match="TP=2 engine vs one-chip"):
+        chip_smoke.multichip_phase(
+            lambda layers: _cfg(heads=4, kv_heads=4, layers=layers), sizes,
+            jax.devices()[:2], attention_impl="ref",
+            report=lambda phase, **f: None)
+
+
+def test_token_agreement_reports_first_break():
+    a = [[1, 2, 3, 4], [5, 6, 7, 8]]
+    b = [[1, 2, 9, 4], [5, 6, 7, 8]]
+    got = chip_smoke.token_agreement(a, b)
+    assert got == {"tokens_equal": False, "first_break": [2, None],
+                   "agreement": 0.75}
+    assert chip_smoke.token_agreement(a, a)["tokens_equal"] is True
+
+
+def test_device_phase_fetches_a_complex_array(tmp_path):
+    facts = chip_smoke.device_phase(str(tmp_path))
+    assert facts["platform"] == "cpu" and len(facts["complex64_fetch"]) == 3
+    assert np.complex64(facts["complex64_fetch"][0]) == np.complex64(-3 + 4j)
