@@ -104,9 +104,22 @@ check per hook site, zero per-token work, outputs bit-identical either
 way.  All timestamps are host clock reads at EXISTING sync boundaries —
 telemetry adds no device round-trips (graftlint SYNC001 stays clean) and
 no jitted code (sanitize(0) variant counts unchanged).
+
+Profiler trace: independent of telemetry, every host phase of `step()` is a
+`jax.profiler.TraceAnnotation` (`ServingEngine._span`): `serve.step` and,
+tiling it, `serve.sched` (with the admission's `serve.prefill_dense` /
+`serve.prefill_chunk` / `serve.first_token_sync` inside), `serve.provision`,
+`serve.{decode,overlap,verify}_{dispatch,sync,record}` and
+`serve.overlap_join_sync`, with `step` / `rid` / `tokens` / `padded` /
+`slots` / `k` as stats.  They cost nothing until somebody opens a profiler
+session and then lie on the device events' clock, so an idle gap of the
+device can be laid under the host phase that caused it
+(benchmark/host_spans.py).  The kernels carry `kernel_metadata` labels and
+the decode horizon's executable is `jit_decode_horizon`, for the same trace.
 """
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import json
 import math
@@ -119,7 +132,7 @@ import numpy as np
 
 from ..analysis.sanitize import (RecompileBudgetError, instrument,
                                  jit_cache_size)
-from ..observability.telemetry import Telemetry
+from ..observability.telemetry import ENGINE_PHASES, Telemetry
 from ..resilience.faults import InjectedFault, fault_point
 
 __all__ = ["PagePool", "PrefixCache", "Request", "ServingEngine",
@@ -1019,7 +1032,18 @@ class ServingEngine:
         self.rejections = 0            # AdmissionRejected count
         self.cache_hits = 0            # admissions that attached a prefix
         self.cache_hit_tokens = 0      # prefill tokens skipped via the cache
-        self.prefill_tokens = 0        # prefill tokens actually executed
+        self.prefill_tokens = 0        # un-cached prompt tokens of the
+                                       #   requests ADMITTED (counted at
+                                       #   admission; their chunks may
+                                       #   still be to run)
+        self.prefill_tokens_dispatched = 0  # prompt tokens handed to a
+                                       #   prefill executable, counted at
+                                       #   each dense / chunk call ...
+        self.prefill_tokens_padded = 0  # ... and the padded rows that call
+                                       #   computed (T_bucket / C_bucket)
+        self.decode_kv_tokens_attended = 0  # KV positions the horizon's
+                                       #   live decode steps had to read
+                                       #   (host ints at the drain)
         self.cache_evictions = 0       # cached pages evicted under pressure
         self.cow_copies = 0            # copy-on-write page copies
         self.verify_steps = 0          # speculative verify dispatches
@@ -1210,6 +1234,48 @@ class ServingEngine:
         return live or self._finished.pop(rid, None) is not None
 
     # -- internals ---------------------------------------------------------
+    @contextlib.contextmanager
+    def _span(self, name, **attrs):
+        """One host phase of the step loop.  ALWAYS a
+        ``jax.profiler.TraceAnnotation("serve.<name>", **attrs)``: with a
+        profiler session open the span is in the trace's host plane, on
+        this thread's line, on the clock the device events are on, nested
+        by containment (`serve.step` > `serve.sched` > `serve.prefill_dense`
+        ...); with none open entering and leaving it costs about two
+        microseconds and records nothing.  There is no switch: tracing is
+        on when somebody is tracing.  With telemetry attached, a phase of
+        `ENGINE_PHASES` also feeds its histogram and the tracer's engine
+        track (an exception in the body skips that, as it always did).
+        Yields a dict: what the body puts in it becomes further stats of
+        the annotation (a record span's ``tokens``)."""
+        tel = self.telemetry
+        late = {}
+        with self._jax.profiler.TraceAnnotation("serve." + name,
+                                                **attrs) as ann:
+            if tel is None or name not in ENGINE_PHASES:
+                yield late
+            else:
+                t0 = tel.sched_begin() if name == "sched" else tel.clock()
+                yield late
+                if name == "sched":
+                    tel.sched_done(t0, tel.clock())
+                elif name in ("prefill_dense", "prefill_chunk"):
+                    tel.prefill_dispatch(attrs["rid"], pos=attrs["pos"],
+                                         tokens=attrs["tokens"], t0=t0,
+                                         kind=name)
+                elif name == "overlap_join_sync":
+                    tel.join_wait(t0, tel.clock())
+                else:
+                    tel.phase(name, t0, tel.clock(), **attrs)
+            if late:
+                ann.set_metadata(**late)
+
+    def _fetch_first_token(self, slot, tok) -> int:
+        """The one host fetch of a prefill's fused first token (the wait
+        is the prefill's device time)."""
+        with self._span("first_token_sync", rid=slot.req.rid):
+            return int(np.asarray(tok))  # graftlint: disable=SYNC001
+
     def _jit(self, name, fn, **jit_kw):
         """jax.jit + recompile instrumentation: every compile-cache miss of
         the returned callable lands in `self.jit_cache_misses[name]` and is
@@ -1566,11 +1632,11 @@ class ServingEngine:
             first_admit = req.admit_time == 0.0
             if first_admit:
                 req.admit_time = admit_now
-            tel = self.telemetry
-            if tel is not None:
-                tel.admitted(req, slot=s, t=admit_now, resuming=resuming,
-                             first=first_admit, cached_tokens=matched,
-                             prefill_tokens=T - matched)
+            if self.telemetry is not None:
+                self.telemetry.admitted(
+                    req, slot=s, t=admit_now, resuming=resuming,
+                    first=first_admit, cached_tokens=matched,
+                    prefill_tokens=T - matched)
             chunked = self.prefill_chunk is not None \
                 and (T - matched) > self.prefill_chunk
             if matched == 0 and not chunked:
@@ -1590,6 +1656,11 @@ class ServingEngine:
                 pf = self._prefill_jit.get((Tb, greedy))
                 if pf is None:
                     fn = self._prefill_fn
+                    # stays a lambda (module `jit__lambda`): the benchmark's
+                    # model.prefill_dev_tok_s matches that name and, now
+                    # that the horizon is `jit_decode_horizon`, reads the
+                    # dense prefill alone by it.  Rename together with the
+                    # matcher in a `benchmark` PR (PERF.md section 7).
                     pf = self._jit(
                         "prefill",
                         (lambda *a: fn(*a, greedy=True)) if greedy
@@ -1599,18 +1670,23 @@ class ServingEngine:
                     # bucket ladder  # graftlint: disable=LEAK001
                     self._prefill_jit[(Tb, greedy)] = pf
                 self._join_dispatch()   # prefill chains on concrete pages
-                if tel is not None:
-                    t_pf0 = tel.clock()
-                    ann = tel.bridge_begin("prefill_dense")
+                self.prefill_tokens_dispatched += T
+                self.prefill_tokens_padded += Tb
                 try:
-                    tok, self._pages_k, self._pages_v = self._call_paged(
-                        pf,
-                        self.params, jnp.asarray(ids),
-                        jnp.asarray(T, jnp.int32),
-                        jnp.asarray(row), self._pages_k, self._pages_v,
-                        self._split_key(),
-                        jnp.asarray(req.temperature, jnp.float32),
-                        jnp.asarray(req.top_p, jnp.float32))
+                    # the span closes BEFORE the bookkeeping below samples
+                    # the first token, so the request record keeps ladder
+                    # order: admitted -> prefill_dense -> first_token
+                    with self._span("prefill_dense", rid=req.rid, pos=0,
+                                    tokens=T, padded=Tb):
+                        tok, self._pages_k, self._pages_v = \
+                            self._call_paged(
+                                pf,
+                                self.params, jnp.asarray(ids),
+                                jnp.asarray(T, jnp.int32),
+                                jnp.asarray(row), self._pages_k,
+                                self._pages_v, self._split_key(),
+                                jnp.asarray(req.temperature, jnp.float32),
+                                jnp.asarray(req.top_p, jnp.float32))
                 except RecompileBudgetError as e:
                     # the prefill DID run (pages already rebound by
                     # _call_paged) — finish the admission bookkeeping with
@@ -1622,15 +1698,6 @@ class ServingEngine:
                     self._finish_admission(s, e.result[0], ctx, pages,
                                            resuming)
                     raise
-                finally:
-                    if tel is not None:
-                        tel.bridge_end(ann)
-                if tel is not None:
-                    # dispatch span recorded BEFORE the bookkeeping below
-                    # samples the first token, so the request record keeps
-                    # ladder order: admitted -> prefill_dense -> first_token
-                    tel.prefill_dispatch(req.rid, pos=0, tokens=T,
-                                         t0=t_pf0, kind="prefill_dense")
                 self._finish_admission(s, tok, ctx, pages, resuming)
             else:
                 # suffix / chunked prefill: only the un-cached tokens run,
@@ -1663,8 +1730,8 @@ class ServingEngine:
             slot.pending_dev = tok
         else:
             # the ONE per-admission sync: the fused prefill+sample's
-            # first token  # graftlint: disable=SYNC001
-            self._record_token(s, int(np.asarray(tok)))
+            # first token
+            self._record_token(s, self._fetch_first_token(slot, tok))
 
     def _prefill_advance(self, s: int):               # graftlint: hot
         """Run ONE prefill chunk for slot s (suffix prefill after a cache
@@ -1694,11 +1761,10 @@ class ServingEngine:
         Pb = min(self.max_pages_per_seq, math.ceil(ctx_pages / 4) * 4)
         ids = np.zeros((1, Cb), np.int32)
         ids[0, :c] = slot.ctx[pos:pos + c]
-        tel = self.telemetry
-        if tel is not None:
-            t_ck0 = tel.clock()
-            ann = tel.bridge_begin("prefill_chunk")
-        try:
+        self.prefill_tokens_dispatched += c
+        self.prefill_tokens_padded += Cb
+        with self._span("prefill_chunk", rid=req.rid, pos=pos, tokens=c,
+                        padded=Cb):
             logits, tok_g, self._pages_k, self._pages_v = self._call_paged(
                 self._chunk_jit,
                 self.params, jnp.asarray(ids), jnp.asarray(pos, jnp.int32),
@@ -1708,11 +1774,6 @@ class ServingEngine:
                 # host-side table growth (CPU jnp.asarray can alias)
                 jnp.asarray(self._page_tables[s, :Pb].copy()),
                 self._pages_k, self._pages_v)
-        finally:
-            if tel is not None:
-                tel.bridge_end(ann)
-        if tel is not None:
-            tel.prefill_dispatch(req.rid, pos=pos, tokens=c, t0=t_ck0)
         slot.chunk_step = self._step_seq
         pos += c
         slot.prefill_pos = pos
@@ -1739,7 +1800,7 @@ class ServingEngine:
                 slot.pending_dev = tok_g
             else:
                 # the ONE final-chunk sync: the fused first token
-                self._record_token(s, int(np.asarray(tok_g)))  # graftlint: disable=SYNC001
+                self._record_token(s, self._fetch_first_token(slot, tok_g))
         else:
             try:
                 tok = self._sampler(False)(
@@ -1759,7 +1820,7 @@ class ServingEngine:
                 slot.pending_dev = tok
             else:
                 # the ONE final-chunk sync: the sampled first token
-                self._record_token(s, int(np.asarray(tok)))  # graftlint: disable=SYNC001
+                self._record_token(s, self._fetch_first_token(slot, tok))
 
     def _sampler(self, greedy: bool):
         """Jitted single-logits NUCLEUS sampler (the sampled final chunk
@@ -1772,7 +1833,11 @@ class ServingEngine:
         sf = self._sample_jit
         if sf is None:
             fn = self._sample_fn
-            sf = self._jit("sample", lambda *a: fn(*a, greedy=False))
+
+            def sample_logits(*a):     # its module: `jit_sample_logits`
+                return fn(*a, greedy=False)
+
+            sf = self._jit("sample", sample_logits)
             self._sample_jit = sf
         return sf
 
@@ -1796,37 +1861,39 @@ class ServingEngine:
         page about to receive a write is copied first (copy-on-write —
         belt and braces: admission already copies the only shareable
         written page).  Returns the list of runnable slot indices."""
-        per_slot = steps if isinstance(steps, dict) else None
-        run = []
-        for s, slot in enumerate(self._slots):
-            if slot is None or slot.prefill_pos is not None:
-                continue
-            want = per_slot.get(s, 1) if per_slot is not None else steps
-            slot.stalled = False
-            w0 = int(self._lengths[s]) // self.page_size
-            if w0 < len(slot.pages) \
-                    and self.pool.refcount(slot.pages[w0]) > 1:
-                if self._avail() < 1:
-                    self._evict(1)
-                if self._avail() < 1:
-                    slot.stalled = True
+        with self._span("provision"):
+            per_slot = steps if isinstance(steps, dict) else None
+            run = []
+            for s, slot in enumerate(self._slots):
+                if slot is None or slot.prefill_pos is not None:
                     continue
-                self._cow(s, w0)
-            m = min(want, self._remaining(s))
-            need = math.ceil((int(self._lengths[s]) + m) / self.page_size)
-            grow = need - len(slot.pages)
-            if grow > 0:
-                if grow > self._avail():
-                    self._evict(grow - self._avail())
-                if grow > self._avail():
-                    slot.stalled = True
-                    continue
-                pages = self.pool.alloc(grow)
-                start = len(slot.pages)
-                slot.pages.extend(pages)
-                self._page_tables[s, start:start + grow] = pages
-            run.append(s)
-        return run
+                want = per_slot.get(s, 1) if per_slot is not None else steps
+                slot.stalled = False
+                w0 = int(self._lengths[s]) // self.page_size
+                if w0 < len(slot.pages) \
+                        and self.pool.refcount(slot.pages[w0]) > 1:
+                    if self._avail() < 1:
+                        self._evict(1)
+                    if self._avail() < 1:
+                        slot.stalled = True
+                        continue
+                    self._cow(s, w0)
+                m = min(want, self._remaining(s))
+                need = math.ceil((int(self._lengths[s]) + m)
+                                 / self.page_size)
+                grow = need - len(slot.pages)
+                if grow > 0:
+                    if grow > self._avail():
+                        self._evict(grow - self._avail())
+                    if grow > self._avail():
+                        slot.stalled = True
+                        continue
+                    pages = self.pool.alloc(grow)
+                    start = len(slot.pages)
+                    slot.pages.extend(pages)
+                    self._page_tables[s, start:start + grow] = pages
+                run.append(s)
+            return run
 
     # -- speculative decoding ----------------------------------------------
     def _propose_drafts(self) -> dict:
@@ -1873,23 +1940,24 @@ class ServingEngine:
             if d:
                 toks[s, 1:1 + len(d)] = d
             n_q[s] = 1 + len(d)
-        tel = self.telemetry
-        if tel is not None:
-            t_v0 = tel.clock()
-            ann = tel.bridge_begin("verify_dispatch")
-        try:
+        with self._span("verify_dispatch", slots=len(run),
+                        k=self.speculative):
             logits0, gtoks, self._pages_k, self._pages_v = self._call_paged(
                 self._verify_jit,
                 self.params, jnp.asarray(toks), jnp.asarray(self._lengths),
                 jnp.asarray(self._page_tables), self._pages_k,
                 self._pages_v, jnp.asarray(n_q))
-        finally:
-            if tel is not None:
-                tel.bridge_end(ann)
-        t_v1 = tel.clock() if tel is not None else 0.0
-        # the ONE per-verify-dispatch sync: every slot's K+1 argmaxes land
-        # in one transfer (acceptance is host logic by design)
-        gtoks = np.asarray(gtoks)  # graftlint: disable=SYNC001
+        with self._span("verify_sync"):
+            # the ONE per-verify-dispatch sync: every slot's K+1 argmaxes
+            # land in one transfer (acceptance is host logic by design)
+            gtoks = np.asarray(gtoks)  # graftlint: disable=SYNC001
+        with self._span("verify_record") as late:
+            late["tokens"] = self._verify_record(run, drafts, logits0, gtoks)
+
+    def _verify_record(self, run, drafts, logits0, gtoks):  # graftlint: hot
+        """The host half of a verify dispatch: acceptance, emission and
+        the length rewind (see `_verify`).  Returns the tokens emitted."""
+        jnp = self._jnp
         self.steps_run += 1
         self.verify_steps += 1
         if all(self._slots[s].req.temperature <= 0.0 for s in run):
@@ -1897,13 +1965,12 @@ class ServingEngine:
             # argmax row — a token-emitting step; one sampled ride-along
             # lane makes it a logit-path dispatch instead
             self.fused_sample_steps += 1
+        tel = self.telemetry
         if tel is not None:
-            t_v2 = tel.clock()
-            tel.phase("verify_dispatch", t_v0, t_v1, slots=len(run))
-            tel.phase("verify_sync", t_v1, t_v2)
             for s in run:
                 tel.request_event(self._slots[s].req.rid, "verify_dispatch",
                                   drafted=len(drafts.get(s, ())))
+        total = 0
         lens = self._lengths.tolist()    # host mirror -> python ints
         for s in run:
             slot = self._slots[s]
@@ -1950,6 +2017,7 @@ class ServingEngine:
                 n_emitted = i
                 if self._record_token(s, tok):
                     break
+            total += n_emitted
             if nd:
                 # credit only drafts that actually LANDED: an EOS/budget
                 # freeze mid-run discards the tail of an accepted run, and
@@ -1960,16 +2028,21 @@ class ServingEngine:
                 self.draft_tokens_accepted += used
                 req.draft_proposed += nd
                 req.draft_accepted += used
-        if tel is not None:
-            tel.phase("verify_record", t_v2, tel.clock())
+        return total
 
     def _horizon_exec(self, K: int, greedy: bool):
         fn = self._horizon_jit.get((K, greedy))
         if fn is None:
-            fn = self._jit(
-                "decode_step",
-                lambda *a: self._horizon_fn(*a, K=K, greedy=greedy),
-                donate_argnums=(4, 5))
+            horizon = self._horizon_fn
+
+            # a NAMED function: a profiler trace lists the executable as
+            # `jit_decode_horizon(<fingerprint>)` on the device's
+            # "XLA Modules" line, which is how the benchmark finds it
+            def decode_horizon(*a):
+                return horizon(*a, K=K, greedy=greedy)
+
+            fn = self._jit("decode_step", decode_horizon,
+                           donate_argnums=(4, 5))
             # keyed by (K, greedy): bounded by the horizon ladder
             # graftlint: disable=LEAK001
             self._horizon_jit[(K, greedy)] = fn
@@ -2129,11 +2202,6 @@ class ServingEngine:
                 pk, pv, jnp.asarray(active), key, jnp.asarray(temps),
                 jnp.asarray(top_ps), rem_in, jnp.asarray(eos_ids), done_in)
 
-        tel = self.telemetry
-        phase = "overlap_dispatch" if self.overlap else "decode_dispatch"
-        if tel is not None:
-            t_d0 = tel.clock()
-            ann = tel.bridge_begin(phase)
         # carry sources are EXACTLY the dispatched lanes: only they got
         # real inputs merged in (a slot skipped by _provision this step
         # has default-filler rows in this dispatch — toks 0, remaining 1 —
@@ -2142,44 +2210,40 @@ class ServingEngine:
         # which the previous drain left exact
         srcs = {lane.s: lane.slot for lane in lanes}
         rec = _Inflight(K, greedy, lanes, srcs, self.overlap)
-        try:
-            if not self.overlap:
-                res = call(self._pages_k, self._pages_v, *merge(
-                    None if prev is None
-                    else (prev.toks, prev.lengths, prev.rem, prev.done)))
-                rec.out, rec.toks, rec.lengths, rec.rem, rec.done = res[:5]
-                self._pages_k, self._pages_v = res[-2], res[-1]
-            elif prev is not None and prev.fut is not None:
-                # chain INSIDE the worker: the previous dispatch's outputs
-                # (pages + carry) flow worker-to-worker, never through the
-                # main thread
-                pfut = prev.fut
+        if not self.overlap:
+            res = call(self._pages_k, self._pages_v, *merge(
+                None if prev is None
+                else (prev.toks, prev.lengths, prev.rem, prev.done)))
+            rec.out, rec.toks, rec.lengths, rec.rem, rec.done = res[:5]
+            self._pages_k, self._pages_v = res[-2], res[-1]
+        elif prev is not None and prev.fut is not None:
+            # chain INSIDE the worker: the previous dispatch's outputs
+            # (pages + carry) flow worker-to-worker, never through the
+            # main thread
+            pfut = prev.fut
 
-                def work_chained():
-                    pres = pfut.result()
-                    return call(pres[-2], pres[-1], *merge(
-                        (pres[1], pres[2], pres[3], pres[4])))
+            def work_chained():
+                pres = pfut.result()
+                return call(pres[-2], pres[-1], *merge(
+                    (pres[1], pres[2], pres[3], pres[4])))
 
-                rec.fut = self._executor.submit(work_chained)
-            else:
-                # pipeline empty (or already joined by an admission): the
-                # page binding and any carry state are concrete arrays
-                pk0, pv0 = self._pages_k, self._pages_v
-                pstate = None if prev is None \
-                    else (prev.toks, prev.lengths, prev.rem, prev.done)
-                rec.fut = self._executor.submit(
-                    lambda: call(pk0, pv0, *merge(pstate)))
-        finally:
-            if tel is not None:
-                tel.bridge_end(ann)
+            rec.fut = self._executor.submit(work_chained)
+        else:
+            # pipeline empty (or already joined by an admission): the
+            # page binding and any carry state are concrete arrays
+            pk0, pv0 = self._pages_k, self._pages_v
+            pstate = None if prev is None \
+                else (prev.toks, prev.lengths, prev.rem, prev.done)
+            rec.fut = self._executor.submit(
+                lambda: call(pk0, pv0, *merge(pstate)))
         self.steps_run += 1
         # horizon dispatches always emit tokens on-device (fused greedy
         # argmax or in-loop sampling) — logits never leave the device
         self.fused_sample_steps += 1
         if prev is not None:
             self.overlap_steps += 1
+        tel = self.telemetry
         if tel is not None:
-            tel.phase(phase, t_d0, tel.clock(), slots=len(run), k=K)
             for s in run:
                 tel.request_event(self._slots[s].req.rid, "decode_dispatch",
                                   k=K)
@@ -2210,12 +2274,9 @@ class ServingEngine:
         rec = self._inflight
         if rec is None or rec.fut is None:
             return
-        tel = self.telemetry
-        t0 = tel.clock() if tel is not None else 0.0
         try:
-            self._resolve(rec, rebind=True)
-            if tel is not None:
-                tel.join_wait(t0, tel.clock())
+            with self._span("overlap_join_sync"):
+                self._resolve(rec, rebind=True)
         except RecompileBudgetError:
             # the dispatch is discarded (its tokens were never recorded;
             # lengths never advanced — the rewind invariant); the worker
@@ -2235,12 +2296,20 @@ class ServingEngine:
         construction.  `rebind=False` marks a record superseded by a
         newer dispatch (its page outputs were donated onward and must
         not re-bind)."""
-        tel = self.telemetry
-        t0 = tel.clock() if tel is not None else 0.0
-        self._resolve(rec, rebind=rebind)
-        # the ONE per-step sync: every lane's K tokens in one batched fetch
-        out = np.asarray(rec.out)  # graftlint: disable=SYNC001
-        t1 = tel.clock() if tel is not None else 0.0
+        pre = "overlap" if rec.overlapped else "decode"
+        with self._span(f"{pre}_sync"):
+            self._resolve(rec, rebind=rebind)
+            # the ONE per-step sync: every lane's K tokens in one batched
+            # fetch
+            out = np.asarray(rec.out)  # graftlint: disable=SYNC001
+        with self._span(f"{pre}_record") as late:
+            late["tokens"] = self._replay(rec, out)
+
+    def _replay(self, rec, out) -> int:               # graftlint: hot
+        """The host half of `_drain`: record each lane's fetched tokens
+        until its EOS/budget stop, advance the length mirror, retire what
+        finished.  Returns the tokens emitted."""
+        total = attended = 0
         lens = self._lengths.tolist()     # host mirror -> python ints
         for lane in rec.lanes:
             s, slot = lane.s, lane.slot
@@ -2257,6 +2326,7 @@ class ServingEngine:
                 tok0 = int(np.asarray(slot.pending_dev))  # graftlint: disable=SYNC001
                 slot.pending_dev = None
                 done = self._emit_token(slot, tok0)
+                total += 1
             emitted = 0
             if not done:
                 for tok in row:
@@ -2264,6 +2334,10 @@ class ServingEngine:
                     done = self._emit_token(slot, tok)
                     if done:
                         break
+            total += emitted
+            # KV positions this lane's live decode steps had to read: step
+            # j of `emitted` attends base + j (its own fresh row included)
+            attended += emitted * base + emitted * (emitted + 1) // 2
             if done:
                 if lane.retiring:
                     self._finish_detached(slot, base + emitted)
@@ -2275,11 +2349,8 @@ class ServingEngine:
                 # pending one; the device carry holds the same state
                 self._lengths[s] = base + emitted
                 slot.pending = row[emitted - 1]
-        if tel is not None:
-            pre = "overlap" if rec.overlapped else "decode"
-            t2 = tel.clock()
-            tel.phase(f"{pre}_sync", t0, t1)
-            tel.phase(f"{pre}_record", t1, t2)
+        self.decode_kv_tokens_attended += attended
+        return total
 
     # -- the serving loop --------------------------------------------------
     @property
@@ -2319,24 +2390,25 @@ class ServingEngine:
         requeued for re-prefill); under a fully injected pool-pressure
         window it parks and reports no progress.
 
-        With telemetry on, the step's host wall time lands in the
+        The step and each of its host phases is a `_span`: inside an open
+        ``jax.profiler`` trace they are ``serve.step`` and its children
+        ``serve.<phase>`` on this thread's line of the host plane.  With
+        telemetry on, the step's host wall time lands in the
         ``engine.step_host_s`` histogram, a per-step summary lands in the
         flight recorder, and an active injected pool-pressure window
         auto-dumps the recorder (postmortem for fault drills)."""
-        tel = self.telemetry
-        if tel is None:
-            return self._step_impl()
-        t0 = tel.clock()
-        pre_tok = self.tokens_generated
-        progressed = self._step_impl()
-        tel.step_done(self, t0, progressed,
-                      self.tokens_generated - pre_tok)
-        return progressed
+        with self._span("step", step=self._step_seq + 1):
+            tel = self.telemetry
+            if tel is None:
+                return self._step_impl()
+            t0 = tel.clock()
+            pre_tok = self.tokens_generated
+            progressed = self._step_impl()
+            tel.step_done(self, t0, progressed,
+                          self.tokens_generated - pre_tok)
+            return progressed
 
     def _step_impl(self) -> bool:                     # graftlint: hot
-        jnp = self._jnp
-        tel = self.telemetry
-        t_s0 = tel.sched_begin() if tel is not None else 0.0
         self._step_seq += 1
         # serve.wedge: the engine "hangs" — the step returns without doing
         # ANY work (no admissions, no dispatch), the deterministic stand-in
@@ -2344,36 +2416,37 @@ class ServingEngine:
         # consecutive no-progress steps and declares the replica wedged.
         if fault_point("serve.wedge", engine=self.name,
                        step=self._step_seq) is not None:
-            if tel is not None:
-                tel.flight.record("fault", point="serve.wedge",
-                                  step=self._step_seq)
+            if self.telemetry is not None:
+                self.telemetry.flight.record("fault", point="serve.wedge",
+                                             step=self._step_seq)
             return False
         self._pressure = fault_point("serve.pool_pressure",
                                      step=self.steps_run) is not None
         pre_tokens = self.tokens_generated
         pre_finished = len(self._finished)
-        # overlap: hand budget-predicted retiring lanes to the admission
-        # queue before admitting, so a retirement costs zero lane idleness
-        self._detach_predicted()
-        self._retire_overdue()
-        pre_admit_seq = self._admit_seq
-        self._admit()
-        if self.overlap:
-            self._flush_exhausted()
-        # serve.crash phase="sched": die mid-step AFTER admissions mutated
-        # slot/pool state but BEFORE any token was produced this step — the
-        # raising InjectedFault models the process dying; host state is
-        # consistent (a step boundary for page accounting) but every
-        # in-flight request is stranded until a fleet migrates it.
-        fault_point("serve.crash", engine=self.name, step=self._step_seq,
-                    phase="sched")
-        if tel is not None:
-            # host scheduling phase: deadline sweep + admissions — the
-            # host-side cost the host-loop overlap refactor (ROADMAP item
-            # 5) needs on the record.  Admission prefill dispatches run
-            # inside this window but record their own spans; sched_done
-            # subtracts them so the utilization buckets stay disjoint
-            tel.sched_done(t_s0, tel.clock())
+        # host scheduling phase: deadline sweep + admissions.  Admission
+        # prefill dispatches run inside this span as its children and
+        # record their own; telemetry's sched_done subtracts them so the
+        # utilization buckets stay disjoint
+        with self._span("sched", queued=len(self._queue),
+                        active=self.num_active):
+            # overlap: hand budget-predicted retiring lanes to the
+            # admission queue before admitting, so a retirement costs zero
+            # lane idleness
+            self._detach_predicted()
+            self._retire_overdue()
+            pre_admit_seq = self._admit_seq
+            self._admit()
+            if self.overlap:
+                self._flush_exhausted()
+            # serve.crash phase="sched": die mid-step AFTER admissions
+            # mutated slot/pool state but BEFORE any token was produced
+            # this step — the raising InjectedFault models the process
+            # dying; host state is consistent (a step boundary for page
+            # accounting) but every in-flight request is stranded until a
+            # fleet migrates it.
+            fault_point("serve.crash", engine=self.name,
+                        step=self._step_seq, phase="sched")
         # chunked prefill: each mid-prefill slot advances ONE chunk per
         # step, interleaved with the decode horizon below — a long prompt
         # never head-of-line blocks the running decodes or short arrivals.
@@ -2478,7 +2551,9 @@ class ServingEngine:
                 or len(self._finished) > pre_finished
         greedy = all(self._temps[s] <= 0.0 for s in run)
         try:
-            rec = self._dispatch_decode(run, K, greedy)
+            with self._span("overlap_dispatch" if self.overlap
+                            else "decode_dispatch", slots=len(run), k=K):
+                rec = self._dispatch_decode(run, K, greedy)
             prev, self._inflight = self._inflight, rec
             if prev is not None:
                 # drain step N-1's tokens WHILE step N runs: the fetch
@@ -2609,6 +2684,8 @@ class ServingEngine:
     _COUNTER_ATTRS = ("steps_run", "tokens_generated", "preemptions",
                       "timeouts", "rejections", "cache_hits",
                       "cache_hit_tokens", "prefill_tokens",
+                      "prefill_tokens_dispatched", "prefill_tokens_padded",
+                      "decode_kv_tokens_attended",
                       "cache_evictions", "cow_copies", "verify_steps",
                       "draft_tokens_proposed", "draft_tokens_accepted",
                       "overlap_steps", "quiesces", "fused_sample_steps",
@@ -3094,7 +3171,13 @@ class ServingEngine:
             "draft_tokens_proposed": prop,
             "draft_tokens_accepted": acc,
             "draft_accept_rate": round(acc / prop, 4) if prop else 0.0,
+            # un-cached prompt tokens of admitted requests (admission
+            # time) / tokens and padded rows of the prefill calls made
+            # (dispatch time) / KV positions the decode horizon read
             "prefill_tokens_executed": self.prefill_tokens,
+            "prefill_tokens_dispatched": self.prefill_tokens_dispatched,
+            "prefill_tokens_padded": self.prefill_tokens_padded,
+            "decode_kv_tokens_attended": self.decode_kv_tokens_attended,
             "cached_prefix_tokens": self.cache_hit_tokens,
             "cache_hits": self.cache_hits,
             "cache_evictions": self.cache_evictions,

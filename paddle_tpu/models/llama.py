@@ -909,7 +909,7 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
         # verify/dense all see the same rounded values on a bf16 engine
         return new, dequantize_kv(qv, sv).astype(d)
 
-    def _attn(q, kc_l, vc_l, page_tables, q_start, q_len, kv_len):
+    def _attn(q, kc_l, vc_l, page_tables, q_start, q_len, kv_len, role):
         """THE attention dispatch: every paged path (decode, speculative
         verify, chunked prefill) routes its ragged query segments
         ``q [S, Qmax, nh, D]`` through the ONE ragged paged-attention
@@ -917,7 +917,8 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
         makes speculative verify lossless by construction rather than by
         bench assert.  On a quantized store the int8/fp8 pages and their
         per-row scales pass straight through; dequant fuses inside the
-        kernel (and inside the ref's gather) for every path."""
+        kernel (and inside the ref's gather) for every path.  ``role``
+        ("decode" | "chunk" | "verify") is the kernel's trace label."""
         if kv_dtype is not None:
             kq, vq = kc_l["q"], vc_l["q"]
             scale_kw = dict(k_scales=kc_l["s"], v_scales=vc_l["s"])
@@ -927,7 +928,7 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
         if use_kernel:
             return ragged_paged_attention(q, kq, vq, page_tables, q_start,
                                           q_len, kv_len, interpret=interpret,
-                                          **scale_kw)
+                                          role=role, **scale_kw)
         return ragged_paged_attention_ref(q, kq, vq, page_tables, q_start,
                                           q_len, kv_len, **scale_kw)
 
@@ -1024,7 +1025,7 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
             kc_l, _ = _scatter(kc_l, k, page, off)
             vc_l, _ = _scatter(vc_l, v, page, off)
             o = _attn(q[None], kc_l, vc_l, page_tab,
-                      start_r, clen_r, kvlen_r)[0]
+                      start_r, clen_r, kvlen_r, "chunk")[0]
             xc = xc + _gather_heads(o).reshape(C, nh * head_dim) @ lp["wo"]
             h = rms_norm_ref(xc, lp["ln2"], c.rms_norm_eps)
             ff = jax.nn.silu(h @ lp["wgate"]) * (h @ lp["wup"])
@@ -1068,7 +1069,7 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
             vc_l, _ = _scatter(vc_l, v, page, off)
             # decode is the q_len = 1 segment of the unified ragged kernel
             o = _attn(q[:, None], kc_l, vc_l, page_tables,
-                      pos, n_q, eff_len)[:, 0]
+                      pos, n_q, eff_len, "decode")[:, 0]
             xc = xc + _gather_heads(o).reshape(S, nh * head_dim) @ lp["wo"]
             h = rms_norm_ref(xc, lp["ln2"], c.rms_norm_eps)
             ff = jax.nn.silu(h @ lp["wgate"]) * (h @ lp["wup"])
@@ -1130,7 +1131,8 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
             kc_l, _ = _scatter(kc_l, k, page, off)
             vc_l, _ = _scatter(vc_l, v, page, off)
             o = _gather_heads(
-                _attn(q, kc_l, vc_l, page_tables, lengths, n_q, kv_len)) \
+                _attn(q, kc_l, vc_l, page_tables, lengths, n_q, kv_len,
+                      "verify")) \
                 .reshape(S, Q, nh * head_dim)
             xc = xc + o @ lp["wo"]
             h = rms_norm_ref(xc, lp["ln2"], c.rms_norm_eps)

@@ -8,8 +8,10 @@ Three pieces, one clock:
     :class:`EngineStats` (flattened ``ServingEngine.stats()`` snapshots
     with exact per-window ``delta()``).
   * :mod:`.tracing` — per-request ordered lifecycle event records +
-    engine phase spans, exportable as Chrome-trace/Perfetto JSON and
-    bridgeable into jax device traces via ``paddle_tpu.profiler``.
+    engine phase spans, exportable as Chrome-trace/Perfetto JSON.  The
+    same phases are ``serve.<phase>`` annotations in any open
+    ``jax.profiler`` trace, telemetry attached or not
+    (``ServingEngine._span``).
   * :mod:`.flight` — a bounded ring of recent engine events that dumps
     automatically on stalls, recompile-budget failures, preemption
     storms, and injected faults.

@@ -9,6 +9,15 @@ existing host-sync boundaries only (no new device round-trips; graftlint
 SYNC001 stays clean and the jit variant counts are untouched — telemetry
 is pure host code).
 
+The engine's step phases reach this object through ONE place,
+``ServingEngine._span``: every phase is first of all a
+``jax.profiler.TraceAnnotation("serve.<phase>")`` — in any open profiler
+trace, on the profiler's clock, whether or not a ``Telemetry`` exists —
+and, with one attached, also the ``engine.phase.<phase>_s`` histogram and
+the tracer's engine track below (this object's clock is
+``time.perf_counter``: its spans cannot be laid over device events, the
+profiler's can).
+
 Metric catalog (README §Observability):
 
   histograms (seconds): ``serve.ttft_s``, ``serve.tpot_s``,
@@ -51,7 +60,7 @@ from .attribution import TailRecorder, attribution_report
 from .flight import FlightRecorder
 from .metrics import MetricsRegistry
 from .slo import slo_report
-from .tracing import NULL_CONTEXT, Tracer
+from .tracing import Tracer
 
 __all__ = ["Telemetry", "ENGINE_PHASES"]
 
@@ -72,19 +81,17 @@ class Telemetry:
 
     ``clock`` is injectable for deterministic tests and is shared by the
     registry, tracer, and flight recorder, so one fake clock drives every
-    timestamp.  ``profiler_bridge=True`` additionally wraps engine
-    dispatch phases in ``paddle_tpu.profiler`` annotations."""
+    timestamp."""
 
     def __init__(self, clock=time.perf_counter, flight_capacity: int = 256,
                  flight_dump_path: str | None = None,
                  storm_threshold: int = 4, storm_window: int = 32,
-                 profiler_bridge: bool = False, max_completed: int = 4096,
+                 max_completed: int = 4096,
                  mem_series_capacity: int = 4096, mem_ramp_events: int = 64,
                  sentinel=None, tail_k: int = 8):
         self.clock = clock
         self.registry = MetricsRegistry(clock=clock)
-        self.tracer = Tracer(clock=clock, bridge=profiler_bridge,
-                             max_completed=max_completed)
+        self.tracer = Tracer(clock=clock, max_completed=max_completed)
         self.flight = FlightRecorder(capacity=flight_capacity, clock=clock,
                                      dump_path=flight_dump_path)
         self.storm_threshold = int(storm_threshold)
@@ -249,24 +256,6 @@ class Telemetry:
         host time (the buckets must remain disjoint)."""
         self._nested_dispatch_s += t1 - t0
         self.phase("overlap_join_sync", t0, t1)
-
-    def bridge_begin(self, name: str):
-        """Enter a ``paddle_tpu.profiler.host_annotation`` span (bridge on
-        only) around a dispatch the caller times manually; returns the
-        entered context (pass it to :meth:`bridge_end`) or None when the
-        bridge is off.  The engine brackets its dispatch calls with these
-        so host phases land INSIDE any active jax device trace, next to
-        the XLA ops they launched."""
-        ann = self.tracer.annotation(f"serve.{name}")
-        if ann is NULL_CONTEXT:
-            return None
-        ann.__enter__()
-        return ann
-
-    @staticmethod
-    def bridge_end(ann):
-        if ann is not None:
-            ann.__exit__(None, None, None)
 
     def request_event(self, rid: int, name: str, t: float | None = None,
                       **attrs):

@@ -1,7 +1,7 @@
 """Request-lifecycle tracing: ordered per-request event records, engine
-phase spans, Chrome-trace/Perfetto export, and a bridge into
-``paddle_tpu.profiler`` so host spans land in the same timeline as jax
-device traces.
+phase spans and their Chrome-trace/Perfetto export.  (The engine's phases
+are ALSO ``serve.<phase>`` annotations in any open ``jax.profiler`` trace,
+emitted by ``ServingEngine._span`` itself — that needs no ``Tracer``.)
 
 Every request carries an ordered event record stamped with HOST timestamps
 taken only at existing host-sync boundaries (the engine never adds a device
@@ -25,24 +25,7 @@ import json
 import time
 from collections import deque
 
-__all__ = ["RequestTrace", "Tracer", "NULL_CONTEXT", "tracer_to_wire",
-           "tracer_from_wire"]
-
-
-class _NullContext:
-    """Reusable no-op context (module singleton — telemetry-off code paths
-    pay one flag check, not an allocation)."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-NULL_CONTEXT = _NullContext()
+__all__ = ["RequestTrace", "Tracer", "tracer_to_wire", "tracer_from_wire"]
 
 # lifecycle events that OPEN a phase span (value: the span name)
 _PHASE_OPEN = {"submitted": "queued", "admitted": "prefill",
@@ -78,11 +61,10 @@ class Tracer:
     host timings) land in their own bounded ring and export on a dedicated
     ``engine`` track."""
 
-    def __init__(self, clock=time.perf_counter, bridge: bool = False,
+    def __init__(self, clock=time.perf_counter,
                  max_completed: int = 1024, max_engine_events: int = 8192,
                  max_counter_events: int = 8192):
         self.clock = clock
-        self.bridge = bool(bridge)
         self._live: dict[int, RequestTrace] = {}
         self._done: deque[RequestTrace] = deque(maxlen=max_completed)
         # (name, t0, t1 | None for instants, attrs)
@@ -119,17 +101,6 @@ class Tracer:
 
     def counter_events(self) -> list[tuple]:
         return list(self._counters)
-
-    def annotation(self, name: str):
-        """Context manager for the profiler bridge: when ``bridge`` is on,
-        wraps the scope in ``paddle_tpu.profiler.host_annotation`` (a
-        ``jax.profiler.TraceAnnotation``), so the host span shows up inside
-        any active jax device trace next to the XLA ops it dispatched.
-        Off-bridge: a shared no-op."""
-        if not self.bridge:
-            return NULL_CONTEXT
-        from ..profiler import host_annotation
-        return host_annotation(name)
 
     # -- introspection -----------------------------------------------------
     def get(self, rid: int) -> RequestTrace | None:

@@ -152,6 +152,7 @@ def _pack_lse(lse3, interpret=False):
         out_specs=pl.BlockSpec((1, chunk // 128, 128), lambda b, i: (b, i, 0)),
         out_shape=_sds((bh, s // 128, 128), lse3.dtype, _vma_of(lse3)),
         interpret=interpret,
+        metadata={"kernel": "flash_attention", "pass": "lse_pack"},
     )(lse3)
     return out.reshape(bh, s)
 
@@ -240,6 +241,7 @@ def flash_attention_fwd_kernel_call(q, k, v, causal, sm_scale, interpret=False,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        metadata={"kernel": "flash_attention", "pass": "fwd"},
     )(*args)
     # COMPACT 2-D lse for the caller: the [bh, s, 1] kernel output tile-pads
     # its last dim 1 -> 128 in HBM (measured 128x, 256 MB per ViT layer);
@@ -490,6 +492,7 @@ def _bwd_call(res, g, causal, sm_scale, interpret, n_q_heads=None,
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,
+        metadata={"kernel": "flash_attention", "pass": "dkv"},
     )(*dkv_args)
     dk, dv = dkv
 
@@ -531,6 +534,7 @@ def _bwd_call(res, g, causal, sm_scale, interpret, n_q_heads=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        metadata={"kernel": "flash_attention", "pass": "dq"},
     )(*dq_args)
     return dq, dk, dv
 

@@ -120,6 +120,7 @@ def _make_rms(eps: float, interpret: bool):
             out_shape=[jax.ShapeDtypeStruct((n, h), x.dtype),
                        jax.ShapeDtypeStruct((n, 1), jnp.float32)],
             interpret=interpret,
+            metadata={"kernel": "rms_norm", "pass": "fwd"},
         )(x, w.reshape(1, h))
         return o, (x, w, inv)
 
@@ -142,6 +143,7 @@ def _make_rms(eps: float, interpret: bool):
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
+            metadata={"kernel": "rms_norm", "pass": "bwd"},
         )(x, w.reshape(1, h), inv, g)
         return dx, dw.reshape(w.shape).astype(w.dtype)
 

@@ -194,7 +194,8 @@ def _ragged_kernel_quant(pt_ref, qs_ref, ql_ref, kl_ref, q_ref, k_ref,
 
 def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start, q_len,
                            kv_len, sm_scale=None, interpret=False,
-                           out_dtype=None, k_scales=None, v_scales=None):
+                           out_dtype=None, k_scales=None, v_scales=None,
+                           *, role=None):
     """Ragged-segment paged attention over each slot's page list.
 
     q [S, Qmax, Hq, D], k_pages/v_pages [Hkv, NP, ps, D], page_table
@@ -215,6 +216,12 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start, q_len,
     before its online-softmax update; the f32 K/V never exist outside the
     kernel).  The scale pages ride the same page-table indirection as the
     data pages.
+
+    role: a LABEL for profiler traces ("decode" | "chunk" | "verify", from
+    the model fn that builds the call) — it changes no code path.  The
+    call's HLO text, which is the name of its event on a device trace's
+    "XLA Ops" line, carries ``kernel_metadata={"kernel":
+    "ragged_paged_attention","role":...}``.
 
     Head-sharded (TP) dispatch: every shape here may be the mp-LOCAL
     shard — Hq = nh/tp query heads against Hkv = nkv/tp KV-head pages.
@@ -298,6 +305,8 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start, q_len,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
+        metadata={"kernel": "ragged_paged_attention",
+                  **({"role": role} if role else {})},
     )(page_table.astype(jnp.int32), q_start.astype(jnp.int32),
       q_len.astype(jnp.int32), kv_len.astype(jnp.int32), *inputs)
     return out[:, :, :rows].reshape(s_slots, hkv, qmax, rep, d) \

@@ -8,7 +8,6 @@ The benchmark `Timer` reproduces timer.py's ips accounting (used by bench.py).
 """
 from __future__ import annotations
 
-import contextlib
 import time
 from enum import Enum
 from typing import Callable, Iterable, Optional
@@ -17,19 +16,7 @@ import jax
 
 __all__ = ["Profiler", "ProfilerTarget", "ProfilerState", "RecordEvent",
            "make_scheduler", "export_chrome_tracing", "load_profiler_result",
-           "benchmark", "Timer", "SummaryView", "host_annotation"]
-
-
-def host_annotation(name: str):
-    """Context manager that lands a host span in the jax device timeline
-    (``jax.profiler.TraceAnnotation``) when the backing jax build supports
-    it, else a no-op — the bridge ``paddle_tpu.observability`` uses so
-    engine-step phase spans appear NEXT TO the XLA ops they dispatched in
-    one Perfetto view.  Safe to enter with no device trace active."""
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return contextlib.nullcontext()
+           "benchmark", "Timer", "SummaryView"]
 
 
 class ProfilerTarget(Enum):

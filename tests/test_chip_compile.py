@@ -18,7 +18,9 @@ one worker owns the library.  Nothing runs on a device: a compile that
 passes is not a chip run.
 """
 import dataclasses
+import functools
 import os
+import re
 
 import pytest
 import jax
@@ -54,7 +56,9 @@ def one_chip(topo):
 
 
 def _compiles_with_kernel(fn, *args):
-    assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
 
 
 def _shapes_on(sharding):
@@ -72,9 +76,18 @@ def test_ragged_paged_attention_compiles(one_chip, slots, qmax, hq, hkv):
     sds = _shapes_on(one_chip)
     pages = sds((hkv, SLOTS * TABLE, PAGE, D), jnp.bfloat16)
     seg = sds((slots,), jnp.int32)
-    _compiles_with_kernel(
-        ragged_paged_attention, sds((slots, qmax, hq, D), jnp.bfloat16),
+    role = {1: "decode", 128: "chunk", 5: "verify"}[qmax]
+    text = _compiles_with_kernel(
+        functools.partial(ragged_paged_attention, role=role),
+        sds((slots, qmax, hq, D), jnp.bfloat16),
         pages, pages, sds((slots, TABLE), jnp.int32), seg, seg, seg)
+    # the label a device trace finds the kernel by: an "XLA Ops" event's
+    # name is this instruction's text (jax writes the JSON object with a
+    # newline after every item, so a matcher allows white space there)
+    assert ('kernel_metadata={"kernel":"ragged_paged_attention","role":"%s"}'
+            % role) in "".join(text.split())
+    assert re.search(r'kernel_metadata=\{\s*"kernel":"ragged_paged_attention"',
+                     text)
 
 
 @pytest.mark.parametrize("storage", [jnp.int8, jnp.float8_e4m3fn],

@@ -254,15 +254,19 @@ class TestLifecycleTrace:
         names2 = tel.tracer.get(r2).names()
         assert "cache_hit" in names2
 
-    def test_profiler_bridge_wraps_dispatches(self, monkeypatch):
-        """profiler_bridge=True must actually enter host annotations
-        around the engine's dispatch calls (the jax-device-timeline
-        bridge), not just hold a flag."""
-        import paddle_tpu.profiler as profiler
+    @pytest.mark.parametrize("telemetry", [False, True],
+                             ids=["telemetry-off", "telemetry-on"])
+    def test_phase_annotations_fire_with_telemetry_off_and_on(
+            self, monkeypatch, telemetry):
+        """Every host phase of a step is a profiler annotation
+        (`jax.profiler.TraceAnnotation("serve.<phase>")`) whether or not a
+        Telemetry is attached — tracing is on when a profiler session is
+        open, nothing else — and both ways give the same names in the same
+        order."""
         entered = []
 
         class _Rec:
-            def __init__(self, name):
+            def __init__(self, name, **attrs):
                 self.name = name
 
             def __enter__(self):
@@ -272,24 +276,28 @@ class TestLifecycleTrace:
             def __exit__(self, *exc):
                 return False
 
-        monkeypatch.setattr(profiler, "host_annotation",
-                            lambda name: _Rec(name))
+            def set_metadata(self, **attrs):
+                pass
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Rec)
         cfg, params = _llama()
-        tel = Telemetry(profiler_bridge=True)
-        eng = _engine(cfg, params, telemetry=tel, prefill_chunk=4,
-                      prompt_bucket=4)
-        eng.submit(rng.integers(1, 64, (13,)).astype(np.int32),
-                   max_new_tokens=4)
-        eng.run()
-        assert "serve.prefill_chunk" in entered
-        assert "serve.decode_dispatch" in entered
-        # bridge off: nothing is entered
-        entered.clear()
-        eng2 = _engine(cfg, params, telemetry=Telemetry())
-        eng2.submit(rng.integers(1, 64, (6,)).astype(np.int32),
-                    max_new_tokens=2)
-        eng2.run()
-        assert entered == []
+
+        def names(tel):
+            del entered[:]
+            eng = _engine(cfg, params, telemetry=tel, prefill_chunk=4,
+                          prompt_bucket=4)
+            eng.submit(np.random.default_rng(7).integers(1, 64, (13,))
+                       .astype(np.int32), max_new_tokens=4)
+            eng.run()
+            assert (eng.telemetry is not None) == bool(tel)
+            return list(entered)
+
+        got = names(telemetry)
+        assert got[:2] == ["serve.step", "serve.sched"]
+        assert {"serve.prefill_chunk", "serve.first_token_sync",
+                "serve.provision", "serve.decode_dispatch",
+                "serve.decode_sync", "serve.decode_record"} <= set(got)
+        assert got == names(not telemetry)
 
     def test_preemption_events_recorded(self):
         cfg, params = _llama(seed=5)
