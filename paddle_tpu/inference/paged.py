@@ -924,8 +924,14 @@ class ServingEngine:
                 quantized_allreduce=self.quantized_allreduce)
         cache = init_pages()
         # each side is a raw [L, Hkv, NP+1, ps, D] array (f32/bf16) or a
-        # {"q": data, "s": scales} dict (kv_dtype set); the engine treats
-        # them as opaque pytrees everywhere except snapshot/restore
+        # {"q": data, "s": scales} dict (kv_dtype set).  The engine only
+        # hands them on: every paged executable takes both sides DONATED,
+        # carries them whole through its layer loop (rows written in place,
+        # the layer indexed inside the attention kernel — no executable
+        # copies, slices or relays out the pool) and returns them as its
+        # last two outputs, which `_call_paged` rebinds.  What the engine
+        # itself knows of the layout is the page axis, axis 2 of every leaf
+        # (`_copy_page`; snapshot/restore through gather/scatter_kv_pages)
         self._pages_k, self._pages_v = cache["k"], cache["v"]
         if self.tp > 1:
             # commit params + pages onto the mesh with the same specs the

@@ -30,7 +30,7 @@ __all__ = ["LlamaConfig", "LlamaForCausalLM", "LlamaModel", "LlamaDecoderLayer",
            "llama_config_7b", "llama_config_tiny", "build_llama_decode",
            "build_llama_paged_decode", "make_paged_decode_horizon",
            "functional_params_from_layer", "llama_generate",
-           "gather_kv_pages", "scatter_kv_pages"]
+           "gather_kv_pages", "scatter_kv_pages", "scatter_kv_rows"]
 
 
 @dataclass
@@ -722,6 +722,23 @@ def scatter_kv_pages(store, ids, planes):
     return store.at[:, :, ids].set(jnp.asarray(planes, store.dtype))
 
 
+def scatter_kv_rows(store, layer, rows, page, off):
+    """Write per-token rows into one layer of a page store, in place:
+    ``store [L, Hkv, NP+1, ps, D]`` data pages with ``rows [*tok, Hkv, D]``
+    (or ``[L, Hkv, NP+1, ps]`` scale pages with ``rows [*tok, Hkv]``), token
+    t landing at ``[layer, :, page[t], off[t]]``; ``layer`` may be traced.
+
+    The scatter's INDICES run over (layer, head, page, offset) and its
+    update window is D only (a scalar for scales).  A window over (Hkv, D)
+    — ``store[layer].at[:, page, off].set(...)`` — makes the TPU's layout
+    assignment put Hkv next to D in the pool, while the Mosaic attention
+    kernel reads it row-major: a relayout of a layer per layer and copies
+    of the whole pool around the layer loop (PERF.md section 6, PR 28)."""
+    heads = jnp.arange(store.shape[1])
+    return store.at[layer, heads, page[..., None], off[..., None]].set(
+        rows.astype(store.dtype))
+
+
 def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
                              num_pages: int = 64, dtype=None,
                              attention_impl: str = "auto",
@@ -793,6 +810,16 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
     All shapes static; jit once and every decode step of a whole serving
     run reuses the same executable regardless of which requests occupy
     which slots.
+
+    The page pool stays IN PLACE through all four fns (jit them with
+    pages_k/pages_v donated): ONE layer loop (`_layers`) carries both sides
+    whole, `scatter_kv_rows` writes the fresh rows into layer ``li`` with a
+    D-only window, and the ragged kernel takes the whole pool plus ``li``
+    and picks the layer in its page DMA.  Scanning over the pool (xs/ys) or
+    scattering a (Hkv, D) window made every executable slice, relay out
+    and stack back a layer per layer and copy the whole pool around the
+    loop — 41 % of a decode step on the v5e (PERF.md section 6, PR 28;
+    tests/test_chip_compile.py holds the compiled programs to it).
 
     ``kv_dtype`` ("int8" / "fp8", ROADMAP item 2): the page store holds
     QUANTIZED K/V — each side becomes a ``{"q": [L, Hkv, NP+1, ps, D]
@@ -890,47 +917,49 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
                     "s": jnp.zeros(sshape, jnp.float32)}
         return {"k": side(), "v": side()}
 
-    def _scatter(store, vals, page, off):
-        """Write per-token K or V rows (``vals [..., nkv, D]``) into the
-        (per-layer) page store at ``[:, page, off]``; returns the updated
-        store plus the LOCAL view of what was written — ``vals`` itself
-        on the f32/bf16 path, the dequantized round trip on a quantized
-        store (so a caller attending over its own fresh rows sees exactly
-        what any later gather of the pages will see)."""
+    def _scatter(store, li, vals, page, off):
+        """Write per-token K or V rows (``vals [*tok, nkv, D]``, tokens at
+        ``page/off [*tok]``) into layer ``li`` of the WHOLE page store, in
+        place (:func:`scatter_kv_rows`); returns the updated store plus the
+        LOCAL view of what was written — ``vals`` itself on the f32/bf16
+        path, the dequantized round trip on a quantized store (so a caller
+        attending over its own fresh rows sees exactly what any later
+        gather of the pages will see)."""
         if kv_dtype is None:
-            return store.at[:, page, off].set(
-                jnp.moveaxis(vals.astype(d), -2, 0)), vals
+            return scatter_kv_rows(store, li, vals, page, off), vals
         qv, sv = quantize_kv(vals, qmax=kv_qmax, dtype=kv_storage)
-        new = {"q": store["q"].at[:, page, off].set(jnp.moveaxis(qv, -2, 0)),
-               "s": store["s"].at[:, page, off].set(jnp.moveaxis(sv, -1, 0))}
+        new = {"q": scatter_kv_rows(store["q"], li, qv, page, off),
+               "s": scatter_kv_rows(store["s"], li, sv, page, off)}
         # .astype(d): the jnp paths consume dequantized rows in the
         # COMPUTE dtype, exactly like the f32/bf16 store — activations
         # keep their dtype (no silent f32 promotion) and decode/chunk/
         # verify/dense all see the same rounded values on a bf16 engine
         return new, dequantize_kv(qv, sv).astype(d)
 
-    def _attn(q, kc_l, vc_l, page_tables, q_start, q_len, kv_len, role):
+    def _attn(q, pk, pv, li, page_tables, q_start, q_len, kv_len, role):
         """THE attention dispatch: every paged path (decode, speculative
         verify, chunked prefill) routes its ragged query segments
         ``q [S, Qmax, nh, D]`` through the ONE ragged paged-attention
         kernel (or, off-TPU, its ONE jnp ref) — impl-uniformity is what
         makes speculative verify lossless by construction rather than by
-        bench assert.  On a quantized store the int8/fp8 pages and their
-        per-row scales pass straight through; dequant fuses inside the
-        kernel (and inside the ref's gather) for every path.  ``role``
-        ("decode" | "chunk" | "verify") is the kernel's trace label."""
+        bench assert.  ``pk/pv`` are the WHOLE page stores and ``li`` the
+        layer: the kernel indexes the layer itself.  On a quantized store
+        the int8/fp8 pages and their per-row scales pass straight through;
+        dequant fuses inside the kernel (and inside the ref's gather) for
+        every path.  ``role`` ("decode" | "chunk" | "verify") is the
+        kernel's trace label."""
         if kv_dtype is not None:
-            kq, vq = kc_l["q"], vc_l["q"]
-            scale_kw = dict(k_scales=kc_l["s"], v_scales=vc_l["s"])
+            kq, vq = pk["q"], pv["q"]
+            scale_kw = dict(k_scales=pk["s"], v_scales=pv["s"])
         else:
-            kq, vq = kc_l, vc_l
+            kq, vq = pk, pv
             scale_kw = {}
         if use_kernel:
             return ragged_paged_attention(q, kq, vq, page_tables, q_start,
                                           q_len, kv_len, interpret=interpret,
-                                          role=role, **scale_kw)
+                                          role=role, layer=li, **scale_kw)
         return ragged_paged_attention_ref(q, kq, vq, page_tables, q_start,
-                                          q_len, kv_len, **scale_kw)
+                                          q_len, kv_len, layer=li, **scale_kw)
 
     def _rope_at(x, sin_p, cos_p):
         # x: [..., H, D]; sin_p/cos_p: [..., D] (per-row positions — the
@@ -945,6 +974,45 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
         h = rms_norm_ref(h_last, hp["ln_f"], c.rms_norm_eps)
         return (h @ hp["lm"]).astype(jnp.float32)
 
+    def _layers(bp, x, pages_k, pages_v, sin, cos, page, off, attend):
+        """THE layer loop of all four paged fns.  ``x [*tok, H]`` are the
+        tokens' activations (``tok`` = [T] dense, [C] chunk, [S] decode,
+        [S, Q] verify), ``sin/cos [*tok, D]`` their rotary rows, ``page/off
+        [*tok]`` where each token's K/V row lands.  Per layer: norm, q/k/v,
+        RoPE, the K/V rows written into the pool, ``attend(q, k_loc, v_loc,
+        pk, pv, li) -> o [*tok, nh_l, D]`` (the one thing the fns differ
+        in), wo, MLP.  The page pool is the loop's CARRY, never its xs/ys:
+        scanning over it slices a layer out and stacks it back, a layer of
+        pool read and written twice per layer to change a few rows of it.
+        Carried, indexed by ``li`` in the scatter and inside the kernel, it
+        is updated in place.  Returns (x, pages_k, pages_v)."""
+        tok = x.shape[:-1]
+
+        def body(carry, layer_in):
+            xc, pk, pv = carry
+            lp, li = layer_in
+            # head counts from the LOCAL weight shards: under shard_map
+            # each rank holds nh/tp q heads and nkv/tp kv heads
+            nh_l = lp["wq"].shape[-1] // head_dim
+            nkv_l = lp["wk"].shape[-1] // head_dim
+            h = rms_norm_ref(xc, lp["ln1"], c.rms_norm_eps)
+            q = (h @ lp["wq"]).reshape(*tok, nh_l, head_dim)
+            k = (h @ lp["wk"]).reshape(*tok, nkv_l, head_dim)
+            v = (h @ lp["wv"]).reshape(*tok, nkv_l, head_dim)
+            q = _rope_at(q, sin, cos)
+            k = _rope_at(k, sin, cos)
+            pk, k_loc = _scatter(pk, li, k, page, off)
+            pv, v_loc = _scatter(pv, li, v, page, off)
+            o = _gather_heads(attend(q, k_loc, v_loc, pk, pv, li))
+            xc = xc + o.reshape(*tok, nh * head_dim) @ lp["wo"]
+            h = rms_norm_ref(xc, lp["ln2"], c.rms_norm_eps)
+            ff = jax.nn.silu(h @ lp["wgate"]) * (h @ lp["wup"])
+            return (xc + _mp_reduce(ff @ lp["wdown"]), pk, pv), None
+
+        (x, pages_k, pages_v), _ = jax.lax.scan(
+            body, (x, pages_k, pages_v), (bp, jnp.arange(L)))
+        return x, pages_k, pages_v
+
     def prefill(params, ids, true_len, page_row, pages_k, pages_v):  # graftlint: jit
         ep, bp, hp = params
         T = ids.shape[1]
@@ -953,38 +1021,21 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
         valid = t_idx < true_len
         page = jnp.where(valid, page_row[t_idx // page_size], TRASH)
         off = t_idx % page_size
-        sin, cos = sin_t[:T], cos_t[:T]
+        mask = (t_idx[None, :] <= t_idx[:, None]) & valid[None, :]
 
-        def body(carry, layer_in):
-            xc, = carry
-            lp, kc_l, vc_l = layer_in
-            # head counts from the LOCAL weight shards: under shard_map
-            # each rank holds nh/tp q heads and nkv/tp kv heads
-            nh_l = lp["wq"].shape[-1] // head_dim
-            nkv_l = lp["wk"].shape[-1] // head_dim
-            h = rms_norm_ref(xc, lp["ln1"], c.rms_norm_eps)
-            q = (h @ lp["wq"]).reshape(T, nh_l, head_dim)
-            k = (h @ lp["wk"]).reshape(T, nkv_l, head_dim)
-            v = (h @ lp["wv"]).reshape(T, nkv_l, head_dim)
-            q = _rope_at(q, sin, cos)
-            k = _rope_at(k, sin, cos)
-            kc_l, k_loc = _scatter(kc_l, k, page, off)
-            vc_l, v_loc = _scatter(vc_l, v, page, off)
-            rep = nh_l // nkv_l
+        def attend(q, k_loc, v_loc, pk, pv, li):
+            # dense causal attention over the prompt's own fresh rows
+            rep = q.shape[1] // k_loc.shape[1]
             kf = jnp.repeat(k_loc, rep, axis=1) if rep > 1 else k_loc
             vf = jnp.repeat(v_loc, rep, axis=1) if rep > 1 else v_loc
             s = jnp.einsum("qhd,khd->hqk", q.astype(jnp.float32),
                            kf.astype(jnp.float32)) / math.sqrt(head_dim)
-            mask = (t_idx[None, :] <= t_idx[:, None]) & valid[None, :]
             s = jnp.where(mask[None, :, :], s, -jnp.inf)
-            p = jax.nn.softmax(s, axis=-1).astype(xc.dtype)
-            o = jnp.einsum("hqk,khd->qhd", p, vf)
-            xc = xc + _gather_heads(o).reshape(T, nh * head_dim) @ lp["wo"]
-            h = rms_norm_ref(xc, lp["ln2"], c.rms_norm_eps)
-            ff = jax.nn.silu(h @ lp["wgate"]) * (h @ lp["wup"])
-            return (xc + _mp_reduce(ff @ lp["wdown"]),), (kc_l, vc_l)
+            p = jax.nn.softmax(s, axis=-1).astype(d)
+            return jnp.einsum("hqk,khd->qhd", p, vf)
 
-        (x,), (ks, vs) = jax.lax.scan(body, (x,), (bp, pages_k, pages_v))
+        x, ks, vs = _layers(bp, x, pages_k, pages_v, sin_t[:T], cos_t[:T],
+                            page, off, attend)
         h_last = jax.lax.dynamic_index_in_dim(x, true_len - 1, 0,
                                               keepdims=False)
         return _head(hp, h_last), ks, vs
@@ -1011,27 +1062,12 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
         kvlen_r = start_r + clen_r
         page_tab = page_row[None]                     # [1, P]
 
-        def body(carry, layer_in):
-            xc, = carry
-            lp, kc_l, vc_l = layer_in
-            nh_l = lp["wq"].shape[-1] // head_dim
-            nkv_l = lp["wk"].shape[-1] // head_dim
-            h = rms_norm_ref(xc, lp["ln1"], c.rms_norm_eps)
-            q = (h @ lp["wq"]).reshape(C, nh_l, head_dim)
-            k = (h @ lp["wk"]).reshape(C, nkv_l, head_dim)
-            v = (h @ lp["wv"]).reshape(C, nkv_l, head_dim)
-            q = _rope_at(q, sin, cos)
-            k = _rope_at(k, sin, cos)
-            kc_l, _ = _scatter(kc_l, k, page, off)
-            vc_l, _ = _scatter(vc_l, v, page, off)
-            o = _attn(q[None], kc_l, vc_l, page_tab,
-                      start_r, clen_r, kvlen_r, "chunk")[0]
-            xc = xc + _gather_heads(o).reshape(C, nh * head_dim) @ lp["wo"]
-            h = rms_norm_ref(xc, lp["ln2"], c.rms_norm_eps)
-            ff = jax.nn.silu(h @ lp["wgate"]) * (h @ lp["wup"])
-            return (xc + _mp_reduce(ff @ lp["wdown"]),), (kc_l, vc_l)
+        def attend(q, k_loc, v_loc, pk, pv, li):
+            return _attn(q[None], pk, pv, li, page_tab,
+                         start_r, clen_r, kvlen_r, "chunk")[0]
 
-        (x,), (ks, vs) = jax.lax.scan(body, (x,), (bp, pages_k, pages_v))
+        x, ks, vs = _layers(bp, x, pages_k, pages_v, sin, cos, page, off,
+                            attend)
         h_last = jax.lax.dynamic_index_in_dim(x, chunk_len - 1, 0,
                                               keepdims=False)
         logits = _head(hp, h_last)
@@ -1044,7 +1080,6 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
     def decode_step(params, toks, lengths, page_tables, pages_k, pages_v,
                     active):                          # graftlint: jit
         ep, bp, hp = params
-        S = toks.shape[0]
         x = ep["tok"][toks].astype(d)                 # [S, H]
         pos = jnp.where(active, lengths, 0)
         page = jnp.where(active, jnp.take_along_axis(
@@ -1052,30 +1087,14 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
         off = pos % page_size
         eff_len = jnp.where(active, lengths + 1, 0)
         n_q = active.astype(jnp.int32)                # q_len: 1 live, 0 idle
-        sin_p, cos_p = sin_t[pos], cos_t[pos]         # [S, D]
 
-        def body(carry, layer_in):
-            xc, = carry
-            lp, kc_l, vc_l = layer_in
-            nh_l = lp["wq"].shape[-1] // head_dim
-            nkv_l = lp["wk"].shape[-1] // head_dim
-            h = rms_norm_ref(xc, lp["ln1"], c.rms_norm_eps)
-            q = (h @ lp["wq"]).reshape(S, nh_l, head_dim)
-            k = (h @ lp["wk"]).reshape(S, nkv_l, head_dim)
-            v = (h @ lp["wv"]).reshape(S, nkv_l, head_dim)
-            q = _rope_at(q, sin_p, cos_p)
-            k = _rope_at(k, sin_p, cos_p)
-            kc_l, _ = _scatter(kc_l, k, page, off)
-            vc_l, _ = _scatter(vc_l, v, page, off)
+        def attend(q, k_loc, v_loc, pk, pv, li):
             # decode is the q_len = 1 segment of the unified ragged kernel
-            o = _attn(q[:, None], kc_l, vc_l, page_tables,
-                      pos, n_q, eff_len, "decode")[:, 0]
-            xc = xc + _gather_heads(o).reshape(S, nh * head_dim) @ lp["wo"]
-            h = rms_norm_ref(xc, lp["ln2"], c.rms_norm_eps)
-            ff = jax.nn.silu(h @ lp["wgate"]) * (h @ lp["wup"])
-            return (xc + _mp_reduce(ff @ lp["wdown"]),), (kc_l, vc_l)
+            return _attn(q[:, None], pk, pv, li, page_tables,
+                         pos, n_q, eff_len, "decode")[:, 0]
 
-        (x,), (ks, vs) = jax.lax.scan(body, (x,), (bp, pages_k, pages_v))
+        x, ks, vs = _layers(bp, x, pages_k, pages_v, sin_t[pos], cos_t[pos],
+                            page, off, attend)
         return _head(hp, x), ks, vs
 
     def verify_step(params, toks, lengths, page_tables, pages_k, pages_v,
@@ -1100,7 +1119,7 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
         masks by `lengths`, so stale entries are overwritten by later
         writes before any query can ever attend to them."""
         ep, bp, hp = params
-        S, Q = toks.shape
+        Q = toks.shape[1]
         x = ep["tok"][toks].astype(d)                 # [S, Q, H]
         q_idx = jnp.arange(Q)
         valid = q_idx[None, :] < n_q[:, None]         # [S, Q]
@@ -1110,36 +1129,18 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
         page = jnp.where(valid, jnp.take_along_axis(
             page_tables, pos // page_size, axis=1), TRASH)
         off = pos % page_size
-        sin, cos = sin_t[pos], cos_t[pos]             # [S, Q, D]
         # each slot is one ragged segment of the unified kernel: n_q
         # queries starting at absolute position lengths[s], causal among
         # themselves and over the cached context — the SAME kernel (and
         # off-TPU the same ref) decode dispatches with q_len = 1
         kv_len = lengths + n_q
 
-        def body(carry, layer_in):
-            xc, = carry
-            lp, kc_l, vc_l = layer_in
-            nh_l = lp["wq"].shape[-1] // head_dim
-            nkv_l = lp["wk"].shape[-1] // head_dim
-            h = rms_norm_ref(xc, lp["ln1"], c.rms_norm_eps)
-            q = (h @ lp["wq"]).reshape(S, Q, nh_l, head_dim)
-            k = (h @ lp["wk"]).reshape(S, Q, nkv_l, head_dim)
-            v = (h @ lp["wv"]).reshape(S, Q, nkv_l, head_dim)
-            q = _rope_at(q, sin, cos)
-            k = _rope_at(k, sin, cos)
-            kc_l, _ = _scatter(kc_l, k, page, off)
-            vc_l, _ = _scatter(vc_l, v, page, off)
-            o = _gather_heads(
-                _attn(q, kc_l, vc_l, page_tables, lengths, n_q, kv_len,
-                      "verify")) \
-                .reshape(S, Q, nh * head_dim)
-            xc = xc + o @ lp["wo"]
-            h = rms_norm_ref(xc, lp["ln2"], c.rms_norm_eps)
-            ff = jax.nn.silu(h @ lp["wgate"]) * (h @ lp["wup"])
-            return (xc + _mp_reduce(ff @ lp["wdown"]),), (kc_l, vc_l)
+        def attend(q, k_loc, v_loc, pk, pv, li):
+            return _attn(q, pk, pv, li, page_tables, lengths, n_q, kv_len,
+                         "verify")
 
-        (x,), (ks, vs) = jax.lax.scan(body, (x,), (bp, pages_k, pages_v))
+        x, ks, vs = _layers(bp, x, pages_k, pages_v, sin_t[pos], cos_t[pos],
+                            page, off, attend)
         logits = _head(hp, x)                         # [S, Q, V] f32
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return logits[:, 0], greedy, ks, vs

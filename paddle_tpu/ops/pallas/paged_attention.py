@@ -27,9 +27,12 @@ whole, the one shape rule the lowering never refuses):
                                  relaid OUTSIDE the kernel to kv-head-major
                                  rows [S, Hkv, R, D] (R = Qmax*rep rounded up
                                  to 8 sublanes), and the output laid back
-  k_pages    [Hkv, NP, ps, D]    page-pooled keys; last two dims are the
-  v_pages    [Hkv, NP, ps, D]    (sublane, lane) tile => D=128-friendly
-  k/v_scales [Hkv, NP, ps]       passed as [Hkv, NP, 1, ps]: a (1, ps) tile
+  k_pages    [L, Hkv, NP, ps, D] the WHOLE page pool, every layer of it (or
+  v_pages    [L, Hkv, NP, ps, D] one layer [Hkv, NP, ps, D]); last two dims
+                                 are the (sublane, lane) tile => D=128-friendly
+  k/v_scales [L, Hkv, NP, ps]    this layer's passed as [1, Hkv, NP, 1, ps]: a
+                                 (1, ps) tile
+  layer      scalar int32        which layer of the pool this call attends
   page_table [S, P] int32        physical page of each logical page slot
   q_start    [S]   int32         absolute position of query 0 per slot
   q_len      [S]   int32         valid queries per slot (0 = inactive)
@@ -37,10 +40,12 @@ whole, the one shape rule the lowering never refuses):
 
 Grid: (S, Hkv, P) with the page dim innermost ("arbitrary" semantics) so
 the per-slot online-softmax scratch survives across a sequence's pages.
-The page table and segment descriptors ride scalar prefetch
-(`pltpu.PrefetchScalarGridSpec`), so the K/V BlockSpec index maps resolve
-the PHYSICAL page to DMA before the kernel body runs — the indirection
-costs no kernel time.  GQA is native: the q block for grid step (s, h) is
+The page table, the segment descriptors and the layer index ride scalar
+prefetch (`pltpu.PrefetchScalarGridSpec`), so the K/V BlockSpec index maps
+resolve the PHYSICAL (layer, page) to DMA before the kernel body runs — the
+indirection costs no kernel time, and the model's layer loop never slices a
+layer out of the pool (a slice is a copy of that layer, every layer, every
+step).  GQA is native: the q block for grid step (s, h) is
 the R rows (every query of the segment x the `Hq // Hkv` heads sharing kv
 head h), and K/V pages are fetched once per kv head, never materialized per
 q head.  At q_len = 1 with MHA that block is ONE real row padded to 8 — it
@@ -139,9 +144,9 @@ def _init_scratch(i, m_scr, l_scr, acc_scr):
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
 
-def _ragged_kernel(pt_ref, qs_ref, ql_ref, kl_ref, q_ref, k_ref, v_ref,
-                   o_ref, m_scr, l_scr, acc_scr, *, page_size, sm_scale,
-                   rep):
+def _ragged_kernel(pt_ref, qs_ref, ql_ref, kl_ref, ly_ref, q_ref, k_ref,
+                   v_ref, o_ref, m_scr, l_scr, acc_scr, *, page_size,
+                   sm_scale, rep):
     b = pl.program_id(0)          # sequence slot
     i = pl.program_id(2)          # logical page index (innermost, reduction)
     n_pages = pl.num_programs(2)
@@ -153,15 +158,15 @@ def _ragged_kernel(pt_ref, qs_ref, ql_ref, kl_ref, q_ref, k_ref, v_ref,
         q = q_ref[0, 0].astype(jnp.float32)
         mask = _segment_mask((q.shape[0], page_size), i, page_size, rep,
                              q_start, q_len, kv_len)
-        _attend_page(q, k_ref[0, 0].astype(jnp.float32),
-                     v_ref[0, 0].astype(jnp.float32),
+        _attend_page(q, k_ref[0, 0, 0].astype(jnp.float32),
+                     v_ref[0, 0, 0].astype(jnp.float32),
                      mask, sm_scale, m_scr, l_scr, acc_scr)
 
     _finalize_out(i, n_pages, o_ref, m_scr, l_scr, acc_scr)
 
 
-def _ragged_kernel_quant(pt_ref, qs_ref, ql_ref, kl_ref, q_ref, k_ref,
-                         ks_ref, v_ref, vs_ref, o_ref, m_scr, l_scr,
+def _ragged_kernel_quant(pt_ref, qs_ref, ql_ref, kl_ref, ly_ref, q_ref,
+                         k_ref, ks_ref, v_ref, vs_ref, o_ref, m_scr, l_scr,
                          acc_scr, *, page_size, sm_scale, rep):
     """Fused-dequant variant: K/V pages arrive in their int8/fp8 STORAGE
     dtype plus a per-row f32 absmax scale page ([1, ps] per page), and the
@@ -183,11 +188,11 @@ def _ragged_kernel_quant(pt_ref, qs_ref, ql_ref, kl_ref, q_ref, k_ref,
         q = q_ref[0, 0].astype(jnp.float32)
         mask = _segment_mask((q.shape[0], page_size), i, page_size, rep,
                              q_start, q_len, kv_len)
-        _attend_page(q, k_ref[0, 0].astype(jnp.float32),
-                     v_ref[0, 0].astype(jnp.float32),
+        _attend_page(q, k_ref[0, 0, 0].astype(jnp.float32),
+                     v_ref[0, 0, 0].astype(jnp.float32),
                      mask, sm_scale, m_scr, l_scr, acc_scr,
-                     k_scale=ks_ref[0, 0].astype(jnp.float32),
-                     v_scale=vs_ref[0, 0].astype(jnp.float32))
+                     k_scale=ks_ref[0, 0, 0].astype(jnp.float32),
+                     v_scale=vs_ref[0, 0, 0].astype(jnp.float32))
 
     _finalize_out(i, n_pages, o_ref, m_scr, l_scr, acc_scr)
 
@@ -195,27 +200,46 @@ def _ragged_kernel_quant(pt_ref, qs_ref, ql_ref, kl_ref, q_ref, k_ref,
 def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start, q_len,
                            kv_len, sm_scale=None, interpret=False,
                            out_dtype=None, k_scales=None, v_scales=None,
-                           *, role=None):
+                           *, role=None, layer=None):
     """Ragged-segment paged attention over each slot's page list.
 
-    q [S, Qmax, Hq, D], k_pages/v_pages [Hkv, NP, ps, D], page_table
-    [S, P] int32 (entries past a slot's pages must hold any in-range page
-    id), q_start/q_len/kv_len [S] int32 -> o [S, Qmax, Hq, D].  Query j of
-    slot s sits at absolute position q_start[s] + j and attends kv
-    positions <= its own (and < kv_len[s]); rows past q_len[s] — and every
-    row of a q_len = 0 slot — come back exactly zero.  Requires
-    Hq % Hkv == 0.
+    q [S, Qmax, Hq, D], page_table [S, P] int32 (entries past a slot's
+    pages must hold any in-range page id), q_start/q_len/kv_len [S] int32
+    -> o [S, Qmax, Hq, D].  Query j of slot s sits at absolute position
+    q_start[s] + j and attends kv positions <= its own (and < kv_len[s]);
+    rows past q_len[s] — and every row of a q_len = 0 slot — come back
+    exactly zero.  Requires Hq % Hkv == 0.
+
+    k_pages/v_pages come in one of two forms:
+
+      layer=None     [Hkv, NP, ps, D]: ONE layer's pages (the decode-shape
+                     wrapper, the parity tests);
+      layer=<int32>  [L, Hkv, NP, ps, D]: the WHOLE pool, and ``layer`` (a
+                     traced scalar: the model's layer loop index) names the
+                     layer this call attends.  It rides scalar prefetch
+                     after the four descriptors and the K/V index map
+                     returns ``(layer, h, page_table[b, i], 0, 0)``, so the
+                     layer is picked by the page DMA itself.  Slicing
+                     ``pool[layer]`` outside instead hands XLA a
+                     layer-sized copy per call (PERF.md section 6, PR 28).
+
+    Same grid, masks and arithmetic either way (the 4-D form is the 5-D one
+    with L = 1).  The custom call keeps a fixed outline that the
+    benchmark's trace readers match by shape: its FIRST operand is the
+    [S, P] page table and it has ONE array result [S, Hkv, rows_pad, D] —
+    so no ``input_output_aliases`` here (a tuple result), and new scalars
+    go AFTER the four that exist.
 
     out_dtype: output dtype (default q.dtype).  Accumulation is f32 either
     way; pass jnp.float32 with bf16 inputs to read the un-downcast result
     (the parity tests' bf16→f32 bound).
 
     k_scales/v_scales (both or neither): per-row absmax scale pages
-    [Hkv, NP, ps] f32 for int8/fp8-quantized k_pages/v_pages — dequant
-    then FUSES into the kernel (each page tile dequantizes in VMEM right
-    before its online-softmax update; the f32 K/V never exist outside the
-    kernel).  The scale pages ride the same page-table indirection as the
-    data pages.
+    [Hkv, NP, ps] f32 ([L, Hkv, NP, ps] with ``layer``) for
+    int8/fp8-quantized k_pages/v_pages — dequant then FUSES into the kernel
+    (each page tile dequantizes in VMEM right before its online-softmax
+    update; the f32 K/V never exist outside the kernel).  The scale pages
+    ride the same page-table indirection as the data pages.
 
     role: a LABEL for profiler traces ("decode" | "chunk" | "verify", from
     the model fn that builds the call) — it changes no code path.  The
@@ -235,14 +259,20 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start, q_len,
     the divisibility guard below enforces the local ratio, the builder
     (models/llama.build_llama_paged_decode) enforces mp | nkv.
     """
+    if (k_scales is None) != (v_scales is None):
+        raise ValueError("pass both k_scales and v_scales, or neither")
+    if layer is None:
+        # one layer's pages are a pool of one layer (a bitcast, no copy)
+        k_pages, v_pages = k_pages[None], v_pages[None]
+        if k_scales is not None:
+            k_scales, v_scales = k_scales[None], v_scales[None]
+        layer = 0
     s_slots, qmax, hq, d = q.shape
-    hkv, _np_, page_size, _d = k_pages.shape
+    _l, hkv, _np_, page_size, _d = k_pages.shape
     n_ptab = page_table.shape[1]
     if hq % hkv != 0:
         raise ValueError(f"num q heads ({hq}) must be a multiple of kv "
                          f"heads ({hkv})")
-    if (k_scales is None) != (v_scales is None):
-        raise ValueError("pass both k_scales and v_scales, or neither")
     rep = hq // hkv
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
@@ -263,29 +293,39 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start, q_len,
 
     grid = (s_slots, hkv, n_ptab)
 
-    def q_idx(b, h, i, pt, qs, ql, kl):
+    def q_idx(b, h, i, pt, qs, ql, kl, ly):
         return (b, h, 0, 0)
 
-    def kv_idx(b, h, i, pt, qs, ql, kl):
-        return (h, pt[b, i], 0, 0)
+    def kv_idx(b, h, i, pt, qs, ql, kl, ly):
+        return (ly[0], h, pt[b, i], 0, 0)
 
     q_spec = pl.BlockSpec((1, 1, rows_pad, d), q_idx)
-    kv_spec = pl.BlockSpec((1, 1, page_size, d), kv_idx)
+    kv_spec = pl.BlockSpec((1, 1, 1, page_size, d), kv_idx)
     quant = k_scales is not None
     if quant:
-        # scale pages ride as [Hkv, NP, 1, ps]: a (1, ps) tile per page
-        # (again whole last-two dims), lane-major like the scores it scales
-        sc_spec = pl.BlockSpec((1, 1, 1, page_size), kv_idx)
+        # scale pages ride as [1, Hkv, NP, 1, ps]: a (1, ps) tile per page
+        # (again whole last-two dims), lane-major like the scores it scales.
+        # That tile is a relayout of the array, so it is made of THIS
+        # layer's scales only (4 B a token row against D codes: a 64th of
+        # a layer of int8 data) — the data pages are never sliced
+        def sc_idx(b, h, i, pt, qs, ql, kl, ly):
+            return (0, h, pt[b, i], 0, 0)
+
+        def layer_tiles(scales):
+            return jax.lax.dynamic_index_in_dim(
+                scales, layer, 0, keepdims=True)[:, :, :, None, :]
+
+        sc_spec = pl.BlockSpec((1, 1, 1, 1, page_size), sc_idx)
         in_specs = [q_spec, kv_spec, sc_spec, kv_spec, sc_spec]
-        inputs = (qr, k_pages, k_scales[:, :, None, :],
-                  v_pages, v_scales[:, :, None, :])
+        inputs = (qr, k_pages, layer_tiles(k_scales),
+                  v_pages, layer_tiles(v_scales))
         body = _ragged_kernel_quant
     else:
         in_specs = [q_spec, kv_spec, kv_spec]
         inputs = (qr, k_pages, v_pages)
         body = _ragged_kernel
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5,
         grid=grid,
         in_specs=in_specs,
         out_specs=q_spec,
@@ -308,7 +348,8 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start, q_len,
         metadata={"kernel": "ragged_paged_attention",
                   **({"role": role} if role else {})},
     )(page_table.astype(jnp.int32), q_start.astype(jnp.int32),
-      q_len.astype(jnp.int32), kv_len.astype(jnp.int32), *inputs)
+      q_len.astype(jnp.int32), kv_len.astype(jnp.int32),
+      jnp.reshape(layer, (1,)).astype(jnp.int32), *inputs)
     return out[:, :, :rows].reshape(s_slots, hkv, qmax, rep, d) \
         .transpose(0, 2, 1, 3, 4).reshape(s_slots, qmax, hq, d)
 
@@ -332,7 +373,7 @@ def paged_gather_scales(scales, page_table):
 
 def ragged_paged_attention_ref(q, k_pages, v_pages, page_table, q_start,
                                q_len, kv_len, sm_scale=None, out_dtype=None,
-                               k_scales=None, v_scales=None):
+                               k_scales=None, v_scales=None, *, layer=None):
     """jnp reference/fallback with identical semantics to the ragged
     kernel (gathers pages dense, masks causally inside each slot's
     segment, zeros padding query rows and q_len-0 slots; with
@@ -342,15 +383,21 @@ def ragged_paged_attention_ref(q, k_pages, v_pages, page_table, q_start,
     chunked prefill — one implementation per engine, every path.  Like
     the kernel it is head-shard agnostic: under TP serving each rank
     passes its mp-local Hq/Hkv shapes and the ref computes that rank's
-    heads exactly (same guard, same local GQA pairing)."""
+    heads exactly (same guard, same local GQA pairing).  With ``layer``
+    the pages (and scales) are the whole [L, ...] pool and the ref reads
+    ``pool[layer]`` — the kernel's 5-D form, spelled the plain way."""
+    if (k_scales is None) != (v_scales is None):
+        raise ValueError("pass both k_scales and v_scales, or neither")
+    if layer is not None:
+        k_pages, v_pages = k_pages[layer], v_pages[layer]
+        if k_scales is not None:
+            k_scales, v_scales = k_scales[layer], v_scales[layer]
     s_slots, qmax, hq, d = q.shape
     hkv = k_pages.shape[0]
     page_size = k_pages.shape[2]
     if hq % hkv != 0:
         raise ValueError(f"num q heads ({hq}) must be a multiple of kv "
                          f"heads ({hkv})")
-    if (k_scales is None) != (v_scales is None):
-        raise ValueError("pass both k_scales and v_scales, or neither")
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     k = paged_gather_kv(k_pages, page_table)      # [S, T, Hkv, D]

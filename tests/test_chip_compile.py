@@ -19,8 +19,10 @@ passes is not a chip run.
 """
 import dataclasses
 import functools
+import math
 import os
 import re
+import sys
 
 import pytest
 import jax
@@ -141,6 +143,91 @@ def test_flash_attention_forward_backward_compiles(one_chip, dropout):
                           sds((), jnp.int32))
 
 
+# ---------------------------------------------------------------------------
+# The KV page pool stays where it is (PR 28).  On the parent every serving
+# executable sliced a layer out of the pool, relaid it out twice for the
+# Mosaic kernel, stacked it back and copied the whole pool around the layer
+# loop: 41 % of a decode step on the chip.  None of that is visible off the
+# chip except HERE, in the compiled program's text.
+# ---------------------------------------------------------------------------
+# serve_chat_c16's engine sizes (benchmark/configs/mistral-7b-serve-1chip.json)
+CELL = {"num_slots": 16, "max_pages_per_seq": TABLE, "page_size": PAGE,
+        "decode_horizon": 8, "prefill_chunk": 512, "prompt_bucket": 128,
+        "prompt_lens": [512]}
+CELL_LAYERS, HKV = 2, 8                 # two layers keep a compile to seconds
+_MOVERS = ("copy", "copy-start", "dynamic-slice", "dynamic-update-slice")
+# result dtype, dims, minor-to-major (without tiling), opcode
+_INSTR = re.compile(r"= (\w+)\[([\d,]*)\](?:\{([\d,]*)[^}]*\})? ([\w-]+)\(")
+
+
+def _assert_pool_stays_in_place(compiled, pool_shape):
+    """No instruction of the compiled program — fused or not — copies,
+    slices or update-slices a value with as many elements as the bf16 pool
+    ``pool_shape`` or one layer of it, whatever shape a bitcast gave it;
+    every value of the pool's own shape is row-major; the donated pool is
+    aliased to the output and no second one sits among the temporaries."""
+    n_pool = math.prod(pool_shape)
+    sizes = {n_pool, n_pool // pool_shape[0]}
+    movers, layouts = [], set()
+    for dtype, dims, layout, op in _INSTR.findall(compiled.as_text()):
+        shape = tuple(int(n) for n in dims.split(",") if n)
+        if op in _MOVERS and math.prod(shape) in sizes:
+            movers.append((op, f"{dtype}[{dims}]{{{layout}}}"))
+        if shape == pool_shape:
+            layouts.add(layout)
+    assert not movers, movers
+    # the parameter's and the Mosaic kernel's layout, everywhere
+    assert layouts == {"4,3,2,1,0"}, layouts
+    pool_bytes = 2 * 2 * n_pool                    # K and V, bf16
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= pool_bytes
+    assert m.temp_size_in_bytes < pool_bytes // 2, m.temp_size_in_bytes
+
+
+@pytest.fixture(scope="module")
+def cell_programs(one_chip):
+    """The engine's four paged executables at `serve_chat_c16`'s widths
+    (Mistral-7B: H 4096, 32/8 heads, D 128), slots and pool (16 x 32 pages
+    of 64 tokens: bf16[L,8,513,64,128] a side), lowered as `ServingEngine`
+    jits them, pool donated — by perf/chip_fit.py, which sizes the chip
+    runs with the same programs."""
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "perf"))
+    import chip_fit
+    from paddle_tpu.models.llama import LlamaConfig
+
+    cfg = dataclasses.replace(
+        LlamaConfig(), hidden_size=4096, intermediate_size=14336,
+        num_attention_heads=32, num_key_value_heads=HKV, vocab_size=32768,
+        num_hidden_layers=CELL_LAYERS, max_position_embeddings=32768,
+        rope_theta=1e6)
+    place = chip_fit.placed_on(one_chip)
+    return chip_fit.paged_programs(cfg, CELL, place, place, one_chip)
+
+
+@pytest.mark.parametrize("program", ["decode horizon", "dense prefill",
+                                     "prefill chunk", "verify"])
+def test_serving_executable_leaves_the_page_pool_in_place(cell_programs,
+                                                          program):
+    (fn, args), = [v for k, v in cell_programs.items()
+                   if k.startswith(program)]
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    if program != "dense prefill":                 # dense attends locally
+        # what the benchmark's shape matchers key on: the kernel call has
+        # ONE array result [S, Hkv, rows, D] (no tuple: no
+        # input_output_aliases) and the [S, P] page table is its FIRST
+        # operand (the compiled text lists operand shapes here; a trace
+        # event's name has them inline)
+        assert re.search(
+            r'= \w+\[\d+,8,\d+,128\]\S* custom-call\([^)]*\), '
+            r'custom_call_target="tpu_custom_call", '
+            r'operand_layout_constraints=\{s32\[\d+,32\]', text)
+        assert re.search(
+            r'kernel_metadata=\{\s*"kernel":"ragged_paged_attention"', text)
+    _assert_pool_stays_in_place(
+        compiled, (CELL_LAYERS, HKV, CELL["num_slots"] * TABLE + 1, PAGE, D))
+
+
 def test_tp4_paged_decode_step_compiles_with_kernel_and_allreduce(topo):
     """The TP=4 decode step on four described devices, as
     `build_llama_paged_decode(mesh=...)` builds it: the Pallas kernel under
@@ -182,3 +269,6 @@ def test_tp4_paged_decode_step_compiles_with_kernel_and_allreduce(topo):
     pool = 2 * 2 * 32 * (SLOTS * TABLE + 1) * PAGE * D * 2
     out = compiled.memory_analysis().output_size_in_bytes
     assert pool // 4 <= out < pool // 4 + (4 << 20)
+    # and each rank leaves its quarter where it is, like the one-chip engine
+    _assert_pool_stays_in_place(
+        compiled, (2, 32 // 4, SLOTS * TABLE + 1, PAGE, D))

@@ -362,3 +362,51 @@ class TestServingEngine:
 
         np.testing.assert_array_equal(go(5), go(5))
         assert not np.array_equal(go(5), go(6))
+
+
+# ---------------------------------------------------------------------------
+# scatter_kv_rows (PR 28): the row write indexes (layer, head, page, offset)
+# with a window of D only — same values into the same rows as the window
+# over (Hkv, D) it replaces, so the store must come out bit-identical
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("store", ["data", "scales"])
+@pytest.mark.parametrize("tok", [(6,), (40,), (4, 5)],
+                         ids=["decode", "prefill", "verify"])
+def test_scatter_kv_rows_equals_the_per_layer_window_scatter(tok, store):
+    from paddle_tpu.models.llama import scatter_kv_rows
+    L, Hkv, NP, ps, D = 3, 2, 7, 8, 16
+    TRASH = NP
+    lr = np.random.default_rng(5)
+    w = (D,) if store == "data" else ()
+    pool = jnp.asarray(lr.standard_normal((L, Hkv, NP + 1, ps) + w)
+                       .astype(np.float32))
+    rows = jnp.asarray(lr.standard_normal(tok + (Hkv,) + w)
+                       .astype(np.float32))
+    # distinct live (page, off) targets, and a third of the lanes routed to
+    # the TRASH page with DUPLICATE targets there (idle slots, padding rows)
+    n = int(np.prod(tok))
+    flat = lr.permutation(NP * ps)[:n]
+    page, off = flat // ps, flat % ps
+    trash = np.zeros(n, bool)
+    trash[lr.permutation(n)[:max(3, n // 3)]] = True
+    page = np.where(trash, TRASH, page)
+    off = np.where(trash, np.arange(n) % 2, off)
+    assert trash.sum() > len(set(off[trash]))        # duplicates exist
+    page = jnp.asarray(page.reshape(tok).astype(np.int32))
+    off = jnp.asarray(off.reshape(tok).astype(np.int32))
+
+    def old(pool, li):
+        # what `_scatter` did before: the scan's per-layer slice, a window
+        # over (Hkv, D), stacked back
+        layer = pool[li].at[:, page, off].set(jnp.moveaxis(rows, len(tok), 0))
+        return pool.at[li].set(layer)
+
+    for li in range(L):
+        got = jax.jit(lambda li: scatter_kv_rows(pool, li, rows, page, off))(
+            jnp.int32(li))
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(old(pool, li)))
+        # only layer li changed, and only the addressed rows of it
+        changed = np.asarray(got != pool)
+        assert not np.delete(changed, li, axis=0).any()
+        assert changed[li].any()

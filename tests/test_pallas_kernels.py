@@ -1,5 +1,6 @@
 """Pallas kernel tests — run in interpreter mode on the CPU mesh (the kernels
 themselves are TPU-targeted; interpret=True validates the math)."""
+import functools
 import numpy as np
 import pytest
 import jax
@@ -385,12 +386,19 @@ def test_llama_head_chunks_matches_default():
                                    rtol=5e-4, atol=1e-6)
 
 
-def test_pallas_adamw_now_optin():
+def test_pallas_adamw_now_optin(monkeypatch):
     """Round-4: the fused Pallas AdamW measured slower than XLA's chain and
     is gated behind FLAGS_use_pallas_adamw (default off)."""
     import paddle_tpu as paddle
+    from paddle_tpu.core import dispatch
     from paddle_tpu.core.dispatch import get_kernel
+    from paddle_tpu.ops import pallas
     from paddle_tpu.ops.pallas import register_all
+    # the forced registration is this test's alone: a later test of the same
+    # process (tests/test_chip_smoke.py) must find the CPU's registry
+    monkeypatch.setattr(dispatch, "_KERNELS",
+                        {k: dict(v) for k, v in dispatch._KERNELS.items()})
+    monkeypatch.setattr(pallas, "_registered", [pallas._registered[0]])
     register_all(force=True)
     import jax.numpy as jnp
     k = get_kernel("adamw_fused")
@@ -670,3 +678,63 @@ def test_ragged_decode_wrappers_delegate():
     direct_r = ragged_paged_attention_ref(qd[:, None], kp, vp, pt, q_start,
                                           q_len, lens)[:, 0]
     np.testing.assert_array_equal(np.asarray(wrap_r), np.asarray(direct_r))
+
+
+# ---------------------------------------------------------------------------
+# The WHOLE-POOL form (PR 28): `layer=` hands the kernel (and the ref) the
+# 5-D [L, Hkv, NP, ps, D] pool and the layer index rides scalar prefetch —
+# it must equal the 4-D call on `pool[layer]` BIT for bit, on every segment
+# shape the engine dispatches, plain and quantized, kernel and ref alike
+# ---------------------------------------------------------------------------
+_POOL_SEGMENTS = {
+    # (S, Qmax, q_start, q_len, kv_len)
+    "decode": (4, 1, [7, 0, 16, 47], [1, 0, 1, 1], [8, 0, 17, 48]),
+    "chunk": (1, 8, [16], [8], [24]),
+    "verify": (4, 5, [7, 14, 16, 0], [1, 5, 3, 0], [8, 19, 19, 0]),
+}
+
+
+@pytest.mark.parametrize("impl", ["kernel", "ref"])
+@pytest.mark.parametrize("quant", [False, True], ids=["plain", "int8"])
+@pytest.mark.parametrize("shape", list(_POOL_SEGMENTS))
+def test_ragged_paged_attention_pool_layer_form_is_bit_equal(shape, quant,
+                                                             impl):
+    from paddle_tpu.ops.pallas.paged_attention import (
+        ragged_paged_attention, ragged_paged_attention_ref)
+    from paddle_tpu.serving.quant import kv_spec, quantize_kv
+    L, Hq, Hkv, D, ps, NP, P = 3, 8, 2, 64, 16, 13, 3
+    S, qmax, q_start, q_len, kv_len = _POOL_SEGMENTS[shape]
+    lr = np.random.default_rng(31)
+    q = jnp.asarray(lr.standard_normal((S, qmax, Hq, D)).astype(np.float32))
+    kp = jnp.asarray(lr.standard_normal((L, Hkv, NP, ps, D))
+                     .astype(np.float32))
+    vp = jnp.asarray(lr.standard_normal((L, Hkv, NP, ps, D))
+                     .astype(np.float32))
+    pt = jnp.asarray(lr.integers(0, NP, (S, P)).astype(np.int32))
+    seg = [jnp.asarray(np.array(a, np.int32))
+           for a in (q_start, q_len, kv_len)]
+    ks = vs = None
+    if quant:
+        storage, qm = kv_spec("int8")
+        kp, ks = quantize_kv(kp, qmax=qm, dtype=storage)
+        vp, vs = quantize_kv(vp, qmax=qm, dtype=storage)
+    fn = functools.partial(ragged_paged_attention, interpret=True) \
+        if impl == "kernel" else ragged_paged_attention_ref
+
+    # the layer arrives TRACED in both, as the model's layer loop hands it
+    # over: one program indexes it inside the call, the other slices first
+    @jax.jit
+    def pool_form(li):
+        return fn(q, kp, vp, pt, *seg, layer=li,
+                  **(dict(k_scales=ks, v_scales=vs) if quant else {}))
+
+    @jax.jit
+    def layer_form(li):
+        return fn(q, kp[li], vp[li], pt, *seg,
+                  **(dict(k_scales=ks[li], v_scales=vs[li]) if quant else {}))
+
+    for li in range(L):
+        pool = pool_form(jnp.int32(li))
+        np.testing.assert_array_equal(np.asarray(pool),
+                                      np.asarray(layer_form(jnp.int32(li))))
+    assert np.asarray(pool).any()
