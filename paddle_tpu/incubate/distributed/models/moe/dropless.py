@@ -1,0 +1,242 @@
+"""Dropless expert layer: no capacity, no dropped token, and an expert-
+parallel rank's share of the result.
+
+`moe_layer.py` beside this file routes through a one-hot ``[T, E, C]``
+dispatch mask with a capacity that drops tokens; at 8,192 tokens and 128
+experts that mask is not a program a chip can hold.  Here the (token, expert)
+pairs are SORTED by expert and the experts run as one grouped matrix product
+over uneven group sizes:
+
+  route   scores over ALL experts (the router keeps its published width),
+          top-k with a selection bias, weights normalised and scaled;
+  share   a rank is told which experts it holds (``offset``, and the leading
+          dim of its expert-stacked weights) and computes the part of the
+          result its own experts give: the pairs whose expert lives
+          elsewhere are sorted past the held groups and contribute nothing.
+          On one chip there is no exchange, and nothing stands in for one;
+  group   rows per held expert are COUNTED; the row buffer has the static
+          bound T * k (every pair could be held), the grouped products do
+          the work of the counted rows only, and the other passes over the
+          rows run at twice the rows the share expects, or at T * k when the
+          count passes that (chosen on the device);
+  combine the pairs' rows go back by the inverse permutation (a gather and a
+          sum over k, in both directions — never a scatter-add).
+
+The grouped product is ``jax.lax.ragged_dot``: on the TPU XLA lowers it to
+its own Mosaic grouped-matmul kernel (the instruction is named
+``%ragged-dot-*`` and carries ``ragged_dot_tiling=`` among its frontend
+attributes, which a device trace keeps), forward and both gradients; the
+megablox ``gmm`` that ships with jax is the same algorithm without a label
+the trace could find it by.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+__all__ = ["sigmoid_topk_route", "sort_pairs_by_held_expert",
+           "grouped_swiglu", "dropless_expert_ffn", "expert_load",
+           "balance_bias_update", "TRACE_LABEL"]
+
+# what a device trace finds the grouped products by (an "XLA Ops" event's
+# name is the instruction's whole text)
+TRACE_LABEL = "ragged_dot_tiling="
+
+
+def sigmoid_topk_route(u, w_router, bias, top_k, route_scale=1.0,
+                       route_norm=True):
+    """u [T, H], w_router [H, E_total], bias [E_total] -> (sel int32 [T, k],
+    weights f32 [T, k]).  Scores are sigmoids in float32; the BIAS takes part
+    in the selection only, the weights are the unbiased scores of the
+    selected experts, normalised over the k (``route_norm``) and scaled."""
+    scores = jax.nn.sigmoid(jax.lax.dot_general(
+        u, w_router.astype(u.dtype), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32))
+    _, sel = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    # the selected scores by a one-hot select-and-sum: its transpose is a
+    # broadcast, where take_along_axis's would be a scatter-add
+    chosen = sel[..., None] == jnp.arange(scores.shape[-1])[None, None, :]
+    w = jnp.where(chosen, scores[:, None, :], 0.0).sum(-1)
+    if route_norm:
+        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return sel.astype(jnp.int32), w * route_scale
+
+
+def expert_load(sel, num_experts):
+    """sel [T, k] -> int32 [num_experts]: the tokens each of ALL the experts
+    was selected by (counted by comparison, not by a scatter-add)."""
+    return (sel[..., None] == jnp.arange(num_experts, dtype=sel.dtype)) \
+        .sum((0, 1), dtype=jnp.int32)
+
+
+def balance_bias_update(bias, load, coeff):
+    """The selection bias after a step, by the auxiliary-loss-free rule: an
+    expert under the mean load gains ``coeff``, one over it loses ``coeff``,
+    and the deltas are centred.  bias f32 [..., E], load [..., E] (the
+    experts on the last dim) -> f32 [..., E]."""
+    load = load.astype(jnp.float32)
+    delta = coeff * jnp.sign(load.mean(-1, keepdims=True) - load)
+    return bias + (delta - delta.mean(-1, keepdims=True))
+
+
+def sort_pairs_by_held_expert(sel, offset, held):
+    """sel [T, k] (ids over all experts) -> (order, inverse, held_mask,
+    rows).  ``order`` [T*k] lists the flat pairs sorted by held-expert id,
+    the pairs whose expert is not in [offset, offset + held) last;
+    ``inverse`` is its inverse permutation; ``held_mask`` bool [T, k];
+    ``rows`` int32 [held] counts the rows of each held expert — the group
+    sizes of the grouped product, and the layer's counter."""
+    local = sel - offset
+    held_mask = (local >= 0) & (local < held)
+    key = jnp.where(held_mask, local, held).reshape(-1)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    inverse = jnp.argsort(order).astype(jnp.int32)
+    # counted by comparison, not by a scatter-add of T*k ones
+    rows = (key[:, None] == jnp.arange(held, dtype=key.dtype)[None, :]) \
+        .sum(0, dtype=jnp.int32)
+    return order, inverse, held_mask, rows
+
+
+# The two movements between tokens and sorted rows.  ``order`` is cut to the
+# row bound B (the first B sorted pairs: every held pair, as long as their
+# count is <= B), ``inverse`` says where each of the T*k pairs went.  A
+# token's sum over its own k pairs is a gather and a reduction in BOTH
+# directions, where autodiff of a gather would scatter-add.
+def _rows_of_tokens(x, order, k):
+    """[T, H] -> [B, H]: the token's row for each of the first B sorted
+    pairs."""
+    return x[order // k]
+
+
+def _sum_over_pairs(rows, inverse, held_mask, weights=None):
+    """[B, H] -> [T, H] float32: each token's sum over its HELD pairs of
+    their (weighted) rows.  A pair whose expert lives elsewhere names a row
+    of no group (or none at all, past the bound): selected away, never read
+    into the sum — whatever a grouped product left there."""
+    per_pair = rows[jnp.minimum(inverse, rows.shape[0] - 1)].reshape(
+        held_mask.shape + rows.shape[-1:]).astype(jnp.float32)
+    if weights is not None:
+        per_pair = per_pair * weights[..., None]
+    return jnp.where(held_mask[..., None], per_pair, 0.0).sum(1)
+
+
+@jax.custom_vjp
+def _dispatch(u, order, inverse, held_mask):
+    return _rows_of_tokens(u, order, held_mask.shape[1])
+
+
+_dispatch.defvjp(
+    lambda u, order, inverse, held_mask: (
+        _rows_of_tokens(u, order, held_mask.shape[1]), (inverse, held_mask)),
+    lambda res, g: (_sum_over_pairs(g, *res).astype(g.dtype),
+                    None, None, None))
+
+
+@jax.custom_vjp
+def _combine(ys, weights, order, inverse, held_mask):
+    """out[t] = sum over t's held pairs of weight * row, float32 weights."""
+    return _sum_over_pairs(ys, inverse, held_mask, weights).astype(ys.dtype)
+
+
+def _combine_bwd(res, g):
+    ys, weights, order, inverse, held_mask = res
+    g_rows = _rows_of_tokens(g, order, held_mask.shape[1]) \
+        .astype(jnp.float32)
+    w_row = weights.reshape(-1)[order]
+    d_w_row = (g_rows * ys.astype(jnp.float32)).sum(-1)
+    d_w = jnp.where(held_mask, d_w_row[jnp.minimum(
+        inverse, ys.shape[0] - 1)].reshape(held_mask.shape), 0.0)
+    return (g_rows * w_row[:, None]).astype(ys.dtype), d_w, None, None, None
+
+
+_combine.defvjp(
+    lambda ys, weights, order, inverse, held_mask: (
+        _combine(ys, weights, order, inverse, held_mask),
+        (ys, weights, order, inverse, held_mask)), _combine_bwd)
+
+
+def grouped_swiglu(xs, we_gate, we_up, we_down, rows):
+    """Rows sorted by expert, ``rows[e]`` of them for expert e ->
+    (silu(xs W_gate[e]) * (xs W_up[e])) W_down[e] per group.  Rows past
+    sum(rows) belong to no group; what they hold afterwards is unspecified."""
+    gate = jax.lax.ragged_dot(xs, we_gate.astype(xs.dtype), rows)
+    up = jax.lax.ragged_dot(xs, we_up.astype(xs.dtype), rows)
+    return jax.lax.ragged_dot(jax.nn.silu(gate) * up,
+                              we_down.astype(xs.dtype), rows)
+
+
+def _experts_within(bound, operands, sorting):
+    """The held experts' part with every pass over the rows cut to the
+    static ``bound`` (exact while the counted rows are <= bound)."""
+    u, weights, we_gate, we_up, we_down = operands
+    order, inverse, held_mask, rows = sorting
+    first = order[:bound]
+    xs = _dispatch(u, first, inverse, held_mask)
+    ys = grouped_swiglu(xs, we_gate, we_up, we_down, rows)
+    return _combine(ys, weights, first, inverse, held_mask)
+
+
+def _tier(bounds, rows):
+    """Index of the smallest of the ascending ``bounds`` that holds the
+    counted rows (the last one holds any count)."""
+    return sum((rows.sum() > b).astype(jnp.int32) for b in bounds[:-1])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _experts_tiered(bounds, operands, sorting):
+    """`_experts_within` the smallest of the static ``bounds`` that holds
+    the counted rows, chosen on the device.  Forward and backward each take
+    their own ``lax.switch`` and the backward recomputes the rows it needs
+    inside its branch: autodiff THROUGH a switch would keep every branch's
+    [bound, .] residuals alive (zeros for the branches not taken), the
+    largest bound's among them, in every layer."""
+    return jax.lax.switch(
+        _tier(bounds, sorting[3]),
+        [functools.partial(_experts_within, b) for b in bounds],
+        operands, sorting)
+
+
+def _experts_tiered_bwd(bounds, res, g):
+    operands, sorting = res
+
+    def backward(bound, operands, sorting, g):
+        return jax.vjp(lambda *ops: _experts_within(bound, ops, sorting),
+                       *operands)[1](g)
+
+    return jax.lax.switch(
+        _tier(bounds, sorting[3]),
+        [functools.partial(backward, b) for b in bounds],
+        operands, sorting, g), None
+
+
+_experts_tiered.defvjp(
+    lambda bounds, operands, sorting: (
+        _experts_tiered(bounds, operands, sorting), (operands, sorting)),
+    _experts_tiered_bwd)
+
+
+def dropless_expert_ffn(u, sel, weights, we_gate, we_up, we_down, offset,
+                        num_experts):
+    """The held experts' part of sum_{e in sel} w_e Expert_e(u).
+
+    u [T, H]; sel / weights [T, k] from `sigmoid_topk_route` over all
+    ``num_experts``; we_* carry the HELD experts on their leading dim, global
+    ids offset .. offset + held.  Returns (out [T, H] in u's dtype, rows
+    int32 [held]).
+
+    Exact for any routing: the row buffer's bound is T*k.  The WORK follows
+    the counted rows: the grouped products by their group sizes, and every
+    other pass over the rows by a static bound of TWICE the rows the share
+    expects (T*k*held/num_experts), or by T*k when the count passes that,
+    chosen on the device.  Nothing of a layer's [bound, .] buffers is kept
+    for the backward pass, which recomputes them."""
+    t, k = sel.shape
+    held = we_gate.shape[0]
+    sorting = sort_pairs_by_held_expert(sel, offset, held)
+    expected_twice = max(2 * t * k * held // num_experts, 1)
+    bounds = tuple(sorted({min(expected_twice, t * k), t * k}))
+    out = _experts_tiered(bounds, (u, weights, we_gate, we_up, we_down),
+                          sorting)
+    return out, sorting[3]

@@ -29,8 +29,8 @@ ragged_paged_attention_decode = pa_mod.ragged_paged_attention_decode
 
 # the kernel module owns its jnp reference (graftlint PAR001: every Pallas
 # kernel pairs with a `*_ref` in its own module)
-_naive_sdpa = lambda q, k, v, causal: fa_mod.flash_attention_ref(
-    q, k, v, causal=causal)
+_naive_sdpa = lambda q, k, v, causal, window=None: \
+    fa_mod.flash_attention_ref(q, k, v, causal=causal, window=window)
 
 
 def _softmax_pallas(x, *, axis=-1, cast_dtype=None):
@@ -98,9 +98,9 @@ def _fa_dropout(q, k, v, seed, rate=0.1, causal=False):
     return _sdpa_ref(q, k, v, dropout=rate, causal=causal, dropout_key=key)
 
 
-def _fa_causal(q, k, v):
-    out = fa_mod.flash_attention(q, k, v, causal=True)
-    return out if out is not None else _naive_sdpa(q, k, v, True)
+def _fa_causal(q, k, v, window=None):
+    out = fa_mod.flash_attention(q, k, v, causal=True, window=window)
+    return out if out is not None else _naive_sdpa(q, k, v, True, window)
 
 
 _registered = [False]

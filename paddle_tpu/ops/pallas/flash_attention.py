@@ -44,6 +44,64 @@ def _block_sizes(s_q, s_k, d):
 
 
 # ---------------------------------------------------------------------------
+# The band: causal, and with a sliding ``window`` W query i sees keys
+# i-W+1 .. i (positions bottom-right aligned by ``offset`` = s_k - s_q).
+# A (q-block i, k-block j) pair RUNS iff some pair in it lies in the band;
+# every other block is skipped, not masked.  With a window the index maps
+# also clamp the block a skipped step names to the nearest block that runs,
+# so that a skipped step fetches nothing new (Pallas re-fetches a block
+# only when its index changes).  window=None leaves the causal kernels as
+# they were, trace for trace.
+# ---------------------------------------------------------------------------
+def _block_runs(i, j, block_q, block_k, offset, window):
+    run = (j * block_k) <= (i * block_q + block_q - 1 + offset)
+    if window is not None:
+        run &= (i * block_q + offset - (j * block_k + block_k - 1)) < window
+    return run
+
+
+def _band_mask(s, i, j, block_q, block_k, offset, window):
+    q_ids = i * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 0)
+    k_ids = j * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1)
+    keep = q_ids + offset >= k_ids
+    if window is not None:
+        keep &= (q_ids + offset - k_ids) < window
+    return jnp.where(keep, s, NEG_INF)
+
+
+def _clamp_k_block(i, j, block_q, block_k, num_k_blocks, offset, window):
+    """The k-block step (i, j) names: j itself inside q-block i's band, the
+    band's nearest end outside it."""
+    if window is None:
+        return j
+    lo = jnp.maximum(i * block_q + offset - (window - 1), 0) // block_k
+    hi = jnp.minimum((i * block_q + block_q - 1 + offset) // block_k,
+                     num_k_blocks - 1)
+    return jnp.clip(j, lo, hi)
+
+
+def _clamp_q_block(i, j, block_q, block_k, num_q_blocks, offset, window):
+    """The q-block step (j, i) of the dK/dV grid names."""
+    if window is None:
+        return i
+    lo = jnp.maximum(j * block_k - offset, 0) // block_q
+    hi = jnp.minimum(
+        jnp.maximum(j * block_k + block_k - 1 - offset + window - 1, 0)
+        // block_q, num_q_blocks - 1)
+    return jnp.clip(i, lo, hi)
+
+
+def _label(pass_, window):
+    """kernel_metadata: what a device trace finds the kernel by."""
+    label = {"kernel": "flash_attention", "pass": pass_}
+    if window is not None:
+        label["window"] = int(window)
+    return label
+
+
+# ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
 def _dropout_mask(seed_f32, qh, i, j, n_i, n_j, shape, rate):
@@ -63,7 +121,7 @@ def _dropout_mask(seed_f32, qh, i, j, n_i, n_j, shape, rate):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, causal, sm_scale, block_q,
                 block_k, num_k_blocks, offset, has_segments=False,
-                dropout_rate=0.0, num_q_blocks=1):
+                dropout_rate=0.0, num_q_blocks=1, window=None):
     rest = list(rest)
     qseg_ref = kseg_ref = seed_ref = None
     if has_segments:
@@ -80,10 +138,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, causal, sm_scale, block_q,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # causal: process only blocks with k_start <= q_end
+    # causal: process only blocks with k_start <= q_end (and, with a
+    # window, k_end >= q_start - window + 1)
     run = True
     if causal:
-        run = (j * block_k) <= (i * block_q + block_q - 1 + offset)
+        run = _block_runs(i, j, block_q, block_k, offset, window)
 
     @pl.when(run)
     def _body():
@@ -94,11 +153,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, causal, sm_scale, block_q,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale  # [bq, bk]
         if causal:
-            q_ids = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_ids = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_ids + offset >= k_ids, s, NEG_INF)
+            s = _band_mask(s, i, j, block_q, block_k, offset, window)
         if has_segments:
             qs = qseg_ref[0, :, 0]        # [block_q] (f32 segment ids)
             ks = kseg_ref[0, :, 0]        # [block_k]
@@ -175,7 +230,7 @@ def _sds(shape, dtype, vma):
 def flash_attention_fwd_kernel_call(q, k, v, causal, sm_scale, interpret=False,
                                     n_q_heads=None, n_kv_heads=None,
                                     segment_ids=None, dropout_rate=0.0,
-                                    dropout_seed=None):
+                                    dropout_seed=None, window=None):
     """q: [B*Hq, S, D], k/v: [B*Hkv, S, D] -> (o [B*Hq, Sq, D], lse).
 
     GQA (n_kv_heads < n_q_heads) is handled in the BlockSpec index maps: the
@@ -191,14 +246,16 @@ def flash_attention_fwd_kernel_call(q, k, v, causal, sm_scale, interpret=False,
     grid = (bh, s_q // block_q, s_k // block_k)
 
     def kv_idx(b, i, j):
-        return ((b // hq) * hkv + (b % hq) // rep, j, 0)
+        return ((b // hq) * hkv + (b % hq) // rep,
+                _clamp_k_block(i, j, block_q, block_k, s_k // block_k,
+                               s_k - s_q, window), 0)
 
     has_seg = segment_ids is not None
     kernel = functools.partial(
         _fwd_kernel, causal=causal, sm_scale=sm_scale, block_q=block_q,
         block_k=block_k, num_k_blocks=s_k // block_k, offset=s_k - s_q,
         has_segments=has_seg, dropout_rate=dropout_rate,
-        num_q_blocks=s_q // block_q)
+        num_q_blocks=s_q // block_q, window=window)
 
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -241,7 +298,7 @@ def flash_attention_fwd_kernel_call(q, k, v, causal, sm_scale, interpret=False,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        metadata={"kernel": "flash_attention", "pass": "fwd"},
+        metadata=_label("fwd", window),
     )(*args)
     # COMPACT 2-D lse for the caller: the [bh, s, 1] kernel output tile-pads
     # its last dim 1 -> 128 in HBM (measured 128x, 256 MB per ViT layer);
@@ -270,7 +327,7 @@ def _col_from_packed(ref, i, block_q, scr):
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                     causal, sm_scale, block_q, block_k, num_q_blocks,
                     rep_heads, offset, has_segments=False, dropout_rate=0.0,
-                    hq=1, hkv=1, num_k_blocks=1):
+                    hq=1, hkv=1, num_k_blocks=1, window=None):
     rest = list(rest)
     qseg_ref = kseg_ref = seed_ref = None
     if has_segments:
@@ -291,7 +348,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
     run = True
     if causal:
-        run = (j * block_k) <= (i * block_q + block_q - 1 + offset)
+        run = _block_runs(i, j, block_q, block_k, offset, window)
 
     @pl.when(run)
     def _body():
@@ -304,11 +361,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * sm_scale
         if causal:
-            q_ids = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_ids = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_ids + offset >= k_ids, s, NEG_INF)
+            s = _band_mask(s, i, j, block_q, block_k, offset, window)
         if has_segments:
             s = jnp.where(qseg_ref[0, :, 0][:, None]
                           == kseg_ref[0, :, 0][None, :], s, NEG_INF)
@@ -348,7 +401,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                    causal, sm_scale, block_q, block_k, num_k_blocks, offset,
-                   has_segments=False, dropout_rate=0.0, num_q_blocks=1):
+                   has_segments=False, dropout_rate=0.0, num_q_blocks=1,
+                   window=None):
     rest = list(rest)
     qseg_ref = kseg_ref = seed_ref = None
     if has_segments:
@@ -365,7 +419,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
     run = True
     if causal:
-        run = (j * block_k) <= (i * block_q + block_q - 1 + offset)
+        run = _block_runs(i, j, block_q, block_k, offset, window)
 
     @pl.when(run)
     def _body():
@@ -378,11 +432,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * sm_scale
         if causal:
-            q_ids = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_ids = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_ids + offset >= k_ids, s, NEG_INF)
+            s = _band_mask(s, i, j, block_q, block_k, offset, window)
         if has_segments:
             s = jnp.where(qseg_ref[0, :, 0][:, None]
                           == kseg_ref[0, :, 0][None, :], s, NEG_INF)
@@ -407,7 +457,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
 def _bwd_call(res, g, causal, sm_scale, interpret, n_q_heads=None,
               n_kv_heads=None, segment_ids=None, delta=None,
-              dropout_rate=0.0, dropout_seed=None):
+              dropout_rate=0.0, dropout_seed=None, window=None):
     q, k, v, o, lse = res
     do = g
     bh, s_q, d = q.shape
@@ -434,7 +484,9 @@ def _bwd_call(res, g, causal, sm_scale, interpret, n_q_heads=None,
 
     def q_idx_dkv(b, j, rr, i):
         # b indexes B*Hkv; the q head is the rr-th member of its kv group
-        return ((b // hkv) * hq + (b % hkv) * rep + rr, i, 0)
+        return ((b // hkv) * hq + (b % hkv) * rep + rr,
+                _clamp_q_block(i, j, block_q, block_k, s_q // block_q,
+                               s_k - s_q, window), 0)
 
     def kv_idx_dkv(b, j, rr, i):
         return (b, j, 0)
@@ -471,7 +523,7 @@ def _bwd_call(res, g, causal, sm_scale, interpret, n_q_heads=None,
                           num_q_blocks=s_q // block_q, rep_heads=rep,
                           offset=s_k - s_q, has_segments=has_seg,
                           dropout_rate=dropout_rate, hq=hq, hkv=hkv,
-                          num_k_blocks=s_k // block_k),
+                          num_k_blocks=s_k // block_k, window=window),
         grid=(bh_kv, s_k // block_k, rep, s_q // block_q),
         in_specs=dkv_in_specs,
         out_specs=[
@@ -492,12 +544,14 @@ def _bwd_call(res, g, causal, sm_scale, interpret, n_q_heads=None,
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,
-        metadata={"kernel": "flash_attention", "pass": "dkv"},
+        metadata=_label("dkv", window),
     )(*dkv_args)
     dk, dv = dkv
 
     def kv_idx_dq(b, i, j):
-        return ((b // hq) * hkv + (b % hq) // rep, j, 0)
+        return ((b // hq) * hkv + (b % hq) // rep,
+                _clamp_k_block(i, j, block_q, block_k, s_k // block_k,
+                               s_k - s_q, window), 0)
 
     dq_in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -523,7 +577,7 @@ def _bwd_call(res, g, causal, sm_scale, interpret, n_q_heads=None,
                           block_q=block_q, block_k=block_k,
                           num_k_blocks=s_k // block_k, offset=s_k - s_q,
                           has_segments=has_seg, dropout_rate=dropout_rate,
-                          num_q_blocks=s_q // block_q),
+                          num_q_blocks=s_q // block_q, window=window),
         grid=(bh, s_q // block_q, s_k // block_k),
         in_specs=dq_in_specs,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -534,7 +588,7 @@ def _bwd_call(res, g, causal, sm_scale, interpret, n_q_heads=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        metadata={"kernel": "flash_attention", "pass": "dq"},
+        metadata=_label("dq", window),
     )(*dq_args)
     return dq, dk, dv
 
@@ -544,7 +598,7 @@ def _bwd_call(res, g, causal, sm_scale, interpret, n_q_heads=None,
 # ---------------------------------------------------------------------------
 @functools.lru_cache(maxsize=16)
 def _make_op(causal: bool, interpret: bool, has_segments: bool = False,
-             dropout_rate: float = 0.0):
+             dropout_rate: float = 0.0, window: int = None):
     """has_segments: op takes an extra arg seg [B, S] (f32 segment ids —
     intra-segment attention only, the varlen/flash_attn_unpadded mask;
     f32 so custom_vjp's cotangent contract stays uniform).
@@ -572,7 +626,8 @@ def _make_op(causal: bool, interpret: bool, has_segments: bool = False,
                                                  n_kv_heads=hkv,
                                                  segment_ids=sids,
                                                  dropout_rate=dropout_rate,
-                                                 dropout_seed=seed)
+                                                 dropout_seed=seed,
+                                                 window=window)
         o4 = o.reshape(b, h, s_q, d).transpose(0, 2, 1, 3)
         # name the bwd residuals so a save_only_these_names("fa_res") remat
         # policy keeps them and the backward skips re-running the fwd kernel
@@ -610,7 +665,7 @@ def _make_op(causal: bool, interpret: bool, has_segments: bool = False,
         dq, dk, dv = _bwd_call((qr, kr, vr, o, lse), do, causal, sm_scale,
                                interpret, n_q_heads=h, n_kv_heads=hkv,
                                segment_ids=sids, dropout_rate=dropout_rate,
-                               dropout_seed=seed)
+                               dropout_seed=seed, window=window)
         dq4 = dq.reshape(b, h, s_q, d).transpose(0, 2, 1, 3)
         dk4 = dk.reshape(b, hkv, s_k, d).transpose(0, 2, 1, 3)
         dv4 = dv.reshape(b, hkv, s_k, d).transpose(0, 2, 1, 3)
@@ -667,11 +722,12 @@ def _pad_to_tile(q, k, v, segment_ids):
     return qp, kp, vp, segp, s
 
 
-def flash_attention_ref(q, k, v, causal=False):
+def flash_attention_ref(q, k, v, causal=False, window=None):
     """jnp reference with identical semantics to the kernel's core path
     ([B, S, H, D] layout, GQA via up-materialized K/V, fp32 softmax) — the
     parity tests' oracle and the off-TPU dispatch fallback.  Materializes
-    the [B, H, S, S] score tensor; use the kernel for real workloads."""
+    the [B, H, S, S] score tensor; use the kernel for real workloads.
+    ``window`` (causal only): query i sees keys i-window+1 .. i."""
     d = q.shape[-1]
     if k.shape[2] != q.shape[2]:  # GQA: up-materialize only in the fallback
         rep = q.shape[2] // k.shape[2]
@@ -682,13 +738,15 @@ def flash_attention_ref(q, k, v, causal=False):
     if causal:
         sq, sk = s.shape[-2], s.shape[-1]
         mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq - window)
         s = jnp.where(mask, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
 def flash_attention(q, k, v, causal=False, interpret=False, segment_ids=None,
-                    dropout_rate=0.0, dropout_seed=None):
+                    dropout_rate=0.0, dropout_seed=None, window=None):
     """[B, S, H, D] flash attention; falls back unsupported shapes to the
     caller (returns None so the dispatch default runs).
 
@@ -699,7 +757,17 @@ def flash_attention(q, k, v, causal=False, interpret=False, segment_ids=None,
     dropout_rate/dropout_seed: in-kernel attention-probability dropout
     (per-block regenerable PRNG; the mask never exists in HBM).  seed may
     be a traced scalar — it does not bake into the executable.
+
+    window: optional static int, with causal=True — query i sees keys
+    i-window+1 .. i; blocks wholly outside that band are skipped.  None
+    is the causal kernel as it always was.
     """
+    if window is not None:
+        if not causal or int(window) < 1:
+            raise ValueError("a sliding window needs causal=True and "
+                             f"window >= 1 (got causal={causal}, "
+                             f"window={window})")
+        window = int(window)
     drop = float(dropout_rate or 0.0)
     if drop >= 1.0:
         # torch/paddle semantics: dropout_p == 1 zeroes the output (the
@@ -735,7 +803,7 @@ def flash_attention(q, k, v, causal=False, interpret=False, segment_ids=None,
             from ...core.random import split_key
             dropout_seed = jax.random.randint(split_key(), (), 0, 1 << 23)
         extras += (jnp.asarray(dropout_seed, jnp.float32),)
-    out = _make_op(bool(causal), bool(interpret), has_seg, drop)(
+    out = _make_op(bool(causal), bool(interpret), has_seg, drop, window)(
         q, k, v, *extras)
     if unpad_to is not None:
         out = out[:, :unpad_to]

@@ -143,6 +143,55 @@ def test_flash_attention_forward_backward_compiles(one_chip, dropout):
                           sds((), jnp.int32))
 
 
+def test_windowed_flash_attention_compiles_with_its_label(one_chip):
+    """[1, 8192, 32 / 4 KV, 128], window 2048 — the AFMoE train cell's
+    attention (PR 29): the band's clamped index maps lower through Mosaic,
+    forward and both backward kernels carry ``"window":2048`` in the label a
+    device trace finds them by, and ``window=None`` carries none."""
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+    def loss(window):
+        return lambda q, k, v: flash_attention(
+            q, k, v, causal=True, window=window).astype(jnp.float32).sum()
+
+    sds = _shapes_on(one_chip)
+    q, kv = sds((1, 8192, 32, D), jnp.bfloat16), sds((1, 8192, 4, D),
+                                                     jnp.bfloat16)
+    text = "".join(_compiles_with_kernel(
+        jax.grad(loss(2048), argnums=(0, 1, 2)), q, kv, kv).split())
+    for pass_ in ("fwd", "dkv", "dq"):
+        assert ('kernel_metadata={"kernel":"flash_attention","pass":"%s",'
+                '"window":2048}' % pass_) in text
+    plain = "".join(_compiles_with_kernel(
+        jax.grad(loss(None), argnums=(0, 1, 2)), q, kv, kv).split())
+    assert '"window"' not in plain
+    assert 'kernel_metadata={"kernel":"flash_attention","pass":"dq"}' in plain
+
+
+def test_dropless_expert_layer_compiles_to_grouped_matmul_kernels(one_chip):
+    """8,192 tokens, top-8 of 128, 16 experts of width 1024 held (PR 29):
+    XLA lowers every ``jax.lax.ragged_dot`` — forward and both gradients —
+    to its own Mosaic grouped-matmul kernel, labelled by the attribute
+    ``kernel.moe_gmm_*`` match, and the backward holds no scatter."""
+    from paddle_tpu.incubate.distributed.models.moe import dropless
+
+    def loss(u, router, bias, wg, wu, wd):
+        sel, w = dropless.sigmoid_topk_route(u, router, bias, 8, 2.826)
+        out, rows = dropless.dropless_expert_ffn(u, sel, w, wg, wu, wd, 0,
+                                                 128)
+        return out.astype(jnp.float32).sum() + rows.sum()
+
+    sds = _shapes_on(one_chip)
+    text = _compiles_with_kernel(
+        jax.grad(loss, argnums=(0, 1, 3, 4, 5)),
+        sds((8192, 2048), jnp.bfloat16), sds((2048, 128), jnp.bfloat16),
+        sds((128,), jnp.float32), sds((16, 2048, 1024), jnp.bfloat16),
+        sds((16, 2048, 1024), jnp.bfloat16),
+        sds((16, 1024, 2048), jnp.bfloat16))
+    assert text.count(dropless.TRACE_LABEL) >= 8
+    assert not re.search(r"= \S+ scatter\(", text)
+
+
 # ---------------------------------------------------------------------------
 # The KV page pool stays where it is (PR 28).  On the parent every serving
 # executable sliced a layer out of the pool, relaid it out twice for the
