@@ -263,6 +263,13 @@ class PagePool:
                 self._free.append(p)
 
 
+def _pages_up_to(n: int, page_size: int) -> int:
+    """ceil(m / page_size) summed over m = 1..n: the pages a context
+    growing token by token to ``n`` had to read, over all its steps."""
+    q, r = divmod(n, page_size)
+    return page_size * q * (q + 1) // 2 + r * (q + 1)
+
+
 _ROOT = b"\x00root"                   # parent digest of block 0
 
 
@@ -1049,7 +1056,10 @@ class ServingEngine:
                                        #   computed (T_bucket / C_bucket)
         self.decode_kv_tokens_attended = 0  # KV positions the horizon's
                                        #   live decode steps had to read
-                                       #   (host ints at the drain)
+                                       #   (host ints at the drain) ...
+        self.decode_kv_pages_attended = 0  # ... and the pages that hold
+                                       #   them: what the ragged kernel's
+                                       #   page loop walked
         self.cache_evictions = 0       # cached pages evicted under pressure
         self.cow_copies = 0            # copy-on-write page copies
         self.verify_steps = 0          # speculative verify dispatches
@@ -2315,7 +2325,8 @@ class ServingEngine:
         """The host half of `_drain`: record each lane's fetched tokens
         until its EOS/budget stop, advance the length mirror, retire what
         finished.  Returns the tokens emitted."""
-        total = attended = 0
+        total = attended = pages = 0
+        ps = self.page_size
         lens = self._lengths.tolist()     # host mirror -> python ints
         for lane in rec.lanes:
             s, slot = lane.s, lane.slot
@@ -2344,6 +2355,10 @@ class ServingEngine:
             # KV positions this lane's live decode steps had to read: step
             # j of `emitted` attends base + j (its own fresh row included)
             attended += emitted * base + emitted * (emitted + 1) // 2
+            # ... and the pages holding them, ceil((base + j) / ps) summed
+            # over j = 1..emitted in closed form
+            pages += (_pages_up_to(base + emitted, ps)
+                      - _pages_up_to(base, ps))
             if done:
                 if lane.retiring:
                     self._finish_detached(slot, base + emitted)
@@ -2356,6 +2371,7 @@ class ServingEngine:
                 self._lengths[s] = base + emitted
                 slot.pending = row[emitted - 1]
         self.decode_kv_tokens_attended += attended
+        self.decode_kv_pages_attended += pages
         return total
 
     # -- the serving loop --------------------------------------------------
@@ -2692,6 +2708,7 @@ class ServingEngine:
                       "cache_hit_tokens", "prefill_tokens",
                       "prefill_tokens_dispatched", "prefill_tokens_padded",
                       "decode_kv_tokens_attended",
+                      "decode_kv_pages_attended",
                       "cache_evictions", "cow_copies", "verify_steps",
                       "draft_tokens_proposed", "draft_tokens_accepted",
                       "overlap_steps", "quiesces", "fused_sample_steps",
@@ -3184,6 +3201,7 @@ class ServingEngine:
             "prefill_tokens_dispatched": self.prefill_tokens_dispatched,
             "prefill_tokens_padded": self.prefill_tokens_padded,
             "decode_kv_tokens_attended": self.decode_kv_tokens_attended,
+            "decode_kv_pages_attended": self.decode_kv_pages_attended,
             "cached_prefix_tokens": self.cache_hit_tokens,
             "cache_hits": self.cache_hits,
             "cache_evictions": self.cache_evictions,

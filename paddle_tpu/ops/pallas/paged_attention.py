@@ -20,42 +20,52 @@ so decode, verify, and chunked prefill score through the SAME kernel body
 losslessness guarantee rests on.
 
 Layout (what Mosaic lowers for the v5e — compile-tested at Llama-7B widths in
-tests/test_chip_compile.py; every block spans the last two dims of its array
-whole, the one shape rule the lowering never refuses):
+tests/test_chip_compile.py; every block and every copy spans the last two
+dims of its array whole, the one shape rule the lowering never refuses):
 
   q          [S, Qmax, Hq, D]    ragged query segments, right-padded to Qmax;
                                  relaid OUTSIDE the kernel to kv-head-major
                                  rows [S, Hkv, R, D] (R = Qmax*rep rounded up
-                                 to 8 sublanes), and the output laid back
+                                 to 8 sublanes; block (1, Hb, R, D)), and the
+                                 output laid back
   k_pages    [L, Hkv, NP, ps, D] the WHOLE page pool, every layer of it (or
   v_pages    [L, Hkv, NP, ps, D] one layer [Hkv, NP, ps, D]); last two dims
                                  are the (sublane, lane) tile => D=128-friendly
-  k/v_scales [L, Hkv, NP, ps]    this layer's passed as [1, Hkv, NP, 1, ps]: a
-                                 (1, ps) tile
+  k/v_scales [L, Hkv, NP, ps]    this layer's passed as [1, Hkv, NP, 1, lanes]:
+                                 a (1, ps) tile padded to whole lane tiles
   layer      scalar int32        which layer of the pool this call attends
   page_table [S, P] int32        physical page of each logical page slot
   q_start    [S]   int32         absolute position of query 0 per slot
   q_len      [S]   int32         valid queries per slot (0 = inactive)
   kv_len     [S]   int32         total valid KV tokens (incl. the segment)
 
-Grid: (S, Hkv, P) with the page dim innermost ("arbitrary" semantics) so
-the per-slot online-softmax scratch survives across a sequence's pages.
-The page table, the segment descriptors and the layer index ride scalar
-prefetch (`pltpu.PrefetchScalarGridSpec`), so the K/V BlockSpec index maps
-resolve the PHYSICAL (layer, page) to DMA before the kernel body runs — the
-indirection costs no kernel time, and the model's layer loop never slices a
-layer out of the pool (a slice is a copy of that layer, every layer, every
-step).  GQA is native: the q block for grid step (s, h) is
-the R rows (every query of the segment x the `Hq // Hkv` heads sharing kv
-head h), and K/V pages are fetched once per kv head, never materialized per
-q head.  At q_len = 1 with MHA that block is ONE real row padded to 8 — it
-compiles and is correct; how well it feeds the MXU has not been measured.
+Grid: (S, Hkv / Hb) — one step per slot and block of Hb kv heads, Hb every
+head that fits VMEM (`_choose_heads`: all 8 at the 7B decode, verify and
+512-query chunk shapes).  Inside a step a `fori_loop` runs over the slot's
+LIVE pages only, ceil(kv_len / ps) of them, with the per-slot online-softmax
+scratch carried across the pages.  K/V stay in HBM (`pl.ANY`): the page
+table, the segment descriptors and the layer index ride scalar prefetch
+(`pltpu.PrefetchScalarGridSpec`), and the kernel itself copies the PHYSICAL
+(layer, head block, page) tile of each live page into one of two VMEM
+buffers (`pltpu.make_async_copy`, every head of the block in one strided
+copy), one page ahead of the update that reads it and across the boundary
+to the next step's first page — the indirection costs no kernel time, and
+the model's layer loop never slices a layer out of the pool (a slice is a
+copy of that layer, every layer, every step).  GQA is native: the q block of
+a step is, per kv head, the R rows (every query of the segment x the
+`Hq // Hkv` heads sharing that kv head), and K/V pages are fetched once per
+kv head, never materialized per q head.  Measured on the chip (PR 30,
+perf/ragged_kernel_probe.py, PERF.md section 5): at the chat cell's
+contexts the decode call reads its live K/V at 24 % of the HBM roofline
+(4.2 % when the grid was (S, Hkv, P), one 16 KiB tile a step and a step per
+table column, dead or live); what is left is the f32 products of an 8-row
+block (4 real rows at GQA 32/8) against each [64, 128] page.
 
-Pages past a slot's `kv_len` are skipped via `pl.when` (their table entries
-are clamped to a valid page id by the cache manager, so the speculative DMA
-stays in bounds); partial pages and the causal frontier are mask-tailed
-inside the kernel.  Padding query rows (>= q_len) and inactive slots
-(q_len = 0) produce exact zeros, matching the reference.
+A page past a slot's `kv_len` costs nothing: no grid step, no copy, and its
+table entry is never read (so the cache manager may leave anything there);
+partial pages and the causal frontier are mask-tailed inside the kernel.
+Padding query rows (>= q_len) and inactive slots (q_len = 0) produce exact
+zeros, matching the reference.
 
 int8/fp8 pages (`k_scales`/`v_scales`) dequantize INSIDE the kernel for
 every path — the per-(page, head, token-row) scale pages ride the same
@@ -80,15 +90,18 @@ NEG_INF = -1e30
 _SUBLANES = 8      # f32 sublane count: query-row blocks pad to this
 
 
-def _attend_page(q, k, v, mask, sm_scale, m_scr, l_scr, acc_scr,
+def _attend_page(q, k, v, mask, sm_scale, h, m_scr, l_scr, acc_scr,
                  k_scale=None, v_scale=None):
     """One online-softmax update over one f32 K/V page — shared by the
-    plain and fused-dequant kernel bodies so the accumulator math can never
+    plain and fused-dequant bodies so the accumulator math can never
     drift between them.  ``q`` is the [R, D] query-row block (row r = query
     r // rep, head r % rep of the kv group), ``mask`` the [R, ps] validity
-    of each (query row, kv position) pair; a row with no valid position
-    EVER (a padding query or a sublane-padding row) keeps m = NEG_INF and
-    l = 0, so the finalizer emits exact zeros for it.
+    of each (query row, kv position) pair, ``h`` the kv head's place in
+    the step's [Hb, R, 1] / [Hb, R, 1] / [Hb, R, D] running max, sum and
+    accumulator (indexed, not viewed: Mosaic refuses a ref slice of a
+    lane-padded [.., 1] scratch); a row
+    with no valid position EVER (a padding query or a sublane-padding row)
+    keeps m = NEG_INF and l = 0, so the finalizer emits exact zeros for it.
 
     ``k_scale``/``v_scale`` ([1, ps], quantized pages only) are the page's
     per-row dequant scales, applied on the [R, ps] score side — q·(k_t·s_t)
@@ -101,20 +114,20 @@ def _attend_page(q, k, v, mask, sm_scale, m_scr, l_scr, acc_scr,
     if k_scale is not None:
         s = s * k_scale
     s = jnp.where(mask, s, NEG_INF)
-    m_prev = m_scr[:]                                      # [R, 1]
+    m_prev = m_scr[h]                                      # [R, 1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     # re-mask p explicitly: on a row whose every position is masked,
     # exp(NEG_INF - NEG_INF) would be 1, silently averaging garbage V rows
     # into the padding-query output
     p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
     alpha = jnp.exp(m_prev - m_new)
-    l_scr[:] = alpha * l_scr[:] + jnp.sum(p, axis=1, keepdims=True)
+    l_scr[h] = alpha * l_scr[h] + jnp.sum(p, axis=1, keepdims=True)
     if v_scale is not None:
         p = p * v_scale
-    acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+    acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
-    m_scr[:] = m_new
+    m_scr[h] = m_new
 
 
 def _segment_mask(shape, i, page_size, rep, q_start, q_len, kv_len):
@@ -128,48 +141,27 @@ def _segment_mask(shape, i, page_size, rep, q_start, q_len, kv_len):
     return (col <= q_start + qi) & (qi < q_len) & (col < kv_len)
 
 
-def _finalize_out(i, n_pages, o_ref, m_scr, l_scr, acc_scr):
-    @pl.when(i == n_pages - 1)
-    def _finalize():
-        l = l_scr[:]
-        inv = jnp.where(l > 0.0, 1.0 / jnp.where(l > 0.0, l, 1.0), 0.0)
-        o_ref[0, 0] = (acc_scr[:] * inv).astype(o_ref.dtype)
+def _ragged_kernel(pt_ref, qs_ref, ql_ref, kl_ref, ly_ref, q_ref, *refs,
+                   page_size, sm_scale, rep, heads, table_width, quant):
+    """One grid step = one (slot b, block of ``heads`` kv heads): a loop
+    over the slot's LIVE pages only, one ``_attend_page`` per (head, page)
+    in page order — the update sequence a query row sees does not depend on
+    ``heads`` (tests/test_pallas_kernels.py holds every blocking BIT-equal).
+    The pages stay in HBM (``pl.ANY``) and the kernel copies them itself,
+    double-buffered: page c of the step lands in buffer (base + c) % 2 —
+    every head of the block in ONE strided copy — and is sent for while
+    page c - 1 is attended; the step's last update runs over the NEXT
+    step's first copy, so a slot boundary waits out no whole DMA either.
+    ``base`` (SMEM) passes from step to step; whether a step's first page
+    is already on its way it reads off the two steps' live pages.
 
+    ``refs``: the HBM sources (k, v — or k, k_scale, v, v_scale with
+    ``quant``), the output block, one [2, heads, ...] buffer per source,
+    the DMA semaphores [source, buffer], ``base``, and the three scratch
+    refs.
 
-def _init_scratch(i, m_scr, l_scr, acc_scr):
-    @pl.when(i == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-
-def _ragged_kernel(pt_ref, qs_ref, ql_ref, kl_ref, ly_ref, q_ref, k_ref,
-                   v_ref, o_ref, m_scr, l_scr, acc_scr, *, page_size,
-                   sm_scale, rep):
-    b = pl.program_id(0)          # sequence slot
-    i = pl.program_id(2)          # logical page index (innermost, reduction)
-    n_pages = pl.num_programs(2)
-    _init_scratch(i, m_scr, l_scr, acc_scr)
-    q_start, q_len, kv_len = qs_ref[b], ql_ref[b], kl_ref[b]
-
-    @pl.when(i * page_size < kv_len)
-    def _body():
-        q = q_ref[0, 0].astype(jnp.float32)
-        mask = _segment_mask((q.shape[0], page_size), i, page_size, rep,
-                             q_start, q_len, kv_len)
-        _attend_page(q, k_ref[0, 0, 0].astype(jnp.float32),
-                     v_ref[0, 0, 0].astype(jnp.float32),
-                     mask, sm_scale, m_scr, l_scr, acc_scr)
-
-    _finalize_out(i, n_pages, o_ref, m_scr, l_scr, acc_scr)
-
-
-def _ragged_kernel_quant(pt_ref, qs_ref, ql_ref, kl_ref, ly_ref, q_ref,
-                         k_ref, ks_ref, v_ref, vs_ref, o_ref, m_scr, l_scr,
-                         acc_scr, *, page_size, sm_scale, rep):
-    """Fused-dequant variant: K/V pages arrive in their int8/fp8 STORAGE
-    dtype plus a per-row f32 absmax scale page ([1, ps] per page), and the
+    ``quant``: K/V pages arrive in their int8/fp8 STORAGE dtype plus a
+    per-row f32 absmax scale tile ([1, ps] per head and page), and the
     dequant happens here, on the page tile already resident in VMEM —
     quantized K/V never materialize as an f32 tensor anywhere (DTYPE001
     polices the host-side paths).  The row scales are applied on the score
@@ -177,38 +169,140 @@ def _ragged_kernel_quant(pt_ref, qs_ref, ql_ref, kl_ref, ly_ref, q_ref,
     ``serving.quant.dequantize_kv``'s astype-f32-times-row-scale, in another
     association order, so the kernel matches the jnp gather paths to f32
     rounding — on EVERY dispatch path, not just decode."""
-    b = pl.program_id(0)
-    i = pl.program_id(2)
-    n_pages = pl.num_programs(2)
-    _init_scratch(i, m_scr, l_scr, acc_scr)
+    n_src = 4 if quant else 2
+    srcs, o_ref = refs[:n_src], refs[n_src]
+    bufs = refs[n_src + 1:2 * n_src + 1]
+    sem, base_ref, m_scr, l_scr, acc_scr = refs[2 * n_src + 1:]
+    b, hb = pl.program_id(0), pl.program_id(1)
+    n_hb = pl.num_programs(1)
+    n_steps = pl.num_programs(0) * n_hb
+    step = b * n_hb + hb
+
+    def live_pages(slot):
+        # a table is all a slot can own, whatever kv_len claims
+        return pl.cdiv(jnp.minimum(kl_ref[slot], table_width * page_size),
+                       page_size)
+
+    def copies(slot, head_block, col, buf):
+        """the copies of logical page ``col`` of (slot, head block) into
+        buffer ``buf``: ``heads`` [ps, D] tiles (and lane-padded [1, ps]
+        scale tiles) each, a layer and a page apart in the pool"""
+        page = pt_ref[slot, col]
+        first = head_block * heads
+        for t, (src, dst) in enumerate(zip(srcs, bufs)):
+            # the scale sources hold this call's layer only
+            lead = 0 if quant and t % 2 else ly_ref[0]
+            yield pltpu.make_async_copy(
+                src.at[lead, pl.ds(first, heads), page], dst.at[buf],
+                sem.at[t, buf])
+
+    def send(*page):
+        for c in copies(*page):
+            c.start()
+
+    @pl.when(step == 0)
+    def _first():
+        base_ref[0] = 0
+
+    base = base_ref[0]
+    n_live = live_pages(b)
+    # a step with live pages sends for the first page of the step after it,
+    # if that one has any
+    prev, nxt = jnp.maximum(step - 1, 0), jnp.minimum(step + 1, n_steps - 1)
+    has_next = (step + 1 < n_steps) & (live_pages(nxt // n_hb) > 0)
+    on_its_way = (step > 0) & (live_pages(prev // n_hb) > 0)
+
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when((n_live > 0) & jnp.logical_not(on_its_way))
+    def _cold():                  # no step before this one sent for page 0
+        send(b, hb, 0, base)
+
     q_start, q_len, kv_len = qs_ref[b], ql_ref[b], kl_ref[b]
+    rows = q_ref.shape[2]
 
-    @pl.when(i * page_size < kv_len)
-    def _body():
-        q = q_ref[0, 0].astype(jnp.float32)
-        mask = _segment_mask((q.shape[0], page_size), i, page_size, rep,
-                             q_start, q_len, kv_len)
-        _attend_page(q, k_ref[0, 0, 0].astype(jnp.float32),
-                     v_ref[0, 0, 0].astype(jnp.float32),
-                     mask, sm_scale, m_scr, l_scr, acc_scr,
-                     k_scale=ks_ref[0, 0, 0].astype(jnp.float32),
-                     v_scale=vs_ref[0, 0, 0].astype(jnp.float32))
+    def attend(c, carry):
+        buf = (base + c) % 2
 
-    _finalize_out(i, n_pages, o_ref, m_scr, l_scr, acc_scr)
+        @pl.when(c + 1 < n_live)
+        def _ahead():
+            send(b, hb, c + 1, 1 - buf)
+
+        @pl.when((c + 1 == n_live) & has_next)
+        def _next_step():
+            send(nxt // n_hb, nxt % n_hb, 0, 1 - buf)
+
+        for cp in copies(b, hb, c, buf):
+            cp.wait()
+        mask = _segment_mask((rows, page_size), c, page_size, rep, q_start,
+                             q_len, kv_len)
+        for h in range(heads):
+            tiles = [dst[buf, h].astype(jnp.float32) for dst in bufs]
+            scales = dict(k_scale=tiles[1][:, :page_size],
+                          v_scale=tiles[3][:, :page_size]) if quant else {}
+            _attend_page(q_ref[0, h].astype(jnp.float32), tiles[0],
+                         tiles[n_src // 2], mask, sm_scale, h, m_scr, l_scr,
+                         acc_scr, **scales)
+        return carry
+
+    jax.lax.fori_loop(0, n_live, attend, 0)
+    base_ref[0] = (base + n_live) % 2
+    for h in range(heads):
+        l = l_scr[h]
+        inv = jnp.where(l > 0.0, 1.0 / jnp.where(l > 0.0, l, 1.0), 0.0)
+        o_ref[0, h] = (acc_scr[h] * inv).astype(o_ref.dtype)
+
+
+# What the kernel may use of the v5e's 128 MiB of VMEM (Mosaic's scoped
+# default is 16 MiB), and what `_choose_heads` lets its own count reach: the
+# q / out blocks (double-buffered by the pipeline), the page buffers, the
+# scratch with its lane-padded m / l columns and one update's f32
+# temporaries.  The count runs high (8 heads of the 512-query chunk shape
+# count 44 MiB and ran on the chip under a 32 MiB limit: PERF.md section 6,
+# PR 30), so three quarters of the limit is its room.
+_VMEM_LIMIT = 64 << 20
+_VMEM_BUDGET = _VMEM_LIMIT * 3 // 4
+_LANES = 128
+
+
+def _choose_heads(rows_pad, hkv, d, page_size, q_bytes, out_bytes, kv_bytes,
+                  *, quant):
+    """kv heads one grid step carries, from the call's shapes alone: the
+    most that divide ``hkv`` and fit the budget.
+
+    The algorithm is one — an online-softmax update per (head, page) — but
+    what a step holds differs by two orders of magnitude between the
+    dispatch shapes: at decode and verify a head's query rows are 8-24, at
+    the 512-query chunk shape 2,048, where one head's q, out, accumulator
+    and m / l columns count 5 MiB.  More heads a step are fewer, larger
+    page copies and independent work for the scheduler: on the chip every
+    shape gained with every doubling (PERF.md section 5)."""
+    wide = max(page_size, _LANES)
+    per_head = rows_pad * (2 * d * q_bytes + 2 * d * out_bytes   # q, out
+                           + 4 * d + 2 * 4 * _LANES)             # acc, m, l
+    per_head += 2 * 2 * page_size * d * kv_bytes                 # K, V x 2
+    if quant:
+        per_head += 2 * 2 * _SUBLANES * wide * 4                 # scale tiles
+    update = 4 * (rows_pad * (d + 3 * wide) + 2 * page_size * d)
+    return max([h for h in range(1, hkv + 1) if hkv % h == 0
+                and h * per_head + update <= _VMEM_BUDGET] or [1])
 
 
 def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start, q_len,
                            kv_len, sm_scale=None, interpret=False,
                            out_dtype=None, k_scales=None, v_scales=None,
-                           *, role=None, layer=None):
+                           *, role=None, layer=None, _heads=None):
     """Ragged-segment paged attention over each slot's page list.
 
     q [S, Qmax, Hq, D], page_table [S, P] int32 (entries past a slot's
-    pages must hold any in-range page id), q_start/q_len/kv_len [S] int32
-    -> o [S, Qmax, Hq, D].  Query j of slot s sits at absolute position
-    q_start[s] + j and attends kv positions <= its own (and < kv_len[s]);
-    rows past q_len[s] — and every row of a q_len = 0 slot — come back
-    exactly zero.  Requires Hq % Hkv == 0.
+    pages are never read here; the ``*_ref`` gathers the whole table, so
+    callers of both keep them in range), q_start / q_len / kv_len [S] int32
+    -> o [S, Qmax, Hq, D].  Query j of slot s sits
+    at absolute position q_start[s] + j and attends kv positions <= its own
+    (and < kv_len[s]); rows past q_len[s] — and every row of a q_len = 0
+    slot — come back exactly zero.  Requires Hq % Hkv == 0.
 
     k_pages/v_pages come in one of two forms:
 
@@ -217,18 +311,30 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start, q_len,
       layer=<int32>  [L, Hkv, NP, ps, D]: the WHOLE pool, and ``layer`` (a
                      traced scalar: the model's layer loop index) names the
                      layer this call attends.  It rides scalar prefetch
-                     after the four descriptors and the K/V index map
-                     returns ``(layer, h, page_table[b, i], 0, 0)``, so the
-                     layer is picked by the page DMA itself.  Slicing
-                     ``pool[layer]`` outside instead hands XLA a
+                     after the four descriptors and every page copy
+                     reads ``pool[layer, head block, page_table[b, c]]``,
+                     so the layer is picked by the page DMA itself.
+                     Slicing ``pool[layer]`` outside instead hands XLA a
                      layer-sized copy per call (PERF.md section 6, PR 28).
 
     Same grid, masks and arithmetic either way (the 4-D form is the 5-D one
-    with L = 1).  The custom call keeps a fixed outline that the
-    benchmark's trace readers match by shape: its FIRST operand is the
-    [S, P] page table and it has ONE array result [S, Hkv, rows_pad, D] —
-    so no ``input_output_aliases`` here (a tuple result), and new scalars
-    go AFTER the four that exist.
+    with L = 1).
+
+    Blocking (PR 30).  The grid is (S, Hkv / Hb): a step is one slot and
+    Hb kv heads — every head that fits, chosen by ``_choose_heads`` from
+    the shapes (8 of 8 at the 7B decode, verify and 512-query chunk shapes)
+    — and loops over the slot's live pages, ceil(kv_len / ps) of them,
+    copying each out of the pool itself (``pl.ANY`` operands, a
+    double-buffered ``make_async_copy`` of all Hb heads of a page).  The
+    table's dead columns cost nothing: no step, no copy, and no read of
+    what the table holds there.  The per-row arithmetic does not depend on
+    Hb.
+
+    The custom call keeps a fixed outline that the benchmark's trace
+    readers match by shape: its FIRST operand is the [S, P] page table and
+    it has ONE array result [S, Hkv, rows_pad, D] — so no
+    ``input_output_aliases`` here (a tuple result), and new scalars go
+    AFTER the four that exist.
 
     out_dtype: output dtype (default q.dtype).  Accumulation is f32 either
     way; pass jnp.float32 with bf16 inputs to read the un-downcast result
@@ -246,6 +352,9 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start, q_len,
     call's HLO text, which is the name of its event on a device trace's
     "XLA Ops" line, carries ``kernel_metadata={"kernel":
     "ragged_paged_attention","role":...}``.
+
+    _heads: Hb forced — for the tests that hold every blocking bit-equal
+    and for perf/ragged_kernel_probe.py; callers leave it alone.
 
     Head-sharded (TP) dispatch: every shape here may be the mp-LOCAL
     shard — Hq = nh/tp query heads against Hkv = nkv/tp KV-head pages.
@@ -276,10 +385,12 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start, q_len,
     rep = hq // hkv
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
+    out_dtype = out_dtype or q.dtype
+    quant = k_scales is not None
 
     # kv-head-major query rows: [S, Qmax, Hq, D] -> [S, Hkv, R, D] with row
     # r = query r // rep, head r % rep of the group, zero-padded to a
-    # sublane multiple.  The (1, 1, R, D) block then spans the array's last
+    # sublane multiple.  The (1, Hb, R, D) block then spans the array's last
     # two dims whole, which is what the Mosaic lowering accepts for any
     # rep/qmax (a (qmax, rep, D) block out of [.., Hq, D] is refused: rep
     # is neither a multiple of 8 nor Hq), and the kernel body needs no
@@ -291,59 +402,61 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start, q_len,
         .reshape(s_slots, hkv, rows, d)
     qr = jnp.pad(qr, ((0, 0), (0, 0), (0, rows_pad - rows), (0, 0)))
 
-    grid = (s_slots, hkv, n_ptab)
+    if _heads is not None and hkv % _heads:
+        raise ValueError(f"{_heads} heads a step do not divide {hkv}")
+    heads = _heads or _choose_heads(
+        rows_pad, hkv, d, page_size, q.dtype.itemsize,
+        jnp.dtype(out_dtype).itemsize, k_pages.dtype.itemsize, quant=quant)
 
-    def q_idx(b, h, i, pt, qs, ql, kl, ly):
-        return (b, h, 0, 0)
-
-    def kv_idx(b, h, i, pt, qs, ql, kl, ly):
-        return (ly[0], h, pt[b, i], 0, 0)
-
-    q_spec = pl.BlockSpec((1, 1, rows_pad, d), q_idx)
-    kv_spec = pl.BlockSpec((1, 1, 1, page_size, d), kv_idx)
-    quant = k_scales is not None
+    q_spec = pl.BlockSpec((1, heads, rows_pad, d),
+                          lambda b, h, pt, qs, ql, kl, ly: (b, h, 0, 0))
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     if quant:
-        # scale pages ride as [1, Hkv, NP, 1, ps]: a (1, ps) tile per page
-        # (again whole last-two dims), lane-major like the scores it scales.
-        # That tile is a relayout of the array, so it is made of THIS
-        # layer's scales only (4 B a token row against D codes: a 64th of
-        # a layer of int8 data) — the data pages are never sliced
-        def sc_idx(b, h, i, pt, qs, ql, kl, ly):
-            return (0, h, pt[b, i], 0, 0)
+        # scale pages ride as [1, Hkv, NP, 1, lanes]: a (1, ps) tile per
+        # head and page, lane-major like the scores it scales, padded to
+        # whole lane tiles (a copy out of HBM moves nothing narrower).  That
+        # is a relayout of the array, so it is made of THIS layer's scales
+        # only (4 B a token row against D codes: a 64th of a layer of int8
+        # data) — the data pages are never sliced
+        lanes = -(-page_size // _LANES) * _LANES
 
         def layer_tiles(scales):
-            return jax.lax.dynamic_index_in_dim(
+            tiles = jax.lax.dynamic_index_in_dim(
                 scales, layer, 0, keepdims=True)[:, :, :, None, :]
+            return jnp.pad(tiles, ((0, 0),) * 4 + ((0, lanes - page_size),))
 
-        sc_spec = pl.BlockSpec((1, 1, 1, 1, page_size), sc_idx)
-        in_specs = [q_spec, kv_spec, sc_spec, kv_spec, sc_spec]
         inputs = (qr, k_pages, layer_tiles(k_scales),
                   v_pages, layer_tiles(v_scales))
-        body = _ragged_kernel_quant
+        buffers = [pltpu.VMEM((2, heads, page_size, d), k_pages.dtype),
+                 pltpu.VMEM((2, heads, 1, lanes), jnp.float32)] * 2
     else:
-        in_specs = [q_spec, kv_spec, kv_spec]
         inputs = (qr, k_pages, v_pages)
-        body = _ragged_kernel
+        buffers = [pltpu.VMEM((2, heads, page_size, d), k_pages.dtype)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
-        grid=grid,
-        in_specs=in_specs,
+        grid=(s_slots, hkv // heads),
+        in_specs=[q_spec] + [in_hbm] * (len(inputs) - 1),
         out_specs=q_spec,
-        scratch_shapes=[
-            pltpu.VMEM((rows_pad, 1), jnp.float32),
-            pltpu.VMEM((rows_pad, 1), jnp.float32),
-            pltpu.VMEM((rows_pad, d), jnp.float32),
+        scratch_shapes=buffers + [
+            pltpu.SemaphoreType.DMA((len(buffers), 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((heads, rows_pad, 1), jnp.float32),
+            pltpu.VMEM((heads, rows_pad, 1), jnp.float32),
+            pltpu.VMEM((heads, rows_pad, d), jnp.float32),
         ],
     )
-    kernel = functools.partial(body, page_size=page_size,
-                               sm_scale=sm_scale, rep=rep)
+    kernel = functools.partial(
+        _ragged_kernel, page_size=page_size, sm_scale=sm_scale, rep=rep,
+        heads=heads, table_width=n_ptab, quant=quant)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_slots, hkv, rows_pad, d),
-                                       out_dtype or q.dtype),
+                                       out_dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+            # which buffer is next passes from step to step: in order
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         metadata={"kernel": "ragged_paged_attention",
                   **({"role": role} if role else {})},

@@ -92,6 +92,48 @@ def test_ragged_paged_attention_compiles(one_chip, slots, qmax, hq, hkv):
                      text)
 
 
+# name: (slots, q_len, Hq, Hkv, rows of a kv head padded to 8, role)
+_CELL_KERNEL = {"decode": (16, 1, 32, 8, 8, "decode"),
+                "chunk": (1, 512, 32, 8, 2048, "chunk"),
+                "verify": (16, 5, 32, 8, 24, "verify"),
+                "tp4_rank_decode": (16, 1, 8, 2, 8, "decode")}
+
+
+@pytest.mark.parametrize("case", list(_CELL_KERNEL))
+def test_ragged_kernel_keeps_its_outline_at_the_cells_shapes(one_chip, case):
+    """`serve_chat_c16`'s own calls (PR 30): 16 slots x a table of 32 on the
+    5-D pool `bf16[16, 8, 513, 64, 128]` with the layer TRACED, the 1 x 512
+    chunk, the 16 x 5 verify and a TP=4 rank's two kv heads — whatever
+    (heads, ring depth) the kernel chose for them.  The compiled call keeps
+    the outline the benchmark's matchers read: the `[S, P]` page table its
+    FIRST operand, the whole pool handed in (K and V once each, no slice),
+    ONE array result `[S, Hkv, rows_pad, D]`, the label with its role."""
+    from paddle_tpu.ops.pallas.paged_attention import ragged_paged_attention
+
+    slots, q_len, hq, hkv, rows_pad, role = _CELL_KERNEL[case]
+    sds = _shapes_on(one_chip)
+    pool = sds((16, hkv, 16 * TABLE + 1, PAGE, D), jnp.bfloat16)
+    seg = sds((slots,), jnp.int32)
+    text = _compiles_with_kernel(
+        lambda q, k, v, t, a, b, c, layer: ragged_paged_attention(
+            q, k, v, t, a, b, c, role=role, layer=layer),
+        sds((slots, q_len, hq, D), jnp.bfloat16), pool, pool,
+        sds((slots, TABLE), jnp.int32), seg, seg, seg, sds((), jnp.int32))
+    pool_text = r"bf16\[16,%d,513,64,128\]\{4,3,2,1,0\}" % hkv
+    (call,) = [line for line in text.splitlines()
+               if "tpu_custom_call" in line]
+    assert re.search(
+        r"= bf16\[%d,%d,%d,128\]\S* custom-call\(" % (slots, hkv, rows_pad)
+        + r"[^)]*\), custom_call_target=\"tpu_custom_call\", "
+        r"operand_layout_constraints=\{s32\[%d,32\]\{1,0\}, " % slots
+        + r"(s32\[\d+\]\{0\}, ){4}bf16\[%d,%d,%d,128\]\{3,2,1,0\}, "
+        % (slots, hkv, rows_pad) + pool_text + ", " + pool_text + r"\}",
+        call), call[:900]
+    # (jax writes the label's JSON with a newline after every item)
+    assert ('kernel_metadata={"kernel":"ragged_paged_attention","role":"%s"}'
+            % role) in "".join(text.split())
+
+
 @pytest.mark.parametrize("storage", [jnp.int8, jnp.float8_e4m3fn],
                          ids=["int8", "fp8"])
 @pytest.mark.parametrize("slots,qmax", [(SLOTS, 1), (1, 128)],
@@ -273,6 +315,13 @@ def test_serving_executable_leaves_the_page_pool_in_place(cell_programs,
             r'operand_layout_constraints=\{s32\[\d+,32\]', text)
         assert re.search(
             r'kernel_metadata=\{\s*"kernel":"ragged_paged_attention"', text)
+        # and the kernel takes the pool itself, K and V, not a slice of it
+        # (it copies the pages it attends out of HBM on its own: PR 30)
+        assert re.search(
+            r"custom_call_target=\"tpu_custom_call\", "
+            r"operand_layout_constraints=\{s32\[\d+,32\].*"
+            r"(, bf16\[%d,8,513,64,128\]\{4,3,2,1,0\}){2}\}" % CELL_LAYERS,
+            text)
     _assert_pool_stays_in_place(
         compiled, (CELL_LAYERS, HKV, CELL["num_slots"] * TABLE + 1, PAGE, D))
 

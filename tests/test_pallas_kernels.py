@@ -738,3 +738,150 @@ def test_ragged_paged_attention_pool_layer_form_is_bit_equal(shape, quant,
         np.testing.assert_array_equal(np.asarray(pool),
                                       np.asarray(layer_form(jnp.int32(li))))
     assert np.asarray(pool).any()
+
+
+# ---------------------------------------------------------------------------
+# The re-blocked kernel (PR 30): a grid step is one (slot, block of Hb kv
+# heads) and loops over the slot's LIVE pages, which it copies out of HBM
+# itself into two buffers in turn; Hb comes from the shapes.
+# One sweep holds the kernel to the ref over role x head layout x pool form
+# with every kv_len edge in ONE batch; one sweep holds every blocking
+# BIT-equal to the one-head step
+# ---------------------------------------------------------------------------
+_RB = dict(D=64, ps=16, NP=23, P=9, L=2)
+_RB_KV_LEN = [0, 1, 2 * 16, 9 * 16, 37]        # empty, one token, a page
+_RB_ROLES = {"decode": 1, "verify": 5, "chunk": 128}   # multiple, full, ragged
+_RB_HEADS = {"mha8x8": (8, 8), "gqa8x2_tp_local": (8, 2), "gqa16x4": (16, 4)}
+
+
+def _reblocked_case(qmax, hq, hkv, pooled, dtype, quant=False, seed=41):
+    from paddle_tpu.serving.quant import kv_spec, quantize_kv
+    D, ps, NP, P, L = (_RB[k] for k in ("D", "ps", "NP", "P", "L"))
+    lr = np.random.default_rng(seed)
+    kv_len = np.array(_RB_KV_LEN, np.int32)
+    q_len = np.minimum(qmax, kv_len)
+    S = len(kv_len)
+    q = jnp.asarray(lr.standard_normal((S, qmax, hq, D)), dtype)
+    pool = (L, hkv, NP, ps, D) if pooled else (hkv, NP, ps, D)
+    kp = jnp.asarray(lr.standard_normal(pool), dtype)
+    vp = jnp.asarray(lr.standard_normal(pool), dtype)
+    # live columns name real pages; DEAD ones an id past the pool's end —
+    # the kernel never reads a dead entry, whatever it holds
+    pt = lr.integers(0, NP, (S, P)).astype(np.int32)
+    pt[np.arange(P)[None, :] * ps >= kv_len[:, None]] = 10 ** 6
+    kw = {"layer": jnp.int32(L - 1)} if pooled else {}
+    if quant:
+        storage, qm = kv_spec("int8")
+        kp, kw["k_scales"] = quantize_kv(kp.astype(jnp.float32), qmax=qm,
+                                         dtype=storage)
+        vp, kw["v_scales"] = quantize_kv(vp.astype(jnp.float32), qmax=qm,
+                                         dtype=storage)
+    args = (q, kp, vp, jnp.asarray(pt), jnp.asarray(kv_len - q_len),
+            jnp.asarray(q_len), jnp.asarray(kv_len))
+    return args, kw
+
+
+def _ref_with_dead_entries_in_range(args, kw):
+    """the ref GATHERS the whole table, so hand it the dead entries as page
+    0: its mask drops them"""
+    from paddle_tpu.ops.pallas.paged_attention import (
+        ragged_paged_attention_ref)
+    pt = jnp.where(args[3] < _RB["NP"], args[3], 0)
+    return ragged_paged_attention_ref(*args[:3], pt, *args[4:],
+                                      out_dtype=jnp.float32, **kw)
+
+
+@pytest.mark.parametrize("pooled", [False, True], ids=["4d", "5d_layer"])
+@pytest.mark.parametrize("heads", list(_RB_HEADS))
+@pytest.mark.parametrize("role", list(_RB_ROLES))
+def test_ragged_reblocked_parity(role, heads, pooled):
+    """bf16 pages and queries, f32 accumulation read un-downcast: the
+    present bf16→f32 bound (2e-4), whatever Hb the chooser picked."""
+    from paddle_tpu.ops.pallas.paged_attention import ragged_paged_attention
+    args, kw = _reblocked_case(_RB_ROLES[role], *_RB_HEADS[heads], pooled,
+                               jnp.bfloat16)
+    out = np.asarray(ragged_paged_attention(
+        *args, interpret=True, out_dtype=jnp.float32, role=role, **kw))
+    np.testing.assert_allclose(
+        out, np.asarray(_ref_with_dead_entries_in_range(args, kw)),
+        rtol=2e-4, atol=2e-4)
+    q_len = np.asarray(args[5])
+    assert not out[0].any()                        # the q_len = 0 slot
+    for s, n in enumerate(q_len):
+        assert not out[s, n:].any() and (n == 0 or out[s, :n].any())
+
+
+def test_ragged_reblocked_parity_quantized():
+    from paddle_tpu.ops.pallas.paged_attention import ragged_paged_attention
+    args, kw = _reblocked_case(5, 8, 2, True, jnp.float32, quant=True)
+    out = ragged_paged_attention(*args, interpret=True,
+                                 out_dtype=jnp.float32, **kw)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_ref_with_dead_entries_in_range(args, kw)),
+        rtol=2e-5, atol=2e-5)
+
+
+@functools.lru_cache(maxsize=None)
+def _one_head_step(quant):
+    from paddle_tpu.ops.pallas.paged_attention import ragged_paged_attention
+    args, kw = _reblocked_case(5, 8, 4, True, jnp.float32, quant=quant)
+    return np.asarray(ragged_paged_attention(*args, interpret=True,
+                                             _heads=1, **kw))
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["plain", "int8"])
+@pytest.mark.parametrize("heads", [2, 4])
+def test_ragged_blockings_are_bit_equal(heads, quant):
+    """Every blocking `_choose_heads` can return for 4 kv heads gives the
+    SAME bits as the one-head step: a query row's updates come in page
+    order whatever a step carries — with slots of 0, 1, 2, 3 and 9 live
+    pages in the batch, so the two page buffers run dry, start cold and
+    are handed from step to step at either parity.  The int8 body is held
+    to the last bit but one: interpret mode makes each blocking its own
+    XLA:CPU program, and with the two scale products in it the compiler
+    contracts a multiply and an add differently in ~3 % of the outputs
+    (6e-8 absolute)."""
+    from paddle_tpu.ops.pallas.paged_attention import ragged_paged_attention
+    args, kw = _reblocked_case(5, 8, 4, True, jnp.float32, quant=quant)
+    out = np.asarray(ragged_paged_attention(
+        *args, interpret=True, _heads=heads, **kw))
+    if quant:
+        np.testing.assert_allclose(out, _one_head_step(quant), rtol=5e-7,
+                                   atol=1.2e-7)
+    else:
+        np.testing.assert_array_equal(out, _one_head_step(quant))
+    assert out.any()
+
+
+@pytest.mark.parametrize("heads", [1, 2, 4, 8])
+def test_ragged_blockings_are_bit_equal_at_the_decode_shape(heads):
+    """... and at the decode shape with 8 kv heads, GQA 4: the cell's head
+    layout, every divisor against the chooser's own pick."""
+    from paddle_tpu.ops.pallas.paged_attention import ragged_paged_attention
+    args, kw = _reblocked_case(1, 32, 8, True, jnp.float32, seed=43)
+    chosen = ragged_paged_attention(*args, interpret=True, **kw)
+    forced = ragged_paged_attention(*args, interpret=True, _heads=heads,
+                                    **kw)
+    np.testing.assert_array_equal(np.asarray(forced), np.asarray(chosen))
+    assert np.asarray(forced).any()
+
+
+def test_choose_heads_from_shapes():
+    """The chooser at the cell's shapes (Mistral-7B: 8 kv heads of 128,
+    pages of 64, bf16): every head at the decode, verify and 512-query
+    chunk rows, fewer as the rows grow; a TP=4 rank's two heads; the heads
+    always divide and the count always fits the budget (or is one)."""
+    from paddle_tpu.ops.pallas.paged_attention import _choose_heads
+    cell = dict(d=128, page_size=64, q_bytes=2, out_bytes=2, kv_bytes=2,
+                quant=False)
+    assert _choose_heads(8, 8, **cell) == 8
+    assert _choose_heads(24, 8, **cell) == 8
+    assert _choose_heads(2048, 8, **cell) == 8
+    assert _choose_heads(4096, 8, **cell) == 2
+    assert _choose_heads(1 << 20, 8, **cell) == 1
+    assert _choose_heads(8, 2, **cell) == 2
+    for rows in (8, 24, 512, 1024, 2048, 8192, 65536):
+        for hkv in (1, 2, 8, 32):
+            for quant in (False, True):
+                assert hkv % _choose_heads(rows, hkv,
+                                           **{**cell, "quant": quant}) == 0

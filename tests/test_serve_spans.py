@@ -226,6 +226,27 @@ def test_decode_kv_tokens_attended_closed_form(llama, horizon):
     assert eng.stats()["tokens_generated"] == sum(news)
 
 
+@pytest.mark.parametrize("page_size", [4, 8])
+@pytest.mark.parametrize("horizon", [1, 4])
+def test_decode_kv_pages_attended_closed_form(llama, horizon, page_size):
+    """The same two requests: decode step j attends the
+    ceil((p + j) / page_size) pages that hold its p + j positions — what
+    the ragged kernel's page loop walks, crossing page boundaries inside
+    and between horizons — and never more than the tokens, never less than
+    tokens / page_size."""
+    eng = _engine(llama, decode_horizon=horizon, page_size=page_size)
+    lens, news = [5, 11], [6, 9]
+    for p, n in zip(_prompts(lens), news):
+        eng.submit(p, max_new_tokens=n)
+    eng.run()
+    want = sum(-(-(p + j) // page_size)
+               for p, n in zip(lens, news) for j in range(1, n))
+    st = eng.stats()
+    assert st["decode_kv_pages_attended"] == want
+    assert st["decode_kv_tokens_attended"] / page_size <= want \
+        <= st["decode_kv_tokens_attended"]
+
+
 @pytest.mark.parametrize("kw", [dict(prefill_chunk=8),
                                 dict(prefill_chunk=8, overlap=True)],
                          ids=["sync", "overlap"])
@@ -239,7 +260,7 @@ def test_telemetry_on_and_off_give_equal_tokens_and_counters(llama, kw):
     assert {k: v for k, v in a.items() if k not in drop} \
         == {k: v for k, v in b.items() if k not in drop}
     for key in ("prefill_tokens_dispatched", "prefill_tokens_padded",
-                "decode_kv_tokens_attended"):
+                "decode_kv_tokens_attended", "decode_kv_pages_attended"):
         assert a[key] > 0
 
 
@@ -249,7 +270,7 @@ def test_counters_survive_a_snapshot(llama):
     fresh = _engine(llama, prefill_chunk=8)
     fresh.restore(eng.snapshot())
     for key in ("prefill_tokens_dispatched", "prefill_tokens_padded",
-                "decode_kv_tokens_attended"):
+                "decode_kv_tokens_attended", "decode_kv_pages_attended"):
         assert fresh.stats()[key] == eng.stats()[key] > 0
 
 
