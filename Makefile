@@ -1,4 +1,4 @@
-# paddle_tpu developer entry points (documented in README §Tests / bench).
+# paddle_tpu developer entry points (documented in README §Tests / benchmark).
 #
 # `tier1` is the ROADMAP tier-1 verify lane; `tier1-budget` re-runs it with
 # per-test durations and gates the ROADMAP 870 s budget through
@@ -22,14 +22,6 @@ PYTEST_T1 = env JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m 'not slow' \
 	--continue-on-collection-errors -p no:cacheprovider -p no:xdist \
 	-p no:randomly
 
-# `obs-check` is the observability gate (perf/check_obs.py, README
-# §Observability): runs the serving trace with a --json artifact,
-# schema-validates it (engine counters + metrics snapshot + SLO report
-# with quantile fields), then runs the telemetry-overhead gate —
-# telemetry ON must hold >= 0.97x the telemetry-off tokens/s (medians
-# over interleaved rounds; same quiet-machine caveat as the timing
-# gates above).
-#
 # `lint` runs graftlint (paddle_tpu/analysis — the trace-safety +
 # distributed/dataflow static analyzer, README §Static analysis) over the
 # package against the committed baseline of grandfathered findings:
@@ -45,120 +37,31 @@ PYTEST_T1 = env JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m 'not slow' \
 # before committing it.
 #
 # `check` is the aggregate local gate: lint (writing the JSON report
-# artifact next to the BENCH jsons) -> tier1-budget -> obs-check ->
-# proc-smoke (the ISSUE 17 cross-process SIGKILL drill).
+# artifact) -> tier1-budget -> race-check -> proc-smoke (the ISSUE 17
+# cross-process SIGKILL drill).  Nothing in it reads a clock to judge
+# speed: speed is `python3 benchmark/run.py` on the chip (BENCHMARK.json,
+# README §Tests / benchmark).
 
 GRAFTLINT = $(PY) -m paddle_tpu.analysis paddle_tpu \
 	--baseline graftlint.baseline.json
 
 LINT_ARTIFACT ?= GRAFTLINT_report.json
 
-.PHONY: tier1 tier1-budget check-budget bench bench-trend lint \
-	lint-baseline obs-check proc-smoke race-check check
+.PHONY: tier1 tier1-budget check-budget lint lint-baseline proc-smoke \
+	race-check check
 
-# `bench-trend` reads every BENCH_r*.json driver artifact at the repo root
-# and prints the headline tokens/s + serving TTFT-p95 + goodput trajectory
-# across PRs; it exits non-zero on artifact schema drift (perf/bench_trend.py).
-bench-trend:
-	$(PY) perf/bench_trend.py
-
-OBS_ARTIFACT ?= /tmp/_obs_serving.json
-OBS_FRONTEND_ARTIFACT ?= /tmp/_obs_frontend.json
-OBS_FAILOVER_ARTIFACT ?= /tmp/_obs_failover.json
-OBS_FAILOVER_PERFETTO ?= /tmp/_obs_failover_perfetto.json
-OBS_ELASTIC_ARTIFACT ?= /tmp/_obs_elastic.json
-OBS_QUANT_ARTIFACT ?= /tmp/_obs_quant.json
-OBS_DISAGG_ARTIFACT ?= /tmp/_obs_disagg.json
-
-# obs-check additionally runs the ISSUE 11 frontend trace (AsyncFrontend
-# bit-equality + zero-leak asserts, predictive-vs-depth admission A/B on
-# bursty + diurnal traffic) and schema-gates its artifact — admission
-# counters, fraction-sum, prediction-error stats, and the machine-aware
-# goodput-under-SLO gate all live in perf/check_obs.py --trace frontend.
-# Since ISSUE 12 it also runs the failover trace with the fleet-wide
-# observability plane on: the artifact's `fleet` block must carry the
-# bucket-wise MERGED replica histograms + per-replica telemetry, the
-# `stitched` block must show the crashed request as ONE cross-component
-# timeline (>= 3 tracks), and the stitched Perfetto JSON is written to
-# $(OBS_FAILOVER_PERFETTO) for ui.perfetto.dev.  Since ISSUE 13 both
-# traces run SENTINEL-ON and must carry the `attribution` section
-# (per-request critical-path decomposition; exact_requests == requests
-# is the gate) and the `alerts` section (aggregated health-sentinel
-# report); the overhead gate's ON arm runs stitching + fleet
-# aggregation + memory sampling + the health sentinel + tail capture +
-# a live exporter scrape + the attribution report (<3% bar).
-# Since ISSUE 14 it also runs the elastic trace (sentinel-driven
-# autoscaling + prefix-affinity routing on a virtual-clock diurnal
-# replay): zero-loss + bit-equal asserted across every scale event,
-# elastic >= every fixed-N arm on goodput-per-replica-hour, and the
-# affinity fleet's hit rate >= 0.9x the single engine's — all
-# deterministic (perf/check_obs.py --trace elastic).
-# Since ISSUE 15 it also runs the quant trace (the int8-KV + int8-weight
-# serving plane): greedy exact-match >= 0.99 vs the f32 engine on the
-# parity scenarios, >= 1.8x concurrent users at FIXED pool bytes,
-# dequant-tax tokens/s >= 0.95x (best paired), and the failover/elastic/
-# ladder drills re-run with quantized pages — zero-lost, bit-equal,
-# ladder order preserved (perf/check_obs.py --trace quant).
-# Since ISSUE 18 the serving trace runs with --tp 2 (XLA forced-host
-# devices): the tensor-parallel engine must be greedy BIT-EXACT vs the
-# single-chip engine with f32 collectives, the quantized-AllReduce arm
-# must hold exact_match >= 0.99 on the parity scenarios, and the
-# artifact's `tp` block (collective profile + rank skew + attribution
-# decode_sync_frac) is schema-gated.  Forced-host TP time-slices one
-# CPU, so tokens_per_sec_tp measures dispatch overhead, not speedup —
-# the gate is on correctness + schema, never on the paired ratio.
-# Since ISSUE 19 it also runs the disagg trace (prefill/decode on
-# separate mp=2 submeshes, 4 forced-host chips): colocated-TP vs
-# disaggregated arms replay the SAME prefill-heavy scenario at FIXED
-# chip count on the shared virtual clock, greedy bit-exactness vs the
-# single-chip engine is asserted in BOTH arms before anything is
-# reported, and the artifact's TTFT win ratio, rank-local handoff
-# telemetry, and EXACT kv_transfer attribution segment are schema-gated
-# (perf/check_obs.py --trace disagg) — all deterministic.
-obs-check:
-	set -o pipefail; \
-	env JAX_PLATFORMS=cpu $(PY) bench.py --trace serving --tp 2 \
-		--json $(OBS_ARTIFACT) && \
-	env JAX_PLATFORMS=cpu $(PY) perf/check_obs.py \
-		--artifact $(OBS_ARTIFACT) --trace serving --gate && \
-	env JAX_PLATFORMS=cpu $(PY) bench.py --trace frontend \
-		--json $(OBS_FRONTEND_ARTIFACT) && \
-	env JAX_PLATFORMS=cpu $(PY) perf/check_obs.py \
-		--artifact $(OBS_FRONTEND_ARTIFACT) --trace frontend && \
-	env JAX_PLATFORMS=cpu $(PY) bench.py --trace failover \
-		--json $(OBS_FAILOVER_ARTIFACT) \
-		--perfetto $(OBS_FAILOVER_PERFETTO) && \
-	env JAX_PLATFORMS=cpu $(PY) perf/check_obs.py \
-		--artifact $(OBS_FAILOVER_ARTIFACT) --trace failover && \
-	env JAX_PLATFORMS=cpu $(PY) bench.py --trace elastic \
-		--json $(OBS_ELASTIC_ARTIFACT) && \
-	env JAX_PLATFORMS=cpu $(PY) perf/check_obs.py \
-		--artifact $(OBS_ELASTIC_ARTIFACT) --trace elastic && \
-	env JAX_PLATFORMS=cpu $(PY) bench.py --trace quant \
-		--json $(OBS_QUANT_ARTIFACT) && \
-	env JAX_PLATFORMS=cpu $(PY) perf/check_obs.py \
-		--artifact $(OBS_QUANT_ARTIFACT) --trace quant && \
-	env JAX_PLATFORMS=cpu $(PY) bench.py --trace disagg \
-		--json $(OBS_DISAGG_ARTIFACT) && \
-	env JAX_PLATFORMS=cpu $(PY) perf/check_obs.py \
-		--artifact $(OBS_DISAGG_ARTIFACT) --trace disagg
-
-# `proc-smoke` is the ISSUE 17 cross-process CI lane: spawn 2 REAL worker
-# processes (each hosting a full ServingEngine behind the length-prefixed
-# RPC), SIGKILL one mid-decode, and assert zero-loss + bit-equal recovery
-# + a measured wall-clock failover + passing invariants reports for every
-# spawned generation (the killed one vouched by its replacement) BEFORE
-# the artifact is reported; perf/check_obs.py --proc then schema-gates it.
-# The spawn-heavy pytest drills (tests/test_procfleet.py) stay in the slow
-# lane — this target is the fast deterministic smoke that runs in `check`.
-OBS_FAILOVER_PROC_ARTIFACT ?= /tmp/_obs_failover_proc.json
-
+# `proc-smoke` is the ISSUE 17 cross-process CI lane: the SIGKILL drills
+# of tests/test_procfleet.py (slow-marked, so tier-1 never spawns them) —
+# 2 REAL worker processes, each hosting a full ServingEngine behind the
+# length-prefixed RPC, one SIGKILLed mid-decode: zero loss, outputs
+# bit-equal the uninterrupted engine, every token streamed exactly once,
+# a measured wall-clock failover, an invariants report for every spawned
+# generation (the killed one vouched by its replacement), and the stitched
+# trace crossing the process boundary.
 proc-smoke:
-	set -o pipefail; \
-	env JAX_PLATFORMS=cpu $(PY) bench.py --trace failover --proc \
-		--json $(OBS_FAILOVER_PROC_ARTIFACT) && \
-	env JAX_PLATFORMS=cpu $(PY) perf/check_obs.py \
-		--artifact $(OBS_FAILOVER_PROC_ARTIFACT) --trace failover --proc
+	env JAX_PLATFORMS=cpu timeout -k 10 600 $(PY) -m pytest \
+		tests/test_procfleet.py::TestSigkillFailover -q -m slow \
+		-p no:cacheprovider -p no:xdist -p no:randomly
 
 lint:
 	$(GRAFTLINT) --fail-on-stale $(if $(DIFF),--diff $(DIFF))
@@ -173,9 +76,8 @@ lint-baseline:
 # thread_sanitize(): threading.Lock/RLock are instrumented, lock-order
 # inversions raise LockOrderViolation with both stacks instead of
 # deadlocking CI, and the seeded thread.interleave fault point makes the
-# schedules reproducible.  The sanitizer is OFF everywhere timed
-# (tier1-budget, obs-check overhead gates) — it is a test-lane tool, not
-# a production tax.
+# schedules reproducible.  The sanitizer is OFF in tier1-budget — it is
+# a test-lane tool, not a production tax.
 race-check:
 	env JAX_PLATFORMS=cpu GRAFT_THREAD_SANITIZE=1 timeout -k 10 600 \
 		$(PY) -m pytest tests/test_thread_sanitize.py \
@@ -187,7 +89,6 @@ check:
 	$(GRAFTLINT) --fail-on-stale --json-artifact $(LINT_ARTIFACT)
 	$(MAKE) tier1-budget
 	$(MAKE) race-check
-	$(MAKE) obs-check
 	$(MAKE) proc-smoke
 
 tier1:
@@ -200,6 +101,3 @@ tier1-budget:
 
 check-budget:
 	$(PY) perf/check_tier1_budget.py $(LOG)
-
-bench:
-	$(PY) bench.py

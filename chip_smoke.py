@@ -314,12 +314,12 @@ def serve_phase(cfg, sizes, *, attention_impl="auto", interpret=False,
 
 
 # ---------------------------------------------------------------------------
-# train: the donated jitted train step, as bench.py assembles it
+# train: the donated jitted train step
 # ---------------------------------------------------------------------------
 def train_kernels():
     """The registry's attention and norm for the functional train block —
-    which on a TPU must be the Pallas ones (the perf contract bench.py
-    asserts for attention)."""
+    which on a TPU must be the Pallas ones (the perf contract of the
+    train path; tests/test_chip_compile.py compiles them for the chip)."""
     from paddle_tpu.core.dispatch import get_kernel
     names = ("flash_attention_causal", "rms_norm")
     impls = {n: get_kernel(n) for n in names}

@@ -31,7 +31,8 @@ def setup_compile_cache() -> str:
     is set in code; otherwise the cache lives at ``<checkout>/.jax_cache``
     (git-ignored).  The directory is part of every cache key, so it must
     not move between runs — never a temp dir.  Entry points call this once
-    before their first compile (chip_smoke.py, bench.py, serving/worker.py);
+    before their first compile (chip_smoke.py, benchmark/run.py,
+    serving/worker.py);
     nothing else in the repo names a cache path.  Touches no backend."""
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not cache_dir:

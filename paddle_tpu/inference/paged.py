@@ -25,7 +25,7 @@ continuous batching:
     budget), their pages go to the prefix cache, and queued requests are
     admitted into the freed slots (prefill + first-token sample), so new
     traffic joins a RUNNING batch instead of waiting for the whole batch
-    to drain — the throughput win `bench.py serving` measures against the
+    to drain — the throughput win of continuous batching over the
     static-batch `llama_generate_fused` baseline.  Long prompts prefill
     in fixed `prefill_chunk`-token chunks interleaved with decode
     horizons (chunked prefill), so time-to-first-token for queued short
@@ -816,7 +816,7 @@ class ServingEngine:
     weights onto the per-channel int8 grid (serving/quant.py).  Both keep
     the engine deterministic and bit-exact against ITSELF across every
     feature above; parity vs the f32 engine is exact-match-rate gated
-    (`serving.quant.parity_report`, `bench.py --trace quant`), not
+    (`serving.quant.parity_report`, tests/test_quant.py), not
     bit-equality — quantization is lossy by definition."""
 
     def __init__(self, params, config, num_slots: int = 4,
@@ -3176,7 +3176,7 @@ class ServingEngine:
     # -- accounting / invariants -------------------------------------------
     def stats(self) -> dict:
         """Engine observability: one dict of monotonically increasing
-        counters (bench traces print it; dashboards diff it).
+        counters (the benchmark's drivers and dashboards diff it).
         `decode_steps` and `verify_steps` are DISJOINT dispatch counts
         (plain horizon vs speculative verify); their sum is the total
         number of engine dispatches (`steps_run`)."""
@@ -3227,7 +3227,7 @@ class ServingEngine:
             "quantized_allreduce": self.quantized_allreduce,
             # per-model-fn compile-cache misses (analysis.sanitize
             # instrumentation) — a warmed steady state must hold these
-            # flat; bench --json artifacts embed them via engine_stats
+            # flat (tests/test_recompile_budget.py)
             "jit_cache_misses": dict(self.jit_cache_misses),
         }
 

@@ -1,5 +1,4 @@
-"""ERNIE model family (BASELINE.json config #3: ERNIE-3.0 base MLM pretrain,
-sharding stage-2).
+"""ERNIE model family (ERNIE-3.0 base MLM pretrain, sharding stage-2).
 
 Reference: PaddleNLP's ErnieModel (transformer encoder, learned positions,
 token-type embeddings, post-LN) — the reference repo ships the framework it

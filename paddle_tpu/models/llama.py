@@ -1,4 +1,4 @@
-"""LLaMA model family (BASELINE.json config #4: LLaMA-2 7B/13B TP+PP).
+"""LLaMA model family (LLaMA-2 7B/13B, TP+PP).
 
 Two forms:
  * `LlamaForCausalLM` — eager Layer (dygraph parity; PaddleNLP-style config),
@@ -6,8 +6,8 @@ Two forms:
    optional fleet TP layers when mp_degree > 1.
  * `build_functional_llama` — pure param-pytree + apply fns matching
    paddle_tpu.parallel.PipelineTrainStep's (embed, block, head) contract,
-   used by the hybrid dp×pp×mp compiled train step, bench.py, and
-   __graft_entry__.dryrun_multichip.
+   used by the hybrid dp×pp×mp compiled train step, the benchmark's
+   train driver, and __graft_entry__.dryrun_multichip.
 """
 from __future__ import annotations
 
@@ -290,7 +290,7 @@ class LlamaForCausalLM(Layer):
 
 
 # ---------------------------------------------------------------------------
-# Functional form (pipeline/bench path)
+# Functional form (pipeline/benchmark path)
 # ---------------------------------------------------------------------------
 def llama_block_specs(mp_axis: str = "mp", moe: bool = False,
                       ep_axis: str = None):
@@ -942,7 +942,7 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
         ``q [S, Qmax, nh, D]`` through the ONE ragged paged-attention
         kernel (or, off-TPU, its ONE jnp ref) — impl-uniformity is what
         makes speculative verify lossless by construction rather than by
-        bench assert.  ``pk/pv`` are the WHOLE page stores and ``li`` the
+        an assertion.  ``pk/pv`` are the WHOLE page stores and ``li`` the
         layer: the kernel indexes the layer itself.  On a quantized store
         the int8/fp8 pages and their per-row scales pass straight through;
         dequant fuses inside the kernel (and inside the ref's gather) for

@@ -1,5 +1,5 @@
-"""Stable-Diffusion-style UNet (BASELINE.json config #5: SD 1.5 UNet —
-conv + attention mixed workload for the Pallas/conv kernels).
+"""Stable-Diffusion-style UNet (SD 1.5 UNet — conv + attention mixed
+workload for the Pallas/conv kernels).
 
 Compact latent-diffusion UNet following the SD 1.5 topology: sinusoidal
 timestep embedding → MLP; down path of ResBlocks with self+cross attention

@@ -19,7 +19,7 @@ scheduling (``admission``), dispatching or syncing a decode, verifying
 drafts.  Segments are built on shared boundary floats, so they are
 contiguous and disjoint BY CONSTRUCTION and their durations telescope to
 the traced e2e (:meth:`CriticalPath.is_exact` asserts the structure;
-``exact_requests == requests`` is a ``perf/check_obs.py`` gate).
+``exact_requests == requests`` is what tests/test_attribution.py holds).
 
 Cross-replica requests (failover, live migration, snapshot restore)
 attribute through the stitched view (:func:`attribute_stitched`): the

@@ -28,8 +28,8 @@ gaps:
     sum; gauges and memory series stay per-replica side-by-side.  The
     fleet-wide SLO report reads goodput straight off the merged TTFT
     histogram (``fraction_below`` at the deadline).  Powers
-    ``ReplicaFleet.stats_snapshot()`` and the ``fleet`` artifact section
-    ``perf/check_obs.py`` gates.
+    ``ReplicaFleet.stats_snapshot()`` (tests/test_fleet.py holds its
+    ``merged`` and ``per_replica_telemetry`` blocks).
 
 Everything here is pure host code operating on snapshots — zero jit
 calls, zero device syncs, zero engine-thread work.
@@ -154,8 +154,8 @@ class TraceStitcher:
 
     def summary(self) -> dict:
         """Artifact-embeddable digest: event/flow counts, component list,
-        and the longest per-request chain (the stitched-failover gate in
-        perf/check_obs.py reads ``max_chain``)."""
+        and the longest per-request chain (the failover drill of
+        tests/test_fleet.py reads ``max_chain``)."""
         trace = self.to_chrome_trace()["traceEvents"]
         flows = [e for e in trace if e.get("cat") == "request_flow"]
         chains = self.flow_chains()

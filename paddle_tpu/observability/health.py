@@ -647,7 +647,7 @@ class HealthSentinel:
         }
 
     def report(self) -> dict:
-        """The ``/alerts`` endpoint body and the bench artifact section:
+        """The ``/alerts`` endpoint body:
         live status + per-rule fire counts + active/history records +
         rule catalog."""
         act = self.active()

@@ -2,7 +2,7 @@
 
 The serving engine's observability used to be a flat dict of ad-hoc int
 attributes (``ServingEngine.stats()``) plus hand-rolled ``np.percentile``
-blocks scattered through bench.py.  This module is the one shared
+blocks in every script that measured it.  This module is the one shared
 implementation behind all of it:
 
   * :class:`Counter` / :class:`Gauge` — monotonic count / last-value.
@@ -177,7 +177,7 @@ class Histogram:
 
     def reset(self):
         """Drop every observation (a measurement-window boundary — e.g.
-        `Telemetry.reset_window()` between a bench's warm pass and its
+        `Telemetry.reset_window()` between a run's warm pass and its
         timed pass, so quantiles describe the window, not the compiles)."""
         self.count = 0
         self.total = 0.0

@@ -1,7 +1,7 @@
 """SLO reporting: latency quantiles + goodput at a deadline.
 
-One shared implementation for every consumer (bench.py's three serving
-traces, ``make obs-check``, dashboards): given per-request summaries from
+One shared implementation for every consumer (``Telemetry.slo_report``,
+the fleets' snapshots, dashboards): given per-request summaries from
 :class:`~paddle_tpu.observability.telemetry.Telemetry` (or raw latency
 lists), produce TTFT/TPOT/E2E quantiles and **goodput** — the share of
 work that met its deadline, the number a latency SLO actually pays on.
@@ -75,7 +75,7 @@ def latency_percentiles(values_s, name: str = "latency",
                         ps=(50, 95, 99)) -> dict:
     """{p<q>_ms: ...} readout over a list of second-valued latencies, via
     the shared log-bucketed :class:`Histogram` (the single percentile
-    implementation bench.py's traces all use)."""
+    implementation of the package)."""
     h = Histogram(name)
     for v in values_s:
         h.observe(v)

@@ -646,7 +646,7 @@ class Telemetry:
             device is PROVABLY the bottleneck.
 
         With ``window_s`` (the measured wall clock), ``gap_s`` is the
-        unaccounted remainder (inter-step host work, bench bookkeeping)
+        unaccounted remainder (inter-step host work, the caller's bookkeeping)
         and ``device_idle_frac_est`` = (host_busy + gap) / window — the
         fraction of the window the device provably had nothing dispatched
         to run, i.e. the headroom a double-buffered host loop (ROADMAP

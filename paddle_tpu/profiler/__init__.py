@@ -4,7 +4,7 @@ with scheduler states, RecordEvent event_tracing.h, timer.py throughput).
 TPU-native: device tracing delegates to jax.profiler (XPlane → TensorBoard /
 perfetto, the CUPTI-chrome-trace analog); host annotations map RecordEvent →
 jax.profiler.TraceAnnotation + named_scope so they appear in the same trace.
-The benchmark `Timer` reproduces timer.py's ips accounting (used by bench.py).
+The benchmark `Timer` reproduces timer.py's ips accounting.
 """
 from __future__ import annotations
 

@@ -28,7 +28,7 @@ replica-fleet router, and the async front end + traffic harness.
   self-exactness invariant), per-channel int8 serving weights, page-byte
   accounting for the memory observatory, and :func:`parity_report` —
   greedy exact-match + teacher-forced logit drift vs the f32 engine on
-  the standard parity scenarios (`bench.py --trace quant` gates it).
+  the standard parity scenarios (tests/test_quant.py gates it).
 * :mod:`.routing` + :mod:`.autoscale` — the elastic control plane
   (ROADMAP item 5): pluggable placement strategies
   (:class:`LeastLoadedRouter`, :class:`PrefixAffinityRouter` — route

@@ -358,7 +358,7 @@ class ElasticFleet(ReplicaFleet):
         if role is not None:
             ev["role"] = role
         # the drill's scale-event audit log: one entry per scale
-        # decision, read whole by bench/check_obs
+        # decision, read whole by tests/test_autoscale.py
         self.scale_events.append(ev)  # graftlint: disable=LEAK001
 
     # -- readouts ----------------------------------------------------------

@@ -32,7 +32,7 @@ Failovers, migrations, and torn-snapshot rejections land in the fleet's
 flight recorder stamped with the active fault-plan context
 (``observability.fault_context``); ``fleet.migrations`` /
 ``fleet.failovers`` counters and the ``fleet.recovery_s`` histogram feed
-the failover bench trace (``bench.py --trace failover``).
+``stats()["recovery"]`` (the failover drills of tests/test_fleet.py).
 """
 from __future__ import annotations
 

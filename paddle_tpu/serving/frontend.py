@@ -34,7 +34,7 @@ concurrent clients":
     ``frontend.ttft_pred_err_s`` — because an admission controller whose
     predictions silently rot is worse than a depth cap.  The depth-cap
     policy (``policy="depth"``) is kept as the A/B baseline
-    ``bench.py --trace frontend`` gates against.
+    (tests/test_traffic.py replays both on the virtual clock).
 
 Everything here is pure host-side asyncio/numpy: no jitted code, no new
 executables, zero effect on the engine's PERF.md §12 variant table.
@@ -310,8 +310,8 @@ class AdmissionController:
 
     def report(self) -> dict:
         """Admission counters + fraction decomposition + prediction-error
-        stats — the artifact section ``perf/check_obs.py`` schema-gates
-        (admit/queue/reject fractions must sum to ~1 over offered)."""
+        stats (admit/queue/reject fractions must sum to ~1 over
+        offered; tests/test_frontend.py holds that)."""
         offered = self._c_offered.value
         parts = {
             "admitted": self._c_admitted.value,

@@ -25,7 +25,7 @@ quantized page store shares across every layer that touches it:
     Pallas kernel agree on.
   * :func:`page_bytes` — bytes per KV page (both K and V, all layers,
     scales included) for a geometry/dtype: the telemetry
-    `mem.pool_*_bytes` gauges and the fixed-pool-bytes capacity bench
+    `mem.pool_*_bytes` gauges and the fixed-pool-bytes capacity test
     both size pools through this one function.
   * :func:`quantize_params` — per-channel int8 weight quantization for
     serving params (through `quantization.quantize_weight(axis=...)`):
@@ -120,7 +120,7 @@ def page_bytes(config, page_size: int, kv_dtype=None, dtype=None) -> int:
     """Bytes ONE page of KV cache costs (K + V across all layers, per-page
     scales included for quantized dtypes).  This is the unit the telemetry
     memory observatory reports pool occupancy in and the unit the
-    fixed-pool-bytes capacity bench holds constant across arms."""
+    fixed-pool-bytes capacity test holds constant across arms."""
     jnp = _jnp()
     L = config.num_hidden_layers
     hkv = config.num_key_value_heads
@@ -204,7 +204,7 @@ def logit_drift(params_ref, params_q, config, prompts, *, kv_dtype,
     per-step max drifts).
 
     ``ref_build_kw`` / ``q_build_kw``: extra build_llama_paged_decode
-    kwargs per arm — how the TP serving bench drifts the quantized
+    kwargs per arm — how tests/test_tp_serving.py drifts the quantized
     AllReduce against the f32-collective build (both arms
     ``mesh=<mesh>``, the q arm additionally ``quantized_allreduce=True``,
     with ``kv_dtype=None`` so page quantization stays out of the
@@ -278,7 +278,7 @@ def parity_report(params, config, *, kv_dtype="int8", quantize=8,
         the argmax survived).
 
     ``ref_engine_kw`` / ``q_engine_kw`` merge per-arm ON TOP of
-    ``engine_kw`` — this is how the TP serving bench reuses the harness
+    ``engine_kw`` — this is how tests/test_tp_serving.py reuses the harness
     for quantized-vs-f32 COLLECTIVES instead of quantized-vs-f32 pages:
     both arms ``mesh=<mesh>``, the q arm ``quantized_allreduce=True``,
     with ``kv_dtype=None, quantize=None`` so the only difference under

@@ -6,8 +6,8 @@ policy inlined in ``_place`` — correct for interchangeable replicas, and
 provably wrong at fleet scale with per-replica prefix caches: two turns
 of the same conversation land on different replicas, each re-prefills the
 shared history, and the fleet-wide cache hit rate collapses to a fraction
-of what a single engine gets on the identical traffic (``bench.py
---trace elastic`` measures exactly this split).
+of what a single engine gets on the identical traffic
+(tests/test_routing.py sends a second turn back to its cached first).
 
 This module turns placement into a strategy seam:
 

@@ -20,8 +20,7 @@ Three layers:
   * :func:`replay_engine` — drive a real :class:`ServingEngine` through a
     scenario.  Arrivals are paced in TOKEN TIME (request i is submitted
     once the engine has generated ``arrival_s * load_tps`` tokens —
-    machine-independent offered load, the same trick bench.py's serving
-    trace uses), admission goes through an
+    machine-independent offered load), admission goes through an
     :class:`~paddle_tpu.serving.frontend.AdmissionController`, and
     abandon clients cancel their request mid-decode through the engine's
     ``cancel()`` (deferred to the step boundary: ``on_token`` fires
@@ -475,8 +474,7 @@ def replay_fleet(fleet, scenario: Scenario, *, slo_ttft_s: float,
     Abandon clients cancel through ``fleet.cancel`` at the round
     boundary.  Returns the :func:`replay_engine` report shape plus
     ``replica_seconds`` — the integral of live-replica count over the
-    replay (the goodput-per-replica-hour denominator bench.py's elastic
-    trace A/Bs on)."""
+    replay (the denominator of goodput per replica-hour)."""
     import time as _time
 
     from ..inference.paged import AdmissionRejected

@@ -1,6 +1,6 @@
 """ResNet family (reference: python/paddle/vision/models/resnet.py).
 
-BASELINE.json config #1: ResNet-50 single-device dygraph training.
+ResNet-50 is the single-device dygraph training config.
 NCHW at the API; XLA lays out convs for the MXU internally.
 """
 from __future__ import annotations

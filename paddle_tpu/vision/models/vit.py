@@ -1,7 +1,7 @@
-"""Vision Transformer (BASELINE.json config #2: ViT-L/16 data-parallel).
+"""Vision Transformer (ViT-L/16, data-parallel).
 
 Reference ViT implementations live in PaddleClas; paddle.vision itself ships
-the backbone zoo — we provide ViT here since it's a benchmark config.
+the backbone zoo — we provide ViT here as one of the model families.
 """
 from __future__ import annotations
 

@@ -15,7 +15,7 @@ Cumulative runtime prefers the pytest summary wall clock (`... in 681.2s`)
 when present — it includes collection and fixture overhead the duration
 lines miss — and falls back to the summed durations otherwise.
 
-Usage (see README §Tests / bench and the Makefile `tier1-budget` target):
+Usage (see README §Tests / benchmark and the Makefile `tier1-budget` target):
 
     python -m pytest tests/ -q -m 'not slow' --durations=0 ... | tee t1.log
     python perf/check_tier1_budget.py t1.log
@@ -96,8 +96,7 @@ def main(argv=None):
     ap = argparse.ArgumentParser(
         description=__doc__, formatter_class=argparse.RawDescriptionHelpFormatter)
     ap.add_argument("log", help="pytest log file (run with --durations=0)")
-    # machine-aware default, mirroring the telemetry-overhead gate's
-    # single-core floor (perf/check_obs.py): on a 1-core host every
+    # machine-aware default: on a 1-core host every
     # measurement serializes against the interpreter and the observed
     # quiet-run wall drifts ~±10% between days, so the 0.9 fraction
     # calibrated on this host's fast state rejects runs the hard 870 s

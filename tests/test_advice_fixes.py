@@ -1,4 +1,4 @@
-"""Regression tests for the round-1 advisor findings (ADVICE.md):
+"""Regression tests for the round-1 advisor findings:
 1. GradScaler per-optimizer unscale state (no double-unscale).
 2. TrainStep grad_accum is real gradient merge, equivalent to full batch.
 3. Distributed checkpoint shard keys are rank-collision-free.
@@ -298,7 +298,7 @@ class TestCausalOffset:
 
 
 # ---------------------------------------------------------------------------
-# Round-5 advisor findings (ADVICE.md r5; closed in the paged-serving PR)
+# Round-5 advisor findings (closed in the paged-serving PR)
 # ---------------------------------------------------------------------------
 class TestTopPSamplingColumnShape:
     """ADVICE r5 #1: top_p_sampling must return [B, 1] column tensors

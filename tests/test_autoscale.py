@@ -358,104 +358,3 @@ class TestElasticLoop:
         assert st["min_replicas"] == 1 and st["max_replicas"] == 3
         assert set(st["rule_fires"]) == {"queue_growth", "fleet_idle"}
 
-
-# ---------------------------------------------------------------------------
-# validator + trend-finder units (ISSUE 14 CI wiring)
-# ---------------------------------------------------------------------------
-def _elastic_art():
-    arm = {"on_time_requests": 10, "goodput_fraction": 1.0,
-           "replica_seconds_v": 30.0, "goodput_per_replica_hour": 1200.0,
-           "hit_rate": 0.7, "slo_report": {}}
-    return {
-        "metric": "trace_elastic",
-        "lost_requests": 0,
-        "outputs_bitexact": True,
-        "scale_ups": 2, "scale_downs": 2,
-        "scale_events": [{"action": "scale_up"}],
-        "goodput_per_replica_hour": {
-            "elastic": 1200.0,
-            "fixed": {"1": 1000.0, "2": 1100.0, "peak": 800.0},
-            "ratios_elastic_vs_fixed": {"1": 1.2, "2": 1.09,
-                                        "peak": 1.5},
-            "min_ratio": 1.09,
-        },
-        "hit_rate": {"single_engine": 0.75, "affinity_fixed2": 0.7,
-                     "least_loaded_fixed2": 0.6, "elastic": 0.65,
-                     "ratio_vs_single": 0.933,
-                     "split_demonstrated": True},
-        "router": {"router": "prefix_affinity", "routed": 10,
-                   "affinity_hits": 6, "affinity_fallbacks": 1,
-                   "affinity_misses": 3},
-        "arms": {"fixed_1": dict(arm), "elastic": dict(arm)},
-        "fleet": {
-            "scale_ups": 2, "scale_downs": 2, "drain_migrations": 1,
-            "replicas_retired": 2, "cache": {}, "router": {},
-            "merged": {name: {k: 0 for k in
-                              ("count", "sum", "min", "max",
-                               "p50", "p95", "p99")}
-                       for name in ("serve.ttft_s", "serve.e2e_s",
-                                    "engine.step_host_s")},
-            "per_replica_telemetry": {
-                "r0": {"mem.pool_occupancy_frac": 0.5}},
-        },
-        "parallelism": {
-            "model": "virtual (round-driven clock)",
-            "wall_clock_arm": "bench.py --trace failover --proc",
-            "note": "re-measure on wall clock when the autoscaler "
-                    "scales ProcessFleet workers",
-        },
-    }
-
-
-class TestElasticValidator:
-    def _validate(self, art):
-        import os
-        import sys
-        sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-        from perf.check_obs import validate_artifact
-        return validate_artifact(art, "elastic")
-
-    def test_positive(self):
-        assert self._validate(_elastic_art()) == []
-
-    def test_negatives(self):
-        art = _elastic_art()
-        art["lost_requests"] = 1
-        assert any("ZERO" in p for p in self._validate(art))
-        art = _elastic_art()
-        art["outputs_bitexact"] = False
-        assert any("bit-for-bit" in p for p in self._validate(art))
-        art = _elastic_art()
-        art["scale_events"] = []
-        assert any("timeline" in p for p in self._validate(art))
-        art = _elastic_art()
-        art["goodput_per_replica_hour"]["ratios_elastic_vs_fixed"]["2"] \
-            = 0.97
-        assert any("fixed-2" in p for p in self._validate(art))
-        art = _elastic_art()
-        # a zero baseline arm is a degenerate A/B, never a free win
-        art["goodput_per_replica_hour"]["fixed"]["1"] = 0.0
-        assert any("degenerate" in p for p in self._validate(art))
-        art = _elastic_art()
-        art["hit_rate"]["ratio_vs_single"] = 0.85
-        assert any("0.9x" in p for p in self._validate(art))
-        art = _elastic_art()
-        art["hit_rate"]["split_demonstrated"] = False
-        assert any("split" in p.lower() for p in self._validate(art))
-        art = _elastic_art()
-        art["router"]["affinity_hits"] = 0
-        assert any("affinity_hits" in p for p in self._validate(art))
-        art = _elastic_art()
-        del art["fleet"]["merged"]
-        assert any("merged" in p for p in self._validate(art))
-
-    def test_trend_finders(self):
-        import os
-        import sys
-        sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-        from perf.bench_trend import find_fleet_hit_rate, find_gprh
-        art = {"nested": {"serving_elastic": _elastic_art()}}
-        assert find_gprh(art) == 1200.0
-        assert find_fleet_hit_rate(art) == 0.7
-        assert find_gprh({"x": 1}) is None
-        assert find_fleet_hit_rate({"hit_rate": 0.5}) is None

@@ -1,7 +1,6 @@
 """No fallback hides the device (ISSUE 22): a failing device probe raises
-where it used to answer "cpu", the measurement entry point refuses to run
-without a TPU, the compile cache is placed from outside, and a parent that
-spawns JAX workers never initialises a backend itself.
+where it used to answer "cpu", the compile cache is placed from outside,
+and a parent that spawns JAX workers never initialises a backend itself.
 """
 import os
 import subprocess
@@ -48,21 +47,6 @@ def test_get_device_lets_a_backend_error_through(monkeypatch):
         paddle.get_device()
 
 
-def test_bench_refuses_an_unknown_device_kind_and_a_cpu_run(
-        restore_cache_dir):
-    import bench
-
-    class Unknown:
-        device_kind = "TPU v9 hypothetical"
-
-    with pytest.raises(ValueError, match="no bf16 peak on record"):
-        bench._chip_peak_flops(Unknown())
-    with pytest.raises(SystemExit) as exc:
-        bench.main()                 # this process is held to the CPU
-    assert exc.value.code not in (0, None)
-    assert "no TPU" in str(exc.value.code)
-
-
 def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path,
                                               restore_cache_dir):
     from paddle_tpu.core.device import setup_compile_cache
@@ -77,20 +61,19 @@ def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path,
 
 
 def test_only_the_helper_names_a_cache_path():
-    """bench.py, chip_smoke.py and serving/worker.py call the helper; no
-    other code sets a compilation-cache directory."""
+    """chip_smoke.py and serving/worker.py call the helper; no other code
+    sets a compilation-cache directory."""
     hits = []
     for root in ("paddle_tpu", "perf"):
         for dirpath, _, files in os.walk(os.path.join(REPO, root)):
             hits += [os.path.join(dirpath, f) for f in files
                      if f.endswith(".py")]
     hits += [os.path.join(REPO, f) for f in
-             ("bench.py", "chip_smoke.py", "__graft_entry__.py")]
+             ("chip_smoke.py", "__graft_entry__.py")]
     setters = [os.path.relpath(p, REPO) for p in hits
                if "jax_compilation_cache_dir" in open(p).read()]
     assert setters == ["paddle_tpu/core/device.py"]
-    for caller in ("bench.py", "chip_smoke.py",
-                   "paddle_tpu/serving/worker.py"):
+    for caller in ("chip_smoke.py", "paddle_tpu/serving/worker.py"):
         assert "setup_compile_cache()" in open(
             os.path.join(REPO, caller)).read(), caller
 

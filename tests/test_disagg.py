@@ -15,6 +15,7 @@ import pytest
 import jax
 
 import paddle_tpu as paddle  # noqa: F401 — jax compat shims
+from paddle_tpu.distributed.topology import build_mesh
 from paddle_tpu.models.llama import (llama_config_tiny,
                                      build_functional_llama, llama_generate)
 from paddle_tpu.inference.paged import KVHandoffError, ServingEngine
@@ -37,11 +38,11 @@ def _params():
     return _PARAMS
 
 
-def _mk(**kw):
+def _mk(params=None, **kw):
     base = dict(num_slots=2, page_size=4, num_pages=40, max_pages_per_seq=16,
                 attention_impl="ref", prompt_bucket=8, decode_horizon=2)
     base.update(kw)
-    return ServingEngine(_params(), CFG, **base)
+    return ServingEngine(params or _params(), CFG, **base)
 
 
 _PROMPTS = [rng.integers(1, 64, (t,)).astype(np.int32)
@@ -173,6 +174,28 @@ def _factory(**kw):
     return make
 
 
+def _tp_factory_and_refs(mp, n_new):
+    """Each role on an mp-wide submesh of its own (equal degree, so every
+    handoff stays rank-local), and the single-chip references to hold the
+    fleet to.  The params are margin-engineered (blocks x 0.15, LM head
+    tied to the embedding x 4): the argmax stays above the reassociation
+    noise of the per-layer psum."""
+    ep, bp, hp = _params()
+    bp = {k: (v * 0.15 if k.startswith("w") else v) for k, v in bp.items()}
+    hp = dict(hp, lm=(ep["tok"].T * 4.0).astype(hp["lm"].dtype))
+    params = (ep, bp, hp)
+    devs = jax.devices()
+
+    def make(role="any"):
+        sub = devs[:mp] if role == "prefill" else devs[mp:2 * mp]
+        return _mk(params, telemetry=True,
+                   mesh=build_mesh({"mp": mp}, devices=sub))
+    refs = [np.asarray(llama_generate(params, CFG, p[None],
+                                      max_new_tokens=n_new))[0]
+            for p in _PROMPTS]
+    return make, refs
+
+
 class TestDisaggFleet:
     def test_roles_validation(self):
         def boom(role="any"):
@@ -185,18 +208,23 @@ class TestDisaggFleet:
             ReplicaFleet(boom, num_replicas=2,
                          roles=["prefill", "prefill"])
 
-    def test_disagg_bit_exact_with_kv_transfer_attribution(self):
+    @pytest.mark.parametrize("mp", [1, 2])
+    def test_disagg_bit_exact_with_kv_transfer_attribution(self, mp):
         """The tentpole path: prefill replica hands every request to the
         decode replica after the first token; outputs bit-equal the
         single-engine references; the transfer is rank-local (equal mp),
-        counted, and visible as a kv_transfer attribution segment."""
-        fleet = ReplicaFleet(_factory(), num_replicas=2,
+        counted, and visible as a kv_transfer attribution segment.  At
+        mp=2 both replicas are tensor-parallel, each over its own two
+        devices, and the pages that travel are head-sharded."""
+        factory, refs = (_factory(), _refs(8)) if mp == 1 \
+            else _tp_factory_and_refs(mp, 8)
+        fleet = ReplicaFleet(factory, num_replicas=2,
                              roles=["prefill", "decode"],
                              router=PrefixAffinityRouter())
         rids = [fleet.submit(p, max_new_tokens=8) for p in _PROMPTS]
         done = fleet.run()
         assert len(done) == len(rids), "lost requests"
-        for rid, ref in zip(rids, _refs(8)):
+        for rid, ref in zip(rids, refs):
             np.testing.assert_array_equal(done[rid].output_ids, ref)
         st = fleet.stats()
         assert st["roles"] == {"r0": "prefill", "r1": "decode"}
@@ -204,7 +232,7 @@ class TestDisaggFleet:
         assert st["handoff_fallbacks"] == 0 and st["handoffs_pending"] == 0
         kv = st["kv_transfer"]
         assert kv["pages"] > 0 and kv["bytes"] > 0
-        assert kv["rank_local_hit_rate"] == 1.0     # equal mp degree (1)
+        assert kv["rank_local_hit_rate"] == 1.0     # equal mp degree
         assert kv["transfer_s"]["count"] == len(rids)
         # router saw both role dimensions on the PR 14 seam
         roles_routed = fleet.router.stats()["routed_by_role"]

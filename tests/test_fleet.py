@@ -925,93 +925,3 @@ class TestFleetStreaming:
             rep.engine.release_cache()
             assert rep.engine.pool.num_free == rep.engine.pool.num_pages
 
-
-# ---------------------------------------------------------------------------
-# bench --trace failover artifact schema (perf/check_obs.py)
-# ---------------------------------------------------------------------------
-def test_check_obs_failover_validator_pos_neg():
-    import sys
-    from pathlib import Path
-    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
-    from perf.check_obs import validate_artifact
-    hist = {"count": 4, "sum": 1.0, "mean": 0.25, "min": 0.1, "max": 0.5,
-            "p50": 0.2, "p95": 0.5, "p99": 0.5, "unit": "s"}
-    art = {
-        "metric": "trace_failover", "lost_requests": 0,
-        "outputs_bitexact": True,
-        "fleet": {"failovers": 1, "migrations": 2, "torn_snapshots": 0,
-                  "requests_submitted": 4, "requests_resolved": 4,
-                  "recovery": {"count": 1, "p50_ms": 5.0, "p95_ms": 5.0,
-                               "p99_ms": 5.0},
-                  # ISSUE 12: FleetTelemetry aggregation
-                  "merged": {"serve.ttft_s": dict(hist),
-                             "serve.e2e_s": dict(hist),
-                             "engine.step_host_s": dict(hist)},
-                  "per_replica_telemetry": {
-                      "r0": {"mem.pool_occupancy_frac": 0.3},
-                      "r1": {"mem.pool_occupancy_frac": 0.2}}},
-        # ISSUE 12: stitched cross-component trace + merged failover dump
-        "stitched": {"components": ["router", "r0 (crashed#1)", "r1"],
-                     "trace_events": 100, "flow_events": 6,
-                     "requests_stitched": 4,
-                     "max_chain": ["router", "r0 (crashed#1)", "r1"]},
-        "failover_dump": {"reason": "failover", "routing_decisions": 4,
-                          "replica_ring_events": 9},
-        # ISSUE 13: critical-path attribution + health-sentinel sections
-        "attribution": {
-            "requests": 4, "exact_requests": 4, "e2e_s_total": 2.0,
-            "segments": {"queue": {"total_s": 0.5, "frac": 0.25},
-                         "decode_sync": {"total_s": 1.0, "frac": 0.5},
-                         "migration": {"total_s": 0.5, "frac": 0.25}},
-            "decode_sync_frac": 0.5,
-            "slowest": [{"key": 1, "e2e_s": 0.9}]},
-        "alerts": {"status": "ok", "active_alerts": 0, "fired_total": 1,
-                   "components": {"r0": {"fired_total": 1},
-                                  "r1": {"fired_total": 0}}},
-        "slo_report": {
-            "requests": 4, "ttft_deadline_ms": 2000.0,
-            "goodput_fraction": 1.0, "on_time_requests": 4,
-            "total_tokens": 32, "goodput_tokens": 32,
-            **{b: {"p50_ms": 1.0, "p95_ms": 1.0, "p99_ms": 1.0,
-                   "count": 4} for b in ("ttft", "tpot", "e2e")}},
-    }
-    assert validate_artifact(art, "failover") == []
-    bad = dict(art, lost_requests=2)
-    assert any("ZERO" in p for p in validate_artifact(bad, "failover"))
-    bad = dict(art, outputs_bitexact=False)
-    assert any("bit-for-bit" in p
-               for p in validate_artifact(bad, "failover"))
-    bad = dict(art, fleet=dict(art["fleet"], failovers=0))
-    assert any("never fired" in p
-               for p in validate_artifact(bad, "failover"))
-    no_slo = {k: v for k, v in art.items() if k != "slo_report"}
-    assert any("slo_report" in p
-               for p in validate_artifact(no_slo, "failover"))
-    # ISSUE 12 negatives: a crashed request NOT stitched across >= 3
-    # tracks, lost merged histograms, a dump without routing decisions
-    bad = dict(art, stitched=dict(art["stitched"],
-                                  max_chain=["router", "r1"]))
-    assert any("max_chain" in p for p in validate_artifact(bad, "failover"))
-    bad = dict(art, stitched=dict(art["stitched"], flow_events=0))
-    assert any("flow" in p for p in validate_artifact(bad, "failover"))
-    fleet_bad = dict(art["fleet"])
-    fleet_bad.pop("merged")
-    bad = dict(art, fleet=fleet_bad)
-    assert any("merged" in p for p in validate_artifact(bad, "failover"))
-    bad = dict(art, fleet=dict(art["fleet"], per_replica_telemetry={
-        "r0": {"serve.rejections": 0}}))
-    assert any("mem.pool_occupancy_frac" in p
-               for p in validate_artifact(bad, "failover"))
-    bad = dict(art, failover_dump=dict(art["failover_dump"],
-                                       routing_decisions=0))
-    assert any("routing" in p for p in validate_artifact(bad, "failover"))
-    # ISSUE 13 negatives: inexact attribution, lost sections, sentinel-off
-    bad = dict(art, attribution=dict(art["attribution"], exact_requests=2))
-    assert any("exact" in p for p in validate_artifact(bad, "failover"))
-    bad = {k: v for k, v in art.items() if k != "attribution"}
-    assert any("attribution" in p for p in validate_artifact(bad,
-                                                             "failover"))
-    bad = dict(art, alerts=dict(art["alerts"], components={}))
-    assert any("sentinel" in p for p in validate_artifact(bad, "failover"))
-    bad = {k: v for k, v in art.items() if k != "alerts"}
-    assert any("alerts" in p for p in validate_artifact(bad, "failover"))

@@ -1,6 +1,6 @@
-"""ERNIE (BASELINE #3) and SD-UNet (BASELINE #5) model families + the
-LLaMA-MoE variant: forward shapes, training convergence, and the
-BASELINE-prescribed parallel mode (ERNIE: sharding stage-2)."""
+"""ERNIE and SD-UNet model families + the LLaMA-MoE variant: forward
+shapes, training convergence, and ERNIE's parallel mode (sharding
+stage-2)."""
 import numpy as np
 import pytest
 import jax
@@ -77,7 +77,7 @@ def test_ernie_attention_mask_and_classifier():
 
 @requires_8
 def test_ernie_sharding_stage2():
-    """The BASELINE #3 mode: ERNIE MLM under ZeRO stage-2 on the mesh."""
+    """ERNIE's parallel mode: MLM under ZeRO stage-2 on the mesh."""
     from paddle_tpu.models.ernie import ernie_config_tiny, ErnieForMaskedLM
     from paddle_tpu.distributed.topology import build_mesh
     from paddle_tpu.parallel.sharded import ShardedTrainStep

@@ -3,10 +3,8 @@ log-bucketed histogram quantiles, EngineStats snapshot/delta + stats()
 monotonicity across a serving trace, request-lifecycle tracing with a
 nested Chrome-trace export, the crash flight recorder (stall / injected
 fault / preemption-storm dumps), Request timing fields, the telemetry-off
-no-op guarantee, and the obs-check artifact schema validator."""
+no-op guarantee, and the snapshot contract an operator's dashboard reads."""
 import json
-import sys
-from pathlib import Path
 
 import numpy as np
 import pytest
@@ -535,139 +533,29 @@ class TestSLO:
 
 
 # ---------------------------------------------------------------------------
-# obs-check artifact schema validator (perf/check_obs.py)
+# the snapshot an operator scrapes (README §Observability)
 # ---------------------------------------------------------------------------
-sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
-from perf.check_obs import validate_artifact  # noqa: E402
-
-
-def _section_from_engine(eng):
-    tel = eng.telemetry
-    return {
-        "tokens_per_sec": 100.0,
-        "ttft_p50_ms": 1.0, "ttft_p95_ms": 2.0, "ttft_p99_ms": 3.0,
-        "slo_ttft_ms": 1000.0, "goodput_on_time_requests": 1,
-        "goodput_fraction": 1.0,
-        "engine_stats": eng.stats(),
-        "metrics": tel.snapshot(eng.stats()),
-        "slo_report": tel.slo_report(1.0, window_s=1.0),
-        # ISSUE 7 observatory sections (schema-gated like the rest).
-        # The window must COVER the accounted phase time (a fixed 1.0 s
-        # under-covers when the run absorbed compiles on a loaded host,
-        # and the validator rightly rejects fractions summing past 1).
-        "utilization": tel.utilization_report(window_s=_window_for(tel)),
-        "memory": tel.memory_report(eng.stats()),
-        "compile": tel.compile_report(),
-    }
-
-
-def _window_for(tel):
-    u = tel.utilization_report()
-    accounted = u["host_busy_s"] + u["dispatch_s"] + u["device_wait_s"]
-    return max(1.0, accounted * 1.25)
-
-
-def _overlap_section(ratio=1.05, cores=1, steps=10, reduced=True):
-    """A bench_serving-shaped ISSUE 10 overlap A/B section (the serving
-    trace must carry one; perf/check_obs gates its paired ratio)."""
-    return {"enabled": True, "rounds": 3,
-            "tokens_per_sec_on": 105.0, "tokens_per_sec_off": 100.0,
-            "best_paired_ratio": ratio, "pair_ratios": [ratio, 0.99, 1.0],
-            "median_ratio": 1.0, "step_host_p50_ms_on": 9.5,
-            "step_host_p50_ms_off": 10.0, "step_host_p50_reduced": reduced,
-            "outputs_bit_exact": True, "overlap_steps": steps,
-            "quiesces": 1, "inflight_depth_max": 1,
-            "host_cpu_count": cores, "arrival_pacing": "step-replay"}
-
-
-class TestObsCheckValidator:
-    def test_real_engine_section_passes(self):
-        cfg, params = _llama()
-        eng = _engine(cfg, params, telemetry=True)
-        eng.submit(rng.integers(1, 64, (5,)).astype(np.int32),
-                   max_new_tokens=3)
-        eng.run()
-        art = {"metric": "trace_serving", **_section_from_engine(eng),
-               "overlap": _overlap_section()}
-        assert validate_artifact(art, "serving") == []
-        sp = {"metric": "trace_shared_prefix",
-              "prefix_cache": _section_from_engine(eng),
-              "pr1_engine": _section_from_engine(eng)}
-        assert validate_artifact(sp, "shared-prefix") == []
-
-    def test_overlap_gate_pos_neg(self):
-        """The ISSUE 10 overlap gate: schema, bit-exactness, and the
-        machine-aware paired-ratio floor (>= 1.0 multi-core; 0.97
-        no-regression on a single-core host where overlap physically
-        cannot beat time-slicing)."""
-        cfg, params = _llama()
-        eng = _engine(cfg, params, telemetry=True)
-        eng.submit(rng.integers(1, 64, (5,)).astype(np.int32),
-                   max_new_tokens=3)
-        eng.run()
-        base = {"metric": "trace_serving", **_section_from_engine(eng)}
-        # missing section is a failure
-        assert any("overlap" in p
-                   for p in validate_artifact(dict(base), "serving"))
-        ok = dict(base, overlap=_overlap_section(ratio=0.98, cores=1))
-        assert validate_artifact(ok, "serving") == []   # single-core bar
-        multi_bad = dict(base,
-                         overlap=_overlap_section(ratio=0.98, cores=8))
-        assert any("best_paired_ratio" in p
-                   for p in validate_artifact(multi_bad, "serving"))
-        single_bad = dict(base,
-                          overlap=_overlap_section(ratio=0.9, cores=1))
-        assert any("best_paired_ratio" in p
-                   for p in validate_artifact(single_bad, "serving"))
-        p50_bad = dict(base, overlap=_overlap_section(cores=8,
-                                                      reduced=False))
-        assert any("step_host_p50" in p
-                   for p in validate_artifact(p50_bad, "serving"))
-        never = dict(base, overlap=_overlap_section(steps=0))
-        assert any("never actually double-buffered" in p
-                   for p in validate_artifact(never, "serving"))
-        inexact = dict(base, overlap=dict(_overlap_section(),
-                                          outputs_bit_exact=False))
-        assert any("bit" in p
-                   for p in validate_artifact(inexact, "serving"))
-
-    def test_missing_fields_are_reported(self):
-        cfg, params = _llama()
-        eng = _engine(cfg, params, telemetry=True)
-        eng.submit(rng.integers(1, 64, (5,)).astype(np.int32),
-                   max_new_tokens=3)
-        eng.run()
-        art = {"metric": "trace_serving", **_section_from_engine(eng)}
-        art.pop("slo_report")
-        art["metrics"].pop("serve.ttft_s")
-        del art["ttft_p99_ms"]
-        art["utilization"].pop("device_idle_frac_est")
-        art.pop("memory")
-        art["compile"]["per_fn"]["prefill"] = {"count": 1}   # no total_s
-        problems = validate_artifact(art, "serving")
-        text = "\n".join(problems)
-        assert "slo_report" in text
-        assert "serve.ttft_s" in text
-        assert "ttft_p99_ms" in text
-        assert "device_idle_frac_est" in text
-        assert "memory" in text
-        assert "per_fn['prefill']" in text
-        assert validate_artifact({}, "serving")      # empty artifact fails
-        assert validate_artifact(art, "nope")        # unknown trace fails
-
-    def test_overlapping_utilization_fractions_fail(self):
-        """The decomposition must be DISJOINT: buckets summing well past
-        1.0 (the pre-fix sched/prefill double count) are a gate failure."""
-        cfg, params = _llama()
-        eng = _engine(cfg, params, telemetry=True)
-        eng.submit(rng.integers(1, 64, (5,)).astype(np.int32),
-                   max_new_tokens=3)
-        eng.run()
-        art = {"metric": "trace_serving", **_section_from_engine(eng)}
-        art["utilization"]["host_busy_frac"] = 0.6
-        art["utilization"]["dispatch_frac"] = 0.8      # sums to > 1.4
-        problems = validate_artifact(art, "serving")
-        assert any("disjoint" in p for p in problems), problems
+def test_snapshot_carries_the_documented_histograms_and_counters():
+    """After one request through a real telemetry engine the snapshot
+    holds the five latency histograms with their seven fields and the
+    four engine counters: what a dashboard built on the README reads."""
+    cfg, params = _llama()
+    eng = _engine(cfg, params, telemetry=True)
+    eng.submit(rng.integers(1, 64, (5,)).astype(np.int32), max_new_tokens=3)
+    eng.run()
+    snap = eng.telemetry.snapshot(eng.stats())
+    for name in ("serve.ttft_s", "serve.tpot_s", "serve.queue_s",
+                 "serve.e2e_s", "engine.step_host_s"):
+        hist = snap[name]
+        for field in ("count", "sum", "min", "max", "p50", "p95", "p99"):
+            assert field in hist, (name, field)
+        assert hist["count"] >= 1, name
+    for name in ("engine.tokens_generated", "engine.decode_steps",
+                 "engine.prefill_tokens_executed",
+                 "engine.fused_sample_steps"):
+        assert name in snap, name
+    assert snap["engine.tokens_generated"] == 3
+    assert snap["engine.prefill_tokens_executed"] == 5
 
 
 # ---------------------------------------------------------------------------
@@ -728,7 +616,7 @@ class TestUtilization:
         tel = Telemetry()
         eng = _engine(cfg, params, telemetry=tel, prefill_chunk=4,
                       prompt_bucket=4)
-        # warm, then measure a window (mirrors the bench protocol)
+        # warm, then measure a window
         eng.submit(rng.integers(1, 64, (13,)).astype(np.int32),
                    max_new_tokens=4)
         eng.run()
@@ -1188,62 +1076,6 @@ class TestCheckpointTelemetry:
         assert torn2["fault_plan"]["seed"] == 11
 
 
-# ---------------------------------------------------------------------------
-# bench-trend gate (perf/bench_trend.py satellite)
-# ---------------------------------------------------------------------------
-from perf.bench_trend import (find_serving_section, trend,  # noqa: E402
-                              validate as validate_trend)
-
-
-class TestBenchTrend:
-    def _write(self, d, rnd, parsed, rc=0):
-        art = {"n": rnd, "cmd": "python bench.py", "rc": rc,
-               "tail": "...", "parsed": parsed}
-        (d / f"BENCH_r{rnd:02d}.json").write_text(json.dumps(art))
-
-    def test_trajectory_over_valid_artifacts(self, tmp_path, capsys):
-        self._write(tmp_path, 1, {"metric": "m", "value": 100.0,
-                                  "unit": "tok/s"})
-        self._write(tmp_path, 2, {"metric": "m", "value": 150.0,
-                                  "unit": "tok/s", "vs_baseline": 1.5,
-                                  "serving": {"tokens_per_sec": 800.0,
-                                              "ttft_p95_ms": 70.0,
-                                              "goodput_fraction": 1.0}})
-        assert trend(str(tmp_path)) == 0
-        out = capsys.readouterr().out
-        assert "2 artifact(s) OK" in out
-        assert "70.00" in out and "800.0" in out
-        assert "1.50x" in out
-
-    def test_schema_drift_fails(self, tmp_path, capsys):
-        self._write(tmp_path, 1, {"metric": "m", "unit": "x"})  # no value
-        assert trend(str(tmp_path)) == 1
-        assert "headline key 'value'" in capsys.readouterr().out
-
-    def test_nonzero_rc_fails(self, tmp_path, capsys):
-        self._write(tmp_path, 1, {"metric": "m", "value": 1, "unit": "x"},
-                    rc=2)
-        assert trend(str(tmp_path)) == 1
-        assert "rc=2" in capsys.readouterr().out
-
-    def test_losing_serving_section_is_drift(self, tmp_path, capsys):
-        serving = {"ttft_p95_ms": 1.0, "goodput_fraction": 1.0}
-        self._write(tmp_path, 1, {"metric": "m", "value": 1, "unit": "x",
-                                  "deep": {"nest": serving}})
-        self._write(tmp_path, 2, {"metric": "m", "value": 2, "unit": "x"})
-        assert find_serving_section({"deep": {"nest": serving}}) == serving
-        assert trend(str(tmp_path)) == 1
-        assert "missing here" in capsys.readouterr().out
-
-    def test_repo_artifacts_pass(self):
-        """The committed BENCH_r*.json history must satisfy the gate."""
-        root = Path(__file__).resolve().parents[1]
-        for p in sorted(root.glob("BENCH_r*.json")):
-            with open(p) as f:
-                art = json.load(f)
-            assert validate_trend(art, str(p)) == [], p
-
-
 class TestReviewHardening:
     def test_batch_samples_handles_0d_and_unknowable(self):
         from paddle_tpu.observability.train import batch_samples
@@ -1333,20 +1165,3 @@ class TestReviewHardening:
         launch = [e for e in tel.flight.events()
                   if e["event"] == "ckpt.save"]
         assert launch and launch[0]["async_save"] is True
-
-    def test_bench_trend_zero_tps_is_reported_not_dropped(self, tmp_path,
-                                                          capsys):
-        art = {"n": 1, "cmd": "x", "rc": 0, "tail": "",
-               "parsed": {"metric": "m", "value": 1.0, "unit": "x",
-                          "serving": {"tokens_per_sec": 0.0,
-                                      "ttft_p95_ms": 5.0,
-                                      "goodput_fraction": 0.0}}}
-        (tmp_path / "BENCH_r01.json").write_text(json.dumps(art))
-        assert trend(str(tmp_path)) == 0
-        out = capsys.readouterr().out
-        row = next(line for line in out.splitlines() if line.strip()
-                   .startswith("1 "))
-        cols = row.split()
-        # round value vs_base serve_tps ttft goodput — the 0.0 tokens/s is
-        # REPORTED (alarming data point), not rendered as missing "-"
-        assert cols[3] == "0.0" and cols[4] == "5.00" and cols[5] == "0.000"

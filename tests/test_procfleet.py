@@ -66,10 +66,29 @@ def _fleet(tmp_path, **kw):
 
 
 def _check_bitexact(frids, results):
+    """`frids` in PROMPTS order (cycling past its end)."""
     ref = _refs()
     assert len(results) == len(frids), "request lost"
     for i, f in enumerate(frids):
-        assert list(results[f].generated) == ref[i], f"request {i} diverged"
+        assert list(results[f].generated) == ref[i % len(PROMPTS)], \
+            f"request {i} diverged"
+
+
+def _sigkill_busy_worker(fl, submit):
+    """The crash of the SIGKILL drills: let 8 tokens stream, place two
+    more requests through `submit(prompt) -> frid` and SIGKILL a worker
+    that holds one of them, before the supervisor's next round.  What the
+    victim holds is then unfinished by the supervisor's own record,
+    whatever the worker's pace: a worker can finish a whole wave inside
+    one supervisor round (the round waits on the worker's lock through
+    its compiles and snapshots), and a kill that finds nothing assigned
+    fails nothing over.  Returns (the two frids, the victim)."""
+    while fl.tokens_streamed < 8:
+        fl.step()
+    late = [submit(p) for p in PROMPTS[:2]]
+    victim = next(w for w in fl._workers if fl._assigned[w.name])
+    os.kill(victim.pid, signal.SIGKILL)           # real crash mid-decode
+    return late, victim
 
 
 class TestRoundTrip:
@@ -100,28 +119,29 @@ class TestSigkillFailover:
     def test_zero_loss_bitexact_and_stream_once(self, tmp_path):
         fl = _fleet(tmp_path)
         streams: dict[int, list] = {}
-        frids = []
-        for p in PROMPTS:
+
+        def submit(p):
             acc: list = []
             frid = fl.submit(p, max_new_tokens=N_NEW, on_token=acc.append)
             streams[frid] = acc
-            frids.append(frid)
-        while fl.tokens_streamed < 8:
-            fl.step()
-        victim = fl._workers[0]
+            return frid
+
+        frids = [submit(p) for p in PROMPTS]
+        late, victim = _sigkill_busy_worker(fl, submit)
         dead_key = victim.key()
-        os.kill(victim.pid, signal.SIGKILL)       # real crash mid-decode
+        frids += late
         res = fl.run()
         _check_bitexact(frids, res)
         st = fl.stats()
         assert st["failovers"] == 1
-        assert st["worker_restarts"]["w0"] == 1
+        assert st["worker_restarts"][victim.name] == 1
         assert st["recovery"]["count"] == 1
         assert st["recovery"]["p50_ms"] > 0.0     # wall-clock, not virtual
         # the fleet-level hook fired exactly once per position even though
         # the replacement re-decoded tokens the router already streamed
         for i, f in enumerate(frids):
-            assert streams[f] == _refs()[i], "double-streamed token"
+            assert streams[f] == _refs()[i % len(PROMPTS)], \
+                "double-streamed token"
         fl.shutdown()
         fl.assert_worker_invariants()
         # the killed generation is vouched for by its replacement
@@ -130,10 +150,10 @@ class TestSigkillFailover:
 
     def test_stitched_trace_crosses_process_boundary(self, tmp_path):
         fl = _fleet(tmp_path, trace_every=2)
-        frids = [fl.submit(p, max_new_tokens=N_NEW) for p in PROMPTS[:4]]
-        while fl.tokens_streamed < 8:
-            fl.step()
-        os.kill(fl._workers[0].pid, signal.SIGKILL)
+        frids = [fl.submit(p, max_new_tokens=N_NEW) for p in PROMPTS]
+        late, _ = _sigkill_busy_worker(
+            fl, lambda p: fl.submit(p, max_new_tokens=N_NEW))
+        frids += late
         res = fl.run()
         _check_bitexact(frids, res)
         summary = fl.stitcher().summary()

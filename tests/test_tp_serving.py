@@ -151,14 +151,28 @@ class TestTPEngineBitExact:
 
     def test_quantized_allreduce_arm_greedy_parity(self):
         # the EQuARX arm is LOSSY on logits but must hold greedy parity on
-        # the margin-boosted params (the parity_report exact-match gate's
-        # unit-sized cousin; the bench gates the full scenario set)
+        # the margin-boosted params (the unit-sized cousin of the
+        # exact-match gate over the whole scenario set, next test)
         cfg = _cfg()
         params = _echo_params(cfg, seed=14)
         f32, _ = _drive(params, cfg, mesh=_mesh(2))
         q, eng = _drive(params, cfg, mesh=_mesh(2), quantized_allreduce=True)
         assert eng.stats()["quantized_allreduce"] is True
         assert f32 == q
+
+    def test_quantized_allreduce_parity_over_the_scenario_set(self):
+        # the parity harness re-aimed through its per-arm seam: both arms
+        # TP, pages and weights f32, so the one difference under the
+        # exact-match gate is the int8 grid of the per-layer AllReduce
+        from paddle_tpu.serving.quant import parity_report
+        cfg = _cfg()
+        mesh = _mesh(2)
+        rep = parity_report(
+            _echo_params(cfg, seed=14), cfg, kv_dtype=None, quantize=None,
+            drift_prompts=0, ref_engine_kw={"mesh": mesh},
+            q_engine_kw={"mesh": mesh, "quantized_allreduce": True})
+        assert rep["scenarios"] >= 8
+        assert rep["exact_match"] >= 0.99, rep
 
     @pytest.mark.slow
     def test_logit_drift_seam_measures_quantized_collectives(self):
