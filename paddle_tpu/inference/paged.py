@@ -111,7 +111,7 @@ tiling it, `serve.sched` (with the admission's `serve.prefill_dense` /
 `serve.prefill_chunk` / `serve.first_token_sync` inside), `serve.provision`,
 `serve.{decode,overlap,verify}_{dispatch,sync,record}` and
 `serve.overlap_join_sync`, with `step` / `rid` / `tokens` / `padded` /
-`slots` / `k` as stats.  They cost nothing until somebody opens a profiler
+`pages` / `slots` / `k` as stats.  They cost nothing until somebody opens a profiler
 session and then lie on the device events' clock, so an idle gap of the
 device can be laid under the host phase that caused it
 (benchmark/host_spans.py).  The kernels carry `kernel_metadata` labels and
@@ -1054,6 +1054,12 @@ class ServingEngine:
                                        #   each dense / chunk call ...
         self.prefill_tokens_padded = 0  # ... and the padded rows that call
                                        #   computed (T_bucket / C_bucket)
+        self.prefill_kv_pages_written = 0  # ... and the pages their K/V
+                                       #   rows lie on: what a prefill's
+                                       #   page-run writer issues an
+                                       #   update for (a head, side and
+                                       #   layer alike), where the row
+                                       #   form issued one a token
         self.decode_kv_tokens_attended = 0  # KV positions the horizon's
                                        #   live decode steps had to read
                                        #   (host ints at the drain) ...
@@ -1686,14 +1692,13 @@ class ServingEngine:
                     # bucket ladder  # graftlint: disable=LEAK001
                     self._prefill_jit[(Tb, greedy)] = pf
                 self._join_dispatch()   # prefill chains on concrete pages
-                self.prefill_tokens_dispatched += T
-                self.prefill_tokens_padded += Tb
+                kv_pages = self._count_prefill(0, T, Tb)
                 try:
                     # the span closes BEFORE the bookkeeping below samples
                     # the first token, so the request record keeps ladder
                     # order: admitted -> prefill_dense -> first_token
                     with self._span("prefill_dense", rid=req.rid, pos=0,
-                                    tokens=T, padded=Tb):
+                                    tokens=T, padded=Tb, pages=kv_pages):
                         tok, self._pages_k, self._pages_v = \
                             self._call_paged(
                                 pf,
@@ -1722,6 +1727,17 @@ class ServingEngine:
                 slot.prefill_pos = matched
                 self._lengths[s] = matched
                 self._prefill_advance(s)
+
+    def _count_prefill(self, pos: int, c: int, padded: int) -> int:
+        """The dispatch-time counters of ONE prefill call: ``c`` real
+        tokens from position ``pos`` in ``padded`` computed rows.  Returns
+        the pages their K/V rows lie on (``pos`` may sit inside a page
+        after a prefix-cache hit; the last is part full)."""
+        self.prefill_tokens_dispatched += c
+        self.prefill_tokens_padded += padded
+        pages = (pos + c - 1) // self.page_size - pos // self.page_size + 1
+        self.prefill_kv_pages_written += pages
+        return pages
 
     def _finish_admission(self, s, tok, ctx, pages,
                           resuming):                  # graftlint: hot
@@ -1777,10 +1793,9 @@ class ServingEngine:
         Pb = min(self.max_pages_per_seq, math.ceil(ctx_pages / 4) * 4)
         ids = np.zeros((1, Cb), np.int32)
         ids[0, :c] = slot.ctx[pos:pos + c]
-        self.prefill_tokens_dispatched += c
-        self.prefill_tokens_padded += Cb
+        kv_pages = self._count_prefill(pos, c, Cb)
         with self._span("prefill_chunk", rid=req.rid, pos=pos, tokens=c,
-                        padded=Cb):
+                        padded=Cb, pages=kv_pages):
             logits, tok_g, self._pages_k, self._pages_v = self._call_paged(
                 self._chunk_jit,
                 self.params, jnp.asarray(ids), jnp.asarray(pos, jnp.int32),
@@ -2707,6 +2722,7 @@ class ServingEngine:
                       "timeouts", "rejections", "cache_hits",
                       "cache_hit_tokens", "prefill_tokens",
                       "prefill_tokens_dispatched", "prefill_tokens_padded",
+                      "prefill_kv_pages_written",
                       "decode_kv_tokens_attended",
                       "decode_kv_pages_attended",
                       "cache_evictions", "cow_copies", "verify_steps",
@@ -3200,6 +3216,10 @@ class ServingEngine:
             "prefill_tokens_executed": self.prefill_tokens,
             "prefill_tokens_dispatched": self.prefill_tokens_dispatched,
             "prefill_tokens_padded": self.prefill_tokens_padded,
+            # a dispatched token is a K/V row written; rows / pages is the
+            # factor of scatter updates the page-run writer saves
+            "prefill_kv_rows_written": self.prefill_tokens_dispatched,
+            "prefill_kv_pages_written": self.prefill_kv_pages_written,
             "decode_kv_tokens_attended": self.decode_kv_tokens_attended,
             "decode_kv_pages_attended": self.decode_kv_pages_attended,
             "cached_prefix_tokens": self.cache_hit_tokens,

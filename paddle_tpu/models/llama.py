@@ -30,7 +30,8 @@ __all__ = ["LlamaConfig", "LlamaForCausalLM", "LlamaModel", "LlamaDecoderLayer",
            "llama_config_7b", "llama_config_tiny", "build_llama_decode",
            "build_llama_paged_decode", "make_paged_decode_horizon",
            "functional_params_from_layer", "llama_generate",
-           "gather_kv_pages", "scatter_kv_pages", "scatter_kv_rows"]
+           "gather_kv_pages", "scatter_kv_pages", "scatter_kv_rows",
+           "scatter_kv_run"]
 
 
 @dataclass
@@ -739,6 +740,47 @@ def scatter_kv_rows(store, layer, rows, page, off):
         rows.astype(store.dtype))
 
 
+def scatter_kv_run(store, layer, rows, start, length, page_row):
+    """Write a CONTIGUOUS run of token rows into one layer of a page store,
+    in place, a page an update: ``rows [C, Hkv, D]`` (``[C, Hkv]`` for scale
+    pages) are positions ``start .. start+C-1`` of the sequence whose page
+    table is ``page_row [P]``, the first ``length`` of them real; ``layer``,
+    ``start`` and ``length`` may be traced.
+
+    What a prefill writes is whole pages but for the run's two ends, and a
+    page ``[ps, D]`` is whole tiles of the pool where a row is a sixteenth
+    of one.  So the scatter's INDICES run over (layer, head, page) and its
+    update window is ``(ps, D)`` — the pool's two minor dimensions, the
+    row-major layout the attention kernel reads — about ``(C / ps + 1) x
+    Hkv`` updates where :func:`scatter_kv_rows` issues ``C x Hkv``.  The
+    touched pages are gathered first and the rows outside the run keep what
+    they held (``start`` may lie inside a page after a prefix-cache hit, the
+    last real page is part full), so every real page comes out bit-equal to
+    the row form's.  A page that holds only padding is the TRASH page (the
+    store's last, :func:`build_llama_paged_decode`) written back unchanged;
+    the row form throws the padded rows into it."""
+    n_rows, hkv = rows.shape[:2]
+    tail = rows.shape[2:]
+    ps, trash = store.shape[3], store.shape[2] - 1
+    n_pg = -(-n_rows // ps) + 1              # the run may start inside a page
+    first, lead = start // ps, start % ps
+    # the run laid over whole pages: slot s holds row s - lead
+    row_of = jnp.arange(n_pg * ps) - lead
+    real = ((row_of >= 0) & (row_of < length)).reshape(n_pg, ps)
+    view = jax.lax.dynamic_update_slice_in_dim(
+        jnp.zeros((n_pg * ps, hkv) + tail, store.dtype),
+        rows.astype(store.dtype), lead, axis=0)
+    view = jnp.moveaxis(view.reshape((n_pg, ps, hkv) + tail), 2, 0)
+    ids = jnp.where(
+        real.any(axis=1),
+        page_row[jnp.minimum(first + jnp.arange(n_pg), page_row.shape[0] - 1)],
+        trash)[None]
+    heads = jnp.arange(hkv)[:, None]
+    keep = store[layer, heads, ids]                   # [Hkv, n_pg, ps, (D)]
+    mask = real.reshape((1, n_pg, ps) + (1,) * len(tail))
+    return store.at[layer, heads, ids].set(jnp.where(mask, view, keep))
+
+
 def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
                              num_pages: int = 64, dtype=None,
                              attention_impl: str = "auto",
@@ -813,13 +855,16 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
 
     The page pool stays IN PLACE through all four fns (jit them with
     pages_k/pages_v donated): ONE layer loop (`_layers`) carries both sides
-    whole, `scatter_kv_rows` writes the fresh rows into layer ``li`` with a
-    D-only window, and the ragged kernel takes the whole pool plus ``li``
-    and picks the layer in its page DMA.  Scanning over the pool (xs/ys) or
-    scattering a (Hkv, D) window made every executable slice, relay out
-    and stack back a layer per layer and copy the whole pool around the
-    loop — 41 % of a decode step on the v5e (PERF.md section 6, PR 28;
-    tests/test_chip_compile.py holds the compiled programs to it).
+    whole, the fresh rows go into layer ``li`` a ROW an update with a D-only
+    window (`scatter_kv_rows`: decode and verify, per-slot positions) or a
+    PAGE an update with a (ps, D) window (`scatter_kv_run`: both prefills,
+    one contiguous run; PR 32), and the ragged kernel takes the whole pool
+    plus ``li`` and picks the layer in its page DMA.  Scanning over the
+    pool (xs/ys) or scattering a (Hkv, D) window made every executable
+    slice, relay out and stack back a layer per layer and copy the whole
+    pool around the loop — 41 % of a decode step on the v5e (PERF.md
+    section 6, PR 28; tests/test_chip_compile.py holds the compiled
+    programs to it).
 
     ``kv_dtype`` ("int8" / "fp8", ROADMAP item 2): the page store holds
     QUANTIZED K/V — each side becomes a ``{"q": [L, Hkv, NP+1, ps, D]
@@ -917,24 +962,37 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
                     "s": jnp.zeros(sshape, jnp.float32)}
         return {"k": side(), "v": side()}
 
-    def _scatter(store, li, vals, page, off):
-        """Write per-token K or V rows (``vals [*tok, nkv, D]``, tokens at
-        ``page/off [*tok]``) into layer ``li`` of the WHOLE page store, in
-        place (:func:`scatter_kv_rows`); returns the updated store plus the
-        LOCAL view of what was written — ``vals`` itself on the f32/bf16
-        path, the dequantized round trip on a quantized store (so a caller
-        attending over its own fresh rows sees exactly what any later
-        gather of the pages will see)."""
+    def _scatter(store, li, vals, write):
+        """Write per-token K or V rows ``vals [*tok, nkv, D]`` into layer
+        ``li`` of the WHOLE page store, in place, through the caller's
+        ``write(plane, li, rows) -> plane`` (``_rows_at`` or ``_run_at``
+        below); returns the updated store plus the LOCAL view of what was
+        written — ``vals`` itself on the f32/bf16 path, the dequantized
+        round trip on a quantized store (so a caller attending over its own
+        fresh rows sees exactly what any later gather of the pages will
+        see)."""
         if kv_dtype is None:
-            return scatter_kv_rows(store, li, vals, page, off), vals
+            return write(store, li, vals), vals
         qv, sv = quantize_kv(vals, qmax=kv_qmax, dtype=kv_storage)
-        new = {"q": scatter_kv_rows(store["q"], li, qv, page, off),
-               "s": scatter_kv_rows(store["s"], li, sv, page, off)}
+        new = {"q": write(store["q"], li, qv), "s": write(store["s"], li, sv)}
         # .astype(d): the jnp paths consume dequantized rows in the
         # COMPUTE dtype, exactly like the f32/bf16 store — activations
         # keep their dtype (no silent f32 promotion) and decode/chunk/
         # verify/dense all see the same rounded values on a bf16 engine
         return new, dequantize_kv(qv, sv).astype(d)
+
+    def _rows_at(page, off):
+        """The writer of decode and verify: token t's row lands at
+        ``page[t], off[t]``, per-slot positions with no run to exploit."""
+        return lambda plane, li, rows: scatter_kv_rows(plane, li, rows, page,
+                                                       off)
+
+    def _run_at(start, length, page_row):
+        """The writer of both prefills: the tokens are ONE contiguous run of
+        positions from ``start``, the first ``length`` real, so they reach
+        the pool a page an update (:func:`scatter_kv_run`)."""
+        return lambda plane, li, rows: scatter_kv_run(plane, li, rows, start,
+                                                      length, page_row)
 
     def _attn(q, pk, pv, li, page_tables, q_start, q_len, kv_len, role):
         """THE attention dispatch: every paged path (decode, speculative
@@ -974,16 +1032,19 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
         h = rms_norm_ref(h_last, hp["ln_f"], c.rms_norm_eps)
         return (h @ hp["lm"]).astype(jnp.float32)
 
-    def _layers(bp, x, pages_k, pages_v, sin, cos, page, off, attend):
+    def _layers(bp, x, pages_k, pages_v, sin, cos, write, attend):
         """THE layer loop of all four paged fns.  ``x [*tok, H]`` are the
         tokens' activations (``tok`` = [T] dense, [C] chunk, [S] decode,
-        [S, Q] verify), ``sin/cos [*tok, D]`` their rotary rows, ``page/off
-        [*tok]`` where each token's K/V row lands.  Per layer: norm, q/k/v,
-        RoPE, the K/V rows written into the pool, ``attend(q, k_loc, v_loc,
-        pk, pv, li) -> o [*tok, nh_l, D]`` (the one thing the fns differ
-        in), wo, MLP.  The page pool is the loop's CARRY, never its xs/ys:
-        scanning over it slices a layer out and stacks it back, a layer of
-        pool read and written twice per layer to change a few rows of it.
+        [S, Q] verify), ``sin/cos [*tok, D]`` their rotary rows, ``write``
+        how their K/V rows reach the pool — chosen by what the caller knows
+        of its tokens: one contiguous run (``_run_at``: both prefills) or
+        per-slot positions (``_rows_at``: decode, verify).  Per layer: norm,
+        q/k/v, RoPE, the K/V rows written into the pool, ``attend(q, k_loc,
+        v_loc, pk, pv, li) -> o [*tok, nh_l, D]`` (the other thing the fns
+        differ in), wo, MLP.  The page pool is the loop's CARRY, never its
+        xs/ys: scanning over it slices a layer out and stacks it back, a
+        layer of pool read and written twice per layer to change a few rows
+        of it.
         Carried, indexed by ``li`` in the scatter and inside the kernel, it
         is updated in place.  Returns (x, pages_k, pages_v)."""
         tok = x.shape[:-1]
@@ -1001,8 +1062,8 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
             v = (h @ lp["wv"]).reshape(*tok, nkv_l, head_dim)
             q = _rope_at(q, sin, cos)
             k = _rope_at(k, sin, cos)
-            pk, k_loc = _scatter(pk, li, k, page, off)
-            pv, v_loc = _scatter(pv, li, v, page, off)
+            pk, k_loc = _scatter(pk, li, k, write)
+            pv, v_loc = _scatter(pv, li, v, write)
             o = _gather_heads(attend(q, k_loc, v_loc, pk, pv, li))
             xc = xc + o.reshape(*tok, nh * head_dim) @ lp["wo"]
             h = rms_norm_ref(xc, lp["ln2"], c.rms_norm_eps)
@@ -1018,10 +1079,8 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
         T = ids.shape[1]
         x = ep["tok"][ids[0]].astype(d)               # [T, H]
         t_idx = jnp.arange(T)
-        valid = t_idx < true_len
-        page = jnp.where(valid, page_row[t_idx // page_size], TRASH)
-        off = t_idx % page_size
-        mask = (t_idx[None, :] <= t_idx[:, None]) & valid[None, :]
+        mask = (t_idx[None, :] <= t_idx[:, None]) \
+            & (t_idx < true_len)[None, :]
 
         def attend(q, k_loc, v_loc, pk, pv, li):
             # dense causal attention over the prompt's own fresh rows
@@ -1035,7 +1094,7 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
             return jnp.einsum("hqk,khd->qhd", p, vf)
 
         x, ks, vs = _layers(bp, x, pages_k, pages_v, sin_t[:T], cos_t[:T],
-                            page, off, attend)
+                            _run_at(0, true_len, page_row), attend)
         h_last = jax.lax.dynamic_index_in_dim(x, true_len - 1, 0,
                                               keepdims=False)
         return _head(hp, h_last), ks, vs
@@ -1045,11 +1104,7 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
         ep, bp, hp = params
         C = ids.shape[1]
         x = ep["tok"][ids[0]].astype(d)               # [C, H]
-        i_idx = jnp.arange(C)
-        valid = i_idx < chunk_len
-        pos = start + i_idx                           # absolute positions
-        page = jnp.where(valid, page_row[pos // page_size], TRASH)
-        off = pos % page_size
+        pos = start + jnp.arange(C)                   # absolute positions
         sin, cos = jnp.take(sin_t, pos, axis=0), jnp.take(cos_t, pos, axis=0)
         # the whole chunk is ONE ragged query segment of the unified
         # kernel: queries at absolute positions start..start+chunk_len-1
@@ -1066,8 +1121,8 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
             return _attn(q[None], pk, pv, li, page_tab,
                          start_r, clen_r, kvlen_r, "chunk")[0]
 
-        x, ks, vs = _layers(bp, x, pages_k, pages_v, sin, cos, page, off,
-                            attend)
+        x, ks, vs = _layers(bp, x, pages_k, pages_v, sin, cos,
+                            _run_at(start, chunk_len, page_row), attend)
         h_last = jax.lax.dynamic_index_in_dim(x, chunk_len - 1, 0,
                                               keepdims=False)
         logits = _head(hp, h_last)
@@ -1094,7 +1149,7 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
                          pos, n_q, eff_len, "decode")[:, 0]
 
         x, ks, vs = _layers(bp, x, pages_k, pages_v, sin_t[pos], cos_t[pos],
-                            page, off, attend)
+                            _rows_at(page, off), attend)
         return _head(hp, x), ks, vs
 
     def verify_step(params, toks, lengths, page_tables, pages_k, pages_v,
@@ -1140,7 +1195,7 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
                          "verify")
 
         x, ks, vs = _layers(bp, x, pages_k, pages_v, sin_t[pos], cos_t[pos],
-                            page, off, attend)
+                            _rows_at(page, off), attend)
         logits = _head(hp, x)                         # [S, Q, V] f32
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return logits[:, 0], greedy, ks, vs

@@ -275,6 +275,29 @@ def _assert_pool_stays_in_place(compiled, pool_shape):
     assert m.temp_size_in_bytes < pool_bytes // 2, m.temp_size_in_bytes
 
 
+_SCATTER = re.compile(
+    r"= \w+\[([\d,]*)\]\S* scatter\(%[\w.-]+, %([\w.-]+), %[\w.-]+\), "
+    r"update_window_dims=\{([\d,]*)\}.*?index_vector_dim=(\d+)")
+
+
+def _pool_scatters(text, pool_shape):
+    """[(update window dims, number of updates)] of every scatter of the
+    compiled program into a value with as many elements as the pool: the
+    updates are counted on the shape of the scatter's INDICES operand, all
+    of its dimensions but the index vector's."""
+    found = []
+    for dims, indices, window, vector_dim in _SCATTER.findall(text):
+        if math.prod(int(n) for n in dims.split(",")) != math.prod(pool_shape):
+            continue
+        (shape,) = re.findall(
+            r"^\s*%%%s = s32\[([\d,]*)\]" % re.escape(indices), text, re.M)
+        shape = [int(n) for n in shape.split(",")]
+        del shape[int(vector_dim):int(vector_dim) + 1]  # implicit when last
+        found.append((tuple(int(n) for n in window.split(",")),
+                      math.prod(shape)))
+    return found
+
+
 @pytest.fixture(scope="module")
 def cell_programs(one_chip):
     """The engine's four paged executables at `serve_chat_c16`'s widths
@@ -322,8 +345,21 @@ def test_serving_executable_leaves_the_page_pool_in_place(cell_programs,
             r"operand_layout_constraints=\{s32\[\d+,32\].*"
             r"(, bf16\[%d,8,513,64,128\]\{4,3,2,1,0\}){2}\}" % CELL_LAYERS,
             text)
-    _assert_pool_stays_in_place(
-        compiled, (CELL_LAYERS, HKV, CELL["num_slots"] * TABLE + 1, PAGE, D))
+    pool = (CELL_LAYERS, HKV, CELL["num_slots"] * TABLE + 1, PAGE, D)
+    _assert_pool_stays_in_place(compiled, pool)
+    # how the fresh K/V rows reach the pool (PR 32), K and V a scatter each.
+    # A prefill's tokens are one contiguous run: a page an update, window
+    # (ps, D) — 512 rows may start inside a page, so 9 pages x 8 heads —
+    # where the row form issued 512 x 8.  Decode and verify write per-slot
+    # positions a row an update, as before: 16 (x 5) rows x 8 heads.
+    rows, pages = CELL["prefill_chunk"], CELL["prefill_chunk"] // PAGE
+    want = {"dense prefill": ((2, 3), (pages + 1) * HKV),
+            "prefill chunk": ((2, 3), (pages + 1) * HKV),
+            "decode horizon": ((2,), CELL["num_slots"] * HKV),
+            "verify": ((3,), CELL["num_slots"] * 5 * HKV)}[program]
+    scatters = _pool_scatters(text, pool)
+    assert scatters == [want, want], scatters
+    assert all(n < rows * HKV for _, n in scatters)
 
 
 def test_tp4_paged_decode_step_compiles_with_kernel_and_allreduce(topo):

@@ -3,6 +3,7 @@ kernel parity vs dense decode attention, page-pool invariants, and
 end-to-end continuous batching matching `llama_generate`'s per-request
 greedy outputs under staggered arrivals."""
 import math
+import re
 
 import numpy as np
 import pytest
@@ -410,3 +411,121 @@ def test_scatter_kv_rows_equals_the_per_layer_window_scatter(tok, store):
         changed = np.asarray(got != pool)
         assert not np.delete(changed, li, axis=0).any()
         assert changed[li].any()
+
+
+# ---------------------------------------------------------------------------
+# scatter_kv_run (PR 32): a prefill's CONTIGUOUS run of rows reaches the pool
+# a page an update.  Every real page must come out bit-equal to what the row
+# form (`scatter_kv_rows` fed the parent's page/off expressions) leaves, so
+# each bit-exactness contract above it holds untouched.
+# ---------------------------------------------------------------------------
+# id: (page size, rows C, start, real length)
+_RUN_CASES = {
+    "start_0_all_real": (64, 128, 0, 128),
+    "start_0_length_100": (64, 128, 0, 100),
+    "start_on_page_64": (64, 128, 64, 128),
+    "start_inside_page_37": (64, 128, 37, 128),
+    "length_ends_inside_a_page": (64, 128, 37, 70),
+    "one_real_row": (64, 128, 37, 1),
+    "whole_pages_of_padding": (64, 256, 64, 30),
+    "page_16_rows_40_start_5": (16, 40, 5, 33),
+    "page_16_rows_40_start_0": (16, 40, 0, 40),
+}
+
+
+@pytest.mark.parametrize("hkv", [8, 2], ids=["hkv8", "tp4_rank_hkv2"])
+@pytest.mark.parametrize("store", ["plain", "int8_codes", "scales"])
+@pytest.mark.parametrize("case", list(_RUN_CASES))
+def test_scatter_kv_run_leaves_the_pool_the_row_form_leaves(case, store,
+                                                            hkv):
+    from paddle_tpu.models.llama import scatter_kv_rows, scatter_kv_run
+    ps, C, start, length = _RUN_CASES[case]
+    L, NP, D = 2, 11, 8
+    TRASH = NP
+    lr = np.random.default_rng(32)
+    tail = () if store == "scales" else (D,)
+    draw = (lambda shape: lr.integers(-127, 128, shape).astype(np.int8)) \
+        if store == "int8_codes" else \
+        (lambda shape: lr.standard_normal(shape).astype(np.float32))
+    # a pool that already HOLDS something: the rows before `start`, past
+    # `length` and on untouched pages must keep it
+    pool = jnp.asarray(draw((L, hkv, NP + 1, ps) + tail))
+    rows = jnp.asarray(draw((C, hkv) + tail))
+    # the tightest table the engine could hand over: the padded rows'
+    # positions run past its end
+    n_pages = -(-(start + length) // ps)
+    page_row = jnp.asarray(lr.permutation(NP)[:n_pages].astype(np.int32))
+
+    def row_form(pool, li, start, length):
+        # the parent's `prefill` / `prefill_chunk`, word for word
+        i_idx = jnp.arange(C)
+        pos = start + i_idx
+        page = jnp.where(i_idx < length, page_row[pos // ps], TRASH)
+        return scatter_kv_rows(pool, li, rows, page, pos % ps)
+
+    run_form = jax.jit(lambda pool, li, start, length: scatter_kv_run(
+        pool, li, rows, start, length, page_row))
+    row_form = jax.jit(row_form)
+    for li in range(L):
+        at = (pool, jnp.int32(li), jnp.int32(start), jnp.int32(length))
+        got, want = np.asarray(run_form(*at)), np.asarray(row_form(*at))
+        np.testing.assert_array_equal(got[:, :, :NP], want[:, :, :NP])
+        # pages of padding are the TRASH page written back as it was
+        np.testing.assert_array_equal(got[:, :, TRASH],
+                                      np.asarray(pool)[:, :, TRASH])
+        changed = got != np.asarray(pool)
+        assert not np.delete(changed, li, axis=0).any()
+        assert changed[li].any()
+
+
+def test_scatter_kv_run_issues_a_page_an_update():
+    """The point of it: the scatter's update window is (ps, D) and its
+    updates number (pages touched) x Hkv, not C x Hkv."""
+    from paddle_tpu.models.llama import scatter_kv_run
+    ps, C, hkv, D = 64, 512, 8, 16
+    pool = jnp.zeros((2, hkv, 40, ps, D))
+    rows = jnp.zeros((C, hkv, D))
+    table = jnp.arange(32, dtype=jnp.int32)
+    text = jax.jit(lambda li, start, n: scatter_kv_run(
+        pool, li, rows, start, n, table)).lower(
+            jnp.int32(0), jnp.int32(0), jnp.int32(C)).as_text()
+    (dims,) = set(re.findall(r"update_window_dims = \[([\d, ]*)\]", text))
+    assert dims.replace(" ", "") == "2,3", text[-3000:]
+    assert f"tensor<{hkv}x{C // ps + 1}x{ps}x{D}xf32>" in text
+
+
+def test_prefill_kv_counters_say_how_many_updates_a_page_saves():
+    """`prefill_kv_rows_written` / `prefill_kv_pages_written`: host
+    arithmetic from the run's start, its real length and the page size —
+    the rows a prefill wrote and the REAL pages they lie on (per layer,
+    side and head alike).  A 37-token dense prefill, then a 130-token
+    prompt whose first 37 tokens hit the prefix cache (two whole pages of
+    16 and five rows of a third, copied on write): 93 rows from position
+    37, on pages 2..8."""
+    cfg = llama_config_tiny(vocab=64, hidden=32, layers=2, heads=4, seq=256)
+    params = _params(cfg, seed=3)
+    eng = ServingEngine(params, cfg, num_slots=2, page_size=16,
+                        num_pages=40, max_pages_per_seq=12,
+                        attention_impl="ref", prompt_bucket=16)
+    lr = np.random.default_rng(37)
+    first = lr.integers(1, 64, (37,)).astype(np.int32)
+    r1 = eng.submit(first, max_new_tokens=3)
+    done = eng.run()
+    st = eng.stats()
+    assert (st["prefill_kv_rows_written"],
+            st["prefill_kv_pages_written"]) == (37, 3)
+    # the 38th token differs from what the first request generated there,
+    # so the cached part-full page matches in five rows and no more
+    nxt = (int(done[r1].generated[0]) % 62) + 1
+    assert nxt != int(done[r1].generated[0])
+    second = np.concatenate([first, [nxt],
+                             lr.integers(1, 64, (92,))]).astype(np.int32)
+    r2 = eng.submit(second, max_new_tokens=2)
+    done = eng.run()
+    assert done[r2].cached_prefix_tokens == 37
+    st = eng.stats()
+    assert st["prefill_kv_rows_written"] == 37 + 93
+    assert st["prefill_kv_pages_written"] == 3 + 7
+    ref = np.asarray(llama_generate(params, cfg, second[None],
+                                    max_new_tokens=2))[0]
+    np.testing.assert_array_equal(done[r2].output_ids, ref)

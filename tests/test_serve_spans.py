@@ -111,6 +111,10 @@ def test_real_profiler_trace_holds_tiled_steps(llama, tmp_path, kw):
     for s in spans:
         if s[0].startswith("serve.prefill_"):
             assert 0 < s[3]["tokens"] <= s[3]["padded"]
+            # the pages the call's K/V rows lie on (PR 32): one more than
+            # the rows fill when the run starts inside a page
+            full = -(-s[3]["tokens"] // eng.page_size)
+            assert full <= s[3]["pages"] <= full + 1
         if s[0].endswith("_record"):
             assert s[3]["tokens"] >= 0
         if s[0].endswith("_dispatch"):
@@ -260,6 +264,7 @@ def test_telemetry_on_and_off_give_equal_tokens_and_counters(llama, kw):
     assert {k: v for k, v in a.items() if k not in drop} \
         == {k: v for k, v in b.items() if k not in drop}
     for key in ("prefill_tokens_dispatched", "prefill_tokens_padded",
+                "prefill_kv_rows_written", "prefill_kv_pages_written",
                 "decode_kv_tokens_attended", "decode_kv_pages_attended"):
         assert a[key] > 0
 
@@ -270,6 +275,7 @@ def test_counters_survive_a_snapshot(llama):
     fresh = _engine(llama, prefill_chunk=8)
     fresh.restore(eng.snapshot())
     for key in ("prefill_tokens_dispatched", "prefill_tokens_padded",
+                "prefill_kv_rows_written", "prefill_kv_pages_written",
                 "decode_kv_tokens_attended", "decode_kv_pages_attended"):
         assert fresh.stats()[key] == eng.stats()[key] > 0
 
