@@ -79,6 +79,15 @@ SIZES = {
                                 dict(dtype="float32", layers=4,
                                      matmul_precision="highest",
                                      min_agreement=1.0))),
+        # --hybrid: the recurrent family (Mamba-2 + attention + LatentMoE)
+        # at the widths and the cut of the benchmark configuration named
+        # here, a few slots: dense prefill, chunked prefill with the state
+        # carried, and the decode horizon all run
+        "hybrid": dict(config="nemotron-3-super-serve-1of4", num_slots=8,
+                       page_size=64, max_pages_per_seq=16, prefill_chunk=256,
+                       prompt_bucket=128, decode_horizon=8,
+                       prompt_lens=(100, 300, 520, 77, 640),
+                       max_new_tokens=16, compare_tokens=4),
     },
 }
 VERIFY_Q = 5            # speculative verify segment: K+1 at the engine's K=4
@@ -313,6 +322,73 @@ def serve_phase(cfg, sizes, *, attention_impl="auto", interpret=False,
     return {**facts, "vs_ref_engine": versus}
 
 
+def hybrid_config(name):
+    """(NemotronHConfig, the configuration file) of a benchmark
+    configuration of the recurrent family, as its driver reads it."""
+    from benchmark.drivers import serve_nemotron_h as drv
+    from benchmark.run import load_json
+    conf = load_json(os.path.dirname(os.path.abspath(__file__)),
+                     "benchmark", "configs", name + ".json")
+    return drv.model_config(conf), conf
+
+
+def hybrid_phase(cfg, sizes, *, attention_impl="auto", interpret=False,
+                 dtype="bfloat16", seed=0, report=emit):
+    """A family with recurrent state through the same engine: its three
+    executables run (dense prefill, a prefill chunk with the state carried,
+    the decode horizon), no routed row is dropped, and the kernel engine's
+    greedy tokens agree with the reference-attention engine's."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models.nemotron_h import build_functional_nemotron_h
+    params = jax.block_until_ready(jax.jit(
+        lambda k: build_functional_nemotron_h(cfg, k, jnp.dtype(dtype)))(
+            jax.random.PRNGKey(seed)))
+    prompts = make_prompts(cfg, sizes["prompt_lens"], seed + 1)
+    chunk = sizes["prefill_chunk"]
+    check(any(len(p) > chunk for p in prompts)
+          and any(len(p) <= chunk for p in prompts),
+          "prompts must straddle the prefill chunk")
+    outs, facts, eng = run_engine(params, cfg, sizes, prompts,
+                                  sizes["max_new_tokens"],
+                                  attention_impl=attention_impl,
+                                  interpret=interpret)
+    ran, st = facts["executables"], eng.stats()
+    check(ran.get("prefill", 0) > 0 and ran.get("prefill_chunk", 0) > 0
+          and ran.get("decode_step", 0) > 0,
+          f"dense prefill, chunked prefill and decode must all run: {ran}")
+    check(eng.family.recurrent and eng.cache is None,
+          "a recurrent family runs without a prefix cache")
+    check(st["moe_rows_dropped"] == 0 and st["moe_pairs_held"] > 0,
+          f"routed rows: {st['moe_pairs_held']} held, "
+          f"{st['moe_rows_dropped']} dropped")
+    check(st["ssm_slot_resets"] == len(prompts),
+          f"{st['ssm_slot_resets']} slot resets for {len(prompts)} prompts")
+    compiled = eng.decode_horizon_compiled()
+    facts.update(
+        family=eng.family.name, depth=cfg.num_hidden_layers,
+        parameters=count_params(params),
+        moe_pairs_held=st["moe_pairs_held"],
+        ssm_state_bytes=st["ssm_state_bytes"],
+        decode_has_tpu_custom_call="tpu_custom_call" in compiled.as_text(),
+        decode_executable_bytes=executable_bytes(compiled),
+        peak_bytes_in_use=peak_bytes(jax.devices()[:1])[0])
+    report("hybrid", **facts)
+    del eng, compiled
+    gc.collect()
+    n_cmp = sizes["compare_tokens"]
+    ref_outs, _, ref_eng = run_engine(params, cfg, sizes, prompts, n_cmp,
+                                      attention_impl="ref")
+    del ref_eng
+    versus = {"compared": f"first {n_cmp} greedy tokens of every request, "
+                          f"attention_impl={attention_impl!r} vs 'ref'",
+              **token_agreement([o[:n_cmp] for o in outs], ref_outs)}
+    report("hybrid_vs_ref", **versus)
+    check(versus["agreement"] >= KERNEL_VS_REF_FLOOR,
+          f"kernel engine vs ref engine: {versus}")
+    return {**facts, "vs_ref_engine": versus}
+
+
 # ---------------------------------------------------------------------------
 # train: the donated jitted train step
 # ---------------------------------------------------------------------------
@@ -518,6 +594,10 @@ def main(argv=None):
     ap.add_argument("--multichip", action="store_true",
                     help="run ONLY the TP=4 engine and its one-chip "
                          "comparison (needs four chips)")
+    ap.add_argument("--hybrid", action="store_true",
+                    help="run ONLY the recurrent family's engine (Mamba-2 + "
+                         "attention + LatentMoE at the benchmark "
+                         "configuration's widths; one chip)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
@@ -545,6 +625,12 @@ def main(argv=None):
                   for a in arms.values()),
               "no tpu_custom_call in a TP decode executable: the Pallas "
               "kernel did not run under shard_map")
+    elif args.hybrid:
+        hy = sizes["hybrid"]
+        hybrid = hybrid_phase(hybrid_config(hy["config"])[0], hy,
+                              seed=args.seed)
+        check(hybrid["decode_has_tpu_custom_call"],
+              "no tpu_custom_call in the hybrid decode executable")
     else:
         sv = sizes["serve"]
         cfg = cut_config(sv["layers"])

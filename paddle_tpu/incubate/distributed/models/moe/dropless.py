@@ -37,8 +37,9 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["sigmoid_topk_route", "sort_pairs_by_held_expert",
-           "grouped_swiglu", "dropless_expert_ffn", "expert_load",
-           "balance_bias_update", "TRACE_LABEL"]
+           "grouped_swiglu", "grouped_relu2", "dropless_expert_ffn",
+           "dropless_expert_forward", "expert_load", "balance_bias_update",
+           "TRACE_LABEL"]
 
 # what a device trace finds the grouped products by (an "XLA Ops" event's
 # name is the instruction's whole text)
@@ -46,20 +47,21 @@ TRACE_LABEL = "ragged_dot_tiling="
 
 
 def sigmoid_topk_route(u, w_router, bias, top_k, route_scale=1.0,
-                       route_norm=True):
+                       route_norm=True, precision=None):
     """u [T, H], w_router [H, E_total], bias [E_total] -> (sel int32 [T, k],
     weights f32 [T, k]).  Scores are sigmoids in float32; the BIAS takes part
     in the selection only, the weights are the unbiased scores of the
-    selected experts, normalised over the k (``route_norm``) and scaled."""
+    selected experts, normalised over the k (``route_norm``) and scaled.
+    ``precision`` is the score product's (None: the backend's default)."""
     scores = jax.nn.sigmoid(jax.lax.dot_general(
         u, w_router.astype(u.dtype), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32))
+        precision=precision, preferred_element_type=jnp.float32))
     _, sel = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
     # the selected scores by a one-hot select-and-sum: its transpose is a
     # broadcast, where take_along_axis's would be a scatter-add
     chosen = sel[..., None] == jnp.arange(scores.shape[-1])[None, None, :]
     w = jnp.where(chosen, scores[:, None, :], 0.0).sum(-1)
-    if route_norm:
+    if route_norm:     # a python bool  # graftlint: disable=TRACE001
         w = w / (w.sum(-1, keepdims=True) + 1e-20)
     return sel.astype(jnp.int32), w * route_scale
 
@@ -167,14 +169,24 @@ def grouped_swiglu(xs, we_gate, we_up, we_down, rows):
                               we_down.astype(xs.dtype), rows)
 
 
-def _experts_within(bound, operands, sorting):
+def grouped_relu2(xs, we_up, we_down, rows):
+    """The two-matrix expert ``relu(xs W_up[e])^2 W_down[e]`` per group, as
+    :func:`grouped_swiglu` is the three-matrix one."""
+    up = jax.lax.ragged_dot(xs, we_up.astype(xs.dtype), rows)
+    return jax.lax.ragged_dot(jnp.square(jax.nn.relu(up)),
+                              we_down.astype(xs.dtype), rows)
+
+
+def _experts_within(bound, operands, sorting, expert=grouped_swiglu):
     """The held experts' part with every pass over the rows cut to the
-    static ``bound`` (exact while the counted rows are <= bound)."""
-    u, weights, we_gate, we_up, we_down = operands
+    static ``bound`` (exact while the counted rows are <= bound).
+    ``operands`` are (u, weights, the expert's matrices...), ``expert`` the
+    grouped product they feed."""
+    u, weights, *matrices = operands
     order, inverse, held_mask, rows = sorting
     first = order[:bound]
     xs = _dispatch(u, first, inverse, held_mask)
-    ys = grouped_swiglu(xs, we_gate, we_up, we_down, rows)
+    ys = expert(xs, *matrices, rows)
     return _combine(ys, weights, first, inverse, held_mask)
 
 
@@ -217,6 +229,38 @@ _experts_tiered.defvjp(
     _experts_tiered_bwd)
 
 
+def _row_bounds(t, k, held, num_experts):
+    """The two static row bounds of a share: twice the rows it expects, and
+    every pair."""
+    expected_twice = max(2 * t * k * held // num_experts, 1)
+    return tuple(sorted({min(expected_twice, t * k), t * k}))
+
+
+def dropless_expert_forward(u, sel, weights, matrices, offset, num_experts,
+                            expert=grouped_relu2):
+    """The forward pass alone of :func:`dropless_expert_ffn`, for a path
+    that never differentiates (serving): the same route / sort / grouped
+    product / combine over the same two row bounds, the tier chosen on the
+    device by a plain ``lax.switch``, for any ``expert`` (``matrices`` are
+    its expert-stacked weights, the HELD experts on their leading dim).
+    Returns (out [T, H'] in u's dtype, rows int32 [held], beyond int32:
+    the held pairs whose sorted row lies past the chosen tier's bound —
+    the rows a tier would DROP (their tokens would combine another pair's
+    row); 0 while the largest bound is every pair)."""
+    t, k = sel.shape
+    sorting = sort_pairs_by_held_expert(sel, offset, matrices[0].shape[0])
+    bounds = _row_bounds(t, k, matrices[0].shape[0], num_experts)
+    tier = _tier(bounds, sorting[3])
+    out = jax.lax.switch(
+        tier,
+        [functools.partial(_experts_within, b, expert=expert)
+         for b in bounds],
+        (u, weights, *matrices), sorting)
+    beyond = jnp.maximum(
+        sorting[3].sum() - jnp.asarray(bounds, jnp.int32)[tier], 0)
+    return out, sorting[3], beyond
+
+
 def dropless_expert_ffn(u, sel, weights, we_gate, we_up, we_down, offset,
                         num_experts):
     """The held experts' part of sum_{e in sel} w_e Expert_e(u).
@@ -235,8 +279,7 @@ def dropless_expert_ffn(u, sel, weights, we_gate, we_up, we_down, offset,
     t, k = sel.shape
     held = we_gate.shape[0]
     sorting = sort_pairs_by_held_expert(sel, offset, held)
-    expected_twice = max(2 * t * k * held // num_experts, 1)
-    bounds = tuple(sorted({min(expected_twice, t * k), t * k}))
+    bounds = _row_bounds(t, k, held, num_experts)
     out = _experts_tiered(bounds, (u, weights, we_gate, we_up, we_down),
                           sorting)
     return out, sorting[3]

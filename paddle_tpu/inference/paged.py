@@ -597,6 +597,8 @@ class Request:
     draft_accepted: int = 0            #   ... greedy-verified AND emitted
                                        #   (an EOS/budget freeze mid-run
                                        #   discards the tail uncounted)
+    slot: int = -1                     # the engine slot it rides, or last
+                                       #   rode (-1: never admitted)
     trace_id: int | None = None        # fleet-wide stitching id: one per
                                        #   END-TO-END request, shared by the
                                        #   frontend/router/replica trace
@@ -786,6 +788,31 @@ class ServingEngine:
     covers the whole run; prefill executables are cached per prompt-length
     bucket (per chunk size once `prefill_chunk` is set).
 
+    Which model it serves is the CONFIGURATION's to say:
+    ``config.paged_family(...)`` returns the family's paged fns over one
+    cache pytree (`models/paged_family.py`), and the engine never asks for
+    a model's name.  Two families are served.  ``llama`` (`LlamaConfig`:
+    the Llama-shaped dense decoders; a cache of K/V pages) has every
+    feature below.  ``nemotron_h`` (`models/nemotron_h.NemotronHConfig`:
+    Mamba-2 + attention + LatentMoE layers, sparse experts inside the
+    paged fns) keeps, beside the K/V pages of its attention layers, a
+    convolution tail and an SSM state a SLOT and Mamba layer in the same
+    cache: a slot's state starts from zero when a run starts at position 0
+    (admission, slot reuse, the re-prefill after a preemption), is carried
+    from prefill chunk to prefill chunk, and is left alone by a decode step
+    the slot is not live in.  For such a ``recurrent`` family the engine
+    runs WITHOUT a prefix cache (a cached prefix is pages without the state
+    that belongs to them) and refuses, with an error that names what is
+    missing: ``speculative``, ``quantize``, ``kv_dtype``, ``mesh``,
+    ``snapshot("full_kv")``, ``export_kv`` and ``import_kv``
+    (``snapshot("compact")`` / ``adopt`` re-prefill and work).  Its fns
+    count on the device, inside the carried cache, what ``stats()`` reports
+    as ``moe_*``, ``ssm_*`` and ``decode_state_bytes_moved``, and log every
+    consumed token's expert selections by slot and position;
+    ``recurrent_state(rid)`` reads a slot's state and its log (``moe_sel``:
+    what a rollout pool replays the routing of, and what the benchmark
+    routes its reference by).
+
     `prefix_cache=True` (default) turns on automatic prefix caching:
     retired requests park their KV pages in a block-hash index, and later
     prompts sharing a page-aligned prefix attach those pages read-only and
@@ -834,8 +861,7 @@ class ServingEngine:
                  quantized_allreduce: bool = False):
         import jax
         import jax.numpy as jnp
-        from ..models.llama import (build_llama_paged_decode,
-                                    make_paged_decode_horizon,
+        from ..models.llama import (make_paged_decode_horizon,
                                     _sample_per_request)
         self._jax, self._jnp = jax, jnp
         # quantized serving plane (ROADMAP item 2): kv_dtype stores KV
@@ -858,6 +884,35 @@ class ServingEngine:
         self.mp_axis = str(mp_axis)
         self.tp = 1 if mesh is None else int(mesh.shape[mp_axis])
         self.quantized_allreduce = bool(quantized_allreduce and self.tp > 1)
+        # THE seam between the engine and the model it serves: the
+        # configuration names its family's paged fns
+        # (models/paged_family.py); nothing below asks which model this is,
+        # only what the family can do (`recurrent`, `verify_step`, ...)
+        self.num_slots = int(num_slots)
+        self.page_size = int(page_size)
+        cap_pages = math.ceil(config.max_position_embeddings / page_size)
+        self.max_pages_per_seq = int(max_pages_per_seq or cap_pages)
+        if num_pages is None:
+            num_pages = self.num_slots * self.max_pages_per_seq
+        family = config.paged_family(
+            page_size=page_size, num_pages=num_pages,
+            num_slots=self.num_slots,
+            max_pages_per_seq=self.max_pages_per_seq, dtype=dtype,
+            attention_impl=attention_impl, interpret=interpret,
+            kv_dtype=self.kv_dtype, mesh=mesh, mp_axis=self.mp_axis,
+            quantized_allreduce=self.quantized_allreduce)
+        self.family = family
+        if family.recurrent and quantize:
+            raise NotImplementedError(
+                f"quantize: the {family.name} family's leaves have no int8 "
+                f"grid in serving/quant.py (written for the Llama-shaped "
+                f"tree)")
+        if speculative and family.verify_step is None:
+            raise NotImplementedError(
+                f"speculative: the {family.name} family has no verify step "
+                f"— scoring drafted tokens over recurrent state needs a "
+                f"state checkpoint a drafted token, to rewind to on a "
+                f"rejection")
         if quantize:
             bits = 8 if quantize is True or quantize == "int8" \
                 else int(quantize)
@@ -876,15 +931,13 @@ class ServingEngine:
         self._jit_fns: dict[str, list] = {}
         self.config = config
         self.params = params
-        self.num_slots = int(num_slots)
-        self.page_size = int(page_size)
-        cap_pages = math.ceil(config.max_position_embeddings / page_size)
-        self.max_pages_per_seq = int(max_pages_per_seq or cap_pages)
-        if num_pages is None:
-            num_pages = self.num_slots * self.max_pages_per_seq
         self.pool = PagePool(num_pages, page_size)
-        self.cache = PrefixCache(self.pool, page_size) if prefix_cache \
-            else None
+        # a cached prefix is K/V pages alone: a family whose slots also hold
+        # recurrent state may never attach one without the state that
+        # belongs to it, and nothing snapshots that state at page
+        # boundaries yet — so such a family runs with NO prefix cache
+        self.cache = PrefixCache(self.pool, page_size) \
+            if prefix_cache and not family.recurrent else None
         self.prefill_chunk = None if prefill_chunk is None \
             else max(1, int(prefill_chunk))
         self.prompt_bucket = int(prompt_bucket)
@@ -923,41 +976,35 @@ class ServingEngine:
         self._clock = self.telemetry.clock if self.telemetry is not None \
             else time.perf_counter
 
-        init_pages, prefill, prefill_chunk_fn, decode_step, verify_step = \
-            build_llama_paged_decode(
-                config, page_size=page_size, num_pages=num_pages, dtype=dtype,
-                attention_impl=attention_impl, interpret=interpret,
-                kv_dtype=self.kv_dtype, mesh=mesh, mp_axis=self.mp_axis,
-                quantized_allreduce=self.quantized_allreduce)
-        cache = init_pages()
-        # each side is a raw [L, Hkv, NP+1, ps, D] array (f32/bf16) or a
-        # {"q": data, "s": scales} dict (kv_dtype set).  The engine only
-        # hands them on: every paged executable takes both sides DONATED,
-        # carries them whole through its layer loop (rows written in place,
-        # the layer indexed inside the attention kernel — no executable
-        # copies, slices or relays out the pool) and returns them as its
-        # last two outputs, which `_call_paged` rebinds.  What the engine
+        prefill, prefill_chunk_fn = family.prefill, family.prefill_chunk
+        # ONE pytree holds everything the paged executables keep on the
+        # device between calls.  ``["k"]`` / ``["v"]`` are the KV page
+        # stores: each a raw [L, Hkv, NP+1, ps, D] array (f32/bf16) or a
+        # {"q": data, "s": scales} dict (kv_dtype set); a recurrent family
+        # adds its per-slot state and its counters as further leaves.  The
+        # engine only hands the cache on: every paged executable takes it
+        # DONATED, carries it whole through its layer loop (rows written in
+        # place, the layer indexed inside the attention kernel — no
+        # executable copies, slices or relays out the pool) and returns it
+        # as its last output, which `_call_paged` rebinds.  What the engine
         # itself knows of the layout is the page axis, axis 2 of every leaf
-        # (`_copy_page`; snapshot/restore through gather/scatter_kv_pages)
-        self._pages_k, self._pages_v = cache["k"], cache["v"]
+        # of the two page stores (`_copy_page`; snapshot/restore through
+        # gather/scatter_kv_pages)
+        self._cache = family.init_cache()
         if self.tp > 1:
             # commit params + pages onto the mesh with the same specs the
             # shard_map region expects, so every jitted fn compiles ONE
             # variant against stably-placed operands (no silent resharding,
             # no per-call device_put of the weights)
             from jax.sharding import NamedSharding, PartitionSpec
-            from ..models.llama import (llama_paged_page_spec,
-                                        llama_paged_param_specs)
+            param_specs, page_spec = family.mesh_specs(self.mp_axis)
             self.params = params = jax.tree_util.tree_map(
                 lambda s, x: jax.device_put(x, NamedSharding(mesh, s)),
-                llama_paged_param_specs(self.mp_axis), params,
+                param_specs, params,
                 is_leaf=lambda s: isinstance(s, PartitionSpec))
-            pg = NamedSharding(mesh, llama_paged_page_spec(self.mp_axis))
-            place = lambda a: jax.device_put(a, pg)
-            self._pages_k = jax.tree_util.tree_map(place, self._pages_k)
-            self._pages_v = jax.tree_util.tree_map(place, self._pages_v)
-        self._kv_compute_dtype = jnp.dtype(dtype) if dtype is not None \
-            else jnp.float32
+            pg = NamedSharding(mesh, page_spec)
+            self._cache = jax.tree_util.tree_map(
+                lambda a: jax.device_put(a, pg), self._cache)
         self._page_bytes = None        # lazy page_bytes cache
 
         # decode HORIZON: K decode+sample steps fused into one fori_loop
@@ -970,20 +1017,21 @@ class ServingEngine:
         # values so the overlapped engine feeds dispatch N+1 straight from
         # dispatch N's outputs — the synchronous engine passes host values
         # and done0=False, and the math is bit-identical either way.
-        _horizon = make_paged_decode_horizon(decode_step,
+        _horizon = make_paged_decode_horizon(family.decode_step,
                                              sample_fn=_sample_per_request)
 
         # prefill + first-token sample fused into ONE dispatch per admission
         # (a separate sample call would double the per-admission dispatches)
-        def _prefill_sample(params, ids, true_len, page_row, pk, pv, key,
-                            temp, top_p, *, greedy):  # graftlint: jit
-            logits, pk, pv = prefill(params, ids, true_len, page_row, pk, pv)
+        def _prefill_sample(params, ids, true_len, page_row, slot, cache,
+                            key, temp, top_p, *, greedy):  # graftlint: jit
+            logits, cache = prefill(params, ids, true_len, page_row, slot,
+                                    cache)
             if greedy:
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             else:
                 tok = _sample_per_request(logits[None], key, temp[None],
                                           top_p[None])[0]
-            return tok, pk, pv
+            return tok, cache
 
         # single-logits sampler for the final chunk of a chunked / suffix
         # prefill (the chunk executable itself is sampling-agnostic so one
@@ -998,12 +1046,13 @@ class ServingEngine:
         # executable covers every copy).  tree_map keeps it generic over
         # the page-store layout: a raw array copies its page rows, a
         # quantized {"q","s"} store copies data AND scales — the page axis
-        # is axis 2 of every leaf by construction.
-        def _copy_page(pk, pv, src, dst):             # graftlint: jit
+        # is axis 2 of every leaf of the two page stores by construction
+        # (whatever else the cache holds belongs to slots, not to pages).
+        def _copy_page(cache, src, dst):              # graftlint: jit
             def cp(a):
                 return a.at[:, :, dst].set(a[:, :, src])
-            return (jax.tree_util.tree_map(cp, pk),
-                    jax.tree_util.tree_map(cp, pv))
+            return {**cache, "k": jax.tree_util.tree_map(cp, cache["k"]),
+                    "v": jax.tree_util.tree_map(cp, cache["v"])}
 
         self._horizon_fn = _horizon
         self._horizon_jit = {}         # (K, greedy) -> jitted horizon
@@ -1012,16 +1061,16 @@ class ServingEngine:
         # one wrapper: jax.jit already caches per (C_pad, P_slice) shape,
         # and the chunk fn has no Python-level static knobs to key on
         self._chunk_jit = self._jit("prefill_chunk", prefill_chunk_fn,
-                                    donate_argnums=(5, 6))
+                                    donate_argnums=(6,))
         self._sample_fn = _sample_logits
         self._sample_jit = None        # lazily jitted nucleus sampler
         self._copy_jit = self._jit("page_copy", _copy_page,
-                                   donate_argnums=(0, 1))
+                                   donate_argnums=(0,))
         # one wrapper: drafts pad to the STATIC K+1 query width, so the
         # verify executable compiles once per engine K (jax.jit caches by
         # shape) even when slots draft fewer tokens or none at all
-        self._verify_jit = self._jit("verify_step", verify_step,
-                                     donate_argnums=(4, 5))
+        self._verify_jit = None if family.verify_step is None else \
+            self._jit("verify_step", family.verify_step, donate_argnums=(4,))
 
         # host-side slot state
         S, P = self.num_slots, self.max_pages_per_seq
@@ -1321,10 +1370,10 @@ class ServingEngine:
             tel.compiled(name, n, dur_s)
 
     def _call_paged(self, fn, *args):
-        """Call a page-donating executable (its last two outputs are the
-        new K/V page buffers).  A sanitize() budget raise fires only AFTER
+        """Call a cache-donating executable (its last output is the new
+        cache).  A sanitize() budget raise fires only AFTER
         the underlying call ran — its donated inputs are gone — so rebind
-        the page buffers from the executed call's outputs before
+        the cache from the executed call's outputs before
         propagating: lengths were never advanced for the raising step and
         K/V above lengths is never attended (the rewind invariant), so the
         engine stays fully usable."""
@@ -1332,7 +1381,7 @@ class ServingEngine:
             out = fn(*args)
         except RecompileBudgetError as e:
             if e.result is not None:
-                self._pages_k, self._pages_v = e.result[-2], e.result[-1]
+                self._cache = e.result[-1]
             if self.telemetry is not None:
                 # the postmortem the recompile sanitizer never had: the
                 # last N engine events leading up to the budget failure
@@ -1340,6 +1389,15 @@ class ServingEngine:
                                           error=str(e)[:200])
             raise
         return out
+
+    @property
+    def _pages_k(self):
+        """The K side of the KV page store (read-only view of the cache)."""
+        return self._cache["k"]
+
+    @property
+    def _pages_v(self):
+        return self._cache["v"]
 
     def jit_variants(self) -> dict:
         """{model fn name: number of compiled executables} — the bounded,
@@ -1371,8 +1429,8 @@ class ServingEngine:
         shaped = lambda tree: jax.tree_util.tree_map(like, tree)
         return self._horizon_exec(self.decode_horizon, True).lower(
             shaped(self.params), host((S,), jnp.int32), host((S,), jnp.int32),
-            host((S, P), jnp.int32), shaped(self._pages_k),
-            shaped(self._pages_v), host((S,), jnp.bool_),
+            host((S, P), jnp.int32), shaped(self._cache),
+            host((S,), jnp.bool_),
             host(self._key.shape, self._key.dtype),
             host((S,), jnp.float32), host((S,), jnp.float32),
             host((S,), jnp.int32), host((S,), jnp.int32),
@@ -1554,8 +1612,8 @@ class ServingEngine:
         if src is None:
             src = dst
             dst = self.pool.alloc(1)[0]
-        self._pages_k, self._pages_v = self._call_paged(
-            self._copy_jit, self._pages_k, self._pages_v,
+        self._cache = self._call_paged(
+            self._copy_jit, self._cache,
             jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32))
         if slot.pages[idx] != dst:
             self.pool.free([slot.pages[idx]])
@@ -1617,6 +1675,7 @@ class ServingEngine:
             matched = n_shared * self.page_size
             slot = _Slot(req, pages, 0, admit_seq=self._admit_seq)
             slot.resuming = resuming
+            req.slot = s
             self._admit_seq += 1
             self._slots[s] = slot
             if self.speculative and req.temperature <= 0.0:
@@ -1687,7 +1746,7 @@ class ServingEngine:
                         "prefill",
                         (lambda *a: fn(*a, greedy=True)) if greedy
                         else (lambda *a: fn(*a, greedy=False)),
-                        donate_argnums=(4, 5))
+                        donate_argnums=(5,))
                     # keyed by (T bucket, greedy): bounded by the
                     # bucket ladder  # graftlint: disable=LEAK001
                     self._prefill_jit[(Tb, greedy)] = pf
@@ -1698,16 +1757,16 @@ class ServingEngine:
                     # the first token, so the request record keeps ladder
                     # order: admitted -> prefill_dense -> first_token
                     with self._span("prefill_dense", rid=req.rid, pos=0,
-                                    tokens=T, padded=Tb, pages=kv_pages):
-                        tok, self._pages_k, self._pages_v = \
-                            self._call_paged(
-                                pf,
-                                self.params, jnp.asarray(ids),
-                                jnp.asarray(T, jnp.int32),
-                                jnp.asarray(row), self._pages_k,
-                                self._pages_v, self._split_key(),
-                                jnp.asarray(req.temperature, jnp.float32),
-                                jnp.asarray(req.top_p, jnp.float32))
+                                    tokens=T, padded=Tb, pages=kv_pages,
+                                    family=self.family.name):
+                        tok, self._cache = self._call_paged(
+                            pf,
+                            self.params, jnp.asarray(ids),
+                            jnp.asarray(T, jnp.int32),
+                            jnp.asarray(row), jnp.asarray(s, jnp.int32),
+                            self._cache, self._split_key(),
+                            jnp.asarray(req.temperature, jnp.float32),
+                            jnp.asarray(req.top_p, jnp.float32))
                 except RecompileBudgetError as e:
                     # the prefill DID run (pages already rebound by
                     # _call_paged) — finish the admission bookkeeping with
@@ -1795,8 +1854,9 @@ class ServingEngine:
         ids[0, :c] = slot.ctx[pos:pos + c]
         kv_pages = self._count_prefill(pos, c, Cb)
         with self._span("prefill_chunk", rid=req.rid, pos=pos, tokens=c,
-                        padded=Cb, pages=kv_pages):
-            logits, tok_g, self._pages_k, self._pages_v = self._call_paged(
+                        padded=Cb, pages=kv_pages, family=self.family.name,
+                        state_carried=1 if self.family.recurrent and pos else 0):
+            logits, tok_g, self._cache = self._call_paged(
                 self._chunk_jit,
                 self.params, jnp.asarray(ids), jnp.asarray(pos, jnp.int32),
                 jnp.asarray(c, jnp.int32),
@@ -1804,7 +1864,7 @@ class ServingEngine:
                 # table — an async in-flight chunk must not see later
                 # host-side table growth (CPU jnp.asarray can alias)
                 jnp.asarray(self._page_tables[s, :Pb].copy()),
-                self._pages_k, self._pages_v)
+                jnp.asarray(s, jnp.int32), self._cache)
         slot.chunk_step = self._step_seq
         pos += c
         slot.prefill_pos = pos
@@ -1973,11 +2033,11 @@ class ServingEngine:
             n_q[s] = 1 + len(d)
         with self._span("verify_dispatch", slots=len(run),
                         k=self.speculative):
-            logits0, gtoks, self._pages_k, self._pages_v = self._call_paged(
+            logits0, gtoks, self._cache = self._call_paged(
                 self._verify_jit,
                 self.params, jnp.asarray(toks), jnp.asarray(self._lengths),
-                jnp.asarray(self._page_tables), self._pages_k,
-                self._pages_v, jnp.asarray(n_q))
+                jnp.asarray(self._page_tables), self._cache,
+                jnp.asarray(n_q))
         with self._span("verify_sync"):
             # the ONE per-verify-dispatch sync: every slot's K+1 argmaxes
             # land in one transfer (acceptance is host logic by design)
@@ -2073,7 +2133,7 @@ class ServingEngine:
                 return horizon(*a, K=K, greedy=greedy)
 
             fn = self._jit("decode_step", decode_horizon,
-                           donate_argnums=(4, 5))
+                           donate_argnums=(4,))
             # keyed by (K, greedy): bounded by the horizon ladder
             # graftlint: disable=LEAK001
             self._horizon_jit[(K, greedy)] = fn
@@ -2227,10 +2287,10 @@ class ServingEngine:
                 toks_in = toks_in.at[ds].set(dev)
             return toks_in, lengths_in, rem_in, done_in
 
-        def call(pk, pv, toks_in, lengths_in, rem_in, done_in):
+        def call(cache, toks_in, lengths_in, rem_in, done_in):
             return self._call_paged(
                 fn, self.params, toks_in, lengths_in, jnp.asarray(tables),
-                pk, pv, jnp.asarray(active), key, jnp.asarray(temps),
+                cache, jnp.asarray(active), key, jnp.asarray(temps),
                 jnp.asarray(top_ps), rem_in, jnp.asarray(eos_ids), done_in)
 
         # carry sources are EXACTLY the dispatched lanes: only they got
@@ -2242,11 +2302,11 @@ class ServingEngine:
         srcs = {lane.s: lane.slot for lane in lanes}
         rec = _Inflight(K, greedy, lanes, srcs, self.overlap)
         if not self.overlap:
-            res = call(self._pages_k, self._pages_v, *merge(
+            res = call(self._cache, *merge(
                 None if prev is None
                 else (prev.toks, prev.lengths, prev.rem, prev.done)))
             rec.out, rec.toks, rec.lengths, rec.rem, rec.done = res[:5]
-            self._pages_k, self._pages_v = res[-2], res[-1]
+            self._cache = res[-1]
         elif prev is not None and prev.fut is not None:
             # chain INSIDE the worker: the previous dispatch's outputs
             # (pages + carry) flow worker-to-worker, never through the
@@ -2255,18 +2315,18 @@ class ServingEngine:
 
             def work_chained():
                 pres = pfut.result()
-                return call(pres[-2], pres[-1], *merge(
+                return call(pres[-1], *merge(
                     (pres[1], pres[2], pres[3], pres[4])))
 
             rec.fut = self._executor.submit(work_chained)
         else:
             # pipeline empty (or already joined by an admission): the
             # page binding and any carry state are concrete arrays
-            pk0, pv0 = self._pages_k, self._pages_v
+            cache0 = self._cache
             pstate = None if prev is None \
                 else (prev.toks, prev.lengths, prev.rem, prev.done)
             rec.fut = self._executor.submit(
-                lambda: call(pk0, pv0, *merge(pstate)))
+                lambda: call(cache0, *merge(pstate)))
         self.steps_run += 1
         # horizon dispatches always emit tokens on-device (fused greedy
         # argmax or in-loop sampling) — logits never leave the device
@@ -2294,7 +2354,7 @@ class ServingEngine:
         res = fut.result()
         rec.out, rec.toks, rec.lengths, rec.rem, rec.done = res[:5]
         if rebind:
-            self._pages_k, self._pages_v = res[-2], res[-1]
+            self._cache = res[-1]
 
     def _join_dispatch(self):
         """Block until the pending async dispatch's output binding is
@@ -2407,10 +2467,12 @@ class ServingEngine:
         sharded over mp, so each chip holds 1/tp of every page."""
         pb = self._page_bytes
         if pb is None:
-            from ..serving.quant import page_bytes
-            pb = self._page_bytes = page_bytes(
-                self.config, self.page_size, kv_dtype=self.kv_dtype,
-                dtype=self._kv_compute_dtype) // self.tp
+            # the page axis is axis 2 of every leaf of the two page stores
+            leaves = self._jax.tree_util.tree_leaves(
+                (self._cache["k"], self._cache["v"]))
+            pb = self._page_bytes = sum(
+                a.dtype.itemsize * math.prod(a.shape) // a.shape[2]
+                for a in leaves) // self.tp
         return pb
 
     def step(self) -> bool:                           # graftlint: hot
@@ -2589,7 +2651,8 @@ class ServingEngine:
         greedy = all(self._temps[s] <= 0.0 for s in run)
         try:
             with self._span("overlap_dispatch" if self.overlap
-                            else "decode_dispatch", slots=len(run), k=K):
+                            else "decode_dispatch", slots=len(run), k=K,
+                            family=self.family.name):
                 rec = self._dispatch_decode(run, K, greedy)
             prev, self._inflight = self._inflight, rec
             if prev is not None:
@@ -2744,6 +2807,8 @@ class ServingEngine:
         so a restored engine's ``run()`` still returns them."""
         if mode not in ("full_kv", "compact"):
             raise ValueError(f"unknown snapshot mode {mode!r}")
+        if mode == "full_kv":
+            self._refuse_recurrent('snapshot("full_kv")')
         # a snapshot is an EXACT state: drain the double-buffered pipeline
         # (in-flight tokens recorded, deferred first tokens flushed) so
         # the serialized pendings/lengths/pool are host-true
@@ -2824,6 +2889,30 @@ class ServingEngine:
         state["meta"] = json.dumps(meta)
         return state
 
+    def _refuse_recurrent(self, what: str):
+        """Transfers that move K/V PAGES alone leave a recurrent family's
+        slots without the state that belongs to those pages."""
+        if self.family.recurrent:
+            raise NotImplementedError(
+                f"{what}: the {self.family.name} family keeps recurrent "
+                f"state a slot beside its KV pages, and a transfer of that "
+                f"state (a snapshot of it at the page boundary the pages "
+                f"end on) is missing — use the re-prefill path "
+                f'(snapshot("compact"), adopt)')
+
+    def recurrent_state(self, rid: int):
+        """{name: host array} of what the family keeps in the slot request
+        ``rid`` rides or LAST rode (its recurrent state; ``moe_sel
+        [layers, positions, k]``, the experts each consumed position
+        selected) — after every token the engine fed for it, and only until
+        another request is admitted to that slot; None for a family without
+        such state or a request never admitted."""
+        r = self.lookup(rid)
+        if self.family.slot_state is None or r is None or r.slot < 0:
+            return None
+        self.quiesce()
+        return self.family.slot_state(self._cache, r.slot)
+
     def _gather_pages(self, ids) -> dict:
         """Pull pages `ids` to the host as named planes — the read half of
         the full-KV transfer primitive snapshot() and export_kv() share.
@@ -2851,17 +2940,13 @@ class ServingEngine:
         from ..models.llama import scatter_kv_pages
         idx = self._jnp.asarray(np.asarray(ids, np.int32))
         if self.kv_dtype is not None:
-            self._pages_k = scatter_kv_pages(
-                self._pages_k, idx,
-                {"q": planes["kv_k_q"], "s": planes["kv_k_s"]})
-            self._pages_v = scatter_kv_pages(
-                self._pages_v, idx,
-                {"q": planes["kv_v_q"], "s": planes["kv_v_s"]})
+            k = {"q": planes["kv_k_q"], "s": planes["kv_k_s"]}
+            v = {"q": planes["kv_v_q"], "s": planes["kv_v_s"]}
         else:
-            self._pages_k = scatter_kv_pages(self._pages_k, idx,
-                                             planes["kv_k"])
-            self._pages_v = scatter_kv_pages(self._pages_v, idx,
-                                             planes["kv_v"])
+            k, v = planes["kv_k"], planes["kv_v"]
+        self._cache = {**self._cache,
+                       "k": scatter_kv_pages(self._pages_k, idx, k),
+                       "v": scatter_kv_pages(self._pages_v, idx, v)}
 
     # -- KV handoff (disaggregated prefill/decode) -------------------------
     KV_HANDOFF_VERSION = 1
@@ -2889,6 +2974,7 @@ class ServingEngine:
         in this engine's prefix cache, so a fallback re-prefill can still
         hit.  Raises KeyError for a rid not currently riding a slot
         (queued, finished, or unknown — nothing to hand off)."""
+        self._refuse_recurrent("export_kv")
         # exact host state: drain the double-buffered pipeline first (the
         # drain itself may RETIRE a rid — the KeyError below reports it)
         self.quiesce()
@@ -2944,6 +3030,7 @@ class ServingEngine:
         no free pages even after the cache-eviction rung) — the ladder
         order of :meth:`_admit` is preserved.  Returns {source rid: rid
         minted here}."""
+        self._refuse_recurrent("import_kv")
         if packet.get("version") != self.KV_HANDOFF_VERSION:
             raise KVHandoffError(
                 f"kv handoff version {packet.get('version')!r} != "
@@ -3085,6 +3172,7 @@ class ServingEngine:
         self._admit_seq = int(meta["admit_seq"])
         g = meta["geometry"]
         fast = (meta["mode"] == "full_kv"
+                and not self.family.recurrent     # pages without state
                 and g["num_slots"] == self.num_slots
                 and g["page_size"] == self.page_size
                 and g["num_pages"] == self.pool.num_pages
@@ -3249,7 +3337,16 @@ class ServingEngine:
             # instrumentation) — a warmed steady state must hold these
             # flat (tests/test_recompile_budget.py)
             "jit_cache_misses": dict(self.jit_cache_misses),
+            # what the family's fns counted on the device, inside the
+            # carried cache (nothing for a cache of K/V pages alone): one
+            # small fetch, here and nowhere else
+            **self._family_counters(),
         }
+
+    def _family_counters(self) -> dict:
+        if self.family.recurrent:
+            self._join_dispatch()      # the cache must be concrete
+        return self.family.counters(self._cache)
 
     def stats_snapshot(self):
         """Immutable flattened :class:`EngineStats` snapshot of `stats()`

@@ -56,6 +56,11 @@ class LlamaConfig:
     moe_capacity_factor: float = 1.25
     moe_aux_loss_weight: float = 0.01
 
+    def paged_family(self, **build_kw):
+        """The seam `inference.paged.ServingEngine` builds its fns through
+        (`models/paged_family.py`)."""
+        return build_llama_paged_decode(self, **build_kw)
+
 
 def llama_config_7b():
     return LlamaConfig()
@@ -786,29 +791,33 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
                              attention_impl: str = "auto",
                              interpret: bool = False, kv_dtype=None,
                              mesh=None, mp_axis: str = "mp",
-                             quantized_allreduce: bool = False):
+                             quantized_allreduce: bool = False,
+                             num_slots=None, max_pages_per_seq=None):
     """Paged-KV decode path (the `block_multihead_attention` serving analog;
     Ragged Paged Attention arxiv 2604.15464): the KV cache lives in a pool of
     fixed-size pages shared by every in-flight request, so mixed-length
     sequences occupy memory (and attention FLOPs) proportional to their OWN
     length instead of the longest sequence in the batch.
 
-    Returns (init_pages, prefill, prefill_chunk, decode_step, verify_step):
+    Returns the family's `models/paged_family.PagedFamily` — the ONE form
+    every serving family's fns have, which `ServingEngine` builds through
+    ``LlamaConfig.paged_family`` — with these fields (``num_slots``,
+    ``max_pages_per_seq`` and the fns' ``slot`` are of no interest to a
+    cache of K/V pages alone: unused):
 
-      pages = init_pages()
+      cache = init_cache()
           {"k","v": [L, Hkv, num_pages + 1, page_size, head_dim]} — the last
           page is the TRASH page inactive slots write into; the page pool
           (inference/paged.py PagePool) hands out ids < num_pages.
 
-      logits, pages_k, pages_v = prefill(params, ids, true_len, page_row,
-                                         pages_k, pages_v)
+      logits, cache = prefill(params, ids, true_len, page_row, slot, cache)
           ids [1, T_pad] right-padded prompt, true_len the real length,
           page_row [P] this request's page table.  Dense causal attention
           over the prompt; post-RoPE K/V scatter into the request's pages;
           logits [vocab] for the LAST real token.
 
-      logits, greedy_tok, pages_k, pages_v = prefill_chunk(
-              params, ids, start, chunk_len, page_row, pages_k, pages_v)
+      logits, greedy_tok, cache = prefill_chunk(
+              params, ids, start, chunk_len, page_row, slot, cache)
           CHUNKED / SUFFIX prefill for the prefix cache + chunked-prefill
           scheduler: ids [1, C_pad] right-padded chunk of the prompt, start
           the number of tokens ALREADY in this request's pages (a cached
@@ -825,9 +834,8 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
           path for the no-cache-hit whole-prompt case purely so its
           numerics stay byte-identical with the pre-cache engine).
 
-      logits, pages_k, pages_v = decode_step(params, toks, lengths,
-                                             page_tables, pages_k, pages_v,
-                                             active)
+      logits, cache = decode_step(params, toks, lengths, page_tables,
+                                  cache, active)
           One token per slot: toks [S], lengths [S] (tokens already cached —
           the new token lands at position lengths[s]), page_tables [S, P],
           active [S] bool.  Inactive slots write to the trash page and
@@ -839,9 +847,8 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
       segment, verify q_len = K+1, a chunk q_len = chunk_len.  There is
       no per-path attention implementation anywhere in the paged family.
 
-      logits0, greedy, pages_k, pages_v = verify_step(params, toks, lengths,
-                                                      page_tables, pages_k,
-                                                      pages_v, n_q)
+      logits0, greedy, cache = verify_step(params, toks, lengths,
+                                           page_tables, cache, n_q)
           Speculative-decoding verify: toks [S, K+1] (pending token +
           draft tokens per slot), n_q [S] valid query counts — scores all
           K+1 positions in one dispatch so the engine can accept the
@@ -854,7 +861,7 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
     which slots.
 
     The page pool stays IN PLACE through all four fns (jit them with
-    pages_k/pages_v donated): ONE layer loop (`_layers`) carries both sides
+    the cache donated): ONE layer loop (`_layers`) carries both sides
     whole, the fresh rows go into layer ``li`` a ROW an update with a D-only
     window (`scatter_kv_rows`: decode and verify, per-slot positions) or a
     PAGE an update with a (ps, D) window (`scatter_kv_run`: both prefills,
@@ -1032,7 +1039,7 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
         h = rms_norm_ref(h_last, hp["ln_f"], c.rms_norm_eps)
         return (h @ hp["lm"]).astype(jnp.float32)
 
-    def _layers(bp, x, pages_k, pages_v, sin, cos, write, attend):
+    def _layers(bp, x, cache, sin, cos, write, attend):
         """THE layer loop of all four paged fns.  ``x [*tok, H]`` are the
         tokens' activations (``tok`` = [T] dense, [C] chunk, [S] decode,
         [S, Q] verify), ``sin/cos [*tok, D]`` their rotary rows, ``write``
@@ -1046,7 +1053,7 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
         layer of pool read and written twice per layer to change a few rows
         of it.
         Carried, indexed by ``li`` in the scatter and inside the kernel, it
-        is updated in place.  Returns (x, pages_k, pages_v)."""
+        is updated in place.  Returns (x, cache)."""
         tok = x.shape[:-1]
 
         def body(carry, layer_in):
@@ -1071,10 +1078,10 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
             return (xc + _mp_reduce(ff @ lp["wdown"]), pk, pv), None
 
         (x, pages_k, pages_v), _ = jax.lax.scan(
-            body, (x, pages_k, pages_v), (bp, jnp.arange(L)))
-        return x, pages_k, pages_v
+            body, (x, cache["k"], cache["v"]), (bp, jnp.arange(L)))
+        return x, {"k": pages_k, "v": pages_v}
 
-    def prefill(params, ids, true_len, page_row, pages_k, pages_v):  # graftlint: jit
+    def prefill(params, ids, true_len, page_row, slot, cache):  # graftlint: jit
         ep, bp, hp = params
         T = ids.shape[1]
         x = ep["tok"][ids[0]].astype(d)               # [T, H]
@@ -1093,14 +1100,14 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
             p = jax.nn.softmax(s, axis=-1).astype(d)
             return jnp.einsum("hqk,khd->qhd", p, vf)
 
-        x, ks, vs = _layers(bp, x, pages_k, pages_v, sin_t[:T], cos_t[:T],
-                            _run_at(0, true_len, page_row), attend)
+        x, cache = _layers(bp, x, cache, sin_t[:T], cos_t[:T],
+                           _run_at(0, true_len, page_row), attend)
         h_last = jax.lax.dynamic_index_in_dim(x, true_len - 1, 0,
                                               keepdims=False)
-        return _head(hp, h_last), ks, vs
+        return _head(hp, h_last), cache
 
-    def prefill_chunk(params, ids, start, chunk_len, page_row, pages_k,
-                      pages_v):                       # graftlint: jit
+    def prefill_chunk(params, ids, start, chunk_len, page_row, slot,
+                      cache):                         # graftlint: jit
         ep, bp, hp = params
         C = ids.shape[1]
         x = ep["tok"][ids[0]].astype(d)               # [C, H]
@@ -1121,8 +1128,8 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
             return _attn(q[None], pk, pv, li, page_tab,
                          start_r, clen_r, kvlen_r, "chunk")[0]
 
-        x, ks, vs = _layers(bp, x, pages_k, pages_v, sin, cos,
-                            _run_at(start, chunk_len, page_row), attend)
+        x, cache = _layers(bp, x, cache, sin, cos,
+                           _run_at(start, chunk_len, page_row), attend)
         h_last = jax.lax.dynamic_index_in_dim(x, chunk_len - 1, 0,
                                               keepdims=False)
         logits = _head(hp, h_last)
@@ -1130,9 +1137,9 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
         # token, so a greedy request's FINAL chunk needs no separate
         # sample executable — the engine consumes this token directly and
         # the logits feed only sampled-temperature lanes
-        return logits, jnp.argmax(logits).astype(jnp.int32), ks, vs
+        return logits, jnp.argmax(logits).astype(jnp.int32), cache
 
-    def decode_step(params, toks, lengths, page_tables, pages_k, pages_v,
+    def decode_step(params, toks, lengths, page_tables, cache,
                     active):                          # graftlint: jit
         ep, bp, hp = params
         x = ep["tok"][toks].astype(d)                 # [S, H]
@@ -1148,11 +1155,11 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
             return _attn(q[:, None], pk, pv, li, page_tables,
                          pos, n_q, eff_len, "decode")[:, 0]
 
-        x, ks, vs = _layers(bp, x, pages_k, pages_v, sin_t[pos], cos_t[pos],
-                            _rows_at(page, off), attend)
-        return _head(hp, x), ks, vs
+        x, cache = _layers(bp, x, cache, sin_t[pos], cos_t[pos],
+                           _rows_at(page, off), attend)
+        return _head(hp, x), cache
 
-    def verify_step(params, toks, lengths, page_tables, pages_k, pages_v,
+    def verify_step(params, toks, lengths, page_tables, cache,
                     n_q):                             # graftlint: jit
         """Multi-token speculative VERIFY (self-speculative decoding):
         score Q = K+1 query positions per slot in ONE dispatch.  Per slot,
@@ -1167,7 +1174,7 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
         chunked prefill dispatch, so verify-vs-decode losslessness is
         impl-uniform by construction.  Returns (logits0 [S, vocab] f32 —
         position-0 logits for sampled slots; greedy [S, Q] int32 — argmax
-        per position, the engine's acceptance test; pages_k; pages_v).
+        per position, the engine's acceptance test; the cache).
 
         Rewind contract: K/V written for drafts the engine then REJECTS
         sits at positions >= the rewound `lengths` — every attention path
@@ -1194,11 +1201,11 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
             return _attn(q, pk, pv, li, page_tables, lengths, n_q, kv_len,
                          "verify")
 
-        x, ks, vs = _layers(bp, x, pages_k, pages_v, sin_t[pos], cos_t[pos],
-                            _rows_at(page, off), attend)
+        x, cache = _layers(bp, x, cache, sin_t[pos], cos_t[pos],
+                           _rows_at(page, off), attend)
         logits = _head(hp, x)                         # [S, Q, V] f32
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return logits[:, 0], greedy, ks, vs
+        return logits[:, 0], greedy, cache
 
     if tp > 1:
         # TP serving region: the four paged fns run under shard_map over
@@ -1210,21 +1217,27 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
         from jax.sharding import PartitionSpec
         p_specs = llama_paged_param_specs(mp_axis)
         pg = llama_paged_page_spec(mp_axis)
+        pg = {"k": pg, "v": pg}
         r = PartitionSpec()
 
         def _smap(fn, in_specs, out_specs):
             return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                                  out_specs=out_specs, check_vma=False)
 
-        prefill = _smap(prefill, (p_specs, r, r, r, pg, pg), (r, pg, pg))
-        prefill_chunk = _smap(prefill_chunk, (p_specs, r, r, r, r, pg, pg),
-                              (r, r, pg, pg))
-        decode_step = _smap(decode_step, (p_specs, r, r, r, pg, pg, r),
-                            (r, pg, pg))
-        verify_step = _smap(verify_step, (p_specs, r, r, r, pg, pg, r),
-                            (r, r, pg, pg))
+        prefill = _smap(prefill, (p_specs, r, r, r, r, pg), (r, pg))
+        prefill_chunk = _smap(prefill_chunk, (p_specs, r, r, r, r, r, pg),
+                              (r, r, pg))
+        decode_step = _smap(decode_step, (p_specs, r, r, r, pg, r), (r, pg))
+        verify_step = _smap(verify_step, (p_specs, r, r, r, pg, r),
+                            (r, r, pg))
 
-    return init_pages, prefill, prefill_chunk, decode_step, verify_step
+    from .paged_family import PagedFamily
+    return PagedFamily(
+        name="llama", init_cache=init_pages, prefill=prefill,
+        prefill_chunk=prefill_chunk, decode_step=decode_step,
+        verify_step=verify_step,
+        mesh_specs=lambda axis: (llama_paged_param_specs(axis),
+                                 llama_paged_page_spec(axis)))
 
 
 def _sample_per_request(logits, key, temps, top_ps):
@@ -1242,7 +1255,10 @@ def _sample_per_request(logits, key, temps, top_ps):
 
 def make_paged_decode_horizon(decode_step, sample_fn=None):
     """Build the K-step decode-horizon loop with ON-DEVICE token feedback
-    (the serving engine's one decode executable; ROADMAP item 5).
+    (the serving engine's one decode executable; ROADMAP item 5) over a
+    family's ``decode_step(params, toks, lengths, page_tables, cache, live)
+    -> (logits, cache)`` (`models/paged_family.py`): the cache is ONE
+    pytree, carried whole through the loop.
 
     K decode+sample steps fuse into one ``fori_loop`` dispatch, and the
     loop state that used to round-trip through the host between dispatches
@@ -1265,30 +1281,29 @@ def make_paged_decode_horizon(decode_step, sample_fn=None):
     ``done0`` (a lane whose EOS the overlapped host has not yet drained)
     and inactive slots (``active=False``), whose returned ``done`` is the
     ``done0`` passthrough so a momentarily stalled lane is never
-    permanently frozen by one inactive dispatch.
+    permanently frozen by one inactive dispatch.  A frozen slot is NOT
+    live for ``decode_step``: a family's recurrent state stays as it was.
 
-    ``decode_step`` is the paged single-token executable from
-    :func:`build_llama_paged_decode`; ``sample_fn`` defaults to
-    :func:`_sample_per_request` (only consulted when ``greedy=False``).
+    ``sample_fn`` defaults to :func:`_sample_per_request` (only consulted
+    when ``greedy=False``).
 
-    Returns ``horizon(params, toks, lengths, page_tables, pk, pv, active,
+    Returns ``horizon(params, toks, lengths, page_tables, cache, active,
     key, temps, top_ps, remaining, eos_ids, done0, *, K, greedy) ->
-    (out [S, K], toks, lengths, remaining, done, pk, pv)`` — the page
-    buffers stay the LAST two outputs (the engine's ``_call_paged``
-    rebind convention)."""
+    (out [S, K], toks, lengths, remaining, done, cache)`` — the cache stays
+    the LAST output (the engine's ``_call_paged`` rebind convention)."""
     if sample_fn is None:
         sample_fn = _sample_per_request
 
-    def horizon(params, toks, lengths, page_tables, pk, pv, active, key,
+    def horizon(params, toks, lengths, page_tables, cache, active, key,
                 temps, top_ps, remaining, eos_ids, done0, *, K, greedy):  # graftlint: jit
         S = toks.shape[0]
         out = jnp.zeros((S, K), jnp.int32)
 
         def body(t, carry):
-            toks, lengths, rem, pk, pv, done, key, out = carry
+            toks, lengths, rem, cache, done, key, out = carry
             live = ~done
-            logits, pk, pv = decode_step(params, toks, lengths,
-                                         page_tables, pk, pv, live)
+            logits, cache = decode_step(params, toks, lengths, page_tables,
+                                        cache, live)
             if greedy:
                 # static fast path when every running request decodes
                 # greedily (the common serving default): skips the
@@ -1303,15 +1318,15 @@ def make_paged_decode_horizon(decode_step, sample_fn=None):
             lengths = lengths + live.astype(lengths.dtype)
             rem = rem - live.astype(rem.dtype)
             done = done | ((eos_ids >= 0) & (tok == eos_ids)) | (rem <= 0)
-            return (tok, lengths, rem, pk, pv, done, key, out)
+            return (tok, lengths, rem, cache, done, key, out)
 
-        carry = (toks, lengths, remaining, pk, pv, ~active | done0, key, out)
-        toks, lengths, rem, pk, pv, done, key, out = jax.lax.fori_loop(
+        carry = (toks, lengths, remaining, cache, ~active | done0, key, out)
+        toks, lengths, rem, cache, done, key, out = jax.lax.fori_loop(
             0, K, body, carry)
         # inactive lanes pass done0 through untouched: ~active folded into
         # the in-loop freeze must not leak into the carried done state
         done = jnp.where(active, done, done0)
-        return out, toks, lengths, rem, done, pk, pv
+        return out, toks, lengths, rem, done, cache
 
     return horizon
 

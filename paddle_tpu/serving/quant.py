@@ -227,13 +227,10 @@ def logit_drift(params_ref, params_q, config, prompts, *, kv_dtype,
         row = np.arange(per, dtype=np.int32)
         state = {}
         for tag in ("ref", "q"):
-            init_pages, prefill, _c, decode_step, _v = builds[tag]
-            pages = init_pages()
+            fam = builds[tag]
             prm = params_ref if tag == "ref" else params_q
-            logits, pk, pv = prefill(prm, ids, jnp.asarray(T, jnp.int32),
-                                     jnp.asarray(row), pages["k"],
-                                     pages["v"])
-            state[tag] = [logits, pk, pv]
+            state[tag] = fam.prefill(prm, ids, jnp.asarray(T, jnp.int32),
+                                     jnp.asarray(row), 0, fam.init_cache())
         step_drift = [float(jnp.max(jnp.abs(state["q"][0]
                                             - state["ref"][0])))]
         # teacher forcing: the reference argmax feeds BOTH stores
@@ -241,14 +238,12 @@ def logit_drift(params_ref, params_q, config, prompts, *, kv_dtype,
         for i in range(steps - 1):
             pos = T + i
             for tag in ("ref", "q"):
-                decode_step = builds[tag][3]
                 prm = params_ref if tag == "ref" else params_q
-                logits, pk, pv = decode_step(
+                state[tag] = builds[tag].decode_step(
                     prm, jnp.asarray([tok], jnp.int32),
                     jnp.asarray([pos], jnp.int32),
-                    jnp.asarray(row[None]), state[tag][1], state[tag][2],
+                    jnp.asarray(row[None]), state[tag][1],
                     jnp.asarray([True]))
-                state[tag] = [logits, pk, pv]
             step_drift.append(float(jnp.max(jnp.abs(
                 state["q"][0] - state["ref"][0]))))
             tok = int(np.asarray(jnp.argmax(state["ref"][0][0])))

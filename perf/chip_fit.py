@@ -14,9 +14,15 @@ chip time.  A compile that passes is not a chip run.
 
     JAX_PLATFORMS=cpu python perf/chip_fit.py                 # the SIZES table
     JAX_PLATFORMS=cpu python perf/chip_fit.py serve:14 train:3 tp:8 one:4:float32:highest
+    JAX_PLATFORMS=cpu python perf/chip_fit.py hybrid:0      # serve_reason_c64
+    JAX_PLATFORMS=cpu python perf/chip_fit.py --dump <dir> hybrid:0
 
 Arguments are ``phase:layers[:dtype[:matmul_precision]]`` overrides (phases:
-serve, train, and tp / one — the --multichip engines on four devices / one).
+serve, train, and tp / one — the --multichip engines on four devices / one;
+``hybrid`` — the recurrent family's three executables at the benchmark
+configuration ``nemotron-3-super-serve-1of4``, whose depth is the file's:
+the layers argument is ignored).  ``--dump <dir>`` writes every compiled
+program's text there: a device trace's event names ARE those instructions.
 """
 from __future__ import annotations
 
@@ -37,6 +43,7 @@ import chip_smoke  # noqa: E402
 
 KIND = "TPU v5 lite"
 GIB = float(1 << 30)
+DUMP_DIR = None             # --dump: where `report` writes compiled texts
 
 
 def report(name, compiled, t0):
@@ -51,6 +58,10 @@ def report(name, compiled, t0):
           f"=> {need / GIB:.2f} GiB/device  "
           f"tpu_custom_call x{text.count('tpu_custom_call')}  "
           f"all-reduce {'all-reduce' in text}", flush=True)
+    if DUMP_DIR:
+        with open(os.path.join(DUMP_DIR, "_".join(name.split()) + ".hlo.txt"),
+                  "w") as f:
+            f.write(text)
 
 
 def placed_on(sharding):
@@ -69,17 +80,15 @@ def paged_programs(cfg, sizes, place_params, place_pages, rep, mesh=None,
     S, P = sizes["num_slots"], sizes["max_pages_per_seq"]
     params = place_params(jax.eval_shape(
         lambda: build_functional_llama(cfg, dtype=dtype)[:3]))
-    init_pages, prefill, chunk, decode_step, verify = \
-        build_llama_paged_decode(cfg, page_size=sizes["page_size"],
-                                 num_pages=S * P, dtype=dtype,
-                                 attention_impl="pallas", mesh=mesh)
-    pages = place_pages(jax.eval_shape(init_pages))
-    pk, pv = pages["k"], pages["v"]
+    fam = build_llama_paged_decode(cfg, page_size=sizes["page_size"],
+                                   num_pages=S * P, dtype=dtype,
+                                   attention_impl="pallas", mesh=mesh)
+    cache = place_pages(jax.eval_shape(fam.init_cache))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=rep)
     f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=rep)
     flag = lambda *s: jax.ShapeDtypeStruct(s, jnp.bool_, sharding=rep)
     key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)
-    horizon = make_paged_decode_horizon(decode_step)
+    horizon = make_paged_decode_horizon(fam.decode_step)
     K = sizes["decode_horizon"]
     bucket = max(t for t in sizes["prompt_lens"]
                  if t <= sizes["prefill_chunk"])
@@ -87,21 +96,74 @@ def paged_programs(cfg, sizes, place_params, place_pages, rep, mesh=None,
     return {
         f"decode horizon K={K}": (
             jax.jit(lambda *a: horizon(*a, K=K, greedy=True),
-                    donate_argnums=(4, 5)),
-            (params, i32(S), i32(S), i32(S, P), pk, pv, flag(S), key, f32(S),
+                    donate_argnums=(4,)),
+            (params, i32(S), i32(S), i32(S, P), cache, flag(S), key, f32(S),
              f32(S), i32(S), i32(S), flag(S))),
         f"prefill chunk C={sizes['prefill_chunk']}": (
-            jax.jit(chunk, donate_argnums=(5, 6)),
+            jax.jit(fam.prefill_chunk, donate_argnums=(6,)),
             (params, i32(1, sizes["prefill_chunk"]), i32(), i32(), i32(P),
-             pk, pv)),
+             i32(), cache)),
         f"dense prefill T={bucket}": (
-            jax.jit(prefill, donate_argnums=(4, 5)),
-            (params, i32(1, bucket), i32(), i32(P), pk, pv)),
+            jax.jit(fam.prefill, donate_argnums=(5,)),
+            (params, i32(1, bucket), i32(), i32(P), i32(), cache)),
         f"verify Q={chip_smoke.VERIFY_Q}": (
-            jax.jit(verify, donate_argnums=(4, 5)),
-            (params, i32(S, chip_smoke.VERIFY_Q), i32(S), i32(S, P), pk, pv,
+            jax.jit(fam.verify_step, donate_argnums=(4,)),
+            (params, i32(S, chip_smoke.VERIFY_Q), i32(S), i32(S, P), cache,
              i32(S))),
     }
+
+
+def hybrid_programs(conf, place, rep, dtype="bfloat16"):
+    """Lower a recurrent family's three executables the way ServingEngine
+    jits them, at the sizes of a benchmark configuration file ``conf``."""
+    from benchmark.drivers import serve_nemotron_h as drv
+    from paddle_tpu.models.llama import make_paged_decode_horizon
+    from paddle_tpu.models.nemotron_h import build_functional_nemotron_h
+    cfg, e = drv.model_config(conf), conf["engine"]
+    S, P = e["num_slots"], e["max_pages_per_seq"]
+    params = place(jax.eval_shape(
+        lambda: build_functional_nemotron_h(cfg, dtype=dtype)))
+    fam = cfg.paged_family(page_size=e["page_size"], num_pages=S * P,
+                           num_slots=S, max_pages_per_seq=P, dtype=dtype,
+                           attention_impl="pallas")
+    cache = place(jax.eval_shape(fam.init_cache))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=rep)
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=rep)
+    flag = lambda *s: jax.ShapeDtypeStruct(s, jnp.bool_, sharding=rep)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)
+    horizon = make_paged_decode_horizon(fam.decode_step)
+    K, C = e["decode_horizon"], e["prefill_chunk"]
+
+    def decode_horizon(*a):
+        return horizon(*a, K=K, greedy=True)
+
+    return {
+        "weights from a seed": (
+            jax.jit(lambda k: build_functional_nemotron_h(cfg, k, dtype)),
+            (key,)),
+        f"decode horizon K={K}": (
+            jax.jit(decode_horizon, donate_argnums=(4,)),
+            (params, i32(S), i32(S), i32(S, P), cache, flag(S), key, f32(S),
+             f32(S), i32(S), i32(S), flag(S))),
+        f"prefill chunk C={C}": (
+            jax.jit(fam.prefill_chunk, donate_argnums=(6,)),
+            (params, i32(1, C), i32(), i32(), i32(P), i32(), cache)),
+        f"dense prefill T={C}": (
+            jax.jit(fam.prefill, donate_argnums=(5,)),
+            (params, i32(1, C), i32(), i32(P), i32(), cache)),
+    }, cache
+
+
+def fit_hybrid(topo, layers, dtype):
+    from benchmark import run as bench_run
+    root = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
+    conf = bench_run.load_json(root, "benchmark", "configs",
+                               "nemotron-3-super-serve-1of4.json")
+    one = SingleDeviceSharding(topo.devices[0])
+    programs, _ = hybrid_programs(conf, placed_on(one), one, dtype=dtype)
+    for name, (fn, args) in programs.items():
+        t0 = time.time()
+        report(f"hybrid {dtype} {name}", fn.lower(*args).compile(), t0)
 
 
 def fit_one_chip(topo, layers, dtype, phase="serve"):
@@ -166,12 +228,16 @@ def main(argv):
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     sizes = chip_smoke.SIZES[KIND]
+    if argv[:1] == ["--dump"]:
+        global DUMP_DIR
+        DUMP_DIR, argv = argv[1], argv[2:]
     todo = argv or [f"serve:{sizes['serve']['layers']}",
                     f"train:{sizes['train']['layers']}"] + [
         f"{phase}:{arm['layers']}:{arm['dtype']}:"
         f"{arm.get('matmul_precision') or ''}"
         for arm in sizes["multichip"]["arms"] for phase in ("tp", "one")]
     fits = {"serve": fit_one_chip, "train": fit_train, "tp": fit_tp,
+            "hybrid": fit_hybrid,
             "one": lambda *a: fit_one_chip(*a, phase="one")}
     for item in todo:
         phase, layers, dtype, precision = (item.split(":") + ["", ""])[:4]

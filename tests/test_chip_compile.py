@@ -384,17 +384,17 @@ def test_tp4_paged_decode_step_compiles_with_kernel_and_allreduce(topo):
         jax.eval_shape(
             lambda: build_functional_llama(cfg, dtype=jnp.bfloat16)[:3]),
         is_leaf=lambda s: isinstance(s, PartitionSpec))
-    init_pages, _, _, decode_step, _ = build_llama_paged_decode(
+    fam = build_llama_paged_decode(
         cfg, page_size=PAGE, num_pages=SLOTS * TABLE, dtype=jnp.bfloat16,
         attention_impl="pallas", mesh=mesh)
-    pages = jax.tree_util.tree_map(
+    cache = jax.tree_util.tree_map(
         lambda a: placed(llama_paged_page_spec("mp"), a),
-        jax.eval_shape(init_pages))
+        jax.eval_shape(fam.init_cache))
     rep = lambda shape, dtype: placed(PartitionSpec(),
                                       jax.ShapeDtypeStruct(shape, dtype))
-    compiled = jax.jit(decode_step, donate_argnums=(4, 5)).lower(
+    compiled = jax.jit(fam.decode_step, donate_argnums=(4,)).lower(
         params, rep((SLOTS,), jnp.int32), rep((SLOTS,), jnp.int32),
-        rep((SLOTS, TABLE), jnp.int32), pages["k"], pages["v"],
+        rep((SLOTS, TABLE), jnp.int32), cache,
         rep((SLOTS,), jnp.bool_)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "all-reduce" in text
@@ -406,3 +406,73 @@ def test_tp4_paged_decode_step_compiles_with_kernel_and_allreduce(topo):
     # and each rank leaves its quarter where it is, like the one-chip engine
     _assert_pool_stays_in_place(
         compiled, (2, 32 // 4, SLOTS * TABLE + 1, PAGE, D))
+
+
+# ---------------------------------------------------------------------------
+# A family with recurrent state (PR 33): the three executables of the
+# benchmark configuration nemotron-3-super-serve-1of4 at its published
+# widths and full cut (11 layers, 128 experts a layer, 64 slots), lowered as
+# `ServingEngine` jits them, the cache donated — by perf/chip_fit.py.
+# ---------------------------------------------------------------------------
+GIB = float(1 << 30)
+
+
+@pytest.fixture(scope="module")
+def hybrid_programs(one_chip):
+    root = os.path.join(os.path.dirname(__file__), "..")
+    sys.path.insert(0, os.path.join(root, "perf"))
+    import chip_fit
+    from benchmark.run import load_json
+    conf = load_json(root, "benchmark", "configs",
+                     "nemotron-3-super-serve-1of4.json")
+    programs, cache = chip_fit.hybrid_programs(
+        conf, chip_fit.placed_on(one_chip), one_chip)
+    return programs, cache
+
+
+@pytest.mark.parametrize("program", ["decode horizon", "dense prefill",
+                                     "prefill chunk"])
+def test_hybrid_serving_executable_fits_and_leaves_its_cache_in_place(
+        hybrid_programs, program):
+    programs, cache = hybrid_programs
+    (fn, args), = [v for k, v in programs.items() if k.startswith(program)]
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    m = compiled.memory_analysis()
+    need = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    # 9.3 GB of weights, 1.4 GB of state, 0.2 GB of pages: what the cell
+    # holds, with room for the reference beside it (15.75 GiB a chip)
+    assert 9.5 * GIB < need < 12.0 * GIB, need / GIB
+    # the ragged kernel at 2 KV heads x 16 query heads a group lowers, with
+    # its label, and takes the pool itself
+    assert re.search(
+        r'= \w+\[\d+,2,\d+,128\]\S* custom-call\([^)]*\), '
+        r'custom_call_target="tpu_custom_call", '
+        r'operand_layout_constraints=\{s32\[\d+,50\]', text)
+    assert re.search(
+        r'kernel_metadata=\{\s*"kernel":"ragged_paged_attention"', text)
+    assert re.search(
+        r"operand_layout_constraints=\{s32\[\d+,50\].*"
+        r"(, bf16\[1,2,3201,64,128\]\{4,3,2,1,0\}){2}\}", text)
+    # the grouped products are XLA's Mosaic kernels, two an expert layer
+    from paddle_tpu.incubate.distributed.models.moe import dropless
+    assert text.count(dropless.TRACE_LABEL) >= 10
+    # nothing copies the page pool, the SSM state, the selection log, a
+    # layer of any of them, or a layer's expert matrices (a static slice of a STACKED leaf was copied
+    # out before every grouped product: 672 MB a matrix, PR 33)
+    pool = math.prod(cache["k"].shape)
+    ssm = math.prod(cache["ssm"].shape)
+    log = math.prod(cache["sel"].shape)
+    sizes = {pool, ssm, ssm // cache["ssm"].shape[0], 128 * 1024 * 2688,
+             log, log // cache["sel"].shape[0]}
+    copies = [(op, f"{dtype}[{dims}]")
+              for dtype, dims, _, op in _INSTR.findall(text)
+              if op in ("copy", "copy-start")
+              and math.prod(int(n) for n in dims.split(",") if n) in sizes]
+    assert not copies, copies
+    # every leaf of the donated cache is aliased to the output
+    cache_bytes = sum(math.prod(a.shape) * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(cache))
+    assert m.alias_size_in_bytes >= cache_bytes
+    assert m.temp_size_in_bytes < 0.5 * GIB, m.temp_size_in_bytes / GIB

@@ -176,3 +176,32 @@ def test_device_phase_fetches_a_complex_array(tmp_path):
     facts = chip_smoke.device_phase(str(tmp_path))
     assert facts["platform"] == "cpu" and len(facts["complex64_fetch"]) == 3
     assert np.complex64(facts["complex64_fetch"][0]) == np.complex64(-3 + 4j)
+
+
+def test_hybrid_phase_interpret():
+    """`chip_smoke.py --hybrid`'s phase at the recurrent family's tiny
+    size: its three executables run through the engine, nothing dropped."""
+    from paddle_tpu.models.nemotron_h import nemotron_h_config_tiny
+    seen = {}
+    out = chip_smoke.hybrid_phase(
+        nemotron_h_config_tiny(vocab_size=96, experts_held=(4, 8)), SERVE,
+        attention_impl="pallas", interpret=True, dtype="float32",
+        report=lambda phase, **facts: seen.update({phase: facts}))
+    assert set(seen) == {"hybrid", "hybrid_vs_ref"}
+    assert out["family"] == "nemotron_h" and out["moe_pairs_held"] > 0
+    assert out["vs_ref_engine"]["tokens_equal"]
+    json.dumps(seen)
+
+
+def test_hybrid_sizes_name_a_benchmark_configuration_at_published_widths():
+    for kind, sizes in chip_smoke.SIZES.items():
+        cfg, conf = chip_smoke.hybrid_config(sizes["hybrid"]["config"])
+        assert (cfg.hidden_size, cfg.mamba_num_heads, cfg.mamba_head_dim,
+                cfg.ssm_state_size, cfg.num_key_value_heads,
+                cfg.num_experts_per_tok, cfg.moe_latent_size) == (
+                    4096, 128, 64, 128, 2, 22, 1024), kind
+        hy = sizes["hybrid"]
+        assert hy["page_size"] * hy["max_pages_per_seq"] >= max(
+            hy["prompt_lens"]) + hy["max_new_tokens"]
+        assert min(hy["prompt_lens"]) <= hy["prefill_chunk"] \
+            < max(hy["prompt_lens"])

@@ -188,18 +188,16 @@ class TestPagedDecodePath:
         cfg = llama_config_tiny(vocab=64, hidden=32, layers=2, heads=4, seq=32)
         params = _params(cfg)
         ps, NP = 4, 16
-        init_pages, prefill, _prefill_chunk, decode_step, _verify = \
-            build_llama_paged_decode(
-                cfg, page_size=ps, num_pages=NP, attention_impl="ref")
+        fam = build_llama_paged_decode(
+            cfg, page_size=ps, num_pages=NP, attention_impl="ref")
         _, dense_prefill, dense_step = build_llama_decode(cfg, max_seq=32)
         ids = rng.integers(1, 64, (1, 6)).astype(np.int32)
 
-        cache = init_pages()
         row = np.zeros((8,), np.int32)
         row[:4] = [3, 7, 1, 5]                     # non-contiguous pages
-        logits, pk, pv = jax.jit(prefill)(
+        logits, cache = jax.jit(fam.prefill)(
             params, jnp.asarray(ids), jnp.asarray(6, jnp.int32),
-            jnp.asarray(row), cache["k"], cache["v"])
+            jnp.asarray(row), jnp.int32(0), fam.init_cache())
         dl, dcache = dense_prefill(params, jnp.asarray(ids))
         np.testing.assert_allclose(np.asarray(logits), np.asarray(dl[0]),
                                    rtol=2e-4, atol=2e-4)
@@ -208,10 +206,10 @@ class TestPagedDecodePath:
         toks = jnp.argmax(logits)[None].astype(jnp.int32)
         lengths = jnp.asarray([6], jnp.int32)
         dtok = jnp.argmax(dl[0])[None].astype(jnp.int32)
-        step_j = jax.jit(decode_step)
+        step_j = jax.jit(fam.decode_step)
         for _ in range(5):
-            logits, pk, pv = step_j(params, toks, lengths, tables, pk, pv,
-                                    jnp.ones((1,), bool))
+            logits, cache = step_j(params, toks, lengths, tables, cache,
+                                   jnp.ones((1,), bool))
             dl, dcache = dense_step(params, dtok, dcache)
             np.testing.assert_allclose(np.asarray(logits[0]),
                                        np.asarray(dl[0]),

@@ -101,27 +101,25 @@ class TestVerifyStepParity:
                                 seq=64)
         params = _params(cfg, seed=3)
         ps, NP, P = 4, 16, 8
-        init_pages, prefill, _chunk, decode_step, verify_step = \
-            build_llama_paged_decode(cfg, page_size=ps, num_pages=NP,
-                                     attention_impl="ref")
+        fam = build_llama_paged_decode(cfg, page_size=ps, num_pages=NP,
+                                       attention_impl="ref")
         ids = rng.integers(1, 64, (1, 6)).astype(np.int32)
         row = np.zeros((P,), np.int32)
         row[:4] = [3, 7, 1, 5]
-        cache = init_pages()
-        logits, pk, pv = prefill(params, jnp.asarray(ids),
-                                 jnp.asarray(6, jnp.int32), jnp.asarray(row),
-                                 cache["k"], cache["v"])
+        logits, cache = fam.prefill(
+            params, jnp.asarray(ids), jnp.asarray(6, jnp.int32),
+            jnp.asarray(row), 0, fam.init_cache())
         pending = int(jnp.argmax(logits))
         tables = jnp.asarray(row[None])
         # sequential greedy reference (fresh copies of the pages)
         seq_toks, seq_logits = [], []
-        spk, spv = pk, pv
+        seq_cache = cache
         tok, lengths = pending, 6
         for _ in range(4):
-            lg, spk, spv = decode_step(params, jnp.asarray([tok], jnp.int32),
-                                       jnp.asarray([lengths], jnp.int32),
-                                       tables, spk, spv,
-                                       jnp.ones((1,), bool))
+            lg, seq_cache = fam.decode_step(
+                params, jnp.asarray([tok], jnp.int32),
+                jnp.asarray([lengths], jnp.int32), tables, seq_cache,
+                jnp.ones((1,), bool))
             seq_logits.append(np.asarray(lg[0]))
             tok = int(jnp.argmax(lg[0]))
             seq_toks.append(tok)
@@ -130,9 +128,9 @@ class TestVerifyStepParity:
         toks = np.zeros((1, 4), np.int32)
         toks[0, 0] = pending
         toks[0, 1:] = seq_toks[:3]
-        logits0, greedy, vpk, vpv = verify_step(
+        logits0, greedy, _ = fam.verify_step(
             params, jnp.asarray(toks), jnp.asarray([6], jnp.int32),
-            tables, pk, pv, jnp.asarray([4], jnp.int32))
+            tables, cache, jnp.asarray([4], jnp.int32))
         assert [int(t) for t in np.asarray(greedy)[0]] == seq_toks
         np.testing.assert_allclose(np.asarray(logits0[0]), seq_logits[0],
                                    rtol=1e-5, atol=1e-5)
@@ -144,16 +142,14 @@ class TestVerifyStepParity:
                                 seq=64)
         params = _params(cfg, seed=4)
         ps, NP, P = 4, 16, 8
-        init_pages, prefill, _chunk, _dec, verify_step = \
-            build_llama_paged_decode(cfg, page_size=ps, num_pages=NP,
-                                     attention_impl="ref")
+        fam = build_llama_paged_decode(cfg, page_size=ps, num_pages=NP,
+                                       attention_impl="ref")
         ids = rng.integers(1, 64, (1, 5)).astype(np.int32)
         row = np.zeros((P,), np.int32)
         row[:4] = [2, 9, 4, 6]
-        cache = init_pages()
-        logits, pk, pv = prefill(params, jnp.asarray(ids),
-                                 jnp.asarray(5, jnp.int32), jnp.asarray(row),
-                                 cache["k"], cache["v"])
+        logits, cache = fam.prefill(
+            params, jnp.asarray(ids), jnp.asarray(5, jnp.int32),
+            jnp.asarray(row), 0, fam.init_cache())
         pending = int(jnp.argmax(logits))
         tables = jnp.asarray(row[None])
         out = {}
@@ -161,9 +157,9 @@ class TestVerifyStepParity:
             toks = np.zeros((1, 4), np.int32)
             toks[0, 0] = pending
             toks[0, 1:] = draft
-            lg0, greedy, _k, _v = verify_step(
+            lg0, greedy, _ = fam.verify_step(
                 params, jnp.asarray(toks), jnp.asarray([5], jnp.int32),
-                tables, pk, pv, jnp.asarray([4], jnp.int32))
+                tables, cache, jnp.asarray([4], jnp.int32))
             out[name] = (np.asarray(lg0[0]), int(np.asarray(greedy)[0, 0]))
         np.testing.assert_array_equal(out["good"][0], out["bad"][0])
         assert out["good"][1] == out["bad"][1]
@@ -453,27 +449,25 @@ class TestImplUniformAttention:
         orig = pa.ragged_paged_attention_ref
         pa.ragged_paged_attention_ref = recorder
         try:
-            init_pages, _prefill, prefill_chunk, decode_step, verify_step = \
-                build_llama_paged_decode(cfg, page_size=ps, num_pages=NP,
-                                         attention_impl="ref")
-            cache = init_pages()
+            fam = build_llama_paged_decode(cfg, page_size=ps, num_pages=NP,
+                                           attention_impl="ref")
             row = np.zeros((P,), np.int32)
             row[:4] = [3, 7, 1, 5]
             ids = rng.integers(1, 64, (1, 8)).astype(np.int32)
             # chunked prefill: the whole prompt as one chunk (Qmax = 8)
-            logits, tok_g, pk, pv = prefill_chunk(
+            logits, tok_g, cache = fam.prefill_chunk(
                 params, jnp.asarray(ids), jnp.asarray(0, jnp.int32),
-                jnp.asarray(8, jnp.int32), jnp.asarray(row),
-                cache["k"], cache["v"])
+                jnp.asarray(8, jnp.int32), jnp.asarray(row), 0,
+                fam.init_cache())
             assert int(tok_g) == int(jnp.argmax(logits))
             chunk_widths = set(calls)
             assert chunk_widths == {8}, calls
             calls.clear()
             # decode: Qmax = 1
             tables = jnp.asarray(row[None])
-            _lg, pk, pv = decode_step(
+            _lg, cache = fam.decode_step(
                 params, jnp.asarray([int(tok_g)], jnp.int32),
-                jnp.asarray([8], jnp.int32), tables, pk, pv,
+                jnp.asarray([8], jnp.int32), tables, cache,
                 jnp.ones((1,), bool))
             assert set(calls) == {1}, calls
             calls.clear()
@@ -481,9 +475,9 @@ class TestImplUniformAttention:
             toks = np.zeros((1, 4), np.int32)
             toks[0, 0] = int(tok_g)
             toks[0, 1:] = [1, 2, 3]
-            verify_step(params, jnp.asarray(toks),
-                        jnp.asarray([9], jnp.int32), tables, pk, pv,
-                        jnp.asarray([4], jnp.int32))
+            fam.verify_step(params, jnp.asarray(toks),
+                            jnp.asarray([9], jnp.int32), tables, cache,
+                            jnp.asarray([4], jnp.int32))
             assert set(calls) == {4}, calls
         finally:
             pa.ragged_paged_attention_ref = orig
