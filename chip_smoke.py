@@ -87,11 +87,15 @@ SIZES = {
                        page_size=64, max_pages_per_seq=16, prefill_chunk=256,
                        prompt_bucket=128, decode_horizon=8,
                        prompt_lens=(100, 300, 520, 77, 640),
-                       max_new_tokens=16, compare_tokens=4),
+                       max_new_tokens=16, compare_tokens=4,
+                       # the cell's decode batch and prefill chunk: the row
+                       # bounds its grouped products run at
+                       product_tokens=(64, 1024)),
     },
 }
 VERIFY_Q = 5            # speculative verify segment: K+1 at the engine's K=4
 KERNEL_TOL = 2e-2       # |kernel - ref| on f32 outputs of bf16 q/k/v (below)
+PRODUCT_TOL = 2e-4      # |grouped_matmul - ragged_dot|, f32 sums of bf16 products
 KERNEL_VS_REF_FLOOR = 0.75      # serve: kernel engine vs ref engine, bf16
 
 
@@ -330,6 +334,53 @@ def hybrid_config(name):
     conf = load_json(os.path.dirname(os.path.abspath(__file__)),
                      "benchmark", "configs", name + ".json")
     return drv.model_config(conf), conf
+
+
+def grouped_product_phase(cfg, tokens, *, interpret=False, seed=0):
+    """The experts' grouped product at a decode batch's and a prefill run's
+    row bounds (``tokens`` names the two batch sizes), the Pallas kernel and
+    ``jax.lax.ragged_dot`` called directly on ONE set of operands, a third
+    of the held experts empty: the kernel has lowered and run on this
+    device, whatever the engine below would choose."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.incubate.distributed.models.moe.dropless import row_bounds
+    from paddle_tpu.ops.pallas.grouped_matmul import (
+        grouped_matmul, grouped_matmul_ref, tiles)
+
+    held, k = cfg.held()[1], cfg.num_experts_per_tok
+    rng = np.random.default_rng(seed)
+    seen = {}
+    for name, t in zip(("decode", "prefill"), tokens):
+        bound = row_bounds(t, k, held, cfg.n_routed_experts)[0]
+        rows = rng.multinomial(bound * 3 // 4, rng.dirichlet(np.ones(held)))
+        rows[rng.choice(held, held // 3, replace=False)] = 0
+        counted = int(rows.sum())
+        for shape in ((cfg.moe_latent_size, cfg.moe_intermediate_size),
+                      (cfg.moe_intermediate_size, cfg.moe_latent_size)):
+            tiling = tiles(bound, *shape, held)
+            check(tiling is not None,
+                  f"{name}: no kernel tiling for {bound} rows x {shape}")
+            xs = jnp.asarray(rng.normal(0, 1, (bound, shape[0])),
+                             jnp.bfloat16)
+            w = jnp.asarray(rng.normal(0, shape[0] ** -0.5, (held, *shape)),
+                            jnp.bfloat16)
+            args = (xs, w, jnp.asarray(rows, jnp.int32))
+            got = np.asarray(jax.jit(lambda *a: grouped_matmul(
+                *a, role=name, interpret=interpret,
+                out_dtype=jnp.float32))(*args))[:counted]
+            want = np.asarray(jax.jit(lambda *a: grouped_matmul_ref(
+                *a, out_dtype=jnp.float32))(*args))[:counted]
+            err = float(np.abs(got - want).max())
+            seen[f"{name}.{shape[0]}x{shape[1]}"] = dict(
+                rows_bound=bound, rows_counted=counted,
+                experts_touched=int((rows > 0).sum()),
+                tiles=list(tiling), max_abs_err=err)
+            check(np.isfinite(got).all() and err <= PRODUCT_TOL,
+                  f"grouped product {name} {shape}: |kernel - ragged_dot| "
+                  f"{err} over {PRODUCT_TOL}")
+    return seen
 
 
 def hybrid_phase(cfg, sizes, *, attention_impl="auto", interpret=False,
@@ -627,8 +678,10 @@ def main(argv=None):
               "kernel did not run under shard_map")
     elif args.hybrid:
         hy = sizes["hybrid"]
-        hybrid = hybrid_phase(hybrid_config(hy["config"])[0], hy,
-                              seed=args.seed)
+        cfg = hybrid_config(hy["config"])[0]
+        emit("grouped_product", **grouped_product_phase(
+            cfg, hy["product_tokens"], seed=args.seed))
+        hybrid = hybrid_phase(cfg, hy, seed=args.seed)
         check(hybrid["decode_has_tpu_custom_call"],
               "no tpu_custom_call in the hybrid decode executable")
     else:

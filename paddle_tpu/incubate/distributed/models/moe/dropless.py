@@ -22,12 +22,19 @@ over uneven group sizes:
   combine the pairs' rows go back by the inverse permutation (a gather and a
           sum over k, in both directions — never a scatter-add).
 
-The grouped product is ``jax.lax.ragged_dot``: on the TPU XLA lowers it to
-its own Mosaic grouped-matmul kernel (the instruction is named
-``%ragged-dot-*`` and carries ``ragged_dot_tiling=`` among its frontend
-attributes, which a device trace keeps), forward and both gradients; the
-megablox ``gmm`` that ships with jax is the same algorithm without a label
-the trace could find it by.
+The grouped product has two implementations, chosen by the static shapes
+alone.  ``jax.lax.ragged_dot``, which XLA lowers on the TPU to its own Mosaic
+grouped-matmul kernel (the instruction is named ``%ragged-dot-*`` and carries
+``ragged_dot_tiling=`` among its frontend attributes, which a device trace
+keeps), forward and both gradients, with a tiling of XLA's choosing: 512 x
+512 x 512 for a train step's ~1,000 rows an expert (`grouped_swiglu`, always),
+which is right there, but (tm, tk, tn) = 64 x 512 x 128 and 512 x 512 x 128
+for a serving batch's 704 and 11,264 rows over 128 experts — 128 KB weight
+tiles, ~5,000 grid steps a call.  Where a group has few rows and nothing is
+differentiated (`grouped_relu2` on the chip) the product is
+`ops/pallas/grouped_matmul.py`, the same megablox algorithm with whole-K,
+wide-N weight tiles and a row tile that follows the rows a group has.  Both
+carry `TRACE_LABEL` in their instruction's text, so a trace finds either.
 """
 from __future__ import annotations
 
@@ -36,14 +43,13 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .....ops.pallas.grouped_matmul import (TRACE_LABEL, grouped_matmul,
+                                            tiles as _kernel_tiles)
+
 __all__ = ["sigmoid_topk_route", "sort_pairs_by_held_expert",
            "grouped_swiglu", "grouped_relu2", "dropless_expert_ffn",
            "dropless_expert_forward", "expert_load", "balance_bias_update",
-           "TRACE_LABEL"]
-
-# what a device trace finds the grouped products by (an "XLA Ops" event's
-# name is the instruction's whole text)
-TRACE_LABEL = "ragged_dot_tiling="
+           "row_bounds", "row_tier", "TRACE_LABEL"]
 
 
 def sigmoid_topk_route(u, w_router, bias, top_k, route_scale=1.0,
@@ -169,12 +175,35 @@ def grouped_swiglu(xs, we_gate, we_up, we_down, rows):
                               we_down.astype(xs.dtype), rows)
 
 
-def grouped_relu2(xs, we_up, we_down, rows):
+def _grouped_product(xs, w, rows, kernel, **kernel_kw):
+    """``ragged_dot``, or the Pallas kernel where ``kernel`` allows it and
+    the static shapes say a group has few rows (`grouped_matmul.tiles`)."""
+    chosen = kernel and _kernel_tiles(*xs.shape, w.shape[2], w.shape[0],
+                                      xs.dtype.itemsize)
+    if chosen:
+        return grouped_matmul(xs, w, rows, tm=chosen[0], tn=chosen[1],
+                              **kernel_kw)
+    return jax.lax.ragged_dot(xs, w.astype(xs.dtype), rows)
+
+
+def grouped_relu2(xs, we_up, we_down, rows, *, kernel=False, role=None,
+                  interpret=False):
     """The two-matrix expert ``relu(xs W_up[e])^2 W_down[e]`` per group, as
-    :func:`grouped_swiglu` is the three-matrix one."""
-    up = jax.lax.ragged_dot(xs, we_up.astype(xs.dtype), rows)
-    return jax.lax.ragged_dot(jnp.square(jax.nn.relu(up)),
-                              we_down.astype(xs.dtype), rows)
+    :func:`grouped_swiglu` is the three-matrix one; forward only.
+
+    ``kernel`` says the Pallas kernel MAY run (the caller's platform test:
+    a TPU, or ``interpret``); whether it does is read off the shapes, each
+    product for itself: ``xs.shape[0] // rows.shape[0]`` rows a group at
+    most 256 and lane-aligned widths take `grouped_matmul` (a decode batch's
+    704 / 128 = 5: row tile 64; a 1,024-token chunk's 88 or 176: row tile
+    128; an expert's whole matrix a weight tile where XLA takes 512 x 128),
+    anything else ``ragged_dot`` (XLA's 512-cubed tiles are right for the
+    1,024 rows an expert of a train step).  ``role`` labels the kernel's
+    calls in a trace ("decode" | "prefill")."""
+    kw = dict(role=role, interpret=interpret)
+    up = _grouped_product(xs, we_up, rows, kernel, **kw)
+    return _grouped_product(jnp.square(jax.nn.relu(up)), we_down, rows,
+                            kernel, **kw)
 
 
 def _experts_within(bound, operands, sorting, expert=grouped_swiglu):
@@ -190,7 +219,7 @@ def _experts_within(bound, operands, sorting, expert=grouped_swiglu):
     return _combine(ys, weights, first, inverse, held_mask)
 
 
-def _tier(bounds, rows):
+def row_tier(bounds, rows):
     """Index of the smallest of the ascending ``bounds`` that holds the
     counted rows (the last one holds any count)."""
     return sum((rows.sum() > b).astype(jnp.int32) for b in bounds[:-1])
@@ -205,7 +234,7 @@ def _experts_tiered(bounds, operands, sorting):
     [bound, .] residuals alive (zeros for the branches not taken), the
     largest bound's among them, in every layer."""
     return jax.lax.switch(
-        _tier(bounds, sorting[3]),
+        row_tier(bounds, sorting[3]),
         [functools.partial(_experts_within, b) for b in bounds],
         operands, sorting)
 
@@ -218,7 +247,7 @@ def _experts_tiered_bwd(bounds, res, g):
                        *operands)[1](g)
 
     return jax.lax.switch(
-        _tier(bounds, sorting[3]),
+        row_tier(bounds, sorting[3]),
         [functools.partial(backward, b) for b in bounds],
         operands, sorting, g), None
 
@@ -229,7 +258,7 @@ _experts_tiered.defvjp(
     _experts_tiered_bwd)
 
 
-def _row_bounds(t, k, held, num_experts):
+def row_bounds(t, k, held, num_experts):
     """The two static row bounds of a share: twice the rows it expects, and
     every pair."""
     expected_twice = max(2 * t * k * held // num_experts, 1)
@@ -249,8 +278,8 @@ def dropless_expert_forward(u, sel, weights, matrices, offset, num_experts,
     row); 0 while the largest bound is every pair)."""
     t, k = sel.shape
     sorting = sort_pairs_by_held_expert(sel, offset, matrices[0].shape[0])
-    bounds = _row_bounds(t, k, matrices[0].shape[0], num_experts)
-    tier = _tier(bounds, sorting[3])
+    bounds = row_bounds(t, k, matrices[0].shape[0], num_experts)
+    tier = row_tier(bounds, sorting[3])
     out = jax.lax.switch(
         tier,
         [functools.partial(_experts_within, b, expert=expert)
@@ -279,7 +308,7 @@ def dropless_expert_ffn(u, sel, weights, we_gate, we_up, we_down, offset,
     t, k = sel.shape
     held = we_gate.shape[0]
     sorting = sort_pairs_by_held_expert(sel, offset, held)
-    bounds = _row_bounds(t, k, held, num_experts)
+    bounds = row_bounds(t, k, held, num_experts)
     out = _experts_tiered(bounds, (u, weights, we_gate, we_up, we_down),
                           sorting)
     return out, sorting[3]

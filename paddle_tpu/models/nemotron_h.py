@@ -47,6 +47,7 @@ fns accumulate on the device (`ServingEngine.stats` fetches them).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Optional, Tuple
 
@@ -55,7 +56,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..incubate.distributed.models.moe.dropless import (
-    dropless_expert_forward, grouped_relu2, sigmoid_topk_route)
+    dropless_expert_forward, grouped_relu2, row_bounds, row_tier,
+    sigmoid_topk_route)
 from ..ops.ssm import ssd_chunked_scan, ssm_decode_update
 from .llama import scatter_kv_rows, scatter_kv_run
 from .paged_family import PagedFamily
@@ -286,14 +288,15 @@ def _relu2(x):
     return jnp.square(jax.nn.relu(x))
 
 
-def latent_moe(config: NemotronHConfig, lp, x, valid):
+def latent_moe(config: NemotronHConfig, lp, x, valid, expert=grouped_relu2):
     """One LatentMoE mixer's share: x [T, H], ``lp`` the layer's leaves,
     valid bool [T] (a token that is padding, or a dead slot's, selects no
-    expert) -> (out [T, H]: the held experts' part of r through W_2, plus
-    the shared expert; rows int32 [held]; beyond int32, the held pairs past
-    the grouped product's row bound (`dropless_expert_forward`); sel int32
-    [T, k], the selection over ALL experts, ``n_routed_experts`` where the
-    token is not valid)."""
+    expert), ``expert`` the grouped product of the held experts
+    (`grouped_relu2`, with or without its kernel) -> (out [T, H]: the held
+    experts' part of r through W_2, plus the shared expert; rows int32
+    [held]; beyond int32, the held pairs past the grouped product's row
+    bound (`dropless_expert_forward`); sel int32 [T, k], the selection over
+    ALL experts, ``n_routed_experts`` where the token is not valid)."""
     c = config
     offset, _ = c.held()
     u = _rms(x, lp["norm"], c.layer_norm_epsilon)
@@ -304,7 +307,7 @@ def latent_moe(config: NemotronHConfig, lp, x, valid):
     sel = jnp.where(valid[:, None], sel, c.n_routed_experts)
     part, rows, beyond = dropless_expert_forward(
         u @ lp["w_lat_in"], sel, w, (lp["we_up"], lp["we_down"]), offset,
-        c.n_routed_experts, expert=grouped_relu2)
+        c.n_routed_experts, expert=expert)
     shared = _relu2(u @ lp["ws_up"]) @ lp["ws_down"]
     return part @ lp["w_lat_out"] + shared, rows, beyond, sel
 
@@ -334,6 +337,7 @@ def build_nemotron_h_paged(config: NemotronHConfig, page_size: int = 16,
     ``kv_dtype`` and ``mesh`` are refused: the quantized page store and the
     tensor-parallel region are written for K/V pages alone.
     """
+    from ..ops.pallas.grouped_matmul import tiles, weight_visits
     from ..ops.pallas.paged_attention import (ragged_paged_attention,
                                               ragged_paged_attention_ref)
     c = config
@@ -385,6 +389,7 @@ def build_nemotron_h_paged(config: NemotronHConfig, page_size: int = 16,
             # alone wraps within hours of decode at 64 slots
             "ctr": {"moe_pairs": jnp.zeros((2, 2), jnp.int32),
                     "moe_touched": jnp.zeros((2, 2), jnp.int32),
+                    "moe_visits": jnp.zeros((2, 2), jnp.int32),
                     "moe_calls": jnp.zeros((2, 2), jnp.int32),
                     "moe_ratio": jnp.zeros((2, 2), f32),
                     "moe_dropped": jnp.zeros((2,), jnp.int32),
@@ -443,11 +448,25 @@ def build_nemotron_h_paged(config: NemotronHConfig, page_size: int = 16,
     def _moe(lp, x, valid, ctr, phase):
         """x [T, H]; valid bool [T] (padding and dead slots select no
         expert) -> (the mixer's output, ctr, sel [T, log_k])."""
-        out, rows, beyond, sel = latent_moe(c, lp, x, valid)
+        out, rows, beyond, sel = latent_moe(
+            c, lp, x, valid, expert=functools.partial(
+                grouped_relu2, kernel=use_kernel, interpret=interpret,
+                role=("decode", "prefill")[phase]))
         pairs = rows.sum()
         at = lambda v: jnp.zeros((2,), v.dtype).at[phase].set(v)
         ctr = _count(ctr, "moe_pairs", at(pairs))
         ctr = _count(ctr, "moe_touched", at((rows > 0).sum(dtype=jnp.int32)))
+        # the (row tile, group) visits of the grouped-matmul kernel under
+        # the row bound the layer took; 0 where `ragged_dot` ran
+        def visits(bound):
+            t = tiles(bound, *lp["we_up"].shape[1:], held, d.itemsize)
+            if use_kernel and t:   # static  # graftlint: disable=TRACE001
+                return weight_visits(rows, bound, t[0])
+            return jnp.int32(0)
+
+        bounds = row_bounds(x.shape[0], top_k, held, c.n_routed_experts)
+        ctr = _count(ctr, "moe_visits", at(jnp.stack(
+            [visits(b) for b in bounds])[row_tier(bounds, rows)]))
         ctr = _count(ctr, "moe_calls", at(jnp.int32(1)))
         ctr = _count(ctr, "moe_ratio", at(
             rows.max().astype(f32) * held / jnp.maximum(pairs, 1)))
@@ -598,6 +617,8 @@ def build_nemotron_h_paged(config: NemotronHConfig, page_size: int = 16,
             "moe_pairs_held": int(got["moe_pairs"].sum()),
             "moe_experts_touched_decode": int(got["moe_touched"][DECODE]),
             "moe_experts_touched_prefill": int(got["moe_touched"][PREFILL]),
+            "moe_gmm_weight_visits_decode": int(got["moe_visits"][DECODE]),
+            "moe_gmm_weight_visits_prefill": int(got["moe_visits"][PREFILL]),
             "moe_expert_layer_calls_decode": int(calls[DECODE]),
             "moe_expert_layer_calls_prefill": int(calls[PREFILL]),
             "moe_experts_held": held,

@@ -232,6 +232,41 @@ def test_dropless_expert_layer_compiles_to_grouped_matmul_kernels(one_chip):
         sds((16, 1024, 2048), jnp.bfloat16))
     assert text.count(dropless.TRACE_LABEL) >= 8
     assert not re.search(r"= \S+ scatter\(", text)
+    # ~1,000 rows an expert: XLA's own 512-cubed tiles, never the serving
+    # kernel of `ops/pallas/grouped_matmul.py` (PR 34)
+    assert dropless.TRACE_LABEL + '"512,512,512"' in text
+    assert '"kernel":"grouped_matmul"' not in "".join(text.split())
+
+
+# serve_reason_c64's grouped products: (row bound, K, N) -> the tiling the
+# static rule takes; 128 held experts (PR 34)
+_CELL_PRODUCTS = {"decode up": (704, 1024, 2688, "64,1024,2688"),
+                  "decode down": (704, 2688, 1024, "64,2688,1024"),
+                  "decode up, second row bound": (1408, 1024, 2688,
+                                                  "64,1024,2688"),
+                  "chunk up": (11264, 1024, 2688, "128,1024,2688"),
+                  "chunk down, second row bound": (22528, 2688, 1024,
+                                                   "128,2688,1024")}
+
+
+@pytest.mark.parametrize("case", list(_CELL_PRODUCTS))
+def test_grouped_matmul_compiles_with_the_label_the_trace_reads(one_chip,
+                                                                case):
+    """The kernel lowers at the cell's shapes with whole-K weight tiles, and
+    its instruction's text carries `TRACE_LABEL` + the tiling verbatim: the
+    four ``kernel.moe_gmm_*`` metrics find it as they found XLA's."""
+    from paddle_tpu.incubate.distributed.models.moe import dropless
+    m, k, n, tiling = _CELL_PRODUCTS[case]
+    sds = _shapes_on(one_chip)
+    text = _compiles_with_kernel(
+        functools.partial(dropless.grouped_relu2, kernel=True, role="decode"),
+        sds((m, k), jnp.bfloat16), sds((128, k, n), jnp.bfloat16),
+        sds((128, n, k), jnp.bfloat16), sds((128,), jnp.int32))
+    assert "ragged-dot" not in text
+    assert dropless.TRACE_LABEL + tiling in text
+    flat = "".join(text.split())
+    assert 'kernel_metadata={"kernel":"grouped_matmul"' in flat
+    assert '"role":"decode"' in flat
 
 
 # ---------------------------------------------------------------------------
@@ -455,9 +490,14 @@ def test_hybrid_serving_executable_fits_and_leaves_its_cache_in_place(
     assert re.search(
         r"operand_layout_constraints=\{s32\[\d+,50\].*"
         r"(, bf16\[1,2,3201,64,128\]\{4,3,2,1,0\}){2}\}", text)
-    # the grouped products are XLA's Mosaic kernels, two an expert layer
+    # the grouped products are the kernel of `ops/pallas/grouped_matmul.py`
+    # at these rows a group, two an expert layer and row bound, under the
+    # label XLA's own carried
     from paddle_tpu.incubate.distributed.models.moe import dropless
     assert text.count(dropless.TRACE_LABEL) >= 10
+    assert "ragged-dot" not in text
+    assert len(re.findall(r'"kernel":\s*"grouped_matmul"', text)) \
+        == text.count(dropless.TRACE_LABEL)
     # nothing copies the page pool, the SSM state, the selection log, a
     # layer of any of them, or a layer's expert matrices (a static slice of a STACKED leaf was copied
     # out before every grouped product: 672 MB a matrix, PR 33)

@@ -193,6 +193,23 @@ def test_hybrid_phase_interpret():
     json.dumps(seen)
 
 
+def test_grouped_product_phase_interpret():
+    """`chip_smoke.py --hybrid` calls the grouped-matmul kernel and
+    ``ragged_dot`` directly on one set of operands before its engine runs:
+    both products, a decode and a prefill row bound, empty groups."""
+    from paddle_tpu.models.nemotron_h import nemotron_h_config_tiny
+    cfg = nemotron_h_config_tiny(n_routed_experts=32, experts_held=(8, 8),
+                                 moe_latent_size=128,
+                                 moe_intermediate_size=256)
+    seen = chip_smoke.grouped_product_phase(cfg, (32, 256), interpret=True)
+    assert sorted(seen) == ["decode.128x256", "decode.256x128",
+                            "prefill.128x256", "prefill.256x128"]
+    assert [seen[k]["tiles"][0] for k in sorted(seen)] == [64, 64, 128, 128]
+    assert all(0 < v["experts_touched"] < 8 and v["rows_counted"]
+               < v["rows_bound"] for v in seen.values())
+    json.dumps(seen)
+
+
 def test_hybrid_sizes_name_a_benchmark_configuration_at_published_widths():
     for kind, sizes in chip_smoke.SIZES.items():
         cfg, conf = chip_smoke.hybrid_config(sizes["hybrid"]["config"])

@@ -885,3 +885,20 @@ def test_choose_heads_from_shapes():
             for quant in (False, True):
                 assert hkv % _choose_heads(rows, hkv,
                                            **{**cell, "quant": quant}) == 0
+
+
+def test_grouped_matmul_against_ragged_dot():
+    """`ops/pallas/grouped_matmul.py` beside its reference on one uneven
+    load with empty groups (tests/test_grouped_matmul.py has the layouts,
+    the rule and the expert layer on both paths)."""
+    from paddle_tpu.ops.pallas.grouped_matmul import (grouped_matmul,
+                                                      grouped_matmul_ref)
+    lr = np.random.default_rng(34)
+    xs = jnp.asarray(lr.standard_normal((128, 256)), jnp.bfloat16)
+    w = jnp.asarray(lr.standard_normal((8, 256, 384)) / 16, jnp.bfloat16)
+    rows = jnp.asarray([0, 41, 0, 3, 0, 0, 60, 1], jnp.int32)
+    out = grouped_matmul(xs, w, rows, tm=32, tn=128, interpret=True,
+                         out_dtype=jnp.float32)
+    ref = grouped_matmul_ref(xs, w, rows, out_dtype=jnp.float32)
+    np.testing.assert_allclose(np.asarray(out)[:105], np.asarray(ref)[:105],
+                               rtol=2e-4, atol=2e-4)
