@@ -91,6 +91,16 @@ SIZES = {
                        # the cell's decode batch and prefill chunk: the row
                        # bounds its grouped products run at
                        product_tokens=(64, 1024)),
+        # --latent: the latent-attention family (MLA + SwiGLU experts) at the
+        # widths of the benchmark configuration named here, three layers
+        # (the dense one and two expert layers), a few slots
+        "latent": dict(config="kimi-vl-a3b-serve-1of4", layers=3,
+                       num_slots=8, page_size=64, max_pages_per_seq=32,
+                       prefill_chunk=256, prompt_bucket=128,
+                       decode_horizon=8,
+                       prompt_lens=(100, 300, 520, 77, 1200),
+                       max_new_tokens=16, compare_tokens=4,
+                       product_tokens=(64, 512)),
     },
 }
 VERIFY_Q = 5            # speculative verify segment: K+1 at the engine's K=4
@@ -336,12 +346,14 @@ def hybrid_config(name):
     return drv.model_config(conf), conf
 
 
-def grouped_product_phase(cfg, tokens, *, interpret=False, seed=0):
+def grouped_product_phase(cfg, tokens, *, shapes=None, interpret=False,
+                          seed=0):
     """The experts' grouped product at a decode batch's and a prefill run's
     row bounds (``tokens`` names the two batch sizes), the Pallas kernel and
     ``jax.lax.ragged_dot`` called directly on ONE set of operands, a third
     of the held experts empty: the kernel has lowered and run on this
-    device, whatever the engine below would choose."""
+    device, whatever the engine below would choose.  ``shapes``: the
+    [K, N] of an expert's matrices (None: a LatentMoE expert's two)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -357,8 +369,9 @@ def grouped_product_phase(cfg, tokens, *, interpret=False, seed=0):
         rows = rng.multinomial(bound * 3 // 4, rng.dirichlet(np.ones(held)))
         rows[rng.choice(held, held // 3, replace=False)] = 0
         counted = int(rows.sum())
-        for shape in ((cfg.moe_latent_size, cfg.moe_intermediate_size),
-                      (cfg.moe_intermediate_size, cfg.moe_latent_size)):
+        for shape in shapes or (
+                (cfg.moe_latent_size, cfg.moe_intermediate_size),
+                (cfg.moe_intermediate_size, cfg.moe_latent_size)):
             tiling = tiles(bound, *shape, held)
             check(tiling is not None,
                   f"{name}: no kernel tiling for {bound} rows x {shape}")
@@ -435,6 +448,144 @@ def hybrid_phase(cfg, sizes, *, attention_impl="auto", interpret=False,
                           f"attention_impl={attention_impl!r} vs 'ref'",
               **token_agreement([o[:n_cmp] for o in outs], ref_outs)}
     report("hybrid_vs_ref", **versus)
+    check(versus["agreement"] >= KERNEL_VS_REF_FLOOR,
+          f"kernel engine vs ref engine: {versus}")
+    return {**facts, "vs_ref_engine": versus}
+
+
+def latent_config(name, layers):
+    """(MlaMoeConfig cut to ``layers``, the configuration file) of a
+    benchmark configuration of the latent-attention family."""
+    from benchmark.drivers import serve_mla_moe as drv
+    from benchmark.run import load_json
+    conf = load_json(os.path.dirname(os.path.abspath(__file__)),
+                     "benchmark", "configs", name + ".json")
+    return drv.model_config(conf, num_hidden_layers=layers), conf
+
+
+def latent_kernel_phase(cfg, sizes, *, interpret=False, seed=0):
+    """The latent-page attention kernel against its plain form at the
+    config's widths: decode (one query a slot x every head) and a run's
+    segments (`models/mla_moe.SEGMENT` queries each), bf16 queries and rows,
+    f32 outputs, ragged lengths, an idle slot, a segment that reaches the
+    table's last page."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.models.mla_moe import SEGMENT
+    from paddle_tpu.ops.pallas.paged_attention import (
+        mla_paged_attention, mla_paged_attention_ref)
+
+    nh, dl = cfg.num_attention_heads, cfg.kv_lora_rank
+    width = -(-cfg.latent_row // 128) * 128
+    ps, table_w, slots = sizes["page_size"], sizes["max_pages_per_seq"], \
+        sizes["num_slots"]
+    n_pages, layers = slots * table_w, 2
+    rng = np.random.default_rng(seed)
+    pool = jnp.asarray(rng.normal(0, 1, (layers, 1, n_pages, ps, width)),
+                       jnp.bfloat16)
+    kw = dict(dv=dl, sm_scale=(cfg.qk_nope_head_dim
+                               + cfg.qk_rope_head_dim) ** -0.5)
+    rows = {}
+    for name, s, qmax in (("decode", slots, 1), ("chunk", 4, SEGMENT)):
+        # queries as the model makes them: unit scale over the row's REAL
+        # columns, zeros in the store's padding
+        q = rng.normal(0, 1, (s, qmax, nh, width)) * 0.3
+        q[..., cfg.latent_row:] = 0
+        q = jnp.asarray(q, jnp.bfloat16)
+        table = jnp.asarray(rng.permutation(n_pages)[:s * table_w]
+                            .reshape(s, table_w), jnp.int32)
+        q_len = rng.integers(1, qmax + 1, (s,))
+        q_len[0] = qmax
+        q_len[-1] = 0
+        q_start = rng.integers(0, table_w * ps - qmax, (s,))
+        q_start[0] = table_w * ps - qmax
+        args = (q, pool, table, jnp.asarray(q_start, jnp.int32),
+                jnp.asarray(q_len, jnp.int32),
+                jnp.asarray(np.where(q_len > 0, q_start + q_len, 0),
+                            jnp.int32))
+        got = np.asarray(jax.jit(lambda *a: mla_paged_attention(
+            *a, layer=jnp.int32(1), role=name, interpret=interpret,
+            out_dtype=jnp.float32, **kw))(*args))
+        want = np.asarray(jax.jit(lambda *a: mla_paged_attention_ref(
+            *a, layer=1, out_dtype=jnp.float32, **kw))(*args))
+        check(got.shape == (s, qmax, nh, dl), f"{name}: shape {got.shape}")
+        check(np.isfinite(got).all(), f"{name}: non-finite kernel output")
+        pad = np.arange(qmax)[None, :] >= q_len[:, None]
+        check((got[pad] == 0).all(), f"{name}: padded rows not exactly zero")
+        err = float(np.abs(got - want).max())
+        check(err <= KERNEL_TOL, f"{name}: |kernel - plain| = {err} > "
+                                 f"{KERNEL_TOL}")
+        rows[name] = {"q": list(q.shape), "max_abs_err": err,
+                      "ref_abs_max": float(np.abs(want).max())}
+    return {"compared": "mla_paged_attention vs mla_paged_attention_ref, "
+                        "bf16 q/rows, f32 out",
+            "heads": nh, "row": [cfg.latent_row, width], "page_size": ps,
+            "table_width": table_w, "tolerance_abs": KERNEL_TOL,
+            "cases": rows}
+
+
+def latent_phase(cfg, sizes, *, attention_impl="auto", interpret=False,
+                 dtype="bfloat16", seed=0, report=emit):
+    """The latent-attention family through the same engine: dense prefill,
+    prefill chunks that read a prefix back from the latent pages and the
+    decode horizon all run, a repeated prompt is served from the prefix
+    cache, no routed row is dropped, and the kernel engine's greedy tokens
+    agree with the plain-attention engine's."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models.mla_moe import build_functional_mla_moe
+    params = jax.block_until_ready(jax.jit(
+        lambda k: build_functional_mla_moe(cfg, k, jnp.dtype(dtype)))(
+            jax.random.PRNGKey(seed)))
+    prompts = make_prompts(cfg, sizes["prompt_lens"], seed + 1)
+    chunk = sizes["prefill_chunk"]
+    check(any(len(p) > 2 * chunk for p in prompts)
+          and any(len(p) <= chunk for p in prompts),
+          "prompts must straddle the prefill chunk, one over several chunks")
+    outs, facts, eng = run_engine(params, cfg, sizes, prompts,
+                                  sizes["max_new_tokens"],
+                                  attention_impl=attention_impl,
+                                  interpret=interpret)
+    ran, st = facts["executables"], eng.stats()
+    check(ran.get("prefill", 0) > 0 and ran.get("prefill_chunk", 0) > 0
+          and ran.get("decode_step", 0) > 0,
+          f"dense prefill, chunked prefill and decode must all run: {ran}")
+    check(eng.family.page_leaves == ("latent",) and eng.cache is not None,
+          "one latent page store, with a prefix cache")
+    check(st["moe_rows_dropped"] == 0 and st["moe_pairs_held"] > 0,
+          f"routed rows: {st['moe_pairs_held']} held, "
+          f"{st['moe_rows_dropped']} dropped")
+    # the longest prompt again: its whole pages come out of the cache
+    rid = eng.submit(prompts[-1], max_new_tokens=sizes["max_new_tokens"])
+    again = [int(t) for t in eng.run()[rid].generated]
+    hit = eng.stats()["cached_prefix_tokens"] - st["cached_prefix_tokens"]
+    check(hit >= len(prompts[-1]) // sizes["page_size"] * sizes["page_size"]
+          - sizes["page_size"], f"prefix cache served {hit} tokens")
+    same = token_agreement([again], [outs[-1]])
+    compiled = eng.decode_horizon_compiled()
+    facts.update(
+        family=eng.family.name, depth=cfg.num_hidden_layers,
+        parameters=count_params(params),
+        moe_pairs_held=st["moe_pairs_held"],
+        latent_rows_written=st["latent_rows_written"],
+        prefix_tokens_from_cache=hit, prefix_hit_vs_cold=same,
+        decode_has_tpu_custom_call="tpu_custom_call" in compiled.as_text(),
+        decode_executable_bytes=executable_bytes(compiled),
+        peak_bytes_in_use=peak_bytes(jax.devices()[:1])[0])
+    report("latent", **facts)
+    check(same["agreement"] >= KERNEL_VS_REF_FLOOR,
+          f"a prefix hit against the cold run: {same}")
+    del eng, compiled
+    gc.collect()
+    n_cmp = sizes["compare_tokens"]
+    ref_outs, _, ref_eng = run_engine(params, cfg, sizes, prompts, n_cmp,
+                                      attention_impl="ref")
+    del ref_eng
+    versus = {"compared": f"first {n_cmp} greedy tokens of every request, "
+                          f"attention_impl={attention_impl!r} vs 'ref'",
+              **token_agreement([o[:n_cmp] for o in outs], ref_outs)}
+    report("latent_vs_ref", **versus)
     check(versus["agreement"] >= KERNEL_VS_REF_FLOOR,
           f"kernel engine vs ref engine: {versus}")
     return {**facts, "vs_ref_engine": versus}
@@ -649,6 +800,10 @@ def main(argv=None):
                     help="run ONLY the recurrent family's engine (Mamba-2 + "
                          "attention + LatentMoE at the benchmark "
                          "configuration's widths; one chip)")
+    ap.add_argument("--latent", action="store_true",
+                    help="run ONLY the latent-attention family: its kernel "
+                         "against the plain form, the SwiGLU experts' "
+                         "grouped product, its engine (one chip)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
@@ -684,6 +839,17 @@ def main(argv=None):
         hybrid = hybrid_phase(cfg, hy, seed=args.seed)
         check(hybrid["decode_has_tpu_custom_call"],
               "no tpu_custom_call in the hybrid decode executable")
+    elif args.latent:
+        la = sizes["latent"]
+        cfg = latent_config(la["config"], la["layers"])[0]
+        emit("latent_kernel", **latent_kernel_phase(cfg, la, seed=args.seed))
+        h, f = cfg.hidden_size, cfg.moe_intermediate_size
+        emit("grouped_product", **grouped_product_phase(
+            cfg, la["product_tokens"], shapes=((h, f), (f, h)),
+            seed=args.seed))
+        latent = latent_phase(cfg, la, seed=args.seed)
+        check(latent["decode_has_tpu_custom_call"],
+              "no tpu_custom_call in the latent decode executable")
     else:
         sv = sizes["serve"]
         cfg = cut_config(sv["layers"])

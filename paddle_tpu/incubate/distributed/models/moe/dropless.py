@@ -27,11 +27,12 @@ alone.  ``jax.lax.ragged_dot``, which XLA lowers on the TPU to its own Mosaic
 grouped-matmul kernel (the instruction is named ``%ragged-dot-*`` and carries
 ``ragged_dot_tiling=`` among its frontend attributes, which a device trace
 keeps), forward and both gradients, with a tiling of XLA's choosing: 512 x
-512 x 512 for a train step's ~1,000 rows an expert (`grouped_swiglu`, always),
-which is right there, but (tm, tk, tn) = 64 x 512 x 128 and 512 x 512 x 128
-for a serving batch's 704 and 11,264 rows over 128 experts — 128 KB weight
-tiles, ~5,000 grid steps a call.  Where a group has few rows and nothing is
-differentiated (`grouped_relu2` on the chip) the product is
+512 x 512 for a train step's ~1,000 rows an expert (`grouped_swiglu` as the
+train step calls it), which is right there, but (tm, tk, tn) = 64 x 512 x 128
+and 512 x 512 x 128 for a serving batch's 704 and 11,264 rows over 128
+experts — 128 KB weight tiles, ~5,000 grid steps a call.  Where a group has
+few rows and nothing is differentiated (`grouped_relu2`, and `grouped_swiglu`
+as a serving path calls it, on the chip) the product is
 `ops/pallas/grouped_matmul.py`, the same megablox algorithm with whole-K,
 wide-N weight tiles and a row tile that follows the rows a group has.  Both
 carry `TRACE_LABEL` in their instruction's text, so a trace finds either.
@@ -165,16 +166,6 @@ _combine.defvjp(
         (ys, weights, order, inverse, held_mask)), _combine_bwd)
 
 
-def grouped_swiglu(xs, we_gate, we_up, we_down, rows):
-    """Rows sorted by expert, ``rows[e]`` of them for expert e ->
-    (silu(xs W_gate[e]) * (xs W_up[e])) W_down[e] per group.  Rows past
-    sum(rows) belong to no group; what they hold afterwards is unspecified."""
-    gate = jax.lax.ragged_dot(xs, we_gate.astype(xs.dtype), rows)
-    up = jax.lax.ragged_dot(xs, we_up.astype(xs.dtype), rows)
-    return jax.lax.ragged_dot(jax.nn.silu(gate) * up,
-                              we_down.astype(xs.dtype), rows)
-
-
 def _grouped_product(xs, w, rows, kernel, **kernel_kw):
     """``ragged_dot``, or the Pallas kernel where ``kernel`` allows it and
     the static shapes say a group has few rows (`grouped_matmul.tiles`)."""
@@ -184,6 +175,24 @@ def _grouped_product(xs, w, rows, kernel, **kernel_kw):
         return grouped_matmul(xs, w, rows, tm=chosen[0], tn=chosen[1],
                               **kernel_kw)
     return jax.lax.ragged_dot(xs, w.astype(xs.dtype), rows)
+
+
+def grouped_swiglu(xs, we_gate, we_up, we_down, rows, *, kernel=False,
+                   role=None, interpret=False):
+    """Rows sorted by expert, ``rows[e]`` of them for expert e ->
+    (silu(xs W_gate[e]) * (xs W_up[e])) W_down[e] per group.  Rows past
+    sum(rows) belong to no group; what they hold afterwards is unspecified.
+
+    As called by the train step (``kernel`` left False) the three products
+    are ``ragged_dot``, forward and both gradients.  A serving path that
+    never differentiates passes its platform test as ``kernel``, and each
+    product then takes `grouped_matmul` where the static shapes say a group
+    has few rows, under :func:`grouped_relu2`'s rule."""
+    kw = dict(role=role, interpret=interpret)
+    gate = _grouped_product(xs, we_gate, rows, kernel, **kw)
+    up = _grouped_product(xs, we_up, rows, kernel, **kw)
+    return _grouped_product(jax.nn.silu(gate) * up, we_down, rows, kernel,
+                            **kw)
 
 
 def grouped_relu2(xs, we_up, we_down, rows, *, kernel=False, role=None,

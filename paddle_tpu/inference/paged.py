@@ -791,7 +791,7 @@ class ServingEngine:
     Which model it serves is the CONFIGURATION's to say:
     ``config.paged_family(...)`` returns the family's paged fns over one
     cache pytree (`models/paged_family.py`), and the engine never asks for
-    a model's name.  Two families are served.  ``llama`` (`LlamaConfig`:
+    a model's name.  Three families are served.  ``llama`` (`LlamaConfig`:
     the Llama-shaped dense decoders; a cache of K/V pages) has every
     feature below.  ``nemotron_h`` (`models/nemotron_h.NemotronHConfig`:
     Mamba-2 + attention + LatentMoE layers, sparse experts inside the
@@ -811,7 +811,15 @@ class ServingEngine:
     consumed token's expert selections by slot and position;
     ``recurrent_state(rid)`` reads a slot's state and its log (``moe_sel``:
     what a rollout pool replays the routing of, and what the benchmark
-    routes its reference by).
+    routes its reference by).  ``mla_moe`` (`models/mla_moe.MlaMoeConfig`:
+    multi-head latent attention + sigmoid-routed SwiGLU experts with shared
+    experts) has ONE page store, of compressed rows (``page_leaves =
+    ("latent",)``: every layer's state is pages, so the prefix cache,
+    copy-on-write, preemption and the page transfers work as for K/V
+    pages); it refuses ``speculative``, ``quantize``, ``kv_dtype`` and
+    ``mesh`` by name, counts ``latent_*`` and ``moe_*`` on the device and
+    keeps the same selection log (and the greedy token's log-probability)
+    a slot for ``recurrent_state(rid)``.
 
     `prefix_cache=True` (default) turns on automatic prefix caching:
     retired requests park their KV pages in a block-hash index, and later
@@ -902,7 +910,7 @@ class ServingEngine:
             kv_dtype=self.kv_dtype, mesh=mesh, mp_axis=self.mp_axis,
             quantized_allreduce=self.quantized_allreduce)
         self.family = family
-        if family.recurrent and quantize:
+        if quantize and not family.int8_weights:
             raise NotImplementedError(
                 f"quantize: the {family.name} family's leaves have no int8 "
                 f"grid in serving/quant.py (written for the Llama-shaped "
@@ -910,9 +918,9 @@ class ServingEngine:
         if speculative and family.verify_step is None:
             raise NotImplementedError(
                 f"speculative: the {family.name} family has no verify step "
-                f"— scoring drafted tokens over recurrent state needs a "
+                f"(over recurrent state, scoring drafted tokens needs a "
                 f"state checkpoint a drafted token, to rewind to on a "
-                f"rejection")
+                f"rejection; over latent pages, the drafted rows' scores)")
         if quantize:
             bits = 8 if quantize is True or quantize == "int8" \
                 else int(quantize)
@@ -978,17 +986,19 @@ class ServingEngine:
 
         prefill, prefill_chunk_fn = family.prefill, family.prefill_chunk
         # ONE pytree holds everything the paged executables keep on the
-        # device between calls.  ``["k"]`` / ``["v"]`` are the KV page
-        # stores: each a raw [L, Hkv, NP+1, ps, D] array (f32/bf16) or a
-        # {"q": data, "s": scales} dict (kv_dtype set); a recurrent family
-        # adds its per-slot state and its counters as further leaves.  The
+        # device between calls.  The leaves the family names
+        # (`family.page_leaves`) are its page stores: ``["k"]`` / ``["v"]``,
+        # each a raw [L, Hkv, NP+1, ps, D] array (f32/bf16) or a
+        # {"q": data, "s": scales} dict (kv_dtype set), or ONE store of
+        # latent rows ``["latent"]`` [L, 1, NP+1, ps, W]; a family adds its
+        # per-slot state, logs and counters as further leaves.  The
         # engine only hands the cache on: every paged executable takes it
         # DONATED, carries it whole through its layer loop (rows written in
         # place, the layer indexed inside the attention kernel — no
         # executable copies, slices or relays out the pool) and returns it
         # as its last output, which `_call_paged` rebinds.  What the engine
         # itself knows of the layout is the page axis, axis 2 of every leaf
-        # of the two page stores (`_copy_page`; snapshot/restore through
+        # of the named page stores (`_copy_page`; snapshot/restore through
         # gather/scatter_kv_pages)
         self._cache = family.init_cache()
         if self.tp > 1:
@@ -1046,13 +1056,14 @@ class ServingEngine:
         # executable covers every copy).  tree_map keeps it generic over
         # the page-store layout: a raw array copies its page rows, a
         # quantized {"q","s"} store copies data AND scales — the page axis
-        # is axis 2 of every leaf of the two page stores by construction
+        # is axis 2 of every leaf of the stores the family names
+        # (`page_leaves`: K and V, or one latent store) by construction
         # (whatever else the cache holds belongs to slots, not to pages).
         def _copy_page(cache, src, dst):              # graftlint: jit
             def cp(a):
                 return a.at[:, :, dst].set(a[:, :, src])
-            return {**cache, "k": jax.tree_util.tree_map(cp, cache["k"]),
-                    "v": jax.tree_util.tree_map(cp, cache["v"])}
+            return {**cache, **{name: jax.tree_util.tree_map(cp, cache[name])
+                                for name in family.page_leaves}}
 
         self._horizon_fn = _horizon
         self._horizon_jit = {}         # (K, greedy) -> jitted horizon
@@ -1392,12 +1403,17 @@ class ServingEngine:
 
     @property
     def _pages_k(self):
-        """The K side of the KV page store (read-only view of the cache)."""
+        """The K side of a K/V page store (read-only view of the cache; a
+        family with another kind of page store has no such leaf)."""
         return self._cache["k"]
 
     @property
     def _pages_v(self):
         return self._cache["v"]
+
+    def _page_stores(self) -> dict:
+        """{name: store} of the page stores the family names."""
+        return {name: self._cache[name] for name in self.family.page_leaves}
 
     def jit_variants(self) -> dict:
         """{model fn name: number of compiled executables} — the bounded,
@@ -1758,7 +1774,8 @@ class ServingEngine:
                     # order: admitted -> prefill_dense -> first_token
                     with self._span("prefill_dense", rid=req.rid, pos=0,
                                     tokens=T, padded=Tb, pages=kv_pages,
-                                    family=self.family.name):
+                                    family=self.family.name,
+                                    attention=self.family.attention_path):
                         tok, self._cache = self._call_paged(
                             pf,
                             self.params, jnp.asarray(ids),
@@ -1840,21 +1857,25 @@ class ServingEngine:
             c = min(c, self.prefill_chunk)
         # bucket the chunk pad (a short suffix must not pay a full-chunk
         # executable) and slice the page table to the pages this chunk can
-        # actually see (4-page granularity) — attention cost in the chunk
-        # executable is C_pad x table_width, so both knobs matter, and on
-        # TPU the kernel grid is proportional to the table width
+        # actually see (the family's granularity, 4 pages where the
+        # attention's cost follows the table's width — the plain forms
+        # gather the whole table — and the whole table where the kernel
+        # walks live pages only and a width is just one more executable)
         Cb = max(self.prompt_bucket,
                  math.ceil(c / self.prompt_bucket) * self.prompt_bucket)
         if self.prefill_chunk is not None:
             Cb = min(Cb, max(self.prompt_bucket, self.prefill_chunk))
         Cb = min(Cb, self.config.max_position_embeddings)
         ctx_pages = math.ceil((pos + c) / self.page_size)
-        Pb = min(self.max_pages_per_seq, math.ceil(ctx_pages / 4) * 4)
+        granule = self.family.chunk_table_granule or self.max_pages_per_seq
+        Pb = min(self.max_pages_per_seq,
+                 math.ceil(ctx_pages / granule) * granule)
         ids = np.zeros((1, Cb), np.int32)
         ids[0, :c] = slot.ctx[pos:pos + c]
         kv_pages = self._count_prefill(pos, c, Cb)
         with self._span("prefill_chunk", rid=req.rid, pos=pos, tokens=c,
                         padded=Cb, pages=kv_pages, family=self.family.name,
+                        attention=self.family.attention_path,
                         state_carried=1 if self.family.recurrent and pos else 0):
             logits, tok_g, self._cache = self._call_paged(
                 self._chunk_jit,
@@ -2456,8 +2477,9 @@ class ServingEngine:
 
     @property
     def page_bytes(self) -> int:
-        """Bytes ONE pool page costs on device (K + V across all layers;
-        per-page scales included when ``kv_dtype`` is set) — the unit the
+        """Bytes ONE pool page costs on device (every page store the family
+        names, K + V or the latent rows, across all layers; per-page scales
+        included when ``kv_dtype`` is set) — the unit the
         telemetry memory observatory multiplies page counts by, so
         capacity wins from quantized pages are visible in BYTES, not just
         page counts (`mem.pool_allocated_bytes` / `mem.pool_capacity_bytes`
@@ -2467,9 +2489,8 @@ class ServingEngine:
         sharded over mp, so each chip holds 1/tp of every page."""
         pb = self._page_bytes
         if pb is None:
-            # the page axis is axis 2 of every leaf of the two page stores
-            leaves = self._jax.tree_util.tree_leaves(
-                (self._cache["k"], self._cache["v"]))
+            # the page axis is axis 2 of every leaf of the page stores
+            leaves = self._jax.tree_util.tree_leaves(self._page_stores())
             pb = self._page_bytes = sum(
                 a.dtype.itemsize * math.prod(a.shape) // a.shape[2]
                 for a in leaves) // self.tp
@@ -2652,7 +2673,8 @@ class ServingEngine:
         try:
             with self._span("overlap_dispatch" if self.overlap
                             else "decode_dispatch", slots=len(run), k=K,
-                            family=self.family.name):
+                            family=self.family.name,
+                            attention=self.family.attention_path):
                 rec = self._dispatch_decode(run, K, greedy)
             prev, self._inflight = self._inflight, rec
             if prev is not None:
@@ -2926,12 +2948,15 @@ class ServingEngine:
         splice that lost the scales would write back garbage magnitudes."""
         from ..models.llama import gather_kv_pages
         idx = self._jnp.asarray(np.asarray(ids, np.int32))
-        gk = gather_kv_pages(self._pages_k, idx)
-        gv = gather_kv_pages(self._pages_v, idx)
-        if self.kv_dtype is not None:
-            return {"kv_k_q": np.asarray(gk["q"]), "kv_k_s": np.asarray(gk["s"]),
-                    "kv_v_q": np.asarray(gv["q"]), "kv_v_s": np.asarray(gv["s"])}
-        return {"kv_k": np.asarray(gk), "kv_v": np.asarray(gv)}
+        planes = {}
+        for name, store in self._page_stores().items():
+            got = gather_kv_pages(store, idx)
+            if isinstance(got, dict):           # a quantized store's planes
+                planes.update({f"kv_{name}_{part}": np.asarray(a)
+                               for part, a in got.items()})
+            else:
+                planes[f"kv_{name}"] = np.asarray(got)
+        return planes
 
     def _scatter_pages(self, ids, planes: dict):
         """Splice host planes (a `_gather_pages` result, same page order)
@@ -2939,14 +2964,12 @@ class ServingEngine:
         transfer primitive `_restore_full` and `import_kv` share."""
         from ..models.llama import scatter_kv_pages
         idx = self._jnp.asarray(np.asarray(ids, np.int32))
-        if self.kv_dtype is not None:
-            k = {"q": planes["kv_k_q"], "s": planes["kv_k_s"]}
-            v = {"q": planes["kv_v_q"], "s": planes["kv_v_s"]}
-        else:
-            k, v = planes["kv_k"], planes["kv_v"]
-        self._cache = {**self._cache,
-                       "k": scatter_kv_pages(self._pages_k, idx, k),
-                       "v": scatter_kv_pages(self._pages_v, idx, v)}
+        stores = {}
+        for name, store in self._page_stores().items():
+            mine = {part: planes[f"kv_{name}_{part}"] for part in store} \
+                if isinstance(store, dict) else planes[f"kv_{name}"]
+            stores[name] = scatter_kv_pages(store, idx, mine)
+        self._cache = {**self._cache, **stores}
 
     # -- KV handoff (disaggregated prefill/decode) -------------------------
     KV_HANDOFF_VERSION = 1
@@ -3344,8 +3367,8 @@ class ServingEngine:
         }
 
     def _family_counters(self) -> dict:
-        if self.family.recurrent:
-            self._join_dispatch()      # the cache must be concrete
+        if "ctr" in self._cache:       # counted on the device, in the cache
+            self._join_dispatch()      # ... which must be concrete
         return self.family.counters(self._cache)
 
     def stats_snapshot(self):

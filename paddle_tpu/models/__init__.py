@@ -7,14 +7,20 @@ What `inference.paged.ServingEngine` serves is a configuration's
 (`NemotronHConfig`: Mamba-2 + attention + LatentMoE; recurrent state beside
 the K/V pages, so no prefix cache, and ``speculative``, ``quantize``,
 ``kv_dtype``, ``mesh``, ``snapshot("full_kv")``, ``export_kv`` / ``import_kv``
-are refused).  AFMoE runs through the compiled train step only: its window
-layers need a page table a layer kind in the cache (ROADMAP B4)."""
+are refused) and ``mla_moe`` (`MlaMoeConfig`: latent attention +
+sigmoid-routed SwiGLU experts; ONE store of compressed rows, every layer's
+state pages, so the prefix cache and the page transfers work; ``speculative``,
+``quantize``, ``kv_dtype``, ``mesh`` are refused).  AFMoE runs through the
+compiled train step only: its window layers need a page table a layer kind in
+the cache (ROADMAP B4)."""
 from . import llama  # noqa: F401
 from .llama import LlamaConfig, LlamaForCausalLM, build_functional_llama  # noqa: F401
 from . import afmoe  # noqa: F401
 from .afmoe import AfmoeConfig, build_functional_afmoe  # noqa: F401
 from . import nemotron_h  # noqa: F401
 from .nemotron_h import NemotronHConfig, build_functional_nemotron_h  # noqa: F401
+from . import mla_moe  # noqa: F401
+from .mla_moe import MlaMoeConfig, build_functional_mla_moe  # noqa: F401
 from .paged_family import PagedFamily  # noqa: F401
 from . import ernie  # noqa: F401
 from .ernie import ErnieConfig, ErnieModel, ErnieForMaskedLM  # noqa: F401

@@ -1235,7 +1235,7 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
     return PagedFamily(
         name="llama", init_cache=init_pages, prefill=prefill,
         prefill_chunk=prefill_chunk, decode_step=decode_step,
-        verify_step=verify_step,
+        page_leaves=("k", "v"), int8_weights=True, verify_step=verify_step,
         mesh_specs=lambda axis: (llama_paged_param_specs(axis),
                                  llama_paged_page_spec(axis)))
 

@@ -60,7 +60,7 @@ from ..incubate.distributed.models.moe.dropless import (
     sigmoid_topk_route)
 from ..ops.ssm import ssd_chunked_scan, ssm_decode_update
 from .llama import scatter_kv_rows, scatter_kv_run
-from .paged_family import PagedFamily
+from .paged_family import PagedFamily, log_selections_run
 
 __all__ = ["NemotronHConfig", "nemotron_h_config_tiny",
            "build_functional_nemotron_h", "build_nemotron_h_paged",
@@ -350,7 +350,7 @@ def build_nemotron_h_paged(config: NemotronHConfig, page_size: int = 16,
         raise NotImplementedError(
             "mesh: sharding specs for this family's leaves and the experts' "
             "exchange are missing (ROADMAP B1)")
-    d = jnp.dtype(dtype) if dtype is not None else jnp.float32
+    d = jnp.dtype(dtype if dtype is not None else jnp.float32)
     f32 = jnp.float32
     state_dt = jnp.dtype(c.ssm_state_dtype)
     kinds = layer_kinds(c)
@@ -401,20 +401,6 @@ def build_nemotron_h_paged(config: NemotronHConfig, page_size: int = 16,
         high, low = ctr[name][0], ctr[name][1] + inc
         over = (low >= CARRY).astype(low.dtype)
         return {**ctr, name: jnp.stack([high + over, low - over * CARRY])}
-
-    def _log_run(log, j, slot, sel, start):
-        """A run's selections sel [C, log_k] -> log[j, slot, :, start:start
-        + C] in ONE update: where the padded run would pass the end of the
-        row, the block starts earlier and keeps what stands there."""
-        block = sel.T[:, :ctx]
-        width = block.shape[1]
-        at = jnp.minimum(start, ctx - width)
-        old = jax.lax.dynamic_slice(log, (j, slot, 0, at),
-                                    (1, 1, log_k, width))[0, 0]
-        block = jnp.where(jnp.arange(width) < start - at, old,
-                          jnp.roll(block, start - at, axis=1))
-        return jax.lax.dynamic_update_slice(log, block[None, None],
-                                            (j, slot, 0, at))
 
     def _attend(q, cache, li, page_tables, q_start, q_len, kv_len, role):
         fn = ragged_paged_attention if use_kernel \
@@ -532,7 +518,8 @@ def build_nemotron_h_paged(config: NemotronHConfig, page_size: int = 16,
                 x = x + o.reshape(C, nh * D) @ lp["wo"]
             else:
                 out, ctr, sel = _moe(lp, x, real, ctr, PREFILL)
-                cache["sel"] = _log_run(cache["sel"], j, slot, sel, start)
+                cache["sel"] = log_selections_run(cache["sel"], j, slot,
+                                                  sel, start)
                 x = x + out
         cache["ctr"] = ctr
         h_last = jax.lax.dynamic_index_in_dim(x, length - 1, 0,
@@ -644,6 +631,6 @@ def build_nemotron_h_paged(config: NemotronHConfig, page_size: int = 16,
 
     return PagedFamily(name="nemotron_h", init_cache=init_cache,
                        prefill=prefill, prefill_chunk=prefill_chunk,
-                       decode_step=decode_step, verify_step=None,
-                       recurrent=True, counters=counters,
+                       decode_step=decode_step, page_leaves=("k", "v"),
+                       verify_step=None, recurrent=True, counters=counters,
                        slot_state=slot_state)

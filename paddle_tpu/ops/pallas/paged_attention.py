@@ -580,3 +580,220 @@ def paged_attention_decode_ref(q, k_pages, v_pages, page_table, lengths,
         lengths, sm_scale=sm_scale, out_dtype=out_dtype,
         k_scales=k_scales, v_scales=v_scales)
     return o[:, 0]
+
+
+# ---------------------------------------------------------------------------
+# The LATENT-page form: absorbed multi-head latent attention (MLA).
+#
+# A latent store keeps ONE row a token and layer, ``[c | r]`` (the compressed
+# K/V latent and the one rotary key every head shares), in pages
+# ``[L, 1, NP, ps, Dk]``.  In the absorbed form every query head scores the
+# WHOLE row (Dk columns: q'_i . c + q_rope_i . r) and sums the row's first
+# ``dv`` columns (c) under the probabilities; K and V per head never exist.
+# Fed to the ragged kernel above as one kv head it would fetch every page
+# twice (once as K, once as V) and Dk = 576 is no whole number of lane tiles
+# for its V side.  Here a page is fetched ONCE and used as both.
+
+
+__all__ += ["mla_paged_attention", "mla_paged_attention_ref"]
+
+
+def _mla_kernel(pt_ref, qs_ref, ql_ref, kl_ref, ly_ref, q_ref, pool_ref,
+                o_ref, buf, sem, base_ref, m_scr, l_scr, acc_scr, *,
+                page_size, sm_scale, rep, group, table_width, dv):
+    """One grid step = one slot (a ragged query segment): a loop over the
+    slot's LIVE pages, ``group`` of them an update — the pages of a group
+    land side by side in one [group * ps, Dk] buffer, each by its own copy
+    out of the pool (``pl.ANY``), and are scored as one key tile of
+    group * ps columns.  Double-buffered like `_ragged_kernel`: group g of
+    the step lands in buffer (base + g) % 2 while group g - 1 is attended,
+    and the step's last update runs over the next step's first copies.  A
+    group's places past the slot's last live page are filled with that last
+    page again (no read of a dead table column, no stale buffer) and masked
+    by position."""
+    b = pl.program_id(0)
+    n_steps = pl.num_programs(0)
+
+    def live_pages(slot):
+        return pl.cdiv(jnp.minimum(kl_ref[slot], table_width * page_size),
+                       page_size)
+
+    def copies(slot, g, which):
+        last = live_pages(slot) - 1
+        for j in range(group):
+            page = pt_ref[slot, jnp.minimum(g * group + j, last)]
+            yield pltpu.make_async_copy(
+                pool_ref.at[ly_ref[0], 0, page],
+                buf.at[which, pl.ds(j * page_size, page_size)],
+                sem.at[which, j])
+
+    def send(*where):
+        for c in copies(*where):
+            c.start()
+
+    @pl.when(b == 0)
+    def _first():
+        base_ref[0] = 0
+
+    base = base_ref[0]
+    n_live = pl.cdiv(live_pages(b), group)
+    prev, nxt = jnp.maximum(b - 1, 0), jnp.minimum(b + 1, n_steps - 1)
+    has_next = (b + 1 < n_steps) & (live_pages(nxt) > 0)
+    on_its_way = (b > 0) & (live_pages(prev) > 0)
+
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when((n_live > 0) & jnp.logical_not(on_its_way))
+    def _cold():
+        send(b, 0, base)
+
+    q_start, q_len, kv_len = qs_ref[b], ql_ref[b], kl_ref[b]
+    q = q_ref[0]                                       # [rows, Dk]
+    width = group * page_size
+
+    def attend(g, carry):
+        which = (base + g) % 2
+
+        @pl.when(g + 1 < n_live)
+        def _ahead():
+            send(b, g + 1, 1 - which)
+
+        @pl.when((g + 1 == n_live) & has_next)
+        def _next_step():
+            send(nxt, 0, 1 - which)
+
+        for cp in copies(b, g, which):
+            cp.wait()
+        rows_kv = buf[which]                           # [group * ps, Dk]
+        mask = _segment_mask((q.shape[0], width), g, width, rep, q_start,
+                             q_len, kv_len)
+        s = jax.lax.dot_general(
+            q, rows_kv, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale
+        s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p.astype(rows_kv.dtype), rows_kv[:, :dv],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_scr[...] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, n_live, attend, 0)
+    base_ref[0] = (base + n_live) % 2
+    l = l_scr[...]
+    inv = jnp.where(l > 0.0, 1.0 / jnp.where(l > 0.0, l, 1.0), 0.0)
+    o_ref[0] = (acc_scr[...] * inv).astype(o_ref.dtype)
+
+
+def _mla_group(rows_pad, page_size, table_width):
+    """Pages an update: keys enough to fill the MXU's columns where the
+    query rows are many (a chunk's segment: 256 keys), and larger copies in
+    flight where they are few and the page reads set the pace (decode: 512
+    keys)."""
+    # python ints off array shapes  # graftlint: disable=TRACE001
+    keys = 512 if rows_pad <= 64 else 256
+    return max(1, min(keys // page_size, table_width, 8))
+
+
+def mla_paged_attention(q, latent_pages, page_table, q_start, q_len, kv_len,
+                        *, dv, sm_scale, layer=None, role=None,
+                        interpret=False, out_dtype=None, _group=None):
+    """Absorbed latent attention over each slot's page list.
+
+    q [S, Qmax, H, Dk] (per head ``[q' | q_rope]``: the query already
+    carried into the latent space), latent_pages [L, 1, NP, ps, Dk] with
+    ``layer`` (a traced scalar) or [1, NP, ps, Dk] for one layer, rows
+    ``[c | r]`` with c the first ``dv`` columns; page_table [S, P], q_start
+    / q_len / kv_len [S] as `ragged_paged_attention` has them ->
+    o [S, Qmax, H, dv]: per head softmax(q . row * sm_scale) over the
+    visible rows, times their c.  Padding queries and q_len = 0 slots come
+    back exactly zero.
+
+    Grid (S,): a step is one slot, its rows every (query, head) pair (row
+    r = query r // H, head r % H); each live page is fetched ONCE, as keys
+    (all Dk columns) and values (the first dv), ``_mla_group`` pages an
+    update, products in the operands' dtype accumulated in float32.  The
+    query block of a step must fit VMEM: a caller with a long run of
+    queries cuts it into segments (`models/mla_moe.py`: 64 queries x 16
+    heads a segment).  ``sm_scale`` has no default: it is 1 / sqrt(the
+    EXPANDED q/k width), which the latent shapes do not show.
+
+    The call's HLO text carries ``kernel_metadata={"kernel":
+    "mla_paged_attention","role":...}`` ("decode" | "chunk").
+    """
+    if layer is None:
+        latent_pages, layer = latent_pages[None], 0
+    s_slots, qmax, heads, dk = q.shape
+    _l, one, _np_, page_size, dk_p = latent_pages.shape
+    if one != 1 or dk_p != dk or not 0 < dv <= dk:
+        raise ValueError(f"latent pages {latent_pages.shape} do not hold "
+                         f"rows of {dk} columns for one shared head (dv "
+                         f"{dv})")
+    n_ptab = page_table.shape[1]
+    out_dtype = out_dtype or q.dtype
+    rows = qmax * heads
+    rows_pad = -(-rows // _SUBLANES) * _SUBLANES
+    qr = jnp.pad(q.reshape(s_slots, rows, dk),
+                 ((0, 0), (0, rows_pad - rows), (0, 0)))
+    group = _group or _mla_group(rows_pad, page_size, n_ptab)
+    block = lambda width: pl.BlockSpec(
+        (1, rows_pad, width), lambda b, pt, qs, ql, kl, ly: (b, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(s_slots,),
+        in_specs=[block(dk), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=block(dv),
+        scratch_shapes=[
+            pltpu.VMEM((2, group * page_size, dk), latent_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, group)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((rows_pad, 1), jnp.float32),
+            pltpu.VMEM((rows_pad, 1), jnp.float32),
+            pltpu.VMEM((rows_pad, dv), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _mla_kernel, page_size=page_size, sm_scale=sm_scale, rep=heads,
+        group=group, table_width=n_ptab, dv=dv)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((s_slots, rows_pad, dv), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        metadata={"kernel": "mla_paged_attention",
+                  **({"role": role} if role else {})},
+    )(page_table.astype(jnp.int32), q_start.astype(jnp.int32),
+      q_len.astype(jnp.int32), kv_len.astype(jnp.int32),
+      jnp.reshape(layer, (1,)).astype(jnp.int32), qr, latent_pages)
+    return out[:, :rows].reshape(s_slots, qmax, heads, dv)
+
+
+def mla_paged_attention_ref(q, latent_pages, page_table, q_start, q_len,
+                            kv_len, *, dv, sm_scale, layer=None,
+                            out_dtype=None):
+    """The plain form of :func:`mla_paged_attention` (pages gathered dense,
+    float32): the CPU path of the serving engine for this family."""
+    pages = latent_pages[layer, 0] if layer is not None else latent_pages[0]
+    s_slots, qmax = q.shape[:2]
+    g = pages[page_table]                         # [S, P, ps, Dk]
+    kv = g.reshape(s_slots, -1, g.shape[-1]).astype(jnp.float32)
+    s = jnp.einsum("sqhd,std->shqt", q.astype(jnp.float32), kv) * sm_scale
+    t_pos = jnp.arange(kv.shape[1])[None, None, None, :]
+    qi = jnp.arange(qmax)[None, None, :, None]
+    ok = (t_pos <= q_start[:, None, None, None] + qi) \
+        & (qi < q_len[:, None, None, None]) \
+        & (t_pos < kv_len[:, None, None, None])
+    p = jax.nn.softmax(jnp.where(ok, s, NEG_INF), axis=-1)
+    o = jnp.einsum("shqt,std->sqhd", p, kv[..., :dv])
+    o = jnp.where(jnp.arange(qmax)[None, :, None, None]
+                  < q_len[:, None, None, None], o, 0.0)
+    return o.astype(out_dtype or q.dtype)

@@ -15,13 +15,15 @@ chip time.  A compile that passes is not a chip run.
     JAX_PLATFORMS=cpu python perf/chip_fit.py                 # the SIZES table
     JAX_PLATFORMS=cpu python perf/chip_fit.py serve:14 train:3 tp:8 one:4:float32:highest
     JAX_PLATFORMS=cpu python perf/chip_fit.py hybrid:0      # serve_reason_c64
+    JAX_PLATFORMS=cpu python perf/chip_fit.py latent:0      # serve_longdoc_c64
     JAX_PLATFORMS=cpu python perf/chip_fit.py --dump <dir> hybrid:0
 
 Arguments are ``phase:layers[:dtype[:matmul_precision]]`` overrides (phases:
 serve, train, and tp / one — the --multichip engines on four devices / one;
 ``hybrid`` — the recurrent family's three executables at the benchmark
-configuration ``nemotron-3-super-serve-1of4``, whose depth is the file's:
-the layers argument is ignored).  ``--dump <dir>`` writes every compiled
+configuration ``nemotron-3-super-serve-1of4``, ``latent`` — the
+latent-attention family's at ``kimi-vl-a3b-serve-1of4``; their depth is the
+file's: the layers argument is ignored).  ``--dump <dir>`` writes every compiled
 program's text there: a device trace's event names ARE those instructions.
 """
 from __future__ import annotations
@@ -113,16 +115,14 @@ def paged_programs(cfg, sizes, place_params, place_pages, rep, mesh=None,
     }
 
 
-def hybrid_programs(conf, place, rep, dtype="bfloat16"):
-    """Lower a recurrent family's three executables the way ServingEngine
-    jits them, at the sizes of a benchmark configuration file ``conf``."""
-    from benchmark.drivers import serve_nemotron_h as drv
+def family_programs(conf, drv, build, place, rep, dtype="bfloat16"):
+    """Lower a family's three executables the way ServingEngine jits them,
+    at the sizes of a benchmark configuration file ``conf`` (``drv``: the
+    cell's driver module, ``build``: the family's weights from a key)."""
     from paddle_tpu.models.llama import make_paged_decode_horizon
-    from paddle_tpu.models.nemotron_h import build_functional_nemotron_h
     cfg, e = drv.model_config(conf), conf["engine"]
     S, P = e["num_slots"], e["max_pages_per_seq"]
-    params = place(jax.eval_shape(
-        lambda: build_functional_nemotron_h(cfg, dtype=dtype)))
+    params = place(jax.eval_shape(lambda: build(cfg, dtype=dtype)))
     fam = cfg.paged_family(page_size=e["page_size"], num_pages=S * P,
                            num_slots=S, max_pages_per_seq=P, dtype=dtype,
                            attention_impl="pallas")
@@ -139,8 +139,7 @@ def hybrid_programs(conf, place, rep, dtype="bfloat16"):
 
     return {
         "weights from a seed": (
-            jax.jit(lambda k: build_functional_nemotron_h(cfg, k, dtype)),
-            (key,)),
+            jax.jit(lambda k: build(cfg, k, dtype)), (key,)),
         f"decode horizon K={K}": (
             jax.jit(decode_horizon, donate_argnums=(4,)),
             (params, i32(S), i32(S), i32(S, P), cache, flag(S), key, f32(S),
@@ -154,16 +153,35 @@ def hybrid_programs(conf, place, rep, dtype="bfloat16"):
     }, cache
 
 
-def fit_hybrid(topo, layers, dtype):
+def hybrid_programs(conf, place, rep, dtype="bfloat16"):
+    """`family_programs` of the recurrent family (`models/nemotron_h.py`)."""
+    from benchmark.drivers import serve_nemotron_h as drv
+    from paddle_tpu.models.nemotron_h import build_functional_nemotron_h
+    return family_programs(conf, drv, build_functional_nemotron_h, place,
+                           rep, dtype)
+
+
+def latent_programs(conf, place, rep, dtype="bfloat16"):
+    """`family_programs` of the latent-attention family
+    (`models/mla_moe.py`)."""
+    from benchmark.drivers import serve_mla_moe as drv
+    from paddle_tpu.models.mla_moe import build_functional_mla_moe
+    return family_programs(conf, drv, build_functional_mla_moe, place, rep,
+                           dtype)
+
+
+def fit_family(topo, layers, dtype, phase="hybrid"):
     from benchmark import run as bench_run
+    name, programs_of = {
+        "hybrid": ("nemotron-3-super-serve-1of4", hybrid_programs),
+        "latent": ("kimi-vl-a3b-serve-1of4", latent_programs)}[phase]
     root = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
-    conf = bench_run.load_json(root, "benchmark", "configs",
-                               "nemotron-3-super-serve-1of4.json")
+    conf = bench_run.load_json(root, "benchmark", "configs", name + ".json")
     one = SingleDeviceSharding(topo.devices[0])
-    programs, _ = hybrid_programs(conf, placed_on(one), one, dtype=dtype)
+    programs, _ = programs_of(conf, placed_on(one), one, dtype=dtype)
     for name, (fn, args) in programs.items():
         t0 = time.time()
-        report(f"hybrid {dtype} {name}", fn.lower(*args).compile(), t0)
+        report(f"{phase} {dtype} {name}", fn.lower(*args).compile(), t0)
 
 
 def fit_one_chip(topo, layers, dtype, phase="serve"):
@@ -237,7 +255,8 @@ def main(argv):
         f"{arm.get('matmul_precision') or ''}"
         for arm in sizes["multichip"]["arms"] for phase in ("tp", "one")]
     fits = {"serve": fit_one_chip, "train": fit_train, "tp": fit_tp,
-            "hybrid": fit_hybrid,
+            "hybrid": fit_family,
+            "latent": lambda *a: fit_family(*a, phase="latent"),
             "one": lambda *a: fit_one_chip(*a, phase="one")}
     for item in todo:
         phase, layers, dtype, precision = (item.split(":") + ["", ""])[:4]

@@ -516,3 +516,90 @@ def test_hybrid_serving_executable_fits_and_leaves_its_cache_in_place(
                       for a in jax.tree_util.tree_leaves(cache))
     assert m.alias_size_in_bytes >= cache_bytes
     assert m.temp_size_in_bytes < 0.5 * GIB, m.temp_size_in_bytes / GIB
+
+
+@pytest.mark.parametrize("slots,qmax,table,role", [
+    (64, 1, 146, "decode"), (16, 64, 128, "chunk"), (2, 64, 16, "chunk")],
+    ids=["decode", "chunk_long", "chunk_short"])
+def test_latent_page_kernel_compiles_with_its_label(one_chip, slots, qmax,
+                                                    table, role):
+    """`serve_longdoc_c64`'s own calls: 64 slots x a table of 146 pages on
+    the latent store `bf16[9, 1, 9345, 64, 640]` with the layer traced, and
+    a chunk's segments of 64 queries x 16 heads; the store handed in whole,
+    once (keys and values are the same rows)."""
+    from paddle_tpu.ops.pallas.paged_attention import mla_paged_attention
+    sds = _shapes_on(one_chip)
+    seg = sds((slots,), jnp.int32)
+    text = _compiles_with_kernel(
+        lambda q, pool, tab, a, b, c, ly: mla_paged_attention(
+            q, pool, tab, a, b, c, dv=512, sm_scale=192 ** -0.5, layer=ly,
+            role=role),
+        sds((slots, qmax, 16, 640), jnp.bfloat16),
+        sds((9, 1, 9345, 64, 640), jnp.bfloat16),
+        sds((slots, table), jnp.int32), seg, seg, seg, sds((), jnp.int32))
+    assert ('kernel_metadata={"kernel":"mla_paged_attention","role":"%s"}'
+            % role) in "".join(text.split())
+    # the store is ONE operand of the call: keys and values are the same rows
+    (operands,) = re.findall(r"operand_layout_constraints=\{(.*?\})\}", text)
+    assert operands.count("bf16[9,1,9345,64,640]") == 1, operands
+
+
+@pytest.fixture(scope="module")
+def latent_programs(one_chip):
+    root = os.path.join(os.path.dirname(__file__), "..")
+    sys.path.insert(0, os.path.join(root, "perf"))
+    import chip_fit
+    from benchmark.run import load_json
+    conf = load_json(root, "benchmark", "configs",
+                     "kimi-vl-a3b-serve-1of4.json")
+    return chip_fit.latent_programs(conf, chip_fit.placed_on(one_chip),
+                                    one_chip)
+
+
+@pytest.mark.parametrize("program", ["decode horizon", "dense prefill",
+                                     "prefill chunk"])
+def test_latent_serving_executable_fits_and_leaves_its_cache_in_place(
+        latent_programs, program):
+    programs, cache = latent_programs
+    (fn, args), = [v for k, v in programs.items() if k.startswith(program)]
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    m = compiled.memory_analysis()
+    need = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    # 3.2 GB of weights, 6.9 GB of latent pages (rows of 640), 0.15 GB of
+    # logs: what the cell holds (15.75 GiB a chip)
+    assert 9.0 * GIB < need < 11.0 * GIB, need / GIB
+    role = "decode" if program == "decode horizon" else "chunk"
+    assert re.search(r'kernel_metadata=\{\s*"kernel":"mla_paged_attention",'
+                     r'\s*"role":"%s"' % role, text)
+    assert "ragged_paged_attention" not in text
+    # the SwiGLU experts' products are `ops/pallas/grouped_matmul.py` where
+    # a group has few rows: every one of a decode step's, and a chunk's
+    # under the lower row bound (192 rows a group); past it, XLA's own
+    from paddle_tpu.incubate.distributed.models.moe import dropless
+    kernels = len(re.findall(r'"kernel":\s*"grouped_matmul"', text))
+    assert kernels >= 8 * 3
+    if program == "decode horizon":
+        assert kernels == text.count(dropless.TRACE_LABEL)
+        assert "ragged-dot" not in text
+    # nothing copies the latent store, the selection log, a layer of
+    # either, or a layer's expert matrices
+    pool = math.prod(cache["latent"].shape)
+    log = math.prod(cache["sel"].shape)
+    sizes = {pool, pool // cache["latent"].shape[0], log,
+             log // cache["sel"].shape[0], 16 * 2048 * 1408}
+    copies = [(op, f"{dtype}[{dims}]")
+              for dtype, dims, _, op in _INSTR.findall(text)
+              if op in ("copy", "copy-start")
+              and math.prod(int(n) for n in dims.split(",") if n) in sizes]
+    assert not copies, copies
+    layouts = {layout for _, dims, layout, _ in _INSTR.findall(text)
+               if dims == ",".join(map(str, cache["latent"].shape))}
+    # row-major (dim 1 has one element: where it stands changes nothing)
+    assert layouts <= {"4,3,2,1,0", "4,3,2,0,1"}, layouts
+    # every leaf of the donated cache is aliased to the output
+    cache_bytes = sum(math.prod(a.shape) * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(cache))
+    assert m.alias_size_in_bytes >= cache_bytes
+    assert m.temp_size_in_bytes < 0.5 * GIB, m.temp_size_in_bytes / GIB

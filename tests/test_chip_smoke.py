@@ -222,3 +222,55 @@ def test_hybrid_sizes_name_a_benchmark_configuration_at_published_widths():
             hy["prompt_lens"]) + hy["max_new_tokens"]
         assert min(hy["prompt_lens"]) <= hy["prefill_chunk"] \
             < max(hy["prompt_lens"])
+
+
+def _latent_cfg(**kw):
+    from paddle_tpu.models.mla_moe import mla_moe_config_tiny
+    return mla_moe_config_tiny(vocab_size=96, experts_held=(4, 8), **kw)
+
+
+def test_latent_kernel_phase_interpret():
+    """`chip_smoke.py --latent` first holds the latent-page kernel to its
+    plain form: decode and a run's segments, ragged, an idle slot."""
+    facts = chip_smoke.latent_kernel_phase(
+        _latent_cfg(kv_lora_rank=120),
+        dict(SERVE, num_slots=4, max_pages_per_seq=16),
+        interpret=True)
+    assert sorted(facts["cases"]) == ["chunk", "decode"]
+    assert facts["row"] == [128, 128]
+    assert all(c["max_abs_err"] <= chip_smoke.KERNEL_TOL
+               for c in facts["cases"].values())
+    json.dumps(facts)
+
+
+def test_latent_phase_interpret():
+    """... then runs the family's engine: three executables, a prefix hit,
+    the kernel engine's tokens those of the plain engine."""
+    seen = {}
+    sizes = dict(SERVE, prompt_lens=(5, 20, 12, 37), max_pages_per_seq=8)
+    out = chip_smoke.latent_phase(
+        _latent_cfg(), sizes, attention_impl="pallas", interpret=True,
+        dtype="float32",
+        report=lambda phase, **facts: seen.update({phase: facts}))
+    assert set(seen) == {"latent", "latent_vs_ref"}
+    assert out["family"] == "mla_moe" and out["moe_pairs_held"] > 0
+    assert out["prefix_tokens_from_cache"] >= 32
+    assert out["prefix_hit_vs_cold"]["tokens_equal"]
+    assert out["vs_ref_engine"]["tokens_equal"]
+    json.dumps(seen)
+
+
+def test_latent_sizes_name_a_benchmark_configuration_at_published_widths():
+    for kind, sizes in chip_smoke.SIZES.items():
+        la = sizes["latent"]
+        cfg, conf = chip_smoke.latent_config(la["config"], la["layers"])
+        assert (cfg.hidden_size, cfg.num_attention_heads, cfg.kv_lora_rank,
+                cfg.qk_rope_head_dim, cfg.qk_nope_head_dim, cfg.v_head_dim,
+                cfg.moe_intermediate_size, cfg.num_experts_per_tok,
+                cfg.num_hidden_layers) == (2048, 16, 512, 64, 128, 128, 1408,
+                                           6, la["layers"]), kind
+        assert la["layers"] > cfg.first_k_dense_replace
+        assert la["page_size"] * la["max_pages_per_seq"] >= max(
+            la["prompt_lens"]) + la["max_new_tokens"]
+        assert min(la["prompt_lens"]) <= la["prefill_chunk"] \
+            and 2 * la["prefill_chunk"] < max(la["prompt_lens"])

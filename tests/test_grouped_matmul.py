@@ -151,8 +151,16 @@ def test_grouped_relu2_picks_its_product_by_rows_a_group():
     assert {"ragged_dot", "ragged_dot_general"} & many
     off = traced(64, 8)
     assert "pallas_call" not in off
-    # the three-matrix expert of the train step is never the kernel's
-    assert "kernel" not in dropless.grouped_swiglu.__code__.co_varnames
+    # the three-matrix expert AS THE TRAIN STEP CALLS IT (no platform test
+    # passed) is never the kernel's, at few rows a group either; a serving
+    # path passes ``kernel`` (tests/test_mla_moe.py)
+    sds = jax.ShapeDtypeStruct
+    swiglu = _primitives(
+        dropless.grouped_swiglu, sds((64, 128), jnp.bfloat16),
+        sds((8, 128, 256), jnp.bfloat16), sds((8, 128, 256), jnp.bfloat16),
+        sds((8, 256, 128), jnp.bfloat16), sds((8,), jnp.int32))
+    assert "pallas_call" not in swiglu
+    assert dropless.grouped_swiglu.__kwdefaults__["kernel"] is False
 
 
 def test_expert_forward_is_the_same_on_both_paths():
