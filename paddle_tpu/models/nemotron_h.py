@@ -37,8 +37,10 @@ The cache of the paged fns (`build_nemotron_h_paged`) holds two kinds of
 state in ONE pytree: KV pages for the attention layers
 (``k`` / ``v [La, Hkv, NP+1, ps, D]``, the page axis where Llama's is) and,
 per Mamba layer and engine SLOT, a convolution tail ``conv [Lm, slots, K-1,
-conv_dim]`` and an SSM state ``ssm [Lm, slots, heads, P, N]`` (float32) that
-does not grow with the context; beside them ``sel [Le, slots, k, ctx]``, the
+conv_dim]`` and an SSM state ``ssm`` (float32) that does not grow with the
+context: a tuple of ``Lm`` arrays ``[slots, heads, P, N]``, a layer's state
+its OWN buffer like a layer's weights — a decode step then reads it once
+(`init_cache`); beside them ``sel [Le, slots, k, ctx]``, the
 experts every consumed token of the slot's sequence selected in each
 LatentMoE layer (what a rollout pool replays the routing of in training, and
 what the benchmark routes its reference by), and ``ctr``, the counters the
@@ -377,7 +379,14 @@ def build_nemotron_h_paged(config: NemotronHConfig, page_size: int = 16,
         return {
             "k": jnp.zeros(pool, d), "v": jnp.zeros(pool, d),
             "conv": jnp.zeros((m, num_slots, K - 1, conv_dim), d),
-            "ssm": jnp.zeros((m, num_slots, mh, P, N), state_dt),
+            # one leaf a Mamba layer: over a WHOLE leaf the TPU compiler
+            # fuses a decode step's update and its `y = h C` into one pass
+            # that writes the new state in place; over a slice of a stacked
+            # leaf it updated in place and then read the state again for y
+            # (805 MB a layer and step where 537 do: PERF.md section 6,
+            # PR 36; tests/test_chip_compile.py holds the one pass)
+            "ssm": tuple(jnp.zeros((num_slots, mh, P, N), state_dt)
+                         for _ in range(m)),
             # positions on the minor axis (a k there would be padded to 128
             # lanes) and k, rounded up to whole sublane tiles, on the next:
             # with a ragged k the TPU lays the leaf out slots-minor, the
@@ -482,6 +491,7 @@ def build_nemotron_h_paged(config: NemotronHConfig, page_size: int = 16,
         seg_len = jnp.clip(length.astype(jnp.int32) - seg_off, 0, seg)
         tables = jnp.broadcast_to(page_row[None], (nseg,) + page_row.shape)
         cache = dict(cache)
+        ssm = list(cache["ssm"])
         ctr = _count(cache["ctr"], "ssm_resets", fresh.astype(jnp.int32))
         for kind, j in kinds:
             lp = _layer_params(bp, kind, j)
@@ -498,11 +508,10 @@ def build_nemotron_h_paged(config: NemotronHConfig, page_size: int = 16,
                 xs, b, cc = _split(xbc)
                 dt = jnp.where(real[:, None], jax.nn.softplus(
                     dt.astype(f32) + lp["dt_bias"]), 0.0)
-                h0 = jnp.where(fresh, 0, cache["ssm"][j, slot].astype(f32))
+                h0 = jnp.where(fresh, 0, ssm[j][slot].astype(f32))
                 y, h = ssd_chunked_scan(xs, dt, -jnp.exp(lp["A_log"]), b, cc,
                                         h0, chunk=c.chunk_size)
-                cache["ssm"] = cache["ssm"].at[j, slot].set(
-                    h.astype(state_dt))
+                ssm[j] = ssm[j].at[slot].set(h.astype(state_dt))
                 x = x + _mamba_out(lp, y, xs, z)
             elif kind == "attn":
                 u = _rms(x, lp["norm"], eps)
@@ -521,7 +530,7 @@ def build_nemotron_h_paged(config: NemotronHConfig, page_size: int = 16,
                 cache["sel"] = log_selections_run(cache["sel"], j, slot,
                                                   sel, start)
                 x = x + out
-        cache["ctr"] = ctr
+        cache["ssm"], cache["ctr"] = tuple(ssm), ctr
         h_last = jax.lax.dynamic_index_in_dim(x, length - 1, 0,
                                               keepdims=False)
         return _head(hp, h_last), cache
@@ -547,6 +556,7 @@ def build_nemotron_h_paged(config: NemotronHConfig, page_size: int = 16,
         eff_len = jnp.where(active, lengths + 1, 0)
         n_q = active.astype(jnp.int32)
         cache = dict(cache)
+        ssm = list(cache["ssm"])
         ctr = _count(cache["ctr"], "live_slot_steps",
                      active.sum(dtype=jnp.int32))
         # a dead slot's selections fall past the row's end: dropped
@@ -567,9 +577,8 @@ def build_nemotron_h_paged(config: NemotronHConfig, page_size: int = 16,
                 xs, b, cc = _split(xbc)
                 dt = jnp.where(active[:, None], jax.nn.softplus(
                     dt.astype(f32) + lp["dt_bias"]), 0.0)
-                y, h = ssm_decode_update(cache["ssm"][j], xs, dt,
-                                         -jnp.exp(lp["A_log"]), b, cc)
-                cache["ssm"] = cache["ssm"].at[j].set(h)
+                y, ssm[j] = ssm_decode_update(ssm[j], xs, dt,
+                                              -jnp.exp(lp["A_log"]), b, cc)
                 x = x + _mamba_out(lp, y, xs, z)
             elif kind == "attn":
                 u = _rms(x, lp["norm"], eps)
@@ -587,7 +596,7 @@ def build_nemotron_h_paged(config: NemotronHConfig, page_size: int = 16,
                 cache["sel"] = cache["sel"].at[j, slots, :, log_pos].set(
                     sel, mode="drop")
                 x = x + out
-        cache["ctr"] = ctr
+        cache["ssm"], cache["ctr"] = tuple(ssm), ctr
         return _head(hp, x), cache
 
     state_bytes = num_slots * (
@@ -624,7 +633,7 @@ def build_nemotron_h_paged(config: NemotronHConfig, page_size: int = 16,
         """The slot's recurrent state, and ``moe_sel [Le, positions, k]``:
         the selections of the positions the slot's sequence consumed (the
         caller knows how many; the rest is an earlier sequence's)."""
-        return {"ssm": np.asarray(cache["ssm"][:, slot]),
+        return {"ssm": np.stack([np.asarray(h[slot]) for h in cache["ssm"]]),
                 "conv": np.asarray(cache["conv"][:, slot]),
                 "moe_sel": np.asarray(cache["sel"][:, slot, :top_k])
                 .swapaxes(1, 2)}

@@ -498,13 +498,16 @@ def test_hybrid_serving_executable_fits_and_leaves_its_cache_in_place(
     assert "ragged-dot" not in text
     assert len(re.findall(r'"kernel":\s*"grouped_matmul"', text)) \
         == text.count(dropless.TRACE_LABEL)
-    # nothing copies the page pool, the SSM state, the selection log, a
-    # layer of any of them, or a layer's expert matrices (a static slice of a STACKED leaf was copied
-    # out before every grouped product: 672 MB a matrix, PR 33)
+    # nothing copies the page pool, a Mamba layer's SSM state (one leaf a
+    # layer since PR 36), the selection log, a layer of the pool or the log,
+    # or a layer's expert matrices (a static slice of a STACKED leaf was
+    # copied out before every grouped product: 672 MB a matrix, PR 33)
     pool = math.prod(cache["k"].shape)
-    ssm = math.prod(cache["ssm"].shape)
+    layers = len(cache["ssm"])
+    leaf = "f32[%s]" % ",".join(map(str, cache["ssm"][0].shape))
+    ssm = math.prod(cache["ssm"][0].shape)
     log = math.prod(cache["sel"].shape)
-    sizes = {pool, ssm, ssm // cache["ssm"].shape[0], 128 * 1024 * 2688,
+    sizes = {pool, ssm, layers * ssm, 128 * 1024 * 2688,
              log, log // cache["sel"].shape[0]}
     copies = [(op, f"{dtype}[{dims}]")
               for dtype, dims, _, op in _INSTR.findall(text)
@@ -516,6 +519,33 @@ def test_hybrid_serving_executable_fits_and_leaves_its_cache_in_place(
                       for a in jax.tree_util.tree_leaves(cache))
     assert m.alias_size_in_bytes >= cache_bytes
     assert m.temp_size_in_bytes < 0.5 * GIB, m.temp_size_in_bytes / GIB
+    # no instruction has the STACKED state's shape: what the accepted
+    # `kernel.ssm_update_roofline_pct` finds its seconds by (it reads nothing
+    # since PR 36; PERF.md section 7)
+    assert "f32[%d,%s" % (layers, leaf[4:]) not in text
+    if not program.startswith("decode horizon"):
+        return
+    # ONE pass over a Mamba layer's state a decode step: one fusion a layer
+    # has both results, `y = h' C` and the new state ...
+    both = re.findall(
+        r"(%\S+) = \(bf16\[64,128,64\]\S*, " + re.escape(leaf)
+        + r"\S*\) fusion\(", text)
+    assert len(both) == layers, both
+    # ... and nothing else takes a state leaf as an operand: every
+    # instruction that IS a leaf (the loop's carry, a fusion's second
+    # result) feeds one such fusion or only the plumbing of tuples
+    # (the instructions INSIDE a fusion's computation are not the program's)
+    text = re.sub(r"(?m)^%fused_computation[^\n]*\{\n.*?^\}\n", "", text,
+                  flags=re.S)
+    is_leaf = set(re.findall(r"(%\S+) = " + re.escape(leaf), text))
+    plumbing = ("tuple", "get-tuple-element", "while", "parameter",
+                "opt-barrier")
+    readers = [(name, op) for name, op, operands in re.findall(
+        r"(%\S+) = [^=]*? ([\w-]+)\(([^)]*)\)", text)
+        if op not in plumbing
+        and is_leaf & {o.strip() for o in operands.split(",")}]
+    assert sorted(readers) == sorted((name, "fusion") for name in both), \
+        readers
 
 
 @pytest.mark.parametrize("slots,qmax,table,role", [

@@ -3,6 +3,7 @@ the paged model fns against `benchmark/reference_nemotron_h.py`, which imports
 nothing from the program (sequential recurrence, every held expert computed
 for every token)."""
 import dataclasses
+import os
 
 import jax
 import jax.numpy as jnp
@@ -11,7 +12,8 @@ import pytest
 
 from benchmark import reference_nemotron_h as ref
 from paddle_tpu.models.nemotron_h import (build_functional_nemotron_h,
-                                          latent_moe, nemotron_h_config_tiny)
+                                          latent_moe, layer_kinds,
+                                          nemotron_h_config_tiny)
 from paddle_tpu.ops.ssm import ssd_chunked_scan, ssm_decode_update
 
 F32_LIMIT = 2e-5        # |logit| is ~1 here; float32 end to end
@@ -99,23 +101,97 @@ def test_chunked_scan_against_the_sequential_recurrence(length):
     assert np.abs(np.asarray(h) - want_h).max() < 1e-4 * np.abs(want_h).max()
 
 
-def test_decode_update_is_one_step_of_the_recurrence_and_skips_dt_zero():
-    heads, p, groups, n, s = 4, 8, 2, 16, 3
+@pytest.mark.parametrize("shape,state,operands", [
+    ((3, 4, 2, 8, 16), "float32", "float32"),
+    # as the decode step calls it since PR 36 — a layer's whole state leaf,
+    # donated, bfloat16 operands — at the cell's per-slot shape and an odd one
+    ((2, 128, 8, 64, 128), "float32", "bfloat16"),
+    ((2, 128, 8, 64, 128), "bfloat16", "bfloat16"),
+    ((3, 6, 2, 8, 128), "float32", "bfloat16"),
+    ((3, 6, 2, 8, 128), "bfloat16", "bfloat16")])
+def test_decode_update_is_one_step_of_the_recurrence_and_skips_dt_zero(
+        shape, state, operands):
+    s, heads, groups, p, n = shape
     ks = jax.random.split(jax.random.PRNGKey(0), 6)
-    h = jax.random.normal(ks[0], (s, heads, p, n))
-    x = jax.random.normal(ks[1], (s, heads, p))
+    h = jax.random.normal(ks[0], (s, heads, p, n)).astype(state)
+    x = jax.random.normal(ks[1], (s, heads, p)).astype(operands)
     dt = jax.nn.softplus(jax.random.normal(ks[2], (s, heads)))
     dt = dt.at[1].set(0.0)                    # a dead slot
     a = -jnp.exp(jax.random.normal(ks[3], (heads,)))
-    b = jax.random.normal(ks[4], (s, groups, n))
-    c = jax.random.normal(ks[5], (s, groups, n))
-    y, new = ssm_decode_update(h, x, dt, a, b, c)
+    b = jax.random.normal(ks[4], (s, groups, n)).astype(operands)
+    c = jax.random.normal(ks[5], (s, groups, n)).astype(operands)
+    before = np.asarray(h)
+    y, new = jax.jit(ssm_decode_update, donate_argnums=0)(h, x, dt, a, b, c)
+    assert y.dtype == x.dtype and new.dtype == before.dtype
+    # float32 arithmetic, then one rounding to the result's own dtype
+    ulp = {"float32": 2.0 ** -23, "bfloat16": 2.0 ** -8}
     for i in range(s):
-        want_y, want_h = sequential_scan(x[i][None], dt[i][None], a,
-                                         b[i][None], c[i][None], h[i])
-        assert np.allclose(np.asarray(new[i]), want_h, atol=1e-5)
-        assert np.allclose(np.asarray(y[i]), want_y[0], atol=1e-4)
-    assert np.array_equal(np.asarray(new[1]), np.asarray(h[1]))
+        want_y, want_h = sequential_scan(
+            *(np.asarray(v, np.float32) for v in (
+                x[i][None], dt[i][None], a, b[i][None], c[i][None])),
+            before[i].astype(np.float32))
+        assert np.abs(np.asarray(new[i], np.float32) - want_h).max() \
+            <= 4 * ulp[state] * np.abs(want_h).max()
+        assert np.abs(np.asarray(y[i], np.float32) - want_y[0]).max() \
+            <= max(2 * ulp[operands], 1e-5) * np.abs(want_y).max()
+    # a slot whose dt is 0 keeps its state bit for bit
+    assert np.array_equal(np.asarray(new[1]), before[1])
+
+
+@pytest.mark.parametrize("state", ["float32", "bfloat16"])
+def test_a_decode_step_reads_each_layers_state_once(state):
+    """ONE pass over the state a decode step (ISSUE 36): every Mamba
+    layer's state is its own leaf of the cache, ONE equation of the step
+    takes it, and nothing updates a slice of a stack of layers."""
+    cfg = nemotron_h_config_tiny(ssm_state_dtype=state)
+    params = make(cfg)
+    fam = family(cfg)
+    cache = fam.init_cache()
+    layers = sum(kind == "mamba" for kind, _ in layer_kinds(cfg))
+    assert isinstance(cache["ssm"], tuple) and len(cache["ssm"]) == layers > 1
+    row = jnp.arange(10, dtype=jnp.int32)
+    closed = jax.make_jaxpr(fam.decode_step)(
+        params, jnp.zeros((3,), jnp.int32), jnp.zeros((3,), jnp.int32),
+        jnp.stack([row, row + 10, row + 20]), cache,
+        jnp.asarray([True, False, True]))
+    shape = cache["ssm"][0].shape
+    leaves = [v for v in closed.jaxpr.invars
+              if v.aval.shape == shape and v.aval.dtype == jnp.dtype(state)]
+    assert len(leaves) == layers
+    for leaf in leaves:
+        assert sum(leaf in eqn.invars for eqn in closed.jaxpr.eqns) == 1
+    stacked = (layers,) + shape
+    assert not [eqn for eqn in closed.jaxpr.eqns
+                for v in eqn.outvars if v.aval.shape == stacked]
+    # the new state leaves the step in the leaf's own dtype and place
+    outs = [v for v in closed.jaxpr.outvars
+            if v.aval.shape == shape and v.aval.dtype == jnp.dtype(state)]
+    assert len(outs) == layers and not set(outs) & set(leaves)
+
+
+def test_the_update_probe_rehearses_on_the_cpu(tmp_path, capsys):
+    """`perf/ssm_update_probe.py --rehearse`: the chip probe's control flow
+    at a tiny shape (its Pallas calls in interpret mode); every row agrees
+    with the plain form, and a rehearsal reports no time."""
+    import importlib.util
+    import json
+    path = os.path.join(os.path.dirname(__file__), "..", "perf",
+                        "ssm_update_probe.py")
+    spec = importlib.util.spec_from_file_location("ssm_update_probe", path)
+    probe = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(probe)
+    assert probe.main(["--rehearse", "--tag", "t", "--out",
+                       str(tmp_path)]) == 0
+    rows = json.load(open(tmp_path / "ssm_update_probe.t.json"))["rows"]
+    assert [r["impl"] for r in rows] == [
+        "plain", "plain.stacked", "pallas.copy", "pallas.fused"]
+    for r in rows:
+        assert "error" not in r and "host_ms_per_call" not in r
+        if r["impl"] != "pallas.copy":
+            assert r["y_max_abs_diff_vs_plain"] < 1e-2
+            assert r["state_max_abs_diff_vs_plain"] < 1e-5
+    # without the flag a run that finds no TPU refuses
+    assert probe.main(["--out", str(tmp_path)]) == 2
 
 
 @pytest.fixture(scope="module")
@@ -136,8 +212,9 @@ def test_dense_prefill_logits_and_state_against_the_reference(tiny):
     assert max(ref.state_errors(list(state["ssm"]), [
         np.asarray(h) for h in out["states"]])) < 1e-5
     # the other slots' state was not touched
-    assert not np.asarray(cache["ssm"][:, 0]).any()
-    assert not np.asarray(cache["ssm"][:, 2]).any()
+    assert len(cache["ssm"]) == len(state["ssm"])     # one leaf a layer
+    for h in cache["ssm"]:
+        assert not np.asarray(h[0]).any() and not np.asarray(h[2]).any()
 
 
 @pytest.mark.parametrize("chunks", [(8, 8, 5), (16, 5), (3, 8, 8, 2)])
@@ -147,7 +224,7 @@ def test_chunked_prefill_carries_the_state(tiny, chunks):
     cache, pos = fam.init_cache(), 0
     run = jax.jit(fam.prefill_chunk)
     # the slot held another sequence before: position 0 must start from zero
-    cache["ssm"] = cache["ssm"] + 7.0
+    cache["ssm"] = tuple(h + 7.0 for h in cache["ssm"])
     cache["conv"] = cache["conv"] + 7.0
     for c in chunks:
         pad = -(-c // 8) * 8
