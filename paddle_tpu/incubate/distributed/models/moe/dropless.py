@@ -36,6 +36,14 @@ as a serving path calls it, on the chip) the product is
 `ops/pallas/grouped_matmul.py`, the same megablox algorithm with whole-K,
 wide-N weight tiles and a row tile that follows the rows a group has.  Both
 carry `TRACE_LABEL` in their instruction's text, so a trace finds either.
+
+What is NOT a grouped product is plain ``jax.numpy`` that XLA fuses, and a
+trace finds it by the region it was traced under (`profiler.device_span`):
+``moe.route``, ``moe.dispatch`` (the sort and the row gathers),
+``moe.experts`` (the products and what lies between them), ``moe.combine``,
+``moe.balance`` — one name each for training and serving, because the
+functions are one.  A model labels the rest of its expert layer
+``moe.layer`` / ``moe.shared``; the backward of each carries its name.
 """
 from __future__ import annotations
 
@@ -46,6 +54,7 @@ import jax.numpy as jnp
 
 from .....ops.pallas.grouped_matmul import (TRACE_LABEL, grouped_matmul,
                                             tiles as _kernel_tiles)
+from .....profiler import device_span
 
 __all__ = ["sigmoid_topk_route", "sort_pairs_by_held_expert",
            "grouped_swiglu", "grouped_relu2", "dropless_expert_ffn",
@@ -53,6 +62,7 @@ __all__ = ["sigmoid_topk_route", "sort_pairs_by_held_expert",
            "row_bounds", "row_tier", "TRACE_LABEL"]
 
 
+@device_span("moe.route")
 def sigmoid_topk_route(u, w_router, bias, top_k, route_scale=1.0,
                        route_norm=True, precision=None):
     """u [T, H], w_router [H, E_total], bias [E_total] -> (sel int32 [T, k],
@@ -80,6 +90,7 @@ def expert_load(sel, num_experts):
         .sum((0, 1), dtype=jnp.int32)
 
 
+@device_span("moe.balance")
 def balance_bias_update(bias, load, coeff):
     """The selection bias after a step, by the auxiliary-loss-free rule: an
     expert under the mean load gains ``coeff``, one over it loses ``coeff``,
@@ -90,6 +101,7 @@ def balance_bias_update(bias, load, coeff):
     return bias + (delta - delta.mean(-1, keepdims=True))
 
 
+@device_span("moe.dispatch")
 def sort_pairs_by_held_expert(sel, offset, held):
     """sel [T, k] (ids over all experts) -> (order, inverse, held_mask,
     rows).  ``order`` [T*k] lists the flat pairs sorted by held-expert id,
@@ -189,10 +201,11 @@ def grouped_swiglu(xs, we_gate, we_up, we_down, rows, *, kernel=False,
     product then takes `grouped_matmul` where the static shapes say a group
     has few rows, under :func:`grouped_relu2`'s rule."""
     kw = dict(role=role, interpret=interpret)
-    gate = _grouped_product(xs, we_gate, rows, kernel, **kw)
-    up = _grouped_product(xs, we_up, rows, kernel, **kw)
-    return _grouped_product(jax.nn.silu(gate) * up, we_down, rows, kernel,
-                            **kw)
+    with device_span("moe.experts"):
+        gate = _grouped_product(xs, we_gate, rows, kernel, **kw)
+        up = _grouped_product(xs, we_up, rows, kernel, **kw)
+        return _grouped_product(jax.nn.silu(gate) * up, we_down, rows,
+                                kernel, **kw)
 
 
 def grouped_relu2(xs, we_up, we_down, rows, *, kernel=False, role=None,
@@ -210,9 +223,10 @@ def grouped_relu2(xs, we_up, we_down, rows, *, kernel=False, role=None,
     1,024 rows an expert of a train step).  ``role`` labels the kernel's
     calls in a trace ("decode" | "prefill")."""
     kw = dict(role=role, interpret=interpret)
-    up = _grouped_product(xs, we_up, rows, kernel, **kw)
-    return _grouped_product(jnp.square(jax.nn.relu(up)), we_down, rows,
-                            kernel, **kw)
+    with device_span("moe.experts"):
+        up = _grouped_product(xs, we_up, rows, kernel, **kw)
+        return _grouped_product(jnp.square(jax.nn.relu(up)), we_down, rows,
+                                kernel, **kw)
 
 
 def _experts_within(bound, operands, sorting, expert=grouped_swiglu):
@@ -222,10 +236,12 @@ def _experts_within(bound, operands, sorting, expert=grouped_swiglu):
     grouped product they feed."""
     u, weights, *matrices = operands
     order, inverse, held_mask, rows = sorting
-    first = order[:bound]
-    xs = _dispatch(u, first, inverse, held_mask)
+    with device_span("moe.dispatch"):
+        first = order[:bound]
+        xs = _dispatch(u, first, inverse, held_mask)
     ys = expert(xs, *matrices, rows)
-    return _combine(ys, weights, first, inverse, held_mask)
+    with device_span("moe.combine"):
+        return _combine(ys, weights, first, inverse, held_mask)
 
 
 def row_tier(bounds, rows):
@@ -289,11 +305,14 @@ def dropless_expert_forward(u, sel, weights, matrices, offset, num_experts,
     sorting = sort_pairs_by_held_expert(sel, offset, matrices[0].shape[0])
     bounds = row_bounds(t, k, matrices[0].shape[0], num_experts)
     tier = row_tier(bounds, sorting[3])
-    out = jax.lax.switch(
-        tier,
-        [functools.partial(_experts_within, b, expert=expert)
-         for b in bounds],
-        (u, weights, *matrices), sorting)
+    # the `conditional` encloses the products: it takes THEIR name, so that
+    # a trace's glue (`moe.layer` ...) is not the whole layer's interval
+    with device_span("moe.experts"):
+        out = jax.lax.switch(
+            tier,
+            [functools.partial(_experts_within, b, expert=expert)
+             for b in bounds],
+            (u, weights, *matrices), sorting)
     beyond = jnp.maximum(
         sorting[3].sum() - jnp.asarray(bounds, jnp.int32)[tier], 0)
     return out, sorting[3], beyond
@@ -318,6 +337,9 @@ def dropless_expert_ffn(u, sel, weights, we_gate, we_up, we_down, offset,
     held = we_gate.shape[0]
     sorting = sort_pairs_by_held_expert(sel, offset, held)
     bounds = row_bounds(t, k, held, num_experts)
-    out = _experts_tiered(bounds, (u, weights, we_gate, we_up, we_down),
-                          sorting)
+    # the forward's and the backward's `conditional` take the products'
+    # name, as in `dropless_expert_forward`
+    with device_span("moe.experts"):
+        out = _experts_tiered(
+            bounds, (u, weights, we_gate, we_up, we_down), sorting)
     return out, sorting[3]

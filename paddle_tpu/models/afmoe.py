@@ -56,6 +56,7 @@ import jax.numpy as jnp
 from ..incubate.distributed.models.moe.dropless import (dropless_expert_ffn,
                                                         expert_load,
                                                         sigmoid_topk_route)
+from ..profiler import device_span
 
 __all__ = ["AfmoeConfig", "afmoe_config_tiny", "build_functional_afmoe",
            "layer_params", "is_buffer"]
@@ -252,6 +253,7 @@ def build_functional_afmoe(config: AfmoeConfig, key=None, dtype=None,
     def swiglu(u, wgate, wup, wdown):
         return (jax.nn.silu(u @ wgate) * (u @ wup)) @ wdown
 
+    @device_span("embed")
     def embed_apply(p, batch):
         ids, _ = batch
         x = p["tok"][ids]
@@ -263,19 +265,25 @@ def build_functional_afmoe(config: AfmoeConfig, key=None, dtype=None,
         """x [B, S, H] -> (y [B, S, H], routed: None or rows, sel, ...)."""
         B, S, _ = x.shape
         sliding = kinds[layer] == "sliding_attention"
-        h = rms(x, lp["ln_in"])
-        q = rms((h @ lp["wq"]).reshape(B, S, -1, D), lp["ln_q"])
-        k = rms((h @ lp["wk"]).reshape(B, S, -1, D), lp["ln_k"])
-        v = (h @ lp["wv"]).reshape(B, S, -1, D)
-        if sliding:
-            q, k = _rope(q, c.rope_theta), _rope(k, c.rope_theta)
-        a = attention(q, k, v, c.sliding_window if sliding else None)
-        a = a.reshape(B, S, q_dim) * jax.nn.sigmoid(h @ lp["wg"])
-        x = x + rms(a @ lp["wo"], lp["ln_post_attn"])
-        u = rms(x, lp["ln_pre_mlp"])
+        with device_span("block.attn"):
+            h = rms(x, lp["ln_in"])
+            q = rms((h @ lp["wq"]).reshape(B, S, -1, D), lp["ln_q"])
+            k = rms((h @ lp["wk"]).reshape(B, S, -1, D), lp["ln_k"])
+            v = (h @ lp["wv"]).reshape(B, S, -1, D)
+            if sliding:
+                q, k = _rope(q, c.rope_theta), _rope(k, c.rope_theta)
+            a = attention(q, k, v, c.sliding_window if sliding else None)
+            a = a.reshape(B, S, q_dim) * jax.nn.sigmoid(h @ lp["wg"])
+            x = x + rms(a @ lp["wo"], lp["ln_post_attn"])
         if "router" not in lp:
-            mlp, routed = swiglu(u, lp["wgate"], lp["wup"], lp["wdown"]), None
-        else:
+            with device_span("block.mlp"):
+                mlp = swiglu(rms(x, lp["ln_pre_mlp"]), lp["wgate"],
+                             lp["wup"], lp["wdown"])
+                return x + rms(mlp, lp["ln_post_mlp"]), None
+        # the expert half: its norms, the residual and what no `moe.*`
+        # function of `dropless.py` owns are the layer's glue
+        with device_span("moe.layer"):
+            u = rms(x, lp["ln_pre_mlp"])
             uf = u.reshape(B * S, H)
             sel, w = sigmoid_topk_route(
                 uf, lp["router"], lp["router_bias"], c.num_experts_per_tok,
@@ -283,13 +291,15 @@ def build_functional_afmoe(config: AfmoeConfig, key=None, dtype=None,
             part, rows = dropless_expert_ffn(
                 uf, sel, w, lp["we_gate"], lp["we_up"], lp["we_down"],
                 offset, c.num_experts)
-            mlp = swiglu(u, lp["ws_gate"], lp["ws_up"], lp["ws_down"]) \
-                + part.reshape(B, S, H)
+            with device_span("moe.shared"):
+                shared = swiglu(u, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
+            mlp = shared + part.reshape(B, S, H)
             load = expert_load(sel, c.num_experts)
             routed = {"rows": rows, "sel": sel, "load": load,
                       "held_pairs": load[offset:offset + held].sum()}
-        return x + rms(mlp, lp["ln_post_mlp"]), routed
+            return x + rms(mlp, lp["ln_post_mlp"]), routed
 
+    @device_span("head_loss")
     def head_loss_apply(p, y, batch):
         """y [1, B, S, H] -> mean token NLL over the vocabulary held."""
         _, labels = batch

@@ -24,6 +24,7 @@ from ..nn import Linear, Embedding, RMSNorm, LayerList
 from ..nn import functional as F
 from ..tensor import manipulation as manip
 from ..incubate.nn.functional import fused_rotary_position_embedding
+from ..profiler import device_span
 
 __all__ = ["LlamaConfig", "LlamaForCausalLM", "LlamaModel", "LlamaDecoderLayer",
            "build_functional_llama", "llama_microbatch_fns", "llama_block_specs",
@@ -434,6 +435,7 @@ def build_functional_llama(config: LlamaConfig, key=None, dtype=None,
             return impl(x, w, epsilon=eps)
         return rms_norm_ref(x, w, eps)
 
+    @device_span("embed")
     def embed_apply(p, batch):
         ids, labels = batch
         # [B, S] -> [n_micro, mbs, S, H]
@@ -457,35 +459,38 @@ def build_functional_llama(config: LlamaConfig, key=None, dtype=None,
         B, S, H = x.shape
         nh_l = lp["wq"].shape[-1] // head_dim
         nkv_l = lp["wk"].shape[-1] // head_dim
-        h = rms(x, lp["ln1"])
-        q = (h @ lp["wq"]).reshape(B, S, nh_l, head_dim)
-        k = (h @ lp["wk"]).reshape(B, S, nkv_l, head_dim)
-        v = (h @ lp["wv"]).reshape(B, S, nkv_l, head_dim)
-        sin, cos = sin_t[:S], cos_t[:S]
-        q = _apply_rope(q, sin, cos)
-        k = _apply_rope(k, sin, cos)
-        from ..core.dispatch import get_kernel
-        attn_impl = get_kernel("flash_attention_causal")
-        # GQA: the Pallas kernel indexes KV heads natively; only the jnp
-        # fallback up-materializes (reference flash_attn GQA path)
-        o = attn_impl(q, k, v) if attn_impl is not None else None
-        if o is None:
-            if nh_l != nkv_l:
-                rep = nh_l // nkv_l
-                k = jnp.repeat(k, rep, axis=2)
-                v = jnp.repeat(v, rep, axis=2)
-            logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(head_dim)
-            mask = jnp.tril(jnp.ones((S, S), bool))
-            logits = jnp.where(mask, logits.astype(jnp.float32), -jnp.inf)
-            w = jax.nn.softmax(logits, -1).astype(x.dtype)
-            o = jnp.einsum("bhqk,bkhd->bqhd", w, v)
-        o = _mp_reduce(o.reshape(B, S, nh_l * head_dim) @ lp["wo"])
-        x = x + o
-        h = rms(x, lp["ln2"])
-        if moe:
-            return x + _moe_ffn_block(lp, h, B, S)
-        ff = jax.nn.silu(h @ lp["wgate"]) * (h @ lp["wup"])
-        return x + _mp_reduce(ff @ lp["wdown"])
+        with device_span("block.attn"):
+            h = rms(x, lp["ln1"])
+            q = (h @ lp["wq"]).reshape(B, S, nh_l, head_dim)
+            k = (h @ lp["wk"]).reshape(B, S, nkv_l, head_dim)
+            v = (h @ lp["wv"]).reshape(B, S, nkv_l, head_dim)
+            sin, cos = sin_t[:S], cos_t[:S]
+            q = _apply_rope(q, sin, cos)
+            k = _apply_rope(k, sin, cos)
+            from ..core.dispatch import get_kernel
+            attn_impl = get_kernel("flash_attention_causal")
+            # GQA: the Pallas kernel indexes KV heads natively; only the jnp
+            # fallback up-materializes (reference flash_attn GQA path)
+            o = attn_impl(q, k, v) if attn_impl is not None else None
+            if o is None:
+                if nh_l != nkv_l:
+                    rep = nh_l // nkv_l
+                    k = jnp.repeat(k, rep, axis=2)
+                    v = jnp.repeat(v, rep, axis=2)
+                logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) \
+                    / math.sqrt(head_dim)
+                mask = jnp.tril(jnp.ones((S, S), bool))
+                logits = jnp.where(mask, logits.astype(jnp.float32), -jnp.inf)
+                w = jax.nn.softmax(logits, -1).astype(x.dtype)
+                o = jnp.einsum("bhqk,bkhd->bqhd", w, v)
+            o = _mp_reduce(o.reshape(B, S, nh_l * head_dim) @ lp["wo"])
+            x = x + o
+        with device_span("block.mlp"):
+            h = rms(x, lp["ln2"])
+            if moe:
+                return x + _moe_ffn_block(lp, h, B, S)
+            ff = jax.nn.silu(h @ lp["wgate"]) * (h @ lp["wup"])
+            return x + _mp_reduce(ff @ lp["wdown"])
 
     def _moe_ffn_block(lp, h, B, S):
         """Sparse SwiGLU FFN over the expert-stacked leaves. Under shard_map
@@ -519,6 +524,7 @@ def build_functional_llama(config: LlamaConfig, key=None, dtype=None,
         out = moe_combine(y, combine)
         return out.reshape(B, S, -1).astype(h.dtype)
 
+    @device_span("head_loss")
     def head_loss_apply(p, y, batch):
         # y: [n_micro, mbs, S, H]
         ids, labels = batch
@@ -1035,6 +1041,7 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
         rot = jnp.concatenate([-x2, x1], axis=-1)
         return x * cos_p[..., None, :] + rot * sin_p[..., None, :]
 
+    @device_span("head")
     def _head(hp, h_last):
         h = rms_norm_ref(h_last, hp["ln_f"], c.rms_norm_eps)
         return (h @ hp["lm"]).astype(jnp.float32)
@@ -1063,22 +1070,27 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
             # each rank holds nh/tp q heads and nkv/tp kv heads
             nh_l = lp["wq"].shape[-1] // head_dim
             nkv_l = lp["wk"].shape[-1] // head_dim
-            h = rms_norm_ref(xc, lp["ln1"], c.rms_norm_eps)
-            q = (h @ lp["wq"]).reshape(*tok, nh_l, head_dim)
-            k = (h @ lp["wk"]).reshape(*tok, nkv_l, head_dim)
-            v = (h @ lp["wv"]).reshape(*tok, nkv_l, head_dim)
-            q = _rope_at(q, sin, cos)
-            k = _rope_at(k, sin, cos)
-            pk, k_loc = _scatter(pk, li, k, write)
-            pv, v_loc = _scatter(pv, li, v, write)
-            o = _gather_heads(attend(q, k_loc, v_loc, pk, pv, li))
-            xc = xc + o.reshape(*tok, nh * head_dim) @ lp["wo"]
-            h = rms_norm_ref(xc, lp["ln2"], c.rms_norm_eps)
-            ff = jax.nn.silu(h @ lp["wgate"]) * (h @ lp["wup"])
-            return (xc + _mp_reduce(ff @ lp["wdown"]), pk, pv), None
+            with device_span("attn.proj"):
+                h = rms_norm_ref(xc, lp["ln1"], c.rms_norm_eps)
+                q = (h @ lp["wq"]).reshape(*tok, nh_l, head_dim)
+                k = (h @ lp["wk"]).reshape(*tok, nkv_l, head_dim)
+                v = (h @ lp["wv"]).reshape(*tok, nkv_l, head_dim)
+                q = _rope_at(q, sin, cos)
+                k = _rope_at(k, sin, cos)
+                pk, k_loc = _scatter(pk, li, k, write)
+                pv, v_loc = _scatter(pv, li, v, write)
+                o = _gather_heads(attend(q, k_loc, v_loc, pk, pv, li))
+                xc = xc + o.reshape(*tok, nh * head_dim) @ lp["wo"]
+            with device_span("block.mlp"):
+                h = rms_norm_ref(xc, lp["ln2"], c.rms_norm_eps)
+                ff = jax.nn.silu(h @ lp["wgate"]) * (h @ lp["wup"])
+                return (xc + _mp_reduce(ff @ lp["wdown"]), pk, pv), None
 
-        (x, pages_k, pages_v), _ = jax.lax.scan(
-            body, (x, cache["k"], cache["v"]), (bp, jnp.arange(L)))
+        # the loop's own work — a layer's weights sliced out of the stacks —
+        # is `block.scan`; what the body does takes the body's names
+        with device_span("block.scan"):
+            (x, pages_k, pages_v), _ = jax.lax.scan(
+                body, (x, cache["k"], cache["v"]), (bp, jnp.arange(L)))
         return x, {"k": pages_k, "v": pages_v}
 
     def prefill(params, ids, true_len, page_row, slot, cache):  # graftlint: jit
@@ -1137,7 +1149,9 @@ def build_llama_paged_decode(config: LlamaConfig, page_size: int = 16,
         # token, so a greedy request's FINAL chunk needs no separate
         # sample executable — the engine consumes this token directly and
         # the logits feed only sampled-temperature lanes
-        return logits, jnp.argmax(logits).astype(jnp.int32), cache
+        with device_span("head"):
+            tok = jnp.argmax(logits).astype(jnp.int32)
+        return logits, tok, cache
 
     def decode_step(params, toks, lengths, page_tables, cache,
                     active):                          # graftlint: jit
@@ -1304,15 +1318,16 @@ def make_paged_decode_horizon(decode_step, sample_fn=None):
             live = ~done
             logits, cache = decode_step(params, toks, lengths, page_tables,
                                         cache, live)
-            if greedy:
-                # static fast path when every running request decodes
-                # greedily (the common serving default): skips the
-                # sort/cumsum of the nucleus mask — the same shortcut
-                # _sample_token takes for temperature == 0.0
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            else:
-                key, sub = jax.random.split(key)
-                tok = sample_fn(logits, sub, temps, top_ps)
+            with device_span("head"):
+                if greedy:
+                    # static fast path when every running request decodes
+                    # greedily (the common serving default): skips the
+                    # sort/cumsum of the nucleus mask — the same shortcut
+                    # _sample_token takes for temperature == 0.0
+                    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                else:
+                    key, sub = jax.random.split(key)
+                    tok = sample_fn(logits, sub, temps, top_ps)
             tok = jnp.where(done, eos_ids, tok)
             out = out.at[:, t].set(tok)
             lengths = lengths + live.astype(lengths.dtype)

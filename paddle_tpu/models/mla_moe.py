@@ -65,6 +65,7 @@ import numpy as np
 
 from ..incubate.distributed.models.moe.dropless import (
     dropless_expert_forward, grouped_swiglu, sigmoid_topk_route)
+from ..profiler import device_span
 from .llama import scatter_kv_rows, scatter_kv_run
 from .paged_family import PagedFamily, log_selections_run
 
@@ -297,16 +298,18 @@ def moe_layer(config: MlaMoeConfig, lp, g, valid, expert=grouped_swiglu):
     ``n_routed_experts`` where the token is not valid)."""
     c = config
     offset, _ = c.held()
-    sel, w = sigmoid_topk_route(
-        g.astype(jnp.float32), lp["router"], lp["router_bias"],
-        c.num_experts_per_tok, c.routed_scaling_factor, c.norm_topk_prob,
-        precision=jax.lax.Precision.HIGHEST)
-    sel = jnp.where(valid[:, None], sel, c.n_routed_experts)
-    part, rows, beyond = dropless_expert_forward(
-        g, sel, w, (lp["we_gate"], lp["we_up"], lp["we_down"]), offset,
-        c.n_routed_experts, expert=expert)
-    shared = _swiglu(g, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
-    return part + shared, rows, beyond, sel
+    with device_span("moe.layer"):
+        sel, w = sigmoid_topk_route(
+            g.astype(jnp.float32), lp["router"], lp["router_bias"],
+            c.num_experts_per_tok, c.routed_scaling_factor, c.norm_topk_prob,
+            precision=jax.lax.Precision.HIGHEST)
+        sel = jnp.where(valid[:, None], sel, c.n_routed_experts)
+        part, rows, beyond = dropless_expert_forward(
+            g, sel, w, (lp["we_gate"], lp["we_up"], lp["we_down"]), offset,
+            c.n_routed_experts, expert=expert)
+        with device_span("moe.shared"):
+            shared = _swiglu(g, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
+        return part + shared, rows, beyond, sel
 
 
 def build_mla_moe_paged(config: MlaMoeConfig, page_size: int = 16,
@@ -432,10 +435,17 @@ def build_mla_moe_paged(config: MlaMoeConfig, page_size: int = 16,
         ctr = {**ctr, "moe_dropped": ctr["moe_dropped"] + beyond}
         return out, ctr, jnp.pad(sel, ((0, 0), (0, log_k - top_k)))
 
+    def _mlp_span(li):
+        """The region of layer ``li``'s MLP half: a dense MLP or the expert
+        layer's glue (what `moe.route` ... `moe.shared` do not own)."""
+        # a python int  # graftlint: disable=TRACE001
+        return "block.mlp" if li < nd else "moe.layer"
+
     def _normed(x, w):
         """The float32 residual stream, normed, in the compute dtype."""
         return _rms(x, w, eps).astype(d)
 
+    @device_span("head")
     def _head(hp, h_last):
         """-> (logits float32, the greedy token's log-probability)."""
         logits = jnp.dot(_normed(h_last, hp["ln_f"]), hp["lm"],
@@ -466,20 +476,22 @@ def build_mla_moe_paged(config: MlaMoeConfig, page_size: int = 16,
             PREFILL, length * start + length * (length + 1) // 2))
         for li in range(L):
             lp = {leaf: per[li] for leaf, per in bp["attn"].items()}
-            q, row = mla_project(c, lp, _normed(x, lp["norm"]), pos)
-            cache["latent"] = scatter_kv_run(cache["latent"], li,
-                                             _stored(row), start, length,
-                                             page_row)
-            o = _attend(q.reshape(nseg, seg, nh, -1), cache["latent"], li,
-                        tables, seg_start, seg_len, seg_start + seg_len,
-                        role="chunk")
-            x = x + _attn_out(lp, o.reshape(C, nh, dl)).astype(f32)
-            out, ctr, sel = _mlp(bp, li, _normed(x, lp["post_norm"]), real,
-                                 ctr, PREFILL)
-            if sel is not None:
-                cache["sel"] = log_selections_run(cache["sel"], li - nd,
-                                                  slot, sel, start)
-            x = x + out.astype(f32)
+            with device_span("mla.project"):
+                q, row = mla_project(c, lp, _normed(x, lp["norm"]), pos)
+                cache["latent"] = scatter_kv_run(cache["latent"], li,
+                                                 _stored(row), start, length,
+                                                 page_row)
+                o = _attend(q.reshape(nseg, seg, nh, -1), cache["latent"],
+                            li, tables, seg_start, seg_len,
+                            seg_start + seg_len, role="chunk")
+                x = x + _attn_out(lp, o.reshape(C, nh, dl)).astype(f32)
+            with device_span(_mlp_span(li)):
+                out, ctr, sel = _mlp(bp, li, _normed(x, lp["post_norm"]),
+                                     real, ctr, PREFILL)
+                if sel is not None:
+                    cache["sel"] = log_selections_run(cache["sel"], li - nd,
+                                                      slot, sel, start)
+                x = x + out.astype(f32)
         cache["ctr"] = ctr
         h_last = jax.lax.dynamic_index_in_dim(x, length - 1, 0,
                                               keepdims=False)
@@ -496,7 +508,9 @@ def build_mla_moe_paged(config: MlaMoeConfig, page_size: int = 16,
                       cache):                         # graftlint: jit
         logits, cache = _run(params, ids, start, chunk_len, page_row, slot,
                              cache)
-        return logits, jnp.argmax(logits).astype(jnp.int32), cache
+        with device_span("head"):
+            tok = jnp.argmax(logits).astype(jnp.int32)
+        return logits, tok, cache
 
     def decode_step(params, toks, lengths, page_tables, cache,
                     active):                          # graftlint: jit
@@ -516,18 +530,20 @@ def build_mla_moe_paged(config: MlaMoeConfig, page_size: int = 16,
         slots = jnp.arange(toks.shape[0])
         for li in range(L):
             lp = {leaf: per[li] for leaf, per in bp["attn"].items()}
-            q, row = mla_project(c, lp, _normed(x, lp["norm"]), pos)
-            cache["latent"] = scatter_kv_rows(cache["latent"], li,
-                                              _stored(row), page, off)
-            o = _attend(q[:, None], cache["latent"], li, page_tables, pos,
-                        n_q, eff_len, role="decode")[:, 0]
-            x = x + _attn_out(lp, o).astype(f32)
-            out, ctr, sel = _mlp(bp, li, _normed(x, lp["post_norm"]),
-                                 active, ctr, DECODE)
-            if sel is not None:
-                cache["sel"] = cache["sel"].at[
-                    li - nd, slots, :, log_pos].set(sel, mode="drop")
-            x = x + out.astype(f32)
+            with device_span("mla.project"):
+                q, row = mla_project(c, lp, _normed(x, lp["norm"]), pos)
+                cache["latent"] = scatter_kv_rows(cache["latent"], li,
+                                                  _stored(row), page, off)
+                o = _attend(q[:, None], cache["latent"], li, page_tables,
+                            pos, n_q, eff_len, role="decode")[:, 0]
+                x = x + _attn_out(lp, o).astype(f32)
+            with device_span(_mlp_span(li)):
+                out, ctr, sel = _mlp(bp, li, _normed(x, lp["post_norm"]),
+                                     active, ctr, DECODE)
+                if sel is not None:
+                    cache["sel"] = cache["sel"].at[
+                        li - nd, slots, :, log_pos].set(sel, mode="drop")
+                x = x + out.astype(f32)
         cache["ctr"] = ctr
         logits, logp = _head(hp, x)
         cache["logp"] = cache["logp"].at[slots, log_pos].set(logp,

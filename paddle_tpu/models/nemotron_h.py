@@ -61,6 +61,7 @@ from ..incubate.distributed.models.moe.dropless import (
     dropless_expert_forward, grouped_relu2, row_bounds, row_tier,
     sigmoid_topk_route)
 from ..ops.ssm import ssd_chunked_scan, ssm_decode_update
+from ..profiler import device_span
 from .llama import scatter_kv_rows, scatter_kv_run
 from .paged_family import PagedFamily, log_selections_run
 
@@ -69,6 +70,9 @@ __all__ = ["NemotronHConfig", "nemotron_h_config_tiny",
            "latent_moe", "layer_kinds"]
 
 KINDS = {"M": "mamba", "*": "attn", "E": "moe"}
+# the region (`profiler.device_span`) a layer of each kind runs under; what
+# an inner function owns (`ssm.*`, `moe.route` ...) takes its own name
+SPANS = {"mamba": "mamba.proj", "attn": "attn.proj", "moe": "moe.layer"}
 DECODE, PREFILL = 0, 1          # the two halves of every per-phase counter
 CARRY = 1 << 20                 # a counter's low word carries over at this
 
@@ -301,17 +305,19 @@ def latent_moe(config: NemotronHConfig, lp, x, valid, expert=grouped_relu2):
     ALL experts, ``n_routed_experts`` where the token is not valid)."""
     c = config
     offset, _ = c.held()
-    u = _rms(x, lp["norm"], c.layer_norm_epsilon)
-    sel, w = sigmoid_topk_route(
-        u.astype(jnp.float32), lp["router"], lp["router_bias"],
-        c.num_experts_per_tok, c.routed_scaling_factor, True,
-        precision=jax.lax.Precision.HIGHEST)
-    sel = jnp.where(valid[:, None], sel, c.n_routed_experts)
-    part, rows, beyond = dropless_expert_forward(
-        u @ lp["w_lat_in"], sel, w, (lp["we_up"], lp["we_down"]), offset,
-        c.n_routed_experts, expert=expert)
-    shared = _relu2(u @ lp["ws_up"]) @ lp["ws_down"]
-    return part @ lp["w_lat_out"] + shared, rows, beyond, sel
+    with device_span("moe.layer"):
+        u = _rms(x, lp["norm"], c.layer_norm_epsilon)
+        sel, w = sigmoid_topk_route(
+            u.astype(jnp.float32), lp["router"], lp["router_bias"],
+            c.num_experts_per_tok, c.routed_scaling_factor, True,
+            precision=jax.lax.Precision.HIGHEST)
+        sel = jnp.where(valid[:, None], sel, c.n_routed_experts)
+        part, rows, beyond = dropless_expert_forward(
+            u @ lp["w_lat_in"], sel, w, (lp["we_up"], lp["we_down"]), offset,
+            c.n_routed_experts, expert=expert)
+        with device_span("moe.shared"):
+            shared = _relu2(u @ lp["ws_up"]) @ lp["ws_down"]
+        return part @ lp["w_lat_out"] + shared, rows, beyond, sel
 
 
 def build_nemotron_h_paged(config: NemotronHConfig, page_size: int = 16,
@@ -471,6 +477,7 @@ def build_nemotron_h_paged(config: NemotronHConfig, page_size: int = 16,
     def _layer_params(bp, kind, j):
         return {leaf: per_layer[j] for leaf, per_layer in bp[kind].items()}
 
+    @device_span("head")
     def _head(hp, h_last):
         return (_rms(h_last, hp["ln_f"], eps) @ hp["lm"]).astype(f32)
 
@@ -495,41 +502,44 @@ def build_nemotron_h_paged(config: NemotronHConfig, page_size: int = 16,
         ctr = _count(cache["ctr"], "ssm_resets", fresh.astype(jnp.int32))
         for kind, j in kinds:
             lp = _layer_params(bp, kind, j)
-            if kind == "mamba":
-                z, xbc, dt = _mamba_in(lp, x)
-                tail = jnp.where(fresh, 0, cache["conv"][j, slot])
-                window = jnp.concatenate([tail.astype(d), xbc])
-                # the last K-1 REAL inputs: the next run's (or decode's) tail
-                cache["conv"] = cache["conv"].at[j, slot].set(
-                    jax.lax.dynamic_slice_in_dim(window, length, K - 1))
-                xbc = jax.nn.silu(
-                    sum(window[i:i + C] * lp["conv_w"][i] for i in range(K))
-                    + lp["conv_b"])
-                xs, b, cc = _split(xbc)
-                dt = jnp.where(real[:, None], jax.nn.softplus(
-                    dt.astype(f32) + lp["dt_bias"]), 0.0)
-                h0 = jnp.where(fresh, 0, ssm[j][slot].astype(f32))
-                y, h = ssd_chunked_scan(xs, dt, -jnp.exp(lp["A_log"]), b, cc,
-                                        h0, chunk=c.chunk_size)
-                ssm[j] = ssm[j].at[slot].set(h.astype(state_dt))
-                x = x + _mamba_out(lp, y, xs, z)
-            elif kind == "attn":
-                u = _rms(x, lp["norm"], eps)
-                q = (u @ lp["wq"]).reshape(C, nh, D)
-                k = (u @ lp["wk"]).reshape(C, nkv, D)
-                v = (u @ lp["wv"]).reshape(C, nkv, D)
-                cache["k"] = scatter_kv_run(cache["k"], j, k, start, length,
-                                            page_row)
-                cache["v"] = scatter_kv_run(cache["v"], j, v, start, length,
-                                            page_row)
-                o = _attend(q.reshape(nseg, seg, nh, D), cache, j, tables,
-                            seg_start, seg_len, seg_start + seg_len, "chunk")
-                x = x + o.reshape(C, nh * D) @ lp["wo"]
-            else:
-                out, ctr, sel = _moe(lp, x, real, ctr, PREFILL)
-                cache["sel"] = log_selections_run(cache["sel"], j, slot,
-                                                  sel, start)
-                x = x + out
+            with device_span(SPANS[kind]):
+                if kind == "mamba":
+                    z, xbc, dt = _mamba_in(lp, x)
+                    tail = jnp.where(fresh, 0, cache["conv"][j, slot])
+                    window = jnp.concatenate([tail.astype(d), xbc])
+                    # the last K-1 REAL inputs: the next run's (or
+                    # decode's) tail
+                    cache["conv"] = cache["conv"].at[j, slot].set(
+                        jax.lax.dynamic_slice_in_dim(window, length, K - 1))
+                    xbc = jax.nn.silu(
+                        sum(window[i:i + C] * lp["conv_w"][i]
+                            for i in range(K)) + lp["conv_b"])
+                    xs, b, cc = _split(xbc)
+                    dt = jnp.where(real[:, None], jax.nn.softplus(
+                        dt.astype(f32) + lp["dt_bias"]), 0.0)
+                    h0 = jnp.where(fresh, 0, ssm[j][slot].astype(f32))
+                    y, h = ssd_chunked_scan(xs, dt, -jnp.exp(lp["A_log"]), b,
+                                            cc, h0, chunk=c.chunk_size)
+                    ssm[j] = ssm[j].at[slot].set(h.astype(state_dt))
+                    x = x + _mamba_out(lp, y, xs, z)
+                elif kind == "attn":
+                    u = _rms(x, lp["norm"], eps)
+                    q = (u @ lp["wq"]).reshape(C, nh, D)
+                    k = (u @ lp["wk"]).reshape(C, nkv, D)
+                    v = (u @ lp["wv"]).reshape(C, nkv, D)
+                    cache["k"] = scatter_kv_run(cache["k"], j, k, start,
+                                                length, page_row)
+                    cache["v"] = scatter_kv_run(cache["v"], j, v, start,
+                                                length, page_row)
+                    o = _attend(q.reshape(nseg, seg, nh, D), cache, j, tables,
+                                seg_start, seg_len, seg_start + seg_len,
+                                "chunk")
+                    x = x + o.reshape(C, nh * D) @ lp["wo"]
+                else:
+                    out, ctr, sel = _moe(lp, x, real, ctr, PREFILL)
+                    cache["sel"] = log_selections_run(cache["sel"], j, slot,
+                                                      sel, start)
+                    x = x + out
         cache["ssm"], cache["ctr"] = tuple(ssm), ctr
         h_last = jax.lax.dynamic_index_in_dim(x, length - 1, 0,
                                               keepdims=False)
@@ -543,7 +553,9 @@ def build_nemotron_h_paged(config: NemotronHConfig, page_size: int = 16,
                       cache):                         # graftlint: jit
         logits, cache = _run(params, ids, start, chunk_len, page_row, slot,
                              cache)
-        return logits, jnp.argmax(logits).astype(jnp.int32), cache
+        with device_span("head"):
+            tok = jnp.argmax(logits).astype(jnp.int32)
+        return logits, tok, cache
 
     def decode_step(params, toks, lengths, page_tables, cache,
                     active):                          # graftlint: jit
@@ -564,38 +576,39 @@ def build_nemotron_h_paged(config: NemotronHConfig, page_size: int = 16,
         slots = jnp.arange(toks.shape[0])
         for kind, j in kinds:
             lp = _layer_params(bp, kind, j)
-            if kind == "mamba":
-                z, xbc, dt = _mamba_in(lp, x)
-                tail = cache["conv"][j]               # [S, K-1, conv]
-                window = jnp.concatenate([tail, xbc[:, None].astype(
-                    tail.dtype)], axis=1)
-                cache["conv"] = cache["conv"].at[j].set(jnp.where(
-                    active[:, None, None], window[:, 1:], tail))
-                xbc = jax.nn.silu(
-                    (window.astype(d) * lp["conv_w"][None]).sum(1)
-                    + lp["conv_b"])
-                xs, b, cc = _split(xbc)
-                dt = jnp.where(active[:, None], jax.nn.softplus(
-                    dt.astype(f32) + lp["dt_bias"]), 0.0)
-                y, ssm[j] = ssm_decode_update(ssm[j], xs, dt,
-                                              -jnp.exp(lp["A_log"]), b, cc)
-                x = x + _mamba_out(lp, y, xs, z)
-            elif kind == "attn":
-                u = _rms(x, lp["norm"], eps)
-                S = x.shape[0]
-                q = (u @ lp["wq"]).reshape(S, nh, D)
-                k = (u @ lp["wk"]).reshape(S, nkv, D)
-                v = (u @ lp["wv"]).reshape(S, nkv, D)
-                cache["k"] = scatter_kv_rows(cache["k"], j, k, page, off)
-                cache["v"] = scatter_kv_rows(cache["v"], j, v, page, off)
-                o = _attend(q[:, None], cache, j, page_tables, pos, n_q,
-                            eff_len, "decode")[:, 0]
-                x = x + o.reshape(S, nh * D) @ lp["wo"]
-            else:
-                out, ctr, sel = _moe(lp, x, active, ctr, DECODE)
-                cache["sel"] = cache["sel"].at[j, slots, :, log_pos].set(
-                    sel, mode="drop")
-                x = x + out
+            with device_span(SPANS[kind]):
+                if kind == "mamba":
+                    z, xbc, dt = _mamba_in(lp, x)
+                    tail = cache["conv"][j]               # [S, K-1, conv]
+                    window = jnp.concatenate([tail, xbc[:, None].astype(
+                        tail.dtype)], axis=1)
+                    cache["conv"] = cache["conv"].at[j].set(jnp.where(
+                        active[:, None, None], window[:, 1:], tail))
+                    xbc = jax.nn.silu(
+                        (window.astype(d) * lp["conv_w"][None]).sum(1)
+                        + lp["conv_b"])
+                    xs, b, cc = _split(xbc)
+                    dt = jnp.where(active[:, None], jax.nn.softplus(
+                        dt.astype(f32) + lp["dt_bias"]), 0.0)
+                    y, ssm[j] = ssm_decode_update(ssm[j], xs, dt,
+                                                  -jnp.exp(lp["A_log"]), b, cc)
+                    x = x + _mamba_out(lp, y, xs, z)
+                elif kind == "attn":
+                    u = _rms(x, lp["norm"], eps)
+                    S = x.shape[0]
+                    q = (u @ lp["wq"]).reshape(S, nh, D)
+                    k = (u @ lp["wk"]).reshape(S, nkv, D)
+                    v = (u @ lp["wv"]).reshape(S, nkv, D)
+                    cache["k"] = scatter_kv_rows(cache["k"], j, k, page, off)
+                    cache["v"] = scatter_kv_rows(cache["v"], j, v, page, off)
+                    o = _attend(q[:, None], cache, j, page_tables, pos, n_q,
+                                eff_len, "decode")[:, 0]
+                    x = x + o.reshape(S, nh * D) @ lp["wo"]
+                else:
+                    out, ctr, sel = _moe(lp, x, active, ctr, DECODE)
+                    cache["sel"] = cache["sel"].at[j, slots, :, log_pos].set(
+                        sel, mode="drop")
+                    x = x + out
         cache["ssm"], cache["ctr"] = tuple(ssm), ctr
         return _head(hp, x), cache
 

@@ -646,11 +646,11 @@ class Telemetry:
             device is PROVABLY the bottleneck.
 
         With ``window_s`` (the measured wall clock), ``gap_s`` is the
-        unaccounted remainder (inter-step host work, the caller's bookkeeping)
-        and ``device_idle_frac_est`` = (host_busy + gap) / window — the
-        fraction of the window the device provably had nothing dispatched
-        to run, i.e. the headroom a double-buffered host loop (ROADMAP
-        item 5) could reclaim."""
+        unaccounted remainder (inter-step host work, the caller's
+        bookkeeping).  How long the DEVICE idled is not estimated from
+        these host buckets: a device trace says it (the busy union of
+        `benchmark/trace_reduce.py`, the idle seconds under each
+        ``serve.<phase>`` span of `benchmark/host_spans.py`)."""
         host = disp = wait = 0.0
         per_phase = {}
         for name in sorted(self._phase_h):
@@ -677,7 +677,6 @@ class Telemetry:
             rep["dispatch_frac"] = round(disp / window_s, 4)
             rep["device_wait_frac"] = round(wait / window_s, 4)
             rep["gap_frac"] = round(gap / window_s, 4)
-            rep["device_idle_frac_est"] = round((host + gap) / window_s, 4)
         return rep
 
     def snapshot(self, engine_stats: dict | None = None) -> dict:

@@ -13,16 +13,23 @@ products a head (what the matrix unit is for), BETWEEN chunks only the state
 passes on, in a loop of T / chunk steps.  `ssm_decode_update` is the decode
 form: one token a slot, the state read and written once.  Both are plain
 ``jax.numpy``; a token whose ``dt`` is 0 leaves the state as it was (decay
-1, no input), which is how padding and dead slots are masked.
+1, no input), which is how padding and dead slots are masked.  Each runs
+under its own region (`profiler.device_span`: ``ssm.chunked_scan``, the
+loop between chunks included, and ``ssm.decode_update``), which is how a
+device trace finds them: XLA's fusions of plain ``jax.numpy`` have no other
+name.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from ..profiler import device_span
+
 __all__ = ["ssd_chunked_scan", "ssm_decode_update"]
 
 
+@device_span("ssm.chunked_scan")
 def ssd_chunked_scan(x, dt, a, b, c, h0, chunk: int = 128):
     """x [T, heads, P], dt [T, heads] (after softplus; 0 = the token is
     padding), a [heads] (negative), b / c [T, groups, N], h0 [heads, P, N]
@@ -79,6 +86,7 @@ def ssd_chunked_scan(x, dt, a, b, c, h0, chunk: int = 128):
     return y.reshape(nc * chunk, heads, p)[:t].astype(x.dtype), h_last
 
 
+@device_span("ssm.decode_update")
 def ssm_decode_update(h, x, dt, a, b, c):
     """One token a slot.  h [S, heads, P, N] (its own dtype, float32 as
     served), x [S, heads, P], dt [S, heads] (0 = leave the slot's state as

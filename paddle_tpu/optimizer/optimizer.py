@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from ..core.tensor import Tensor, Parameter
 from ..core.dispatch import no_grad
+from ..profiler import device_span
 from .lr import LRScheduler
 
 __all__ = ["Optimizer"]
@@ -151,22 +152,23 @@ class Optimizer:
         lr = self.get_lr() if lr is None else lr
         wd = self._weight_decay
         new_params, new_state = {}, {}
-        for name, pv in params.items():
-            gv = grads.get(name)
-            if gv is None:
-                new_params[name] = pv
-                new_state[name] = opt_state.get(name, {})
-                continue
-            if wd is not None and self._decoupled_wd is False:
-                # same L1Decay/L2Decay-object handling as the eager step()
-                gv = gv + (wd(pv) if callable(wd) else float(wd) * pv)
-            st = opt_state.get(name)
-            if st is None or not st:
-                st = self._init_state(pv)
-            plr = lr * lr_scales[name] if lr_scales and name in lr_scales else lr
-            np_, ns = self._update(pv, gv, st, plr)
-            new_params[name] = np_
-            new_state[name] = ns
+        with device_span("optimizer"):
+            for name, pv in params.items():
+                gv = grads.get(name)
+                if gv is None:
+                    new_params[name] = pv
+                    new_state[name] = opt_state.get(name, {})
+                    continue
+                if wd is not None and self._decoupled_wd is False:
+                    # same L1Decay/L2Decay-object handling as the eager step()
+                    gv = gv + (wd(pv) if callable(wd) else float(wd) * pv)
+                st = opt_state.get(name)
+                if st is None or not st:
+                    st = self._init_state(pv)
+                plr = lr * lr_scales[name] if lr_scales and name in lr_scales else lr
+                np_, ns = self._update(pv, gv, st, plr)
+                new_params[name] = np_
+                new_state[name] = ns
         return new_params, new_state
 
     def init_opt_state(self, params: dict) -> dict:

@@ -8,14 +8,16 @@ The benchmark `Timer` reproduces timer.py's ips accounting.
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from enum import Enum
 from typing import Callable, Iterable, Optional
 
 import jax
+from jax.experimental.xla_metadata import set_xla_metadata
 
 __all__ = ["Profiler", "ProfilerTarget", "ProfilerState", "RecordEvent",
-           "make_scheduler", "export_chrome_tracing", "load_profiler_result",
+           "device_span", "make_scheduler", "export_chrome_tracing", "load_profiler_result",
            "benchmark", "Timer", "SummaryView"]
 
 
@@ -52,6 +54,26 @@ def make_scheduler(closed=0, ready=0, record=1, repeat=0, skip_first=0):
             return ProfilerState.RECORD_AND_RETURN
         return ProfilerState.RECORD
     return scheduler
+
+
+REGION_KEY = "pt_region"
+
+
+@contextlib.contextmanager
+def device_span(name: str):
+    """Name a region of a COMPILED program: every operation traced inside
+    carries the frontend attribute ``pt_region="<name>"`` and the
+    ``jax.named_scope`` ``name``.  A device trace names an "XLA Ops" event by
+    its instruction's whole HLO text, frontend attributes included, so the
+    trace splits by region (``benchmark/regions.py``); ``op_name`` in the
+    compiled text carries the scope.  A fusion takes its root's label; the
+    backward operations of a labelled forward carry the forward's label; an
+    inner span replaces the outer one.  The label is compile-time text: it
+    costs nothing at run time and there is nothing to switch on.  Names are
+    lower case (jax lower-cases an attribute's value) and a contract: the
+    benchmark's metric files match them."""
+    with set_xla_metadata(**{REGION_KEY: name}), jax.named_scope(name):
+        yield
 
 
 class RecordEvent:

@@ -28,7 +28,8 @@ def _cases(filename):
             if name.startswith("test_")}
 
 
-for _file in ("test_trace_reduce.py", "test_host_spans.py"):
+for _file in ("test_trace_reduce.py", "test_host_spans.py",
+              "test_regions.py"):
     _found = _cases(_file)
     # a name both files use would shadow a case without a word
     assert _found and not set(_found) & set(globals()), _file

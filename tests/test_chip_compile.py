@@ -68,6 +68,13 @@ def _shapes_on(sharding):
                                                      sharding=sharding)
 
 
+def _fusions_under(region, text):
+    """The fusion instructions of a compiled text that carry
+    ``pt_region="<region>"`` (`paddle_tpu.profiler.device_span`)."""
+    return [line for line in text.splitlines()
+            if " fusion(" in line and f'pt_region="{region}"' in line]
+
+
 @pytest.mark.parametrize("hq,hkv", [(32, 32), (32, 8)],
                          ids=["mha32", "gqa32x8"])
 @pytest.mark.parametrize("slots,qmax", [(SLOTS, 1), (1, 128), (SLOTS, 5)],
@@ -373,6 +380,11 @@ def test_serving_executable_leaves_the_page_pool_in_place(cell_programs,
             r'operand_layout_constraints=\{s32\[\d+,32\]', text)
         assert re.search(
             r'kernel_metadata=\{\s*"kernel":"ragged_paged_attention"', text)
+        # ... and, traced inside `device_span("attn.proj")`, it keeps that
+        # label and gains the region beside it (ISSUE 37)
+        assert re.search(
+            r'kernel_metadata=\{\s*"kernel":"ragged_paged_attention"[^}]*\},'
+            r'pt_region="attn\.proj"\}', text)
         # and the kernel takes the pool itself, K and V, not a slice of it
         # (it copies the pages it attends out of HBM on its own: PR 30)
         assert re.search(
@@ -380,6 +392,9 @@ def test_serving_executable_leaves_the_page_pool_in_place(cell_programs,
             r"operand_layout_constraints=\{s32\[\d+,32\].*"
             r"(, bf16\[%d,8,513,64,128\]\{4,3,2,1,0\}){2}\}" % CELL_LAYERS,
             text)
+    # the regions of `models/llama.py`'s paged fns are on the TPU compile's
+    # fusions (found by label, never by an instruction's name)
+    assert _fusions_under("head", text) and _fusions_under("block.mlp", text)
     pool = (CELL_LAYERS, HKV, CELL["num_slots"] * TABLE + 1, PAGE, D)
     _assert_pool_stays_in_place(compiled, pool)
     # how the fresh K/V rows reach the pool (PR 32), K and V a scatter each.
@@ -531,6 +546,13 @@ def test_hybrid_serving_executable_fits_and_leaves_its_cache_in_place(
         r"(%\S+) = \(bf16\[64,128,64\]\S*, " + re.escape(leaf)
         + r"\S*\) fusion\(", text)
     assert len(both) == layers, both
+    # ... and it is found by NAME: the region of `ops/ssm.ssm_decode_update`
+    # is on exactly those fusions' instructions (what
+    # `kernel.ssm_update_named_roofline_pct` reads; ISSUE 37)
+    named = [line.split(" = ")[0].strip()
+             for line in _fusions_under("ssm.decode_update", text)
+             if "f32[64,128,64,128]" in line.split(" fusion(")[0]]
+    assert sorted(named) == sorted(both), (named, both)
     # ... and nothing else takes a state leaf as an operand: every
     # instruction that IS a leaf (the loop's carry, a fusion's second
     # result) feeds one such fusion or only the plumbing of tuples
@@ -633,3 +655,29 @@ def test_latent_serving_executable_fits_and_leaves_its_cache_in_place(
                       for a in jax.tree_util.tree_leaves(cache))
     assert m.alias_size_in_bytes >= cache_bytes
     assert m.temp_size_in_bytes < 0.5 * GIB, m.temp_size_in_bytes / GIB
+
+
+def test_the_optimizers_update_compiles_to_fusions_under_its_region(one_chip):
+    """`AdamW.apply_gradients_functional` ALONE at two of the dense train
+    cell's leaves: every fusion of the update carries
+    ``pt_region="optimizer"`` — what `train.optimizer_ms_per_step` finds in
+    a trace — by label, whatever XLA calls the instruction."""
+    from paddle_tpu import optimizer
+    opt = optimizer.AdamW(learning_rate=1e-4, parameters=[])
+    at = _shapes_on(one_chip)
+    params = {"wq": at((4, 4096, 4096), jnp.bfloat16),
+              "ln1": at((4, 4096), jnp.bfloat16)}
+    state = jax.tree_util.tree_map(
+        lambda a: at(a.shape, a.dtype),
+        jax.eval_shape(opt.init_opt_state, params))
+    lr = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    text = jax.jit(
+        lambda p, g, o, lr: opt.apply_gradients_functional(p, g, o, lr=lr),
+        donate_argnums=(0, 2)).lower(params, params, state, lr) \
+        .compile().as_text()
+    fusions = [line for line in text.splitlines() if " fusion(" in line]
+    assert len(fusions) >= 2
+    assert len(_fusions_under("optimizer", text)) == len(fusions), [
+        line.split(" = ")[0] for line in fusions
+        if 'pt_region="optimizer"' not in line]
+

@@ -639,7 +639,6 @@ class TestUtilization:
         fsum = (u["host_busy_frac"] + u["dispatch_frac"]
                 + u["device_wait_frac"] + u["gap_frac"])
         assert fsum == pytest.approx(1.0, abs=0.01)
-        assert 0.0 <= u["device_idle_frac_est"] <= 1.0
         # the phases that actually ran are in the per-phase table
         assert "sched" in u["per_phase"]
         assert "decode_dispatch" in u["per_phase"]
