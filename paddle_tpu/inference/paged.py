@@ -111,7 +111,10 @@ tiling it, `serve.sched` (with the admission's `serve.prefill_dense` /
 `serve.prefill_chunk` / `serve.first_token_sync` inside), `serve.provision`,
 `serve.{decode,overlap,verify}_{dispatch,sync,record}` and
 `serve.overlap_join_sync`, with `step` / `rid` / `tokens` / `padded` /
-`pages` / `slots` / `k` as stats.  They cost nothing until somebody opens a profiler
+`pages` / `slots` / `k` as stats, and `launches` / `uploads` on every span
+under which the engine launched an executable (one and one under a model
+call's span: `stats()["step_launches"]` / `["step_uploads"]` are their
+sums).  They cost nothing until somebody opens a profiler
 session and then lie on the device events' clock, so an idle gap of the
 device can be laid under the host phase that caused it
 (benchmark/host_spans.py).  The kernels carry `kernel_metadata` labels and
@@ -775,6 +778,83 @@ class _Inflight:
         self.overlapped = overlapped
 
 
+# ---------------------------------------------------------------------------
+# The model calls of one engine step.  Each is ONE executable fed by ONE
+# packed int32 upload: a field that is an argument of its own is an upload
+# of its own (~0.3 ms of the device waiting, on the chip), and what the host
+# computes with `jax.numpy` before a call — a key split, a zero fill, a
+# merge — is an executable of its own (~0.5 ms).  The layouts live HERE (the
+# horizon's beside it, `models/llama.pack_decode_state`); the engine, and
+# the compile rehearsals that lower what the engine lowers
+# (`perf/chip_fit.py`), use these and nothing else.
+# ---------------------------------------------------------------------------
+def pack_prefill(ids, true_len, slot, temperature, top_p, page_row):
+    """A dense prefill's host state: ``true_len | slot | temperature |
+    top_p | page_row [P] | ids [T_bucket]`` (the two floats as the int32
+    words of their bits: `jax.lax.bitcast_convert_type` gives them back)."""
+    return np.concatenate([
+        np.asarray([true_len, slot], np.int32),
+        np.asarray([temperature, top_p], np.float32).view(np.int32),
+        np.asarray(page_row, np.int32), np.asarray(ids, np.int32).ravel()])
+
+
+def pack_chunk(ids, pos, c, slot, table):
+    """A prefill chunk's host state: ``pos | c | slot | table [P_slice] |
+    ids [C_bucket]``.  Two of its widths vary, so the chunk executable
+    takes ``C=`` as a static argument."""
+    return np.concatenate([
+        np.asarray([pos, c, slot], np.int32), np.asarray(table, np.int32),
+        np.asarray(ids, np.int32).ravel()])
+
+
+def make_step_calls(family, max_pages_per_seq):
+    """``(decode_horizon, prefill_sample, prefill_chunk, sample_logits)``
+    over a `PagedFamily`'s fns: what `ServingEngine` jits (the first three
+    with argument 1, the cache, donated; the horizon and the dense prefill
+    once for ``greedy=True`` and once for ``False``).  Every fn that draws
+    takes the engine's key, splits it inside (`split_call_key`: one split
+    a call, greedy or not) and returns the next key; the cache is the LAST
+    output and the key the one before it."""
+    import jax
+    import jax.numpy as jnp
+    from ..models.llama import (_sample_per_request, split_call_key,
+                                make_paged_decode_horizon)
+    P = int(max_pages_per_seq)
+
+    def sample_one(logits, sub, temp_top_p):
+        return _sample_per_request(logits[None], sub, temp_top_p[:1],
+                                   temp_top_p[1:])[0]
+
+    # prefill + first-token sample fused into ONE dispatch per admission
+    # (a separate sample call would double the per-admission dispatches)
+    def prefill_sample(params, cache, key, ints, *, greedy):  # graftlint: jit
+        logits, cache = family.prefill(params, ints[4 + P:][None], ints[0],
+                                       ints[4:4 + P], ints[1], cache)
+        key, sub = split_call_key(key)
+        if greedy:
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        else:
+            tok = sample_one(logits, sub, jax.lax.bitcast_convert_type(
+                ints[2:4], jnp.float32))
+        return tok, key, cache
+
+    def prefill_chunk(params, cache, ints, *, C):     # graftlint: jit
+        n = ints.shape[0]
+        return family.prefill_chunk(params, ints[n - C:][None], ints[0],
+                                    ints[1], ints[3:n - C], ints[2], cache)
+
+    # single-logits NUCLEUS sampler for the final chunk of a chunked /
+    # suffix prefill and a verify's sampled lanes (the chunk executable
+    # itself is sampling-agnostic so one executable serves every request)
+    def sample_logits(logits, key, temp_top_p):       # graftlint: jit
+        key, sub = split_call_key(key)
+        return sample_one(logits, sub, temp_top_p), key
+
+    return (make_paged_decode_horizon(family.decode_step,
+                                      sample_fn=_sample_per_request),
+            prefill_sample, prefill_chunk, sample_logits)
+
+
 # every live engine, for the tests' refcount-invariant leak guard
 # (tests/conftest.py checks each one after every test)
 _LIVE_ENGINES: "weakref.WeakSet[ServingEngine]" = weakref.WeakSet()
@@ -869,8 +949,6 @@ class ServingEngine:
                  quantized_allreduce: bool = False):
         import jax
         import jax.numpy as jnp
-        from ..models.llama import (make_paged_decode_horizon,
-                                    _sample_per_request)
         self._jax, self._jnp = jax, jnp
         # quantized serving plane (ROADMAP item 2): kv_dtype stores KV
         # pages int8/fp8 with per-(page, head, row) absmax scales held in
@@ -984,7 +1062,6 @@ class ServingEngine:
         self._clock = self.telemetry.clock if self.telemetry is not None \
             else time.perf_counter
 
-        prefill, prefill_chunk_fn = family.prefill, family.prefill_chunk
         # ONE pytree holds everything the paged executables keep on the
         # device between calls.  The leaves the family names
         # (`family.page_leaves`) are its page stores: ``["k"]`` / ``["v"]``,
@@ -1001,6 +1078,10 @@ class ServingEngine:
         # of the named page stores (`_copy_page`; snapshot/restore through
         # gather/scatter_kv_pages)
         self._cache = family.init_cache()
+        # where `_upload` places a packed host row: the default device, or
+        # under TP replicated over the mesh like every scalar the host
+        # touches (the key and the overlap carry's fillers with it)
+        self._host_sharding = None
         if self.tp > 1:
             # commit params + pages onto the mesh with the same specs the
             # shard_map region expects, so every jitted fn compiles ONE
@@ -1015,6 +1096,7 @@ class ServingEngine:
             pg = NamedSharding(mesh, page_spec)
             self._cache = jax.tree_util.tree_map(
                 lambda a: jax.device_put(a, pg), self._cache)
+            self._host_sharding = NamedSharding(mesh, PartitionSpec())
         self._page_bytes = None        # lazy page_bytes cache
 
         # decode HORIZON: K decode+sample steps fused into one fori_loop
@@ -1025,55 +1107,35 @@ class ServingEngine:
         # with the model math (models/llama.make_paged_decode_horizon);
         # it returns the sampled-token/length/budget/done carry as DEVICE
         # values so the overlapped engine feeds dispatch N+1 straight from
-        # dispatch N's outputs — the synchronous engine passes host values
-        # and done0=False, and the math is bit-identical either way.
-        _horizon = make_paged_decode_horizon(family.decode_step,
-                                             sample_fn=_sample_per_request)
+        # dispatch N's outputs — the synchronous engine passes no carry,
+        # and the math is bit-identical either way.  The horizon, the dense
+        # prefill (+ fused first-token sample), the chunk and the sampler
+        # each take their per-call host state PACKED (`make_step_calls`)
+        (self._horizon_fn, self._prefill_fn, prefill_chunk_fn,
+         self._sample_fn) = make_step_calls(family, self.max_pages_per_seq)
 
-        # prefill + first-token sample fused into ONE dispatch per admission
-        # (a separate sample call would double the per-admission dispatches)
-        def _prefill_sample(params, ids, true_len, page_row, slot, cache,
-                            key, temp, top_p, *, greedy):  # graftlint: jit
-            logits, cache = prefill(params, ids, true_len, page_row, slot,
-                                    cache)
-            if greedy:
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            else:
-                tok = _sample_per_request(logits[None], key, temp[None],
-                                          top_p[None])[0]
-            return tok, cache
-
-        # single-logits sampler for the final chunk of a chunked / suffix
-        # prefill (the chunk executable itself is sampling-agnostic so one
-        # executable serves every request)
-        def _sample_logits(logits, key, temp, top_p, *, greedy):  # graftlint: jit
-            if greedy:
-                return jnp.argmax(logits).astype(jnp.int32)
-            return _sample_per_request(logits[None], key, temp[None],
-                                       top_p[None])[0]
-
-        # copy-on-write page copy (src/dst are traced scalars: ONE
+        # copy-on-write page copy (src | dst are a traced pair: ONE
         # executable covers every copy).  tree_map keeps it generic over
         # the page-store layout: a raw array copies its page rows, a
         # quantized {"q","s"} store copies data AND scales — the page axis
         # is axis 2 of every leaf of the stores the family names
         # (`page_leaves`: K and V, or one latent store) by construction
         # (whatever else the cache holds belongs to slots, not to pages).
-        def _copy_page(cache, src, dst):              # graftlint: jit
+        def _copy_page(cache, src_dst):               # graftlint: jit
+            src, dst = src_dst[0], src_dst[1]
+
             def cp(a):
                 return a.at[:, :, dst].set(a[:, :, src])
             return {**cache, **{name: jax.tree_util.tree_map(cp, cache[name])
                                 for name in family.page_leaves}}
 
-        self._horizon_fn = _horizon
         self._horizon_jit = {}         # (K, greedy) -> jitted horizon
-        self._prefill_fn = _prefill_sample
         self._prefill_jit = {}         # (T_bucket, greedy) -> jitted prefill
-        # one wrapper: jax.jit already caches per (C_pad, P_slice) shape,
-        # and the chunk fn has no Python-level static knobs to key on
+        # one wrapper: jax.jit caches per (C_pad, packed length) — the
+        # static chunk width tells the ids from the page-table slice
         self._chunk_jit = self._jit("prefill_chunk", prefill_chunk_fn,
-                                    donate_argnums=(6,))
-        self._sample_fn = _sample_logits
+                                    donate_argnums=(1,),
+                                    static_argnames=("C",))
         self._sample_jit = None        # lazily jitted nucleus sampler
         self._copy_jit = self._jit("page_copy", _copy_page,
                                    donate_argnums=(0,))
@@ -1090,10 +1152,25 @@ class ServingEngine:
         self._lengths = np.zeros((S,), np.int32)
         self._temps = np.zeros((S,), np.float32)
         self._top_ps = np.ones((S,), np.float32)
+        # ... and `temps | top_ps` as the horizon's device row: uploaded
+        # again only after an admission changed a value (None = stale)
+        self._sampling_dev = None
         self._queue: deque[Request] = deque()
         self._finished: dict[int, Request] = {}
         self._next_rid = 0
-        self._key = jax.random.PRNGKey(seed)
+        # the PRNG key is DEVICE state like the cache: every executable
+        # that draws splits it inside and returns the next key, which the
+        # engine rebinds with the cache (no split executable of its own)
+        self._key = jax.device_put(jax.random.PRNGKey(seed),
+                                   self._host_sharding)
+        # overlap: what a dispatch carries when no previous dispatch's
+        # outputs are there to carry — made once, here, never by step()
+        self._no_carry = self._zero_tok = None
+        if self.overlap:
+            put = lambda a: jax.device_put(a, self._host_sharding)
+            self._no_carry = (put(np.zeros((S,), np.int32)),) * 3 \
+                + (put(np.zeros((S,), bool)),)
+            self._zero_tok = put(np.int32(0))
         self.max_queue = None if max_queue is None else int(max_queue)
         self._admit_seq = 0
         self._pressure = False         # this-step injected pool pressure
@@ -1109,6 +1186,7 @@ class ServingEngine:
                                        #   requests ADMITTED (counted at
                                        #   admission; their chunks may
                                        #   still be to run)
+        self.prefill_calls = 0         # dense prefills + prefill chunks run
         self.prefill_tokens_dispatched = 0  # prompt tokens handed to a
                                        #   prefill executable, counted at
                                        #   each dense / chunk call ...
@@ -1147,6 +1225,13 @@ class ServingEngine:
         self.kv_imports = 0            # import_kv packets spliced in
         self.kv_pages_exported = 0     # pages shipped in those packets
         self.kv_pages_imported = 0
+        self.step_launches = 0         # executables step() launched ...
+        self.step_uploads = 0          # ... and host->device arrays it
+                                       #   made: one launch a model call
+                                       #   (dense prefill, chunk, horizon)
+                                       #   and one packed upload (two when
+                                       #   the sampling row changed) is
+                                       #   the whole of a steady step
         _LIVE_ENGINES.add(self)
 
     # -- submission --------------------------------------------------------
@@ -1329,9 +1414,13 @@ class ServingEngine:
         `ENGINE_PHASES` also feeds its histogram and the tracer's engine
         track (an exception in the body skips that, as it always did).
         Yields a dict: what the body puts in it becomes further stats of
-        the annotation (a record span's ``tokens``)."""
+        the annotation (a record span's ``tokens``); a span under which
+        the engine launched executables or made host->device arrays gets
+        ``launches`` and ``uploads`` the same way (a dispatch or prefill
+        span reads 1 and 1, or 2 where the sampling row had changed)."""
         tel = self.telemetry
         late = {}
+        launched, uploaded = self.step_launches, self.step_uploads
         with self._jax.profiler.TraceAnnotation("serve." + name,
                                                 **attrs) as ann:
             if tel is None or name not in ENGINE_PHASES:
@@ -1349,6 +1438,9 @@ class ServingEngine:
                     tel.join_wait(t0, tel.clock())
                 else:
                     tel.phase(name, t0, tel.clock(), **attrs)
+            if self.step_launches != launched:
+                late["launches"] = self.step_launches - launched
+                late["uploads"] = self.step_uploads - uploaded
             if late:
                 ann.set_metadata(**late)
 
@@ -1380,19 +1472,23 @@ class ServingEngine:
         if tel is not None:
             tel.compiled(name, n, dur_s)
 
-    def _call_paged(self, fn, *args):
+    def _call_paged(self, fn, *args, keyed=False, **static):
         """Call a cache-donating executable (its last output is the new
-        cache).  A sanitize() budget raise fires only AFTER
+        cache; where it draws, `keyed`, the one before it is the next
+        key).  A sanitize() budget raise fires only AFTER
         the underlying call ran — its donated inputs are gone — so rebind
-        the cache from the executed call's outputs before
+        the cache (and the key the call consumed) from the executed call's
+        outputs before
         propagating: lengths were never advanced for the raising step and
         K/V above lengths is never attended (the rewind invariant), so the
         engine stays fully usable."""
         try:
-            out = fn(*args)
+            out = fn(*args, **static)
         except RecompileBudgetError as e:
             if e.result is not None:
                 self._cache = e.result[-1]
+                if keyed:
+                    self._key = e.result[-2]
             if self.telemetry is not None:
                 # the postmortem the recompile sanitizer never had: the
                 # last N engine events leading up to the budget failure
@@ -1400,6 +1496,14 @@ class ServingEngine:
                                           error=str(e)[:200])
             raise
         return out
+
+    def _upload(self, host):
+        """ONE host->device array of `step()`, replicated over the mesh
+        under TP.  It may ALIAS the numpy memory on the CPU backend: hand
+        it a fresh buffer (a packed row is one), never a view of a host
+        mirror that changes while the call is in flight."""
+        self.step_uploads += 1
+        return self._jax.device_put(host, self._host_sharding)
 
     @property
     def _pages_k(self):
@@ -1443,18 +1547,12 @@ class ServingEngine:
             return jax.ShapeDtypeStruct(shape, dtype)
 
         shaped = lambda tree: jax.tree_util.tree_map(like, tree)
+        carry = None if not self.overlap else \
+            shaped(self._no_carry + ((self._zero_tok,) * S,))
         return self._horizon_exec(self.decode_horizon, True).lower(
-            shaped(self.params), host((S,), jnp.int32), host((S,), jnp.int32),
-            host((S, P), jnp.int32), shaped(self._cache),
-            host((S,), jnp.bool_),
-            host(self._key.shape, self._key.dtype),
-            host((S,), jnp.float32), host((S,), jnp.float32),
-            host((S,), jnp.int32), host((S,), jnp.int32),
-            host((S,), jnp.bool_)).compile()
-
-    def _split_key(self):
-        self._key, sub = self._jax.random.split(self._key)
-        return sub
+            shaped(self.params), shaped(self._cache), shaped(self._key),
+            host(((6 + P) * S,), jnp.int32), host((2 * S,), jnp.float32),
+            carry).compile()
 
     def _avail(self) -> int:
         """Free pages as THIS step sees them: zero while an injected
@@ -1621,16 +1719,16 @@ class ServingEngine:
         table index idx before anything writes into it.  `src` overrides
         the copy source (admission attaches a cached partial page without
         ever putting the shared id in the table)."""
-        jnp = self._jnp
         self._join_dispatch()      # the copy chains on concrete pages
         slot = self._slots[s]
         dst = slot.pages[idx]
         if src is None:
             src = dst
             dst = self.pool.alloc(1)[0]
+        self.step_launches += 1
         self._cache = self._call_paged(
             self._copy_jit, self._cache,
-            jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32))
+            self._upload(np.asarray([src, dst], np.int32)))
         if slot.pages[idx] != dst:
             self.pool.free([slot.pages[idx]])
             slot.pages[idx] = dst
@@ -1640,7 +1738,6 @@ class ServingEngine:
             self.telemetry.cow_copy(slot.req.rid, src=int(src), dst=int(dst))
 
     def _admit(self):                                 # graftlint: hot
-        jnp = self._jnp
         while self._queue:
             free_slots = [i for i, sl in enumerate(self._slots) if sl is None]
             if not free_slots:
@@ -1715,8 +1812,7 @@ class ServingEngine:
                 self._cow(s, n_shared, src=src)
                 self.pool.free([src])
                 matched += m
-            self._temps[s] = req.temperature
-            self._top_ps[s] = req.top_p
+            self._set_sampling(s, req)
             if matched:
                 self.cache_hits += 1
                 self.cache_hit_tokens += matched
@@ -1747,8 +1843,8 @@ class ServingEngine:
                 Tb = max(self.prompt_bucket,
                          math.ceil(T / self.prompt_bucket) * self.prompt_bucket)
                 Tb = min(Tb, self.config.max_position_embeddings)
-                ids = np.zeros((1, Tb), np.int32)
-                ids[0, :T] = ctx
+                ids = np.zeros((Tb,), np.int32)
+                ids[:T] = ctx
                 greedy = req.temperature <= 0.0
                 pf = self._prefill_jit.get((Tb, greedy))
                 if pf is None:
@@ -1762,7 +1858,7 @@ class ServingEngine:
                         "prefill",
                         (lambda *a: fn(*a, greedy=True)) if greedy
                         else (lambda *a: fn(*a, greedy=False)),
-                        donate_argnums=(5,))
+                        donate_argnums=(1,))
                     # keyed by (T bucket, greedy): bounded by the
                     # bucket ladder  # graftlint: disable=LEAK001
                     self._prefill_jit[(Tb, greedy)] = pf
@@ -1776,14 +1872,12 @@ class ServingEngine:
                                     tokens=T, padded=Tb, pages=kv_pages,
                                     family=self.family.name,
                                     attention=self.family.attention_path):
-                        tok, self._cache = self._call_paged(
-                            pf,
-                            self.params, jnp.asarray(ids),
-                            jnp.asarray(T, jnp.int32),
-                            jnp.asarray(row), jnp.asarray(s, jnp.int32),
-                            self._cache, self._split_key(),
-                            jnp.asarray(req.temperature, jnp.float32),
-                            jnp.asarray(req.top_p, jnp.float32))
+                        self.step_launches += 1
+                        tok, self._key, self._cache = self._call_paged(
+                            pf, self.params, self._cache, self._key,
+                            self._upload(pack_prefill(
+                                ids, T, s, req.temperature, req.top_p, row)),
+                            keyed=True)
                 except RecompileBudgetError as e:
                     # the prefill DID run (pages already rebound by
                     # _call_paged) — finish the admission bookkeeping with
@@ -1804,11 +1898,21 @@ class ServingEngine:
                 self._lengths[s] = matched
                 self._prefill_advance(s)
 
+    def _set_sampling(self, s: int, req):
+        """Slot s samples as `req` asks; the horizon's device row goes
+        stale only where a value really changed (all-greedy traffic never
+        uploads it twice)."""
+        t, p = np.float32(req.temperature), np.float32(req.top_p)
+        if self._temps[s] != t or self._top_ps[s] != p:
+            self._temps[s], self._top_ps[s] = t, p
+            self._sampling_dev = None
+
     def _count_prefill(self, pos: int, c: int, padded: int) -> int:
         """The dispatch-time counters of ONE prefill call: ``c`` real
         tokens from position ``pos`` in ``padded`` computed rows.  Returns
         the pages their K/V rows lie on (``pos`` may sit inside a page
         after a prefix-cache hit; the last is part full)."""
+        self.prefill_calls += 1
         self.prefill_tokens_dispatched += c
         self.prefill_tokens_padded += padded
         pages = (pos + c - 1) // self.page_size - pos // self.page_size + 1
@@ -1846,7 +1950,6 @@ class ServingEngine:
         hit is the single- or few-chunk case).  On the final chunk: index
         the prompt's full blocks into the cache and sample the first
         token."""
-        jnp = self._jnp
         self._join_dispatch()      # the chunk chains on concrete pages
         slot = self._slots[s]
         req = slot.req
@@ -1870,22 +1973,21 @@ class ServingEngine:
         granule = self.family.chunk_table_granule or self.max_pages_per_seq
         Pb = min(self.max_pages_per_seq,
                  math.ceil(ctx_pages / granule) * granule)
-        ids = np.zeros((1, Cb), np.int32)
-        ids[0, :c] = slot.ctx[pos:pos + c]
+        ids = np.zeros((Cb,), np.int32)
+        ids[:c] = slot.ctx[pos:pos + c]
         kv_pages = self._count_prefill(pos, c, Cb)
         with self._span("prefill_chunk", rid=req.rid, pos=pos, tokens=c,
                         padded=Cb, pages=kv_pages, family=self.family.name,
                         attention=self.family.attention_path,
                         state_carried=1 if self.family.recurrent and pos else 0):
+            self.step_launches += 1
             logits, tok_g, self._cache = self._call_paged(
-                self._chunk_jit,
-                self.params, jnp.asarray(ids), jnp.asarray(pos, jnp.int32),
-                jnp.asarray(c, jnp.int32),
-                # .copy(): the row slice is a VIEW of the mutable host
-                # table — an async in-flight chunk must not see later
-                # host-side table growth (CPU jnp.asarray can alias)
-                jnp.asarray(self._page_tables[s, :Pb].copy()),
-                jnp.asarray(s, jnp.int32), self._cache)
+                self._chunk_jit, self.params, self._cache,
+                # packed = copied: the row slice is a VIEW of the mutable
+                # host table — an async in-flight chunk must not see later
+                # host-side table growth (a CPU upload can alias)
+                self._upload(pack_chunk(ids, pos, c, s,
+                                        self._page_tables[s, :Pb])), C=Cb)
         slot.chunk_step = self._step_seq
         pos += c
         slot.prefill_pos = pos
@@ -1915,17 +2017,14 @@ class ServingEngine:
                 self._record_token(s, self._fetch_first_token(slot, tok_g))
         else:
             try:
-                tok = self._sampler(False)(
-                    logits, self._split_key(),
-                    jnp.asarray(req.temperature, jnp.float32),
-                    jnp.asarray(req.top_p, jnp.float32))
+                tok = self._sample(logits, req)
             except RecompileBudgetError as e:
                 # the sampler DID run — record the token it produced so
                 # the completed-prefill transition above stays consistent
                 # and a later run() decodes from the right first token
                 if e.result is None:
                     raise
-                self._record_token(s, int(np.asarray(e.result)))  # graftlint: disable=SYNC001
+                self._record_token(s, int(np.asarray(e.result[0])))  # graftlint: disable=SYNC001
                 raise
             if self.overlap:
                 slot.pending = None
@@ -1934,24 +2033,28 @@ class ServingEngine:
                 # the ONE final-chunk sync: the sampled first token
                 self._record_token(s, self._fetch_first_token(slot, tok))
 
-    def _sampler(self, greedy: bool):
-        """Jitted single-logits NUCLEUS sampler (the sampled final chunk
-        of a chunked/suffix prefill and the sampled lanes of a speculative
-        verify share it).  Greedy lanes never reach here — their argmax is
-        FUSED into the chunk/verify/decode dispatch itself (tokens, not
-        logits, leave the device), so the greedy sampler variant of the
-        pre-unification engine no longer exists; `greedy` must be False."""
-        assert not greedy, "greedy sampling is fused into the dispatch"
+    def _sample(self, logits, req):
+        """One NUCLEUS draw from single-position logits (the sampled final
+        chunk of a chunked/suffix prefill and the sampled lanes of a
+        speculative verify share the jitted sampler): one launch and one
+        upload, the key split inside and rebound here.  Greedy lanes never
+        reach here — their argmax is FUSED into the chunk/verify/decode
+        dispatch itself (tokens, not logits, leave the device), so no
+        greedy sampler exists.  A `RecompileBudgetError` carries the
+        executed call's ``(token, next key)``; the key is rebound before
+        it propagates, so the resumed engine stays on the seeded stream."""
         sf = self._sample_jit
-        if sf is None:
-            fn = self._sample_fn
-
-            def sample_logits(*a):     # its module: `jit_sample_logits`
-                return fn(*a, greedy=False)
-
-            sf = self._jit("sample", sample_logits)
-            self._sample_jit = sf
-        return sf
+        if sf is None:                 # its module: `jit_sample_logits`
+            sf = self._sample_jit = self._jit("sample", self._sample_fn)
+        self.step_launches += 1
+        try:
+            tok, self._key = sf(logits, self._key, self._upload(np.asarray(
+                [req.temperature, req.top_p], np.float32)))
+        except RecompileBudgetError as e:
+            if e.result is not None:
+                self._key = e.result[1]
+            raise
+        return tok
 
     def _remaining(self, s: int) -> int:
         slot = self._slots[s]
@@ -2040,7 +2143,6 @@ class ServingEngine:
         decode horizon (`_record_token` stops the emit loop); sampled
         (temperature > 0) slots ride the same dispatch as single-token
         lanes drawn from the position-0 logits."""
-        jnp = self._jnp
         Q = self.speculative + 1
         S = self.num_slots
         toks = np.zeros((S, Q), np.int32)
@@ -2054,11 +2156,16 @@ class ServingEngine:
             n_q[s] = 1 + len(d)
         with self._span("verify_dispatch", slots=len(run),
                         k=self.speculative):
+            # no benchmark cell speculates: the verify step keeps an upload
+            # a field (the sync below comes before the host touches its
+            # mirrors again), and launches the one executable
+            self.step_launches += 1
             logits0, gtoks, self._cache = self._call_paged(
                 self._verify_jit,
-                self.params, jnp.asarray(toks), jnp.asarray(self._lengths),
-                jnp.asarray(self._page_tables), self._cache,
-                jnp.asarray(n_q))
+                self.params, self._upload(toks),
+                self._upload(self._lengths),
+                self._upload(self._page_tables), self._cache,
+                self._upload(n_q))
         with self._span("verify_sync"):
             # the ONE per-verify-dispatch sync: every slot's K+1 argmaxes
             # land in one transfer (acceptance is host logic by design)
@@ -2069,7 +2176,6 @@ class ServingEngine:
     def _verify_record(self, run, drafts, logits0, gtoks):  # graftlint: hot
         """The host half of a verify dispatch: acceptance, emission and
         the length rewind (see `_verify`).  Returns the tokens emitted."""
-        jnp = self._jnp
         self.steps_run += 1
         self.verify_steps += 1
         if all(self._slots[s].req.temperature <= 0.0 for s in run):
@@ -2092,10 +2198,7 @@ class ServingEngine:
             old = lens[s]
             if req.temperature > 0.0:
                 try:
-                    tok = self._sampler(False)(
-                        logits0[s], self._split_key(),
-                        jnp.asarray(req.temperature, jnp.float32),
-                        jnp.asarray(req.top_p, jnp.float32))
+                    tok = self._sample(logits0[s], req)
                 except RecompileBudgetError as e:
                     # same recovery as the final-chunk sampler: the call
                     # ran and consumed a PRNG key — record its token so
@@ -2104,7 +2207,7 @@ class ServingEngine:
                     if e.result is None:
                         raise
                     self._lengths[s] = old + 1
-                    self._record_token(s, int(np.asarray(e.result)))  # graftlint: disable=SYNC001
+                    self._record_token(s, int(np.asarray(e.result[0])))  # graftlint: disable=SYNC001
                     raise
                 # per sampled ride-along lane: one token fetch
                 emitted = [int(np.asarray(tok))]  # graftlint: disable=SYNC001
@@ -2154,7 +2257,7 @@ class ServingEngine:
                 return horizon(*a, K=K, greedy=greedy)
 
             fn = self._jit("decode_step", decode_horizon,
-                           donate_argnums=(4,))
+                           donate_argnums=(1,))
             # keyed by (K, greedy): bounded by the horizon ladder
             # graftlint: disable=LEAK001
             self._horizon_jit[(K, greedy)] = fn
@@ -2250,17 +2353,20 @@ class ServingEngine:
         previous dispatch's future INSIDE the worker, so the main thread
         returns immediately and the engine's page binding lives in the
         future until someone `_join_dispatch()`s or drains."""
-        jnp = self._jnp
+        from ..models.llama import pack_decode_state
         S = self.num_slots
         prev = self._inflight
-        active = np.zeros((S,), bool)
-        active[run] = True
+        active = np.zeros((S,), np.int32)
+        active[run] = 1
         toks = np.zeros((S,), np.int32)
         remaining = np.ones((S,), np.int32)
         eos_ids = np.full((S,), -1, np.int32)
+        # where a lane's entry state comes from (`pack_decode_state`)
+        carried = np.zeros((S,), np.int32)
+        # ... and, for code 2, the first token an admission left on the
+        # device (overlap engines; a filler scalar elsewhere)
+        firsts = [self._zero_tok] * S
         lanes = []
-        carried = []
-        deferred = []
         for s in run:
             slot = self._slots[s]
             remaining[s] = self._remaining(s)
@@ -2268,51 +2374,36 @@ class ServingEngine:
                 eos_ids[s] = slot.req.eos_token_id
             take_first = False
             if prev is not None and prev.srcs.get(s) is slot:
-                carried.append(s)
+                carried[s] = 1
             elif slot.pending_dev is not None:
-                deferred.append((s, slot.pending_dev))
+                carried[s] = 2
+                firsts[s] = slot.pending_dev
                 take_first = True
             else:
                 toks[s] = slot.pending
             lanes.append(_LaneRec(s, slot, take_first))
-        cm = None
-        if carried:
-            cm = np.zeros((S,), bool)
-            cm[carried] = True
-        # .copy() the persistent host mirrors: the dispatch may execute
-        # after the host has already mutated them (admissions, drains,
-        # detaches), and jnp.asarray can ALIAS numpy memory on the CPU
-        # backend.  The freshly built per-dispatch arrays need no copy.
-        lengths_host = self._lengths.copy()
-        tables = self._page_tables.copy()
-        temps = self._temps.copy()
-        top_ps = self._top_ps.copy()
-        key = self._split_key()        # main thread: keeps the key stream
+        # ONE upload: the packed buffer is a copy, so the dispatch may
+        # execute after the host has already mutated its mirrors
+        # (admissions, drains, detaches) — an upload can ALIAS numpy memory
+        # on the CPU backend.  The sampling row is uploaded again only
+        # after an admission changed it.
+        ints = self._upload(pack_decode_state(
+            toks, self._lengths, remaining, eos_ids, active, carried,
+            self._page_tables))
+        if self._sampling_dev is None:
+            self._sampling_dev = self._upload(
+                np.concatenate([self._temps, self._top_ps]))
+        sampling = self._sampling_dev
         fn = self._horizon_exec(K, greedy)
+        self.step_launches += 1        # counted where it is issued
 
-        def merge(prev_state):
-            """Build the dispatch inputs; `prev_state` is (toks, lengths,
-            rem, done) device arrays of the previous dispatch (None when
-            nothing is carried).  Runs on the dispatching thread."""
-            toks_in = jnp.asarray(toks)
-            lengths_in = jnp.asarray(lengths_host)
-            rem_in = jnp.asarray(remaining)
-            done_in = jnp.zeros((S,), bool)
-            if prev_state is not None and cm is not None:
-                cmj = jnp.asarray(cm)
-                toks_in = jnp.where(cmj, prev_state[0], toks_in)
-                lengths_in = jnp.where(cmj, prev_state[1], lengths_in)
-                rem_in = jnp.where(cmj, prev_state[2], rem_in)
-                done_in = cmj & prev_state[3]
-            for ds, dev in deferred:
-                toks_in = toks_in.at[ds].set(dev)
-            return toks_in, lengths_in, rem_in, done_in
-
-        def call(cache, toks_in, lengths_in, rem_in, done_in):
-            return self._call_paged(
-                fn, self.params, toks_in, lengths_in, jnp.asarray(tables),
-                cache, jnp.asarray(active), key, jnp.asarray(temps),
-                jnp.asarray(top_ps), rem_in, jnp.asarray(eos_ids), done_in)
+        def call(cache, key, *prev_state):
+            """The dispatch's ONE launch; `prev_state` (overlap engines
+            only) is the previous dispatch's (toks, lengths, rem, done)
+            device outputs.  Runs on the dispatching thread."""
+            carry = (prev_state + (tuple(firsts),),) if prev_state else ()
+            return self._call_paged(fn, self.params, cache, key, ints,
+                                    sampling, *carry, keyed=True)
 
         # carry sources are EXACTLY the dispatched lanes: only they got
         # real inputs merged in (a slot skipped by _provision this step
@@ -2323,31 +2414,29 @@ class ServingEngine:
         srcs = {lane.s: lane.slot for lane in lanes}
         rec = _Inflight(K, greedy, lanes, srcs, self.overlap)
         if not self.overlap:
-            res = call(self._cache, *merge(
-                None if prev is None
-                else (prev.toks, prev.lengths, prev.rem, prev.done)))
+            # synchronous: no dispatch is ever in flight, nothing to carry
+            res = call(self._cache, self._key)
             rec.out, rec.toks, rec.lengths, rec.rem, rec.done = res[:5]
-            self._cache = res[-1]
+            self._key, self._cache = res[-2:]
         elif prev is not None and prev.fut is not None:
             # chain INSIDE the worker: the previous dispatch's outputs
-            # (pages + carry) flow worker-to-worker, never through the
-            # main thread
+            # (pages, key + carry) flow worker-to-worker, never through
+            # the main thread
             pfut = prev.fut
 
             def work_chained():
                 pres = pfut.result()
-                return call(pres[-1], *merge(
-                    (pres[1], pres[2], pres[3], pres[4])))
+                return call(pres[-1], pres[-2], *pres[1:5])
 
             rec.fut = self._executor.submit(work_chained)
         else:
             # pipeline empty (or already joined by an admission): the
-            # page binding and any carry state are concrete arrays
-            cache0 = self._cache
-            pstate = None if prev is None \
+            # page binding, the key and any carry state are concrete arrays
+            cache0, key0 = self._cache, self._key
+            pstate = self._no_carry if prev is None \
                 else (prev.toks, prev.lengths, prev.rem, prev.done)
             rec.fut = self._executor.submit(
-                lambda: call(cache0, *merge(pstate)))
+                lambda: call(cache0, key0, *pstate))
         self.steps_run += 1
         # horizon dispatches always emit tokens on-device (fused greedy
         # argmax or in-loop sampling) — logits never leave the device
@@ -2375,7 +2464,7 @@ class ServingEngine:
         res = fut.result()
         rec.out, rec.toks, rec.lengths, rec.rem, rec.done = res[:5]
         if rebind:
-            self._cache = res[-1]
+            self._key, self._cache = res[-2:]
 
     def _join_dispatch(self):
         """Block until the pending async dispatch's output binding is
@@ -2805,7 +2894,7 @@ class ServingEngine:
 
     _COUNTER_ATTRS = ("steps_run", "tokens_generated", "preemptions",
                       "timeouts", "rejections", "cache_hits",
-                      "cache_hit_tokens", "prefill_tokens",
+                      "cache_hit_tokens", "prefill_tokens", "prefill_calls",
                       "prefill_tokens_dispatched", "prefill_tokens_padded",
                       "prefill_kv_pages_written",
                       "decode_kv_tokens_attended",
@@ -2814,7 +2903,7 @@ class ServingEngine:
                       "draft_tokens_proposed", "draft_tokens_accepted",
                       "overlap_steps", "quiesces", "fused_sample_steps",
                       "kv_exports", "kv_imports", "kv_pages_exported",
-                      "kv_pages_imported")
+                      "kv_pages_imported", "step_launches", "step_uploads")
 
     def snapshot(self, mode: str = "full_kv",
                  include_finished: bool = True) -> dict:
@@ -3137,8 +3226,7 @@ class ServingEngine:
             row[:len(pages)] = pages
             self._page_tables[s] = row
             self._lengths[s] = int(e["length"])
-            self._temps[s] = req.temperature
-            self._top_ps[s] = req.top_p
+            self._set_sampling(s, req)
             if self.telemetry is not None:
                 # stitched-trace continuity (the restore convention): the
                 # handed-off request opens a track on THIS engine's tracer
@@ -3183,8 +3271,8 @@ class ServingEngine:
             raise RuntimeError(
                 "ServingEngine.restore: target engine already holds state — "
                 "restore into a freshly constructed engine")
-        jnp = self._jnp
-        self._key = jnp.asarray(np.asarray(state["rng"]))
+        self._key = self._jax.device_put(np.asarray(state["rng"]),
+                                         self._host_sharding)
         reqs = {int(r): self._req_from_state(d)
                 for r, d in meta["requests"].items()}
         for rid in meta["finished"]:
@@ -3260,8 +3348,7 @@ class ServingEngine:
             row[:len(slot.pages)] = slot.pages
             self._page_tables[s] = row
             self._lengths[s] = int(sd["length"])
-            self._temps[s] = req.temperature
-            self._top_ps[s] = req.top_p
+            self._set_sampling(s, req)
         for rid in meta["queue"]:
             self._queue.append(reqs[rid])
         if self.cache is not None and meta.get("cache"):
@@ -3325,6 +3412,7 @@ class ServingEngine:
             # time) / tokens and padded rows of the prefill calls made
             # (dispatch time) / KV positions the decode horizon read
             "prefill_tokens_executed": self.prefill_tokens,
+            "prefill_calls": self.prefill_calls,
             "prefill_tokens_dispatched": self.prefill_tokens_dispatched,
             "prefill_tokens_padded": self.prefill_tokens_padded,
             # a dispatched token is a K/V row written; rows / pages is the
@@ -3351,6 +3439,15 @@ class ServingEngine:
             "kv_imports": self.kv_imports,
             "kv_pages_exported": self.kv_pages_exported,
             "kv_pages_imported": self.kv_pages_imported,
+            # executables `step()` launched and host->device arrays it
+            # made: in a steady window launches over the model calls
+            # (`prefill_calls` + `decode_steps` + `verify_steps`) reads 1
+            # — a COW copy and a sampled first token after a chunk are
+            # launches of their own — and a call makes one upload, two
+            # where an admission changed the horizon's sampling row (a
+            # verify keeps four)
+            "step_launches": self.step_launches,
+            "step_uploads": self.step_uploads,
             # tensor-parallel serving: mesh degree over mp (1 = single
             # chip) and whether the per-layer AllReduce rides the EQuARX
             # int8 grid (distributed/quant_collectives)

@@ -17,6 +17,7 @@ from dataclasses import dataclass, field
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..core.tensor import Tensor, Parameter
 from ..nn.layer import Layer
@@ -30,6 +31,7 @@ __all__ = ["LlamaConfig", "LlamaForCausalLM", "LlamaModel", "LlamaDecoderLayer",
            "build_functional_llama", "llama_microbatch_fns", "llama_block_specs",
            "llama_config_7b", "llama_config_tiny", "build_llama_decode",
            "build_llama_paged_decode", "make_paged_decode_horizon",
+           "pack_decode_state", "split_call_key",
            "functional_params_from_layer", "llama_generate",
            "gather_kv_pages", "scatter_kv_pages", "scatter_kv_rows",
            "scatter_kv_run"]
@@ -1267,6 +1269,44 @@ def _sample_per_request(logits, key, temps, top_ps):
     return jnp.where(temps <= 0.0, greedy, sampled)
 
 
+def pack_decode_state(toks, lengths, remaining, eos_ids, active, carried,
+                      page_tables):
+    """The decode horizon's per-call host state as ONE fresh int32 buffer
+    ``toks | lengths | remaining | eos_ids | active | carried |
+    page_tables.ravel()`` (``[S]`` each, the table ``[S, P]``): one upload
+    a dispatch, where a field that is an argument of its own is an upload
+    of its own.  ``active`` is 0 / 1; ``carried`` says where a lane's entry state
+    comes from: 0 the host's values in this buffer, 1 the previous
+    dispatch's device outputs, 2 an admission's first token still on the
+    device (`make_paged_decode_horizon`'s ``carry``).  The horizon takes the
+    buffer apart again with static slices (`_unpack_decode_state`); being a
+    copy, it never aliases a host mirror that changes while the call is in
+    flight."""
+    return np.concatenate(
+        [np.asarray(a, np.int32).ravel()
+         for a in (toks, lengths, remaining, eos_ids, active, carried,
+                   page_tables)])
+
+
+def _unpack_decode_state(ints, S):
+    """`pack_decode_state`'s fields out of the traced buffer."""
+    toks, lengths, remaining, eos_ids, active, carried = (
+        ints[i * S:(i + 1) * S] for i in range(6))
+    return (toks, lengths, remaining, eos_ids, active != 0, carried,
+            ints[6 * S:].reshape(S, -1))
+
+
+def split_call_key(key):
+    """One engine-level split a model call, INSIDE its executable:
+    ``(next key, this call's subkey)`` — rows 0 and 1 of
+    ``jax.random.split(key)``, what ``key, sub = jax.random.split(key)``
+    binds on the host, so the stream of subkeys is the sequential split's
+    whether the split is an executable of its own or a few operations of
+    the call it feeds."""
+    pair = jax.random.split(key)
+    return pair[0], pair[1]
+
+
 def make_paged_decode_horizon(decode_step, sample_fn=None):
     """Build the K-step decode-horizon loop with ON-DEVICE token feedback
     (the serving engine's one decode executable; ROADMAP item 5) over a
@@ -1274,17 +1314,28 @@ def make_paged_decode_horizon(decode_step, sample_fn=None):
     -> (logits, cache)`` (`models/paged_family.py`): the cache is ONE
     pytree, carried whole through the loop.
 
-    K decode+sample steps fuse into one ``fori_loop`` dispatch, and the
-    loop state that used to round-trip through the host between dispatches
-    — the last sampled token per slot, the cache lengths, the remaining
-    generation budget, and the per-slot done flags — is both ACCEPTED and
-    RETURNED as device values.  A double-buffered engine feeds dispatch
-    N+1 directly from dispatch N's ``(toks, lengths, remaining, done)``
-    outputs, so the decode feedback token never touches the host and the
-    host-side drain of dispatch N's emitted tokens moves off the critical
-    path.  A synchronous engine passes host values and ``done0=False``
-    everywhere; the math (and therefore greedy output) is bit-identical
-    either way.
+    K decode+sample steps fuse into one ``fori_loop`` dispatch.  What the
+    host knows of the call arrives as ONE packed int32 buffer
+    (`pack_decode_state`) and one float32 row ``temps | top_ps`` ``[2S]``
+    (passed again as the same device array until an admission changes
+    it); the entry ``done`` flags are made here, and the engine's PRNG key
+    is split here (`split_call_key`) and the next key returned, so nothing
+    but this executable is launched for a dispatch.
+
+    The loop state that used to round-trip through the host between
+    dispatches — the last sampled token per slot, the cache lengths, the
+    remaining generation budget, and the per-slot done flags — is both
+    ACCEPTED and RETURNED as device values.  A double-buffered engine
+    passes ``carry = (toks, lengths, remaining, done, firsts)``: the
+    previous dispatch's four outputs and ``firsts``, ``S`` int32 scalars
+    holding admissions' first tokens that never left the device; a lane
+    whose ``carried`` code is 1 enters with the previous dispatch's state
+    (and its ``done``), a lane with code 2 with ``firsts[s]`` as its token,
+    every other lane with the packed host values — the merge is part of
+    this program too.  A synchronous engine passes ``carry=None`` (no
+    previous dispatch is ever in flight) and gets a program without the
+    merge; the math (and therefore greedy output) is bit-identical either
+    way.
 
     Per-slot freeze semantics inside the loop (mirrors
     ``llama_generate_fused``'s masking, so greedy outputs are step-exact
@@ -1292,29 +1343,43 @@ def make_paged_decode_horizon(decode_step, sample_fn=None):
     or its ``remaining`` budget hits zero; frozen slots echo ``eos_ids``
     into ``out``, stop advancing ``lengths``/``remaining``, and carry
     their state through unchanged — including slots frozen at ENTRY via
-    ``done0`` (a lane whose EOS the overlapped host has not yet drained)
-    and inactive slots (``active=False``), whose returned ``done`` is the
-    ``done0`` passthrough so a momentarily stalled lane is never
+    the carried ``done`` (a lane whose EOS the overlapped host has not yet
+    drained) and inactive slots (``active`` 0), whose returned ``done`` is
+    the entry value passed through so a momentarily stalled lane is never
     permanently frozen by one inactive dispatch.  A frozen slot is NOT
     live for ``decode_step``: a family's recurrent state stays as it was.
 
     ``sample_fn`` defaults to :func:`_sample_per_request` (only consulted
     when ``greedy=False``).
 
-    Returns ``horizon(params, toks, lengths, page_tables, cache, active,
-    key, temps, top_ps, remaining, eos_ids, done0, *, K, greedy) ->
-    (out [S, K], toks, lengths, remaining, done, cache)`` — the cache stays
-    the LAST output (the engine's ``_call_paged`` rebind convention)."""
+    Returns ``horizon(params, cache, key, ints, sampling, carry=None, *,
+    K, greedy) -> (out [S, K], toks, lengths, remaining, done, next_key,
+    cache)`` — the cache stays the LAST output and the key the one before
+    it (the engine's ``_call_paged`` rebind convention)."""
     if sample_fn is None:
         sample_fn = _sample_per_request
 
-    def horizon(params, toks, lengths, page_tables, cache, active, key,
-                temps, top_ps, remaining, eos_ids, done0, *, K, greedy):  # graftlint: jit
-        S = toks.shape[0]
+    def horizon(params, cache, key, ints, sampling, carry=None, *, K,
+                greedy):  # graftlint: jit
+        S = sampling.shape[0] // 2
+        temps, top_ps = sampling[:S], sampling[S:]
+        (toks, lengths, remaining, eos_ids, active, carried,
+         page_tables) = _unpack_decode_state(ints, S)
+        if carry is None:
+            done0 = jnp.zeros((S,), jnp.bool_)
+        else:
+            prev_toks, prev_lengths, prev_rem, prev_done, firsts = carry
+            cm = carried == 1
+            toks = jnp.where(cm, prev_toks, toks)
+            lengths = jnp.where(cm, prev_lengths, lengths)
+            remaining = jnp.where(cm, prev_rem, remaining)
+            done0 = cm & prev_done
+            toks = jnp.where(carried == 2, jnp.stack(firsts), toks)
+        next_key, key = split_call_key(key)
         out = jnp.zeros((S, K), jnp.int32)
 
-        def body(t, carry):
-            toks, lengths, rem, cache, done, key, out = carry
+        def body(t, state):
+            toks, lengths, rem, cache, done, key, out = state
             live = ~done
             logits, cache = decode_step(params, toks, lengths, page_tables,
                                         cache, live)
@@ -1335,13 +1400,13 @@ def make_paged_decode_horizon(decode_step, sample_fn=None):
             done = done | ((eos_ids >= 0) & (tok == eos_ids)) | (rem <= 0)
             return (tok, lengths, rem, cache, done, key, out)
 
-        carry = (toks, lengths, remaining, cache, ~active | done0, key, out)
+        state = (toks, lengths, remaining, cache, ~active | done0, key, out)
         toks, lengths, rem, cache, done, key, out = jax.lax.fori_loop(
-            0, K, body, carry)
+            0, K, body, state)
         # inactive lanes pass done0 through untouched: ~active folded into
         # the in-loop freeze must not leak into the carried done state
         done = jnp.where(active, done, done0)
-        return out, toks, lengths, rem, done, cache
+        return out, toks, lengths, rem, done, next_key, cache
 
     return horizon
 

@@ -73,12 +73,68 @@ def placed_on(sharding):
         tree)
 
 
+def step_programs(fam, params, cache, rep, *, slots, table, horizon, chunk,
+                  bucket, packed=True):
+    """{name: (jitted fn, abstract args)}: the three model calls of an
+    engine step as `ServingEngine` jits them (`inference/paged.
+    make_step_calls`: one packed int32 argument a call, the key split
+    inside, the cache — argument 1 — donated).  ``packed=False``: the same
+    calls with every field an ARGUMENT of its own, as the engine launched
+    them before it packed (the horizon's fields are concatenated in the
+    program, which XLA folds against the unpack's slices) — what
+    `tests/test_chip_compile.py` holds the packed forms against."""
+    from paddle_tpu.inference.paged import make_step_calls
+    from paddle_tpu.models.llama import split_call_key
+    S, P = slots, table
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=rep)
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=rep)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)
+    decode, prefill_sample, chunk_fn, _ = make_step_calls(fam, P)
+    names = (f"decode horizon K={horizon}", f"prefill chunk C={chunk}",
+             f"dense prefill T={bucket}")
+    if packed:
+        def decode_horizon(*a):
+            return decode(*a, K=horizon, greedy=True)
+
+        def prefill_chunk(*a):
+            return chunk_fn(*a, C=chunk)
+
+        return dict(zip(names, (
+            (jax.jit(decode_horizon, donate_argnums=(1,)),
+             (params, cache, key, i32((6 + P) * S), f32(2 * S))),
+            (jax.jit(prefill_chunk, donate_argnums=(1,)),
+             (params, cache, i32(3 + P + chunk))),
+            (jax.jit(lambda *a: prefill_sample(*a, greedy=True),
+                     donate_argnums=(1,)),
+             (params, cache, key, i32(4 + P + bucket))))))
+
+    def decode_horizon(params, toks, lengths, tables, cache, active, key,
+                       temps, top_ps, remaining, eos_ids):
+        return decode(params, cache, key, jnp.concatenate([
+            toks, lengths, remaining, eos_ids, active, jnp.zeros_like(toks),
+            tables.ravel()]), jnp.concatenate([temps, top_ps]), K=horizon,
+            greedy=True)
+
+    def dense_prefill(params, ids, true_len, row, slot, cache, key):
+        logits, cache = fam.prefill(params, ids, true_len, row, slot, cache)
+        return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                split_call_key(key)[0], cache)
+
+    return dict(zip(names, (
+        (jax.jit(decode_horizon, donate_argnums=(4,)),
+         (params, i32(S), i32(S), i32(S, P), cache, i32(S), key, f32(S),
+          f32(S), i32(S), i32(S))),
+        (jax.jit(fam.prefill_chunk, donate_argnums=(6,)),
+         (params, i32(1, chunk), i32(), i32(), i32(P), i32(), cache)),
+        (jax.jit(dense_prefill, donate_argnums=(5,)),
+         (params, i32(1, bucket), i32(), i32(P), i32(), cache, key)))))
+
+
 def paged_programs(cfg, sizes, place_params, place_pages, rep, mesh=None,
-                   dtype="bfloat16"):
+                   dtype="bfloat16", packed=True):
     """Lower the engine's executables the way ServingEngine jits them."""
     from paddle_tpu.models.llama import (build_functional_llama,
-                                         build_llama_paged_decode,
-                                         make_paged_decode_horizon)
+                                         build_llama_paged_decode)
     S, P = sizes["num_slots"], sizes["max_pages_per_seq"]
     params = place_params(jax.eval_shape(
         lambda: build_functional_llama(cfg, dtype=dtype)[:3]))
@@ -87,27 +143,14 @@ def paged_programs(cfg, sizes, place_params, place_pages, rep, mesh=None,
                                    attention_impl="pallas", mesh=mesh)
     cache = place_pages(jax.eval_shape(fam.init_cache))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=rep)
-    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=rep)
-    flag = lambda *s: jax.ShapeDtypeStruct(s, jnp.bool_, sharding=rep)
-    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)
-    horizon = make_paged_decode_horizon(fam.decode_step)
-    K = sizes["decode_horizon"]
     bucket = max(t for t in sizes["prompt_lens"]
                  if t <= sizes["prefill_chunk"])
     bucket = -(-bucket // sizes["prompt_bucket"]) * sizes["prompt_bucket"]
     return {
-        f"decode horizon K={K}": (
-            jax.jit(lambda *a: horizon(*a, K=K, greedy=True),
-                    donate_argnums=(4,)),
-            (params, i32(S), i32(S), i32(S, P), cache, flag(S), key, f32(S),
-             f32(S), i32(S), i32(S), flag(S))),
-        f"prefill chunk C={sizes['prefill_chunk']}": (
-            jax.jit(fam.prefill_chunk, donate_argnums=(6,)),
-            (params, i32(1, sizes["prefill_chunk"]), i32(), i32(), i32(P),
-             i32(), cache)),
-        f"dense prefill T={bucket}": (
-            jax.jit(fam.prefill, donate_argnums=(5,)),
-            (params, i32(1, bucket), i32(), i32(P), i32(), cache)),
+        **step_programs(fam, params, cache, rep, slots=S, table=P,
+                        horizon=sizes["decode_horizon"],
+                        chunk=sizes["prefill_chunk"], bucket=bucket,
+                        packed=packed),
         f"verify Q={chip_smoke.VERIFY_Q}": (
             jax.jit(fam.verify_step, donate_argnums=(4,)),
             (params, i32(S, chip_smoke.VERIFY_Q), i32(S), i32(S, P), cache,
@@ -115,11 +158,11 @@ def paged_programs(cfg, sizes, place_params, place_pages, rep, mesh=None,
     }
 
 
-def family_programs(conf, drv, build, place, rep, dtype="bfloat16"):
+def family_programs(conf, drv, build, place, rep, dtype="bfloat16",
+                    packed=True):
     """Lower a family's three executables the way ServingEngine jits them,
     at the sizes of a benchmark configuration file ``conf`` (``drv``: the
     cell's driver module, ``build``: the family's weights from a key)."""
-    from paddle_tpu.models.llama import make_paged_decode_horizon
     cfg, e = drv.model_config(conf), conf["engine"]
     S, P = e["num_slots"], e["max_pages_per_seq"]
     params = place(jax.eval_shape(lambda: build(cfg, dtype=dtype)))
@@ -127,47 +170,32 @@ def family_programs(conf, drv, build, place, rep, dtype="bfloat16"):
                            num_slots=S, max_pages_per_seq=P, dtype=dtype,
                            attention_impl="pallas")
     cache = place(jax.eval_shape(fam.init_cache))
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=rep)
-    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=rep)
-    flag = lambda *s: jax.ShapeDtypeStruct(s, jnp.bool_, sharding=rep)
     key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)
-    horizon = make_paged_decode_horizon(fam.decode_step)
-    K, C = e["decode_horizon"], e["prefill_chunk"]
-
-    def decode_horizon(*a):
-        return horizon(*a, K=K, greedy=True)
-
     return {
         "weights from a seed": (
             jax.jit(lambda k: build(cfg, k, dtype)), (key,)),
-        f"decode horizon K={K}": (
-            jax.jit(decode_horizon, donate_argnums=(4,)),
-            (params, i32(S), i32(S), i32(S, P), cache, flag(S), key, f32(S),
-             f32(S), i32(S), i32(S), flag(S))),
-        f"prefill chunk C={C}": (
-            jax.jit(fam.prefill_chunk, donate_argnums=(6,)),
-            (params, i32(1, C), i32(), i32(), i32(P), i32(), cache)),
-        f"dense prefill T={C}": (
-            jax.jit(fam.prefill, donate_argnums=(5,)),
-            (params, i32(1, C), i32(), i32(P), i32(), cache)),
+        **step_programs(fam, params, cache, rep, slots=S, table=P,
+                        horizon=e["decode_horizon"],
+                        chunk=e["prefill_chunk"], bucket=e["prefill_chunk"],
+                        packed=packed),
     }, cache
 
 
-def hybrid_programs(conf, place, rep, dtype="bfloat16"):
+def hybrid_programs(conf, place, rep, dtype="bfloat16", **kw):
     """`family_programs` of the recurrent family (`models/nemotron_h.py`)."""
     from benchmark.drivers import serve_nemotron_h as drv
     from paddle_tpu.models.nemotron_h import build_functional_nemotron_h
     return family_programs(conf, drv, build_functional_nemotron_h, place,
-                           rep, dtype)
+                           rep, dtype, **kw)
 
 
-def latent_programs(conf, place, rep, dtype="bfloat16"):
+def latent_programs(conf, place, rep, dtype="bfloat16", **kw):
     """`family_programs` of the latent-attention family
     (`models/mla_moe.py`)."""
     from benchmark.drivers import serve_mla_moe as drv
     from paddle_tpu.models.mla_moe import build_functional_mla_moe
     return family_programs(conf, drv, build_functional_mla_moe, place, rep,
-                           dtype)
+                           dtype, **kw)
 
 
 def fit_family(topo, layers, dtype, phase="hybrid"):

@@ -23,6 +23,7 @@ from __future__ import annotations
 import argparse
 import collections
 import json
+import math
 import os
 import re
 import sys
@@ -60,14 +61,47 @@ def _instructions(text):
 
 def signature(text):
     """{"entry": {key: count}, "fused": {key: count}}: key is ``opcode
-    [kind] shape`` with layouts dropped; "fused" holds the instructions
-    inside `%fused_computation*` bodies, "entry" every other one."""
+    [kind] shape`` with layouts dropped (kind: a fusion's, a custom call's
+    target); "fused" holds the instructions inside `%fused_computation*`
+    bodies, "entry" every other one."""
     out = {"entry": collections.Counter(), "fused": collections.Counter()}
+    kinds = {"fusion": r"kind=(k\w+)",
+             "custom-call": r'custom_call_target="(\w+)"'}
     for fused, line, m in _instructions(text):
-        kind = re.search(r"kind=(k\w+)", line) if m["op"] == "fusion" else None
+        kind = re.search(kinds[m["op"]], line) if m["op"] in kinds else None
         out["fused" if fused else "entry"][" ".join(filter(None, (
             m["op"], kind and kind[1], _LAYOUT.sub("", m["shape"]))))] += 1
     return {k: dict(sorted(v.items())) for k, v in out.items()}
+
+
+# opcodes that only route values, and XLA's own prefetches into fast memory
+# (which tensors it stages there moves with any change to a program)
+ROUTING = {"parameter", "get-tuple-element", "tuple", "bitcast", "constant",
+           "reshape", "slice", "copy", "copy-start", "copy-done",
+           "slice-start", "slice-done", "opt-barrier"}
+_SMALL = re.compile(r"\b(?:s32|u32|pred)\[([\d,]*)\]")
+
+
+def work_signature(text, small):
+    """`signature` less what changing how a call's HOST STATE arrives may
+    change: the instructions that only route values (`ROUTING`, and the
+    `ConcatBitcast` that joins a sliced prefetch) and those whose every
+    result is an integer or predicate array of at most ``small`` elements
+    (slices of a packed buffer, the words of a key split).  What is left
+    is the work: two programs that differ only in how their per-call
+    fields arrive agree on it, entry and fused."""
+    def routed(key):
+        op, _, shape = key.partition(" ")
+        if op in ROUTING or key.startswith("custom-call ConcatBitcast "):
+            return True
+        shapes = re.findall(r"\b[a-z]+\d*\[[\d,]*\]", shape)
+        return bool(shapes) and all(
+            (m := _SMALL.fullmatch(one)) is not None
+            and math.prod(int(n) for n in m[1].split(",") if n) <= small
+            for one in shapes)
+
+    return {part: {k: v for k, v in kinds.items() if not routed(k)}
+            for part, kinds in signature(text).items()}
 
 
 def labelled(text):

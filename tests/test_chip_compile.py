@@ -75,6 +75,19 @@ def _fusions_under(region, text):
             if " fusion(" in line and f'pt_region="{region}"' in line]
 
 
+_COMPILED = {}
+
+
+def _compiled(program):
+    """``(jitted fn, abstract args)`` compiled once a run of this file: the
+    programs come from module fixtures, and two cases read each of the
+    serving executables."""
+    fn, args = program
+    if id(fn) not in _COMPILED:
+        _COMPILED[id(fn)] = fn.lower(*args).compile()
+    return _COMPILED[id(fn)]
+
+
 @pytest.mark.parametrize("hq,hkv", [(32, 32), (32, 8)],
                          ids=["mha32", "gqa32x8"])
 @pytest.mark.parametrize("slots,qmax", [(SLOTS, 1), (1, 128), (SLOTS, 5)],
@@ -340,13 +353,7 @@ def _pool_scatters(text, pool_shape):
     return found
 
 
-@pytest.fixture(scope="module")
-def cell_programs(one_chip):
-    """The engine's four paged executables at `serve_chat_c16`'s widths
-    (Mistral-7B: H 4096, 32/8 heads, D 128), slots and pool (16 x 32 pages
-    of 64 tokens: bf16[L,8,513,64,128] a side), lowered as `ServingEngine`
-    jits them, pool donated — by perf/chip_fit.py, which sizes the chip
-    runs with the same programs."""
+def _cell_programs(one_chip, **kw):
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "perf"))
     import chip_fit
     from paddle_tpu.models.llama import LlamaConfig
@@ -357,16 +364,26 @@ def cell_programs(one_chip):
         num_hidden_layers=CELL_LAYERS, max_position_embeddings=32768,
         rope_theta=1e6)
     place = chip_fit.placed_on(one_chip)
-    return chip_fit.paged_programs(cfg, CELL, place, place, one_chip)
+    return chip_fit.paged_programs(cfg, CELL, place, place, one_chip, **kw)
+
+
+@pytest.fixture(scope="module")
+def cell_programs(one_chip):
+    """The engine's four paged executables at `serve_chat_c16`'s widths
+    (Mistral-7B: H 4096, 32/8 heads, D 128), slots and pool (16 x 32 pages
+    of 64 tokens: bf16[L,8,513,64,128] a side), lowered as `ServingEngine`
+    jits them (each call's host state ONE packed argument), pool donated —
+    by perf/chip_fit.py, which sizes the chip runs with the same
+    programs."""
+    return _cell_programs(one_chip)
 
 
 @pytest.mark.parametrize("program", ["decode horizon", "dense prefill",
                                      "prefill chunk", "verify"])
 def test_serving_executable_leaves_the_page_pool_in_place(cell_programs,
                                                           program):
-    (fn, args), = [v for k, v in cell_programs.items()
-                   if k.startswith(program)]
-    compiled = fn.lower(*args).compile()
+    compiled = _compiled(*[v for k, v in cell_programs.items()
+                           if k.startswith(program)])
     text = compiled.as_text()
     if program != "dense prefill":                 # dense attends locally
         # what the benchmark's shape matchers key on: the kernel call has
@@ -467,17 +484,23 @@ def test_tp4_paged_decode_step_compiles_with_kernel_and_allreduce(topo):
 GIB = float(1 << 30)
 
 
-@pytest.fixture(scope="module")
-def hybrid_programs(one_chip):
+def _family_programs(one_chip, family, **kw):
+    """(programs, cache) of ``chip_fit.<family>_programs`` at its benchmark
+    configuration's sizes."""
     root = os.path.join(os.path.dirname(__file__), "..")
     sys.path.insert(0, os.path.join(root, "perf"))
     import chip_fit
     from benchmark.run import load_json
-    conf = load_json(root, "benchmark", "configs",
-                     "nemotron-3-super-serve-1of4.json")
-    programs, cache = chip_fit.hybrid_programs(
-        conf, chip_fit.placed_on(one_chip), one_chip)
-    return programs, cache
+    conf = load_json(root, "benchmark", "configs", {
+        "hybrid": "nemotron-3-super-serve-1of4.json",
+        "latent": "kimi-vl-a3b-serve-1of4.json"}[family])
+    return getattr(chip_fit, family + "_programs")(
+        conf, chip_fit.placed_on(one_chip), one_chip, **kw)
+
+
+@pytest.fixture(scope="module")
+def hybrid_programs(one_chip):
+    return _family_programs(one_chip, "hybrid")
 
 
 @pytest.mark.parametrize("program", ["decode horizon", "dense prefill",
@@ -485,8 +508,8 @@ def hybrid_programs(one_chip):
 def test_hybrid_serving_executable_fits_and_leaves_its_cache_in_place(
         hybrid_programs, program):
     programs, cache = hybrid_programs
-    (fn, args), = [v for k, v in programs.items() if k.startswith(program)]
-    compiled = fn.lower(*args).compile()
+    compiled = _compiled(*[v for k, v in programs.items()
+                           if k.startswith(program)])
     text = compiled.as_text()
     m = compiled.memory_analysis()
     need = (m.argument_size_in_bytes + m.output_size_in_bytes
@@ -598,14 +621,7 @@ def test_latent_page_kernel_compiles_with_its_label(one_chip, slots, qmax,
 
 @pytest.fixture(scope="module")
 def latent_programs(one_chip):
-    root = os.path.join(os.path.dirname(__file__), "..")
-    sys.path.insert(0, os.path.join(root, "perf"))
-    import chip_fit
-    from benchmark.run import load_json
-    conf = load_json(root, "benchmark", "configs",
-                     "kimi-vl-a3b-serve-1of4.json")
-    return chip_fit.latent_programs(conf, chip_fit.placed_on(one_chip),
-                                    one_chip)
+    return _family_programs(one_chip, "latent")
 
 
 @pytest.mark.parametrize("program", ["decode horizon", "dense prefill",
@@ -613,8 +629,8 @@ def latent_programs(one_chip):
 def test_latent_serving_executable_fits_and_leaves_its_cache_in_place(
         latent_programs, program):
     programs, cache = latent_programs
-    (fn, args), = [v for k, v in programs.items() if k.startswith(program)]
-    compiled = fn.lower(*args).compile()
+    compiled = _compiled(*[v for k, v in programs.items()
+                           if k.startswith(program)])
     text = compiled.as_text()
     m = compiled.memory_analysis()
     need = (m.argument_size_in_bytes + m.output_size_in_bytes
@@ -655,6 +671,61 @@ def test_latent_serving_executable_fits_and_leaves_its_cache_in_place(
                       for a in jax.tree_util.tree_leaves(cache))
     assert m.alias_size_in_bytes >= cache_bytes
     assert m.temp_size_in_bytes < 0.5 * GIB, m.temp_size_in_bytes / GIB
+
+
+# ---------------------------------------------------------------------------
+# One packed argument a call (PR 38).  A call's per-call host state arrives
+# as ONE int32 buffer that the program takes apart with static slices, and
+# the engine's key is split inside; the device's work must be what it was
+# when every field was an argument of its own.
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("program", ["decode horizon", "dense prefill",
+                                     "prefill chunk"])
+@pytest.mark.parametrize("cell", ["chat", "hybrid", "latent"])
+def test_a_packed_call_is_the_unpacked_call_but_for_slices_and_the_split(
+        cell, program, request, one_chip):
+    """At the three serve cells' shapes, the packed executable's WORK — the
+    multiset of (opcode, fusion kind or call target, result shape) over
+    every instruction that does not merely route values, entry and fused
+    (`perf/region_fit.work_signature`) — is that of the same call with
+    every field an argument of its own: the unpack leaves slices of the
+    packed buffer and the in-program split a handful of uint32 words, both
+    no larger than the buffer, and nothing else.  (The PARENT's compiled
+    horizon and chunk, from its checkout, agreed with the packed ones in
+    the same comparison: PERF.md section 6, PR 38.)  The regions and
+    kernel labels are on both; that the donated cache stays in place is
+    held above, on these very executables."""
+    if cell == "chat":
+        packed = request.getfixturevalue("cell_programs")
+        apart = _cell_programs(one_chip, packed=False)
+        slots, table = CELL["num_slots"], CELL["max_pages_per_seq"]
+    else:
+        packed, cache = request.getfixturevalue(cell + "_programs")
+        apart, _ = _family_programs(one_chip, cell, packed=False)
+        slots, table = (cache["sel"].shape[1],
+                        {"hybrid": 50, "latent": 146}[cell])
+    import region_fit                    # perf/: the fixtures put it there
+    (name, one), = [(k, v) for k, v in packed.items()
+                    if k.startswith(program)]
+    text = _compiled(one).as_text()
+    fn, args = apart[name]
+    other = fn.lower(*args).compile().as_text()
+    small = (6 + table) * slots          # the horizon's buffer, the largest
+    assert region_fit.work_signature(text, small) \
+        == region_fit.work_signature(other, small)
+    # the whole host state of a call is ONE int32 parameter (the horizon's
+    # is the `small` above; it has the float row beside it): no per-field
+    # parameter, of a slot's width or a table's, is left
+    entry = text[text.index("\nENTRY "):]
+    width, = [n for a in one[1] if getattr(a, "dtype", None) == jnp.int32
+              for n in a.shape]
+    assert width <= small and (program != "decode horizon" or width == small)
+    params = re.findall(r"= (\w+\[[\d,]*\])\S* parameter\(", entry)
+    assert params.count(f"s32[{width}]") == 1
+    assert not {f"s32[{slots}]", f"pred[{slots}]", f"s32[{slots},{table}]",
+                f"s32[{table}]"} & set(params)
+    assert text.count("pt_region") == other.count("pt_region") > 0
+    assert text.count("kernel_metadata") == other.count("kernel_metadata")
 
 
 def test_the_optimizers_update_compiles_to_fusions_under_its_region(one_chip):

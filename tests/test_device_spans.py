@@ -23,7 +23,8 @@ import jax.numpy as jnp
 from paddle_tpu import optimizer, profiler
 from paddle_tpu.models.llama import (build_functional_llama,
                                      llama_config_tiny,
-                                     make_paged_decode_horizon)
+                                     make_paged_decode_horizon,
+                                     pack_decode_state)
 from paddle_tpu.parallel.pipeline import _flatten, _unflatten
 from paddle_tpu.profiler import device_span
 
@@ -219,13 +220,14 @@ def test_a_decode_horizon_gives_the_same_bits_without_the_labels(
               max_pages_per_seq=P, dtype=jnp.float32, attention_impl="ref")
     rng = np.random.default_rng(5)
     args = lambda cache: (
-        params, jnp.asarray(rng.integers(1, 60, (S,)), jnp.int32),
-        jnp.asarray([5, 0, 9], jnp.int32),
-        jnp.arange(S * P, dtype=jnp.int32).reshape(S, P), cache,
-        jnp.asarray([True, False, True]), jax.random.PRNGKey(0),
-        jnp.zeros((S,), jnp.float32), jnp.ones((S,), jnp.float32),
-        jnp.full((S,), 8, jnp.int32), jnp.full((S,), -1, jnp.int32),
-        jnp.zeros((S,), bool))
+        params, cache, jax.random.PRNGKey(0),
+        jnp.asarray(pack_decode_state(
+            toks=rng.integers(1, 60, (S,)), lengths=[5, 0, 9],
+            remaining=np.full((S,), 8), eos_ids=np.full((S,), -1),
+            active=[1, 0, 1], carried=np.zeros((S,)),
+            page_tables=np.arange(S * P).reshape(S, P))),
+        jnp.concatenate([jnp.zeros((S,), jnp.float32),
+                         jnp.ones((S,), jnp.float32)]))
 
     def run():
         fam = cfg.paged_family(**kw)
