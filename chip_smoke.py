@@ -101,6 +101,15 @@ SIZES = {
                        prompt_lens=(100, 300, 520, 77, 1200),
                        max_new_tokens=16, compare_tokens=4,
                        product_tokens=(64, 512)),
+        # --sambay: the SambaY family (Mamba-1 + window / full differential
+        # attention + GMU over ONE K/V store) at the widths of the benchmark
+        # configuration named here, 8 layers (every kind of layer), a few
+        # slots; prompts longer than the window and than two chunks
+        "sambay": dict(config="phi-4-mini-flash-serve-1chip", layers=8,
+                       num_slots=4, page_size=64, max_pages_per_seq=32,
+                       prefill_chunk=640, prompt_bucket=128,
+                       decode_horizon=8, prompt_lens=(100, 600, 1300, 460),
+                       max_new_tokens=72, compare_tokens=4),
     },
 }
 VERIFY_Q = 5            # speculative verify segment: K+1 at the engine's K=4
@@ -591,6 +600,119 @@ def latent_phase(cfg, sizes, *, attention_impl="auto", interpret=False,
     return {**facts, "vs_ref_engine": versus}
 
 
+def sambay_config(name, layers):
+    """(SambaYConfig cut to ``layers``, the configuration file) of a
+    benchmark configuration of the SambaY family."""
+    from benchmark.drivers import serve_sambay as drv
+    from benchmark.run import load_json
+    conf = load_json(os.path.dirname(os.path.abspath(__file__)),
+                     "benchmark", "configs", name + ".json")
+    return drv.model_config(conf, num_hidden_layers=layers), \
+        {**conf, "num_hidden_layers": layers}
+
+
+def sambay_phase(cfg, conf, sizes, *, attention_impl="auto", interpret=False,
+                 dtype="bfloat16", seed=0, report=emit, limits=None):
+    """The SambaY family through the same engine: dense prefill, prefill
+    chunks (window, state and tail carried; the last alone enters the second
+    half) and the decode horizon all run, past a wrap of the window's ring;
+    every request's tokens, SSM states and window rows are held to the plain
+    reference's full forward (`benchmark/reference_sambay.py`, float32), and
+    the kernel engine's greedy tokens agree with the plain-attention
+    engine's."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from benchmark import reference_sambay as reference
+    from paddle_tpu.models.sambay import build_functional_sambay
+    limits = limits or {"logit": reference.SERVE_LOGIT_DELTA,
+                        "state": reference.SERVE_STATE_RTOL,
+                        "window": reference.SERVE_WINDOW_RTOL}
+    params = jax.block_until_ready(jax.jit(
+        lambda k: build_functional_sambay(cfg, k, jnp.dtype(dtype)))(
+            jax.random.PRNGKey(seed)))
+    prompts = make_prompts(cfg, sizes["prompt_lens"], seed + 1)
+    chunk, W = sizes["prefill_chunk"], cfg.sliding_window
+    check(any(len(p) > 2 * chunk for p in prompts)
+          and any(W < len(p) <= chunk for p in prompts),
+          "prompts must straddle the prefill chunk, one over two chunks, "
+          "and a dense prefill must be longer than the window")
+    from paddle_tpu.inference.paged import ServingEngine
+    eng = ServingEngine(
+        params, cfg, num_slots=sizes["num_slots"],
+        page_size=sizes["page_size"],
+        max_pages_per_seq=sizes["max_pages_per_seq"],
+        dtype=params[0]["tok"].dtype, attention_impl=attention_impl,
+        interpret=interpret, prompt_bucket=sizes["prompt_bucket"],
+        decode_horizon=sizes["decode_horizon"], prefill_chunk=chunk)
+    rids = [eng.submit(p, max_new_tokens=sizes["max_new_tokens"])
+            for p in prompts]
+    states = {}
+    while not all(eng.lookup(r).finish_time for r in rids):
+        eng.step()
+        for r in rids:          # the slot keeps its state until it is reused
+            if r not in states and eng.lookup(r).finish_time:
+                states[r] = eng.recurrent_state(r)
+    eng.check_invariants()
+    outs = [[int(t) for t in eng.lookup(r).generated] for r in rids]
+    st = eng.stats()
+    ran = st["jit_cache_misses"]
+    check(ran.get("prefill", 0) > 0 and ran.get("prefill_chunk", 0) > 1
+          and ran.get("decode_step", 0) > 0,
+          f"dense prefill, both chunk executables and decode must run: {ran}")
+    check(st["prefill_tokens_cross_decoder"] == len(prompts),
+          f"{st['prefill_tokens_cross_decoder']} tokens entered the second "
+          f"half of the model for {len(prompts)} prompts")
+    gaps, state_err, window_err = [], [], []
+    pad = max(len(p) for p in prompts) + sizes["max_new_tokens"]
+    for p, o, r in zip(prompts, outs, rids):
+        want = reference.check_generation(params, conf, p, o, pad_to=pad)
+        gaps += want["gaps"]
+        state_err += reference.relative_errors(list(states[r]["ssm"]),
+                                               want["states"])
+        rows = want["window_positions"] % W
+        for j, kv in enumerate(want["window"]):
+            window_err += reference.relative_errors(
+                [states[r]["window_k"][j][rows],
+                 states[r]["window_v"][j][rows]], kv)
+    compiled = eng.decode_horizon_compiled()
+    facts = {
+        "family": eng.family.name, "depth": cfg.num_hidden_layers,
+        "parameters": count_params(params), "dtype": str(jnp.dtype(dtype)),
+        "prompt_lens": [int(len(p)) for p in prompts],
+        "executables": ran, "tokens_generated": st["tokens_generated"],
+        "compared": "every request's greedy tokens under the float32 "
+                    "reference's logits, its SSM states and window rows",
+        "worst_logit_gap": max(gaps), "logit_delta": limits["logit"],
+        "worst_state_error": max(state_err), "state_rtol": limits["state"],
+        "worst_window_error": max(window_err),
+        "window_rtol": limits["window"],
+        "shared_kv_tokens_attended_decode":
+            st["shared_kv_tokens_attended_decode"],
+        "prefill_tokens_cross_decoder": st["prefill_tokens_cross_decoder"],
+        "decode_has_tpu_custom_call": "tpu_custom_call" in compiled.as_text(),
+        "decode_executable_bytes": executable_bytes(compiled),
+        "peak_bytes_in_use": peak_bytes(jax.devices()[:1])[0]}
+    report("sambay", **facts)
+    check(facts["worst_logit_gap"] <= limits["logit"]
+          and facts["worst_state_error"] <= limits["state"]
+          and facts["worst_window_error"] <= limits["window"],
+          f"the engine against the reference: {facts}")
+    del eng, compiled
+    gc.collect()
+    n_cmp = sizes["compare_tokens"]
+    ref_outs, _, ref_eng = run_engine(params, cfg, sizes, prompts, n_cmp,
+                                      attention_impl="ref")
+    del ref_eng
+    versus = {"compared": f"first {n_cmp} greedy tokens of every request, "
+                          f"attention_impl={attention_impl!r} vs 'ref'",
+              **token_agreement([o[:n_cmp] for o in outs], ref_outs)}
+    report("sambay_vs_ref", **versus)
+    check(versus["agreement"] >= KERNEL_VS_REF_FLOOR,
+          f"kernel engine vs ref engine: {versus}")
+    return {**facts, "vs_ref_engine": versus}
+
+
 # ---------------------------------------------------------------------------
 # train: the donated jitted train step
 # ---------------------------------------------------------------------------
@@ -804,6 +926,10 @@ def main(argv=None):
                     help="run ONLY the latent-attention family: its kernel "
                          "against the plain form, the SwiGLU experts' "
                          "grouped product, its engine (one chip)")
+    ap.add_argument("--sambay", action="store_true",
+                    help="run ONLY the SambaY family's engine (Mamba-1 + "
+                         "differential attention + GMU over one K/V store) "
+                         "against the plain reference (one chip)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
@@ -850,6 +976,12 @@ def main(argv=None):
         latent = latent_phase(cfg, la, seed=args.seed)
         check(latent["decode_has_tpu_custom_call"],
               "no tpu_custom_call in the latent decode executable")
+    elif args.sambay:
+        sa = sizes["sambay"]
+        got = sambay_phase(*sambay_config(sa["config"], sa["layers"]), sa,
+                           seed=args.seed)
+        check(got["decode_has_tpu_custom_call"],
+              "no tpu_custom_call in the sambay decode executable")
     else:
         sv = sizes["serve"]
         cfg = cut_config(sv["layers"])

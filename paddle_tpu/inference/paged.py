@@ -838,10 +838,13 @@ def make_step_calls(family, max_pages_per_seq):
                 ints[2:4], jnp.float32))
         return tok, key, cache
 
-    def prefill_chunk(params, cache, ints, *, C):     # graftlint: jit
+    def prefill_chunk(params, cache, ints, *, C, **last):  # graftlint: jit
+        # ``last=`` only from an engine whose family takes it
+        # (`PagedFamily.chunk_takes_last`): static, like C
         n = ints.shape[0]
         return family.prefill_chunk(params, ints[n - C:][None], ints[0],
-                                    ints[1], ints[3:n - C], ints[2], cache)
+                                    ints[1], ints[3:n - C], ints[2], cache,
+                                    **last)
 
     # single-logits NUCLEUS sampler for the final chunk of a chunked /
     # suffix prefill and a verify's sampled lanes (the chunk executable
@@ -1135,7 +1138,7 @@ class ServingEngine:
         # static chunk width tells the ids from the page-table slice
         self._chunk_jit = self._jit("prefill_chunk", prefill_chunk_fn,
                                     donate_argnums=(1,),
-                                    static_argnames=("C",))
+                                    static_argnames=("C", "last"))
         self._sample_jit = None        # lazily jitted nucleus sampler
         self._copy_jit = self._jit("page_copy", _copy_page,
                                    donate_argnums=(0,))
@@ -1871,7 +1874,10 @@ class ServingEngine:
                     with self._span("prefill_dense", rid=req.rid, pos=0,
                                     tokens=T, padded=Tb, pages=kv_pages,
                                     family=self.family.name,
-                                    attention=self.family.attention_path):
+                                    attention=self.family.attention_path,
+                                    **({"cross_decoder": 1}
+                                       if self.family.chunk_takes_last
+                                       else {})):
                         self.step_launches += 1
                         tok, self._key, self._cache = self._call_paged(
                             pf, self.params, self._cache, self._key,
@@ -1976,10 +1982,15 @@ class ServingEngine:
         ids = np.zeros((Cb,), np.int32)
         ids[:c] = slot.ctx[pos:pos + c]
         kv_pages = self._count_prefill(pos, c, Cb)
+        # a family whose chunk executable depends on it is told (a static
+        # argument) whether this chunk is its prompt's last
+        takes_last, is_last = self.family.chunk_takes_last, pos + c >= T
         with self._span("prefill_chunk", rid=req.rid, pos=pos, tokens=c,
                         padded=Cb, pages=kv_pages, family=self.family.name,
                         attention=self.family.attention_path,
-                        state_carried=1 if self.family.recurrent and pos else 0):
+                        state_carried=1 if self.family.recurrent and pos else 0,
+                        **({"cross_decoder": 1 if is_last else 0}
+                           if takes_last else {})):
             self.step_launches += 1
             logits, tok_g, self._cache = self._call_paged(
                 self._chunk_jit, self.params, self._cache,
@@ -1987,7 +1998,8 @@ class ServingEngine:
                 # host table — an async in-flight chunk must not see later
                 # host-side table growth (a CPU upload can alias)
                 self._upload(pack_chunk(ids, pos, c, s,
-                                        self._page_tables[s, :Pb])), C=Cb)
+                                        self._page_tables[s, :Pb])), C=Cb,
+                **({"last": is_last} if takes_last else {}))
         slot.chunk_step = self._step_seq
         pos += c
         slot.prefill_pos = pos

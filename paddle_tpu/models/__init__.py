@@ -10,7 +10,11 @@ the K/V pages, so no prefix cache, and ``speculative``, ``quantize``,
 are refused) and ``mla_moe`` (`MlaMoeConfig`: latent attention +
 sigmoid-routed SwiGLU experts; ONE store of compressed rows, every layer's
 state pages, so the prefix cache and the page transfers work; ``speculative``,
-``quantize``, ``kv_dtype``, ``mesh`` are refused).  AFMoE runs through the
+``quantize``, ``kv_dtype``, ``mesh`` are refused) and ``sambay``
+(`models.sambay.SambaYConfig`, imported from its module: Mamba-1 + window and
+full differential attention + Gated Memory Units; ONE K/V page store that
+eight layers read, a window ring and a per-channel state a slot; refuses what
+``nemotron_h`` refuses).  AFMoE runs through the
 compiled train step only: its window layers need a page table a layer kind in
 the cache (ROADMAP B4)."""
 from . import llama  # noqa: F401

@@ -3,7 +3,7 @@ between the engine and the model fns it serves.
 
 A configuration object says which family it belongs to by its
 ``paged_family(**build_kw)`` method (`LlamaConfig`, `NemotronHConfig`,
-`MlaMoeConfig`); the engine calls it and never looks at a model's name again.
+`MlaMoeConfig`, `SambaYConfig`); the engine calls it and never looks at a model's name again.
 Every fn takes and returns the WHOLE cache as one pytree, which the engine
 donates, carries through the decode horizon and rebinds:
 
@@ -62,6 +62,11 @@ class PagedFamily:
     # width is a shape: every width is an executable of its own); 0: the
     # whole table, for fns whose cost does not follow the table's width
     chunk_table_granule: int = 4
+    # ``prefill_chunk`` takes a further STATIC keyword ``last``: whether the
+    # chunk is its prompt's last.  For a family whose layers past some depth
+    # run for a prompt's last token alone (`models/sambay.py`): a chunk that
+    # is not the last is an executable without them and returns no logits
+    chunk_takes_last: bool = False
     # which form of attention the fns take, for the engine's spans
     attention_path: str = "paged_kv"
     # (cache, slot) -> {name: host array}: what the cache holds of the slot

@@ -293,7 +293,8 @@ def _choose_heads(rows_pad, hkv, d, page_size, q_bytes, out_bytes, kv_bytes,
 def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start, q_len,
                            kv_len, sm_scale=None, interpret=False,
                            out_dtype=None, k_scales=None, v_scales=None,
-                           *, role=None, layer=None, _heads=None):
+                           *, role=None, kind=None, layer=None,
+                           _heads=None):
     """Ragged-segment paged attention over each slot's page list.
 
     q [S, Qmax, Hq, D], page_table [S, P] int32 (entries past a slot's
@@ -352,6 +353,10 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start, q_len,
     call's HLO text, which is the name of its event on a device trace's
     "XLA Ops" line, carries ``kernel_metadata={"kernel":
     "ragged_paged_attention","role":...}``.
+
+    kind: a second LABEL, after the role, for a model whose attention
+    layers are of several kinds over stores of their own ("window" | "full"
+    | "cross": `models/sambay.py`); None leaves the metadata as it was.
 
     _heads: Hb forced — for the tests that hold every blocking bit-equal
     and for perf/ragged_kernel_probe.py; callers leave it alone.
@@ -459,7 +464,8 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start, q_len,
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         metadata={"kernel": "ragged_paged_attention",
-                  **({"role": role} if role else {})},
+                  **({"role": role} if role else {}),
+                  **({"kind": kind} if kind else {})},
     )(page_table.astype(jnp.int32), q_start.astype(jnp.int32),
       q_len.astype(jnp.int32), kv_len.astype(jnp.int32),
       jnp.reshape(layer, (1,)).astype(jnp.int32), *inputs)

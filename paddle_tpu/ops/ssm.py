@@ -18,6 +18,18 @@ under its own region (`profiler.device_span`: ``ssm.chunked_scan``, the
 loop between chunks included, and ``ssm.decode_update``), which is how a
 device trace finds them: XLA's fusions of plain ``jax.numpy`` have no other
 name.
+
+Mamba-1's recurrence (`selective_scan`, `selective_update`) is another rule:
+
+  s_t[d, n] = exp(dt_t[d] A[d, n]) s_{t-1}[d, n] + dt_t[d] u_t[d] B_t[n],
+  y_t[d] = sum_n s_t[d, n] C_t[n]
+
+a decay a CHANNEL and state index (no heads, no groups), which the chunked
+form above cannot express (its decay is a scalar a head, so a chunk's
+[Q, Q] mask is shared by a head's channels; here it would be one a channel
+and state index).  The state is held ``[N, D]`` — the N state indices major,
+the D channels on the lanes: N = 16 as a minor dimension would be padded to
+a lane tile of 128, eight times the bytes in memory and in every pass.
 """
 from __future__ import annotations
 
@@ -26,7 +38,8 @@ import jax.numpy as jnp
 
 from ..profiler import device_span
 
-__all__ = ["ssd_chunked_scan", "ssm_decode_update"]
+__all__ = ["ssd_chunked_scan", "ssm_decode_update", "selective_scan",
+           "selective_update"]
 
 
 @device_span("ssm.chunked_scan")
@@ -103,3 +116,53 @@ def ssm_decode_update(h, x, dt, a, b, c):
         + (dt[:, :, None] * x.astype(f32))[..., None] * bh[:, :, None, :]
     y = (new * ch[:, :, None, :]).sum(-1)
     return y.astype(x.dtype), new.astype(h.dtype)
+
+
+SCAN_BLOCK = 8      # tokens a loop step of `selective_scan`: a sublane tile
+
+
+@device_span("ssm.selective_scan")
+def selective_scan(u, dt, a, b, c, s0):
+    """A run of T tokens of ONE sequence.  u, dt [T, D] (dt after softplus;
+    0 = the token is padding), a [D, N] (negative), b / c [T, N], s0 [N, D]
+    float32 -> (y [T, D] float32, s after the last token [N, D] float32).
+    Sequential over tokens, ``SCAN_BLOCK`` of them a loop step (their rows
+    are read and y's written a whole sublane tile at a time); the arithmetic
+    is float32 whatever the inputs are."""
+    t, d = u.shape
+    n = a.shape[1]
+    f32 = jnp.float32
+    pad = -t % SCAN_BLOCK
+    u, dt, b, c = (jnp.pad(v.astype(f32), ((0, pad), (0, 0)))
+                   for v in (u, dt, b, c))
+    nb = (t + pad) // SCAN_BLOCK
+    at = a.astype(f32).T                                     # [N, D]
+    blocks = (dt.reshape(nb, SCAN_BLOCK, d),
+              (dt * u).reshape(nb, SCAN_BLOCK, d),
+              b.reshape(nb, SCAN_BLOCK, n), c.reshape(nb, SCAN_BLOCK, n))
+
+    def step(s, blk):
+        dt_b, dtu_b, b_b, c_b = blk
+        ys = []
+        for i in range(SCAN_BLOCK):
+            s = jnp.exp(dt_b[i][None, :] * at) * s \
+                + b_b[i][:, None] * dtu_b[i][None, :]
+            ys.append((s * c_b[i][:, None]).sum(0))
+        return s, jnp.stack(ys)
+
+    s, y = jax.lax.scan(step, s0.astype(f32), blocks)
+    return y.reshape(nb * SCAN_BLOCK, d)[:t], s
+
+
+@device_span("ssm.selective_update")
+def selective_update(s, u, dt, a, b, c):
+    """One token a slot.  s [S, N, D] (its own dtype, float32 as served),
+    u, dt [S, D] (dt 0 = leave the slot's state as it was), a [D, N],
+    b / c [S, N] -> (y [S, D] float32, s' in s's dtype): the state read and
+    written once.  The arithmetic is float32."""
+    f32 = jnp.float32
+    dt, u = dt.astype(f32), u.astype(f32)
+    new = jnp.exp(dt[:, None, :] * a.astype(f32).T[None]) * s.astype(f32) \
+        + b.astype(f32)[:, :, None] * (dt * u)[:, None, :]
+    y = (new * c.astype(f32)[:, :, None]).sum(1)
+    return y, new.astype(s.dtype)

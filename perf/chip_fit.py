@@ -16,14 +16,16 @@ chip time.  A compile that passes is not a chip run.
     JAX_PLATFORMS=cpu python perf/chip_fit.py serve:14 train:3 tp:8 one:4:float32:highest
     JAX_PLATFORMS=cpu python perf/chip_fit.py hybrid:0      # serve_reason_c64
     JAX_PLATFORMS=cpu python perf/chip_fit.py latent:0      # serve_longdoc_c64
+    JAX_PLATFORMS=cpu python perf/chip_fit.py sambay:0      # serve_longreason_c64
     JAX_PLATFORMS=cpu python perf/chip_fit.py --dump <dir> hybrid:0
 
 Arguments are ``phase:layers[:dtype[:matmul_precision]]`` overrides (phases:
 serve, train, and tp / one — the --multichip engines on four devices / one;
 ``hybrid`` — the recurrent family's three executables at the benchmark
 configuration ``nemotron-3-super-serve-1of4``, ``latent`` — the
-latent-attention family's at ``kimi-vl-a3b-serve-1of4``; their depth is the
-file's: the layers argument is ignored).  ``--dump <dir>`` writes every compiled
+latent-attention family's at ``kimi-vl-a3b-serve-1of4``, ``sambay`` —
+`models/sambay.py`'s at ``phi-4-mini-flash-serve-1chip``, with the chunk that
+is not a prompt's last as a fourth program; their depth is the file's: the layers argument is ignored).  ``--dump <dir>`` writes every compiled
 program's text there: a device trace's event names ARE those instructions.
 """
 from __future__ import annotations
@@ -198,11 +200,39 @@ def latent_programs(conf, place, rep, dtype="bfloat16", **kw):
                            dtype, **kw)
 
 
+def sambay_programs(conf, place, rep, dtype="bfloat16", **kw):
+    """`family_programs` of the SambaY family (`models/sambay.py`), and the
+    chunk executable of a chunk that is NOT its prompt's last (the first
+    half of the layers and the K/V layer alone)."""
+    from benchmark.drivers import serve_sambay as drv
+    from paddle_tpu.inference.paged import make_step_calls
+    from paddle_tpu.models.sambay import build_functional_sambay
+    programs, cache = family_programs(conf, drv, build_functional_sambay,
+                                      place, rep, dtype, **kw)
+    e = conf["engine"]
+    name = f"prefill chunk C={e['prefill_chunk']}"
+    fn, args = programs[name]
+    fam = drv.model_config(conf).paged_family(
+        page_size=e["page_size"],
+        num_pages=e["num_slots"] * e["max_pages_per_seq"],
+        num_slots=e["num_slots"], max_pages_per_seq=e["max_pages_per_seq"],
+        dtype=dtype, attention_impl="pallas")
+    chunk_fn = make_step_calls(fam, e["max_pages_per_seq"])[2]
+
+    def prefill_chunk(*a):
+        return chunk_fn(*a, C=e["prefill_chunk"], last=False)
+
+    programs[name + " not last"] = (
+        jax.jit(prefill_chunk, donate_argnums=(1,)), args)
+    return programs, cache
+
+
 def fit_family(topo, layers, dtype, phase="hybrid"):
     from benchmark import run as bench_run
     name, programs_of = {
         "hybrid": ("nemotron-3-super-serve-1of4", hybrid_programs),
-        "latent": ("kimi-vl-a3b-serve-1of4", latent_programs)}[phase]
+        "latent": ("kimi-vl-a3b-serve-1of4", latent_programs),
+        "sambay": ("phi-4-mini-flash-serve-1chip", sambay_programs)}[phase]
     root = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
     conf = bench_run.load_json(root, "benchmark", "configs", name + ".json")
     one = SingleDeviceSharding(topo.devices[0])
@@ -285,6 +315,7 @@ def main(argv):
     fits = {"serve": fit_one_chip, "train": fit_train, "tp": fit_tp,
             "hybrid": fit_family,
             "latent": lambda *a: fit_family(*a, phase="latent"),
+            "sambay": lambda *a: fit_family(*a, phase="sambay"),
             "one": lambda *a: fit_one_chip(*a, phase="one")}
     for item in todo:
         phase, layers, dtype, precision = (item.split(":") + ["", ""])[:4]

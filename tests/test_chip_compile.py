@@ -493,7 +493,8 @@ def _family_programs(one_chip, family, **kw):
     from benchmark.run import load_json
     conf = load_json(root, "benchmark", "configs", {
         "hybrid": "nemotron-3-super-serve-1of4.json",
-        "latent": "kimi-vl-a3b-serve-1of4.json"}[family])
+        "latent": "kimi-vl-a3b-serve-1of4.json",
+        "sambay": "phi-4-mini-flash-serve-1chip.json"}[family])
     return getattr(chip_fit, family + "_programs")(
         conf, chip_fit.placed_on(one_chip), one_chip, **kw)
 
@@ -671,6 +672,87 @@ def test_latent_serving_executable_fits_and_leaves_its_cache_in_place(
                       for a in jax.tree_util.tree_leaves(cache))
     assert m.alias_size_in_bytes >= cache_bytes
     assert m.temp_size_in_bytes < 0.5 * GIB, m.temp_size_in_bytes / GIB
+
+
+@pytest.fixture(scope="module")
+def sambay_programs(one_chip):
+    return _family_programs(one_chip, "sambay")
+
+
+SAMBAY_KINDS = {          # kernel calls by (role, kind) an executable
+    "decode horizon": {("decode", "window"): 8, ("decode", "full"): 1,
+                       ("decode", "cross"): 7},
+    "dense prefill": {("chunk", "full"): 1, ("chunk", "cross"): 7},
+    "prefill chunk C=1024": {("chunk", "full"): 1, ("chunk", "cross"): 7},
+    "prefill chunk C=1024 not last": {}}
+
+
+@pytest.mark.parametrize("program", list(SAMBAY_KINDS))
+def test_sambay_serving_executable_fits_and_leaves_its_cache_in_place(
+        sambay_programs, program):
+    """`serve_longreason_c64`'s four executables at the published widths,
+    32 layers, 64 slots x 146 pages: what fits, which kernel calls each
+    holds (by role and KIND of layer), the regions the per-layer metrics
+    read, and the three kinds of state left in place."""
+    programs, cache = sambay_programs
+    compiled = _compiled(programs[[k for k in programs
+                                   if k.startswith(program)][0]]
+                         if program != "prefill chunk C=1024"
+                         else programs[program])
+    text = compiled.as_text()
+    m = compiled.memory_analysis()
+    need = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    # 7.7 GB of weights, 3.06 GB of the one store, 1.35 GB of window rings,
+    # 0.21 GB of recurrent state (15.75 GiB a chip); a chunk that is not
+    # its prompt's last takes the first half's weights only
+    low, high = (8.5, 9.5) if program.endswith("not last") else (11.0, 12.5)
+    assert low * GIB < need < high * GIB, need / GIB
+    flat = "".join(text.split())
+    for (role, kind), n in SAMBAY_KINDS[program].items():
+        # the compiled text sorts the label's keys
+        label = ('kernel_metadata={"kernel":"ragged_paged_attention",'
+                 '"kind":"%s","role":"%s"}' % (kind, role))
+        assert flat.count(label) == n, (label, flat.count(label))
+    assert flat.count('"kernel":"ragged_paged_attention"') \
+        == sum(SAMBAY_KINDS[program].values())
+    prefill = program != "decode horizon"
+    last = not program.endswith("not last")
+    regions = {"mamba.proj", "attn.window", "attn.shared_kv", "block.mlp",
+               "ssm.selective_scan" if prefill else "ssm.selective_update"}
+    if last:
+        regions |= {"gmu", "head"}
+    for region in regions:
+        assert f'pt_region="{region}"' in text, region
+    assert ('pt_region="gmu"' in text) == last
+    assert ('pt_region="ssm.selective_update"' in text) == (not prefill)
+    # nothing copies the one store, the rings, a layer of the rings or a
+    # Mamba layer's state
+    pool = math.prod(cache["k"].shape)
+    rings = math.prod(cache["win_k"].shape)
+    sizes = {pool, rings, rings // cache["win_k"].shape[0]}
+    copies = [(op, f"{dtype}[{dims}]")
+              for dtype, dims, _, op in _INSTR.findall(text)
+              if op in ("copy", "copy-start") and dtype == "bf16"
+              and math.prod(int(n) for n in dims.split(",") if n) in sizes]
+    assert not copies, copies
+    for leaf in ("k", "win_k"):
+        layouts = {layout for _, dims, layout, _ in _INSTR.findall(text)
+                   if dims == ",".join(map(str, cache[leaf].shape))}
+        assert layouts <= {"4,3,2,1,0"}, (leaf, layouts)
+    # every leaf of the donated cache is aliased to the output
+    cache_bytes = sum(math.prod(a.shape) * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(cache))
+    assert m.alias_size_in_bytes >= cache_bytes
+    assert m.temp_size_in_bytes < 0.5 * GIB, m.temp_size_in_bytes / GIB
+    if program == "decode horizon":
+        # one fusion a Mamba layer updates the state in place and reads y
+        # out of it (two results), as the other recurrent family's does
+        state = "f32[%s]" % ",".join(map(str, cache["ssm"][0].shape))
+        both = [line for line in _fusions_under("ssm.selective_update", text)
+                if re.search(r"= \(f32\[64,5120\]\S*, %s" % re.escape(state),
+                             line)]
+        assert len(both) == len(cache["ssm"]), len(both)
 
 
 # ---------------------------------------------------------------------------

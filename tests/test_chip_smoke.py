@@ -274,3 +274,49 @@ def test_latent_sizes_name_a_benchmark_configuration_at_published_widths():
             la["prompt_lens"]) + la["max_new_tokens"]
         assert min(la["prompt_lens"]) <= la["prefill_chunk"] \
             and 2 * la["prefill_chunk"] < max(la["prompt_lens"])
+
+
+def test_sambay_phase_interpret():
+    """`chip_smoke.py --sambay`: the family's engine (dense prefill, both
+    chunk executables, the horizon past a wrap of the window's ring) held to
+    the plain reference's logits, states and window rows, and the kernel
+    engine's tokens those of the plain engine."""
+    from paddle_tpu.models.sambay import sambay_config_tiny
+    cfg = sambay_config_tiny(vocab_size=96)
+    conf = {"layer_norm_eps": cfg.layer_norm_eps, "num_hidden_layers": 8,
+            "num_attention_heads": 8, "num_key_value_heads": 4,
+            "sliding_window": 16}
+    seen = {}
+    sizes = dict(SERVE, page_size=8, prompt_lens=(5, 20, 12, 60),
+                 prefill_chunk=24, max_pages_per_seq=12, max_new_tokens=20)
+    out = chip_smoke.sambay_phase(
+        cfg, conf, sizes, attention_impl="pallas", interpret=True,
+        dtype="float32", limits=dict(logit=1e-4, state=1e-4, window=1e-4),
+        report=lambda phase, **facts: seen.update({phase: facts}))
+    assert set(seen) == {"sambay", "sambay_vs_ref"}
+    assert out["family"] == "sambay" and out["depth"] == 8
+    assert out["prefill_tokens_cross_decoder"] == 4
+    assert out["executables"]["prefill_chunk"] >= 2
+    assert out["vs_ref_engine"]["tokens_equal"]
+    json.dumps(seen)
+
+
+def test_sambay_sizes_name_a_benchmark_configuration_at_published_widths():
+    for kind, sizes in chip_smoke.SIZES.items():
+        sa = sizes["sambay"]
+        cfg, conf = chip_smoke.sambay_config(sa["config"], sa["layers"])
+        assert (cfg.hidden_size, cfg.num_attention_heads,
+                cfg.num_key_value_heads, cfg.intermediate_size,
+                cfg.vocab_size, cfg.sliding_window, cfg.d_inner,
+                cfg.mamba_d_state, cfg.num_hidden_layers) == (
+                    2560, 40, 20, 10240, 200064, 512, 5120, 16,
+                    sa["layers"]) and conf["num_hidden_layers"] == 8, kind
+        assert sa["page_size"] * sa["max_pages_per_seq"] >= max(
+            sa["prompt_lens"]) + sa["max_new_tokens"]
+        assert any(cfg.sliding_window < t <= sa["prefill_chunk"]
+                   for t in sa["prompt_lens"])
+        assert max(sa["prompt_lens"]) > 2 * sa["prefill_chunk"]
+        # a request's decode passes a multiple of the window: the ring wraps
+        assert any(t // cfg.sliding_window
+                   != (t + sa["max_new_tokens"]) // cfg.sliding_window
+                   for t in sa["prompt_lens"])
