@@ -54,12 +54,40 @@ the model's layer loop never slices a layer out of the pool (a slice is a
 copy of that layer, every layer, every step).  GQA is native: the q block of
 a step is, per kv head, the R rows (every query of the segment x the
 `Hq // Hkv` heads sharing that kv head), and K/V pages are fetched once per
-kv head, never materialized per q head.  Measured on the chip (PR 30,
-perf/ragged_kernel_probe.py, PERF.md section 5): at the chat cell's
-contexts the decode call reads its live K/V at 24 % of the HBM roofline
-(4.2 % when the grid was (S, Hkv, P), one 16 KiB tile a step and a step per
-table column, dead or live); what is left is the f32 products of an 8-row
-block (4 real rows at GQA 32/8) against each [64, 128] page.
+kv head, never materialized per q head.
+
+An update — one (kv head, page) of a step's loop — is three MXU products,
+and their operands are the page tiles in the dtype they were copied in
+(PR 40): where the queries and the pages share a 2-byte dtype (bf16 in every
+serving cell) the scores are ONE product of the bf16 [R, D] query block and
+the bf16 [ps, D] K tile accumulated in f32 (a product of two bf16 values is
+exact in f32), and the values TWO products against the bf16 V tile, of the
+probabilities' two bf16 pieces (`_weighted_values`: p = p_hi + p_lo to
+2^-17), added in f32.  No tile and no query block is widened to f32; mask,
+max, exp, the row sums, m, l, the accumulator and the finalizer are f32.
+Any other pairing of dtypes — f32 pages, a query wider than its pages, the
+int8/fp8 body — runs its two products on f32 operands, the program it was
+before (tests/test_pallas_kernels.py holds its jaxpr to the parent's text);
+on the chip Mosaic runs such a product as ONE bf16 pass (its default
+precision), so there the f32 path rounds p to 2^-9 and the 2-byte path is
+the more exact one.  The rule is the operands' dtypes at trace time;
+nothing selects it.  Measured on the chip (perf/shared_kv_probe.py,
+perf/ragged_kernel_probe.py; PERF.md sections 5 and 6, PR 40), parent ->
+this form: the one K/V store of `serve_longreason_c64` (64 slots, ~4.3 k
+live tokens, ten 128-wide rows, page 128) 3.12 -> 2.22 ms a call, 1.44 ->
+1.02 us a loop step, 55 -> 77 % of the HBM roofline (50 % at a page of 64,
+89 % at 256); the chat cell's decode call 0.111 -> 0.072 ms, every slot at
+2,048 tokens 0.573 -> 0.367 (0.239 with the math emptied); the 512-query
+chunk 0.636 -> 0.652 (its second value product is real MXU time).  What sets
+the pace is not the operands' width — one bf16 piece of p, or the two
+stacked into one [2R, ps] operand, run as slowly as the f32 products did —
+but how many products an update has: two run 1.44 us a step, three 1.02,
+four 2.77.  Read with the compiled body (every K tile is latched
+TRANSPOSED, `xpose.packed.bf16`): a score product holds its MXU ~260 cycles
+whatever the page's rows, and with three products a head the ten heads'
+score products fall on all four MXUs in turn where with two they fall on
+two of them.  What is left is that latch: K tiles stored transposed, or K
+streamed against a resident q (ROADMAP A17).
 
 A page past a slot's `kv_len` costs nothing: no grid step, no copy, and its
 table entry is never read (so the cache manager may leave anything there);
@@ -92,9 +120,12 @@ _SUBLANES = 8      # f32 sublane count: query-row blocks pad to this
 
 def _attend_page(q, k, v, mask, sm_scale, h, m_scr, l_scr, acc_scr,
                  k_scale=None, v_scale=None):
-    """One online-softmax update over one f32 K/V page — shared by the
-    plain and fused-dequant bodies so the accumulator math can never
-    drift between them.  ``q`` is the [R, D] query-row block (row r = query
+    """One online-softmax update over one K/V page — shared by the plain
+    and fused-dequant bodies so the accumulator math can never drift
+    between them.  ``q``, ``k`` and ``v`` arrive either all in the pages'
+    2-byte dtype or all float32 (`_ragged_kernel.attend` decides from the
+    refs' dtypes); scores, probabilities and the running state are float32
+    either way.  ``q`` is the [R, D] query-row block (row r = query
     r // rep, head r % rep of the kv group), ``mask`` the [R, ps] validity
     of each (query row, kv position) pair, ``h`` the kv head's place in
     the step's [Hb, R, 1] / [Hb, R, 1] / [Hb, R, D] running max, sum and
@@ -124,10 +155,32 @@ def _attend_page(q, k, v, mask, sm_scale, h, m_scr, l_scr, acc_scr,
     l_scr[h] = alpha * l_scr[h] + jnp.sum(p, axis=1, keepdims=True)
     if v_scale is not None:
         p = p * v_scale
-    acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc_scr[h] = acc_scr[h] * alpha + _weighted_values(p, v)
     m_scr[h] = m_new
+
+
+def _weighted_values(p, v):
+    """p [R, ps] float32 times the V tile [ps, D], float32.  A 2-byte tile
+    goes to the MXU as it is stored and ``p`` beside it to f32 rounding, as
+    TWO pieces of the tile's dtype (p = p_hi + p_lo to 2^-17 relative in
+    bfloat16), each its own product against the tile, added in f32.  One
+    piece alone (what `_mla_kernel` feeds, and what an f32 product IS on
+    the chip: Mosaic runs it as one bf16 pass unless told "highest") is p
+    to 2^-9.  The second product costs no time at the decode and verify
+    shapes — on the v5e the update with THREE products runs a third faster
+    than with two, and the pieces stacked into one [2R, ps] operand run as
+    slowly as one piece (PERF.md section 6, PR 40: the K tile's transposed
+    latch sets the pace, and three products a head spread the heads'
+    score products over the four MXUs).  An f32 tile takes the one f32
+    product it took before."""
+    product = lambda left: jax.lax.dot_general(
+        left, v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    if v.dtype.itemsize != 2:
+        return product(p)
+    p_hi = p.astype(v.dtype)
+    p_lo = (p - p_hi.astype(jnp.float32)).astype(v.dtype)
+    return product(p_hi) + product(p_lo)
 
 
 def _segment_mask(shape, i, page_size, rep, q_start, q_len, kv_len):
@@ -222,6 +275,12 @@ def _ragged_kernel(pt_ref, qs_ref, ql_ref, kl_ref, ly_ref, q_ref, *refs,
 
     q_start, q_len, kv_len = qs_ref[b], ql_ref[b], kl_ref[b]
     rows = q_ref.shape[2]
+    # 2-byte queries over 2-byte pages of the same dtype go to the MXU as
+    # they are stored; any other pairing as f32
+    native = (not quant and q_ref.dtype == bufs[0].dtype
+              and q_ref.dtype.itemsize == 2)
+    as_operand = (lambda x: x) if native \
+        else (lambda x: x.astype(jnp.float32))
 
     def attend(c, carry):
         buf = (base + c) % 2
@@ -239,10 +298,10 @@ def _ragged_kernel(pt_ref, qs_ref, ql_ref, kl_ref, ly_ref, q_ref, *refs,
         mask = _segment_mask((rows, page_size), c, page_size, rep, q_start,
                              q_len, kv_len)
         for h in range(heads):
-            tiles = [dst[buf, h].astype(jnp.float32) for dst in bufs]
+            tiles = [as_operand(dst[buf, h]) for dst in bufs]
             scales = dict(k_scale=tiles[1][:, :page_size],
                           v_scale=tiles[3][:, :page_size]) if quant else {}
-            _attend_page(q_ref[0, h].astype(jnp.float32), tiles[0],
+            _attend_page(as_operand(q_ref[0, h]), tiles[0],
                          tiles[n_src // 2], mask, sm_scale, h, m_scr, l_scr,
                          acc_scr, **scales)
         return carry
@@ -329,7 +388,9 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, q_start, q_len,
     double-buffered ``make_async_copy`` of all Hb heads of a page).  The
     table's dead columns cost nothing: no step, no copy, and no read of
     what the table holds there.  The per-row arithmetic does not depend on
-    Hb.
+    Hb.  An update's products take 2-byte queries and pages AS STORED
+    (PR 40; the module docstring has the form and what the probes read);
+    f32 pages take the f32 products.
 
     The custom call keeps a fixed outline that the benchmark's trace
     readers match by shape: its FIRST operand is the [S, P] page table and

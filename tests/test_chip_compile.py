@@ -154,6 +154,52 @@ def test_ragged_kernel_keeps_its_outline_at_the_cells_shapes(one_chip, case):
             % role) in "".join(text.split())
 
 
+# name: (layers of the pool, pages a slot, kind)
+_LONGREASON_KERNEL = {"store": (1, 73, "cross"), "ring": (8, 4, "window")}
+
+
+@pytest.mark.parametrize("case", list(_LONGREASON_KERNEL))
+def test_ragged_kernel_at_the_differential_decode_shapes(one_chip, case):
+    """`serve_longreason_c64`'s decode calls (PR 40: the bf16 [8, 128] query
+    block, the probabilities' bf16 pieces and the bf16 tiles go to the MXU
+    as they are — half a bf16 sublane tile a left operand): 64 slots, 40
+    query rows of 128 over 10 K/V pairs, pages of 128 — the ONE store's
+    table of 73 and a window ring's 4 pages — float32 out.  The outline the
+    benchmark's readers match stays: the `[S, P]` table the FIRST operand,
+    ONE result `f32[64,10,8,128]` (`model.decode_ms_per_step` finds the
+    decode module by `[d,d,8,d]`), and the label's `kind` and `role`
+    ADJACENT (the compiled text sorts the keys; the two decode rooflines
+    match `"kind":"…",\\s*"role":"decode"`)."""
+    from paddle_tpu.ops.pallas.paged_attention import ragged_paged_attention
+
+    layers, table, kind = _LONGREASON_KERNEL[case]
+    slots, hq, pairs, page = 64, 40, 10, 128
+    sds = _shapes_on(one_chip)
+    pool = sds((layers, pairs, slots * table + 1, page, D), jnp.bfloat16)
+    seg = sds((slots,), jnp.int32)
+    text = _compiles_with_kernel(
+        lambda q, k, v, t, a, b, c, layer: ragged_paged_attention(
+            q, k, v, t, a, b, c, sm_scale=0.125, out_dtype=jnp.float32,
+            role="decode", kind=kind, layer=layer),
+        sds((slots, 1, hq, D), jnp.bfloat16), pool, pool,
+        sds((slots, table), jnp.int32), seg, seg, seg, sds((), jnp.int32))
+    (call,) = [line for line in text.splitlines()
+               if "tpu_custom_call" in line]
+    pool_text = r"bf16\[%d,10,%d,128,128\]\{4,3,2,1,0\}" % (
+        layers, slots * table + 1)
+    assert re.search(
+        r"= f32\[64,10,8,128\]\S* custom-call\([^)]*\), "
+        r"custom_call_target=\"tpu_custom_call\", "
+        r"operand_layout_constraints=\{s32\[64,%d\]\{1,0\}, " % table
+        + r"(s32\[\d+\]\{0\}, ){4}bf16\[64,10,8,128\]\{3,2,1,0\}, "
+        + pool_text + ", " + pool_text + r"\}", call), call[:900]
+    # the benchmark's own expressions (`benchmark/layer_metrics/kernel.
+    # shared_kv_decode_roofline_pct.json`, `.window_attn_decode_…`)
+    assert re.search(
+        r'kernel_metadata=\{\s*"kernel":"ragged_paged_attention",\s*'
+        r'"kind":"%s",\s*"role":"decode"\s*\}' % kind, text)
+
+
 @pytest.mark.parametrize("storage", [jnp.int8, jnp.float8_e4m3fn],
                          ids=["int8", "fp8"])
 @pytest.mark.parametrize("slots,qmax", [(SLOTS, 1), (1, 128)],

@@ -1,6 +1,7 @@
 """Pallas kernel tests — run in interpreter mode on the CPU mesh (the kernels
 themselves are TPU-targeted; interpret=True validates the math)."""
 import functools
+import math
 import numpy as np
 import pytest
 import jax
@@ -885,6 +886,155 @@ def test_choose_heads_from_shapes():
             for quant in (False, True):
                 assert hkv % _choose_heads(rows, hkv,
                                            **{**cell, "quant": quant}) == 0
+
+
+# ---------------------------------------------------------------------------
+# The products' operands (PR 40): 2-byte queries over 2-byte pages of one
+# dtype go to the MXU as they are stored — scores one bf16 x bf16 product,
+# values p as TWO bf16 pieces, a product each against the V tile — f32
+# accumulation; every other pairing of dtypes runs the f32 products it ran
+# before, bit for bit.  The rule is the operands' dtypes at trace time.
+# ---------------------------------------------------------------------------
+def _kernel_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _kernel_eqns(sub)
+
+
+def _traced_kernel(q_dtype, page_dtype, qmax, quant=False):
+    from paddle_tpu.ops.pallas.paged_attention import ragged_paged_attention
+    S, hq, hkv, D, ps, NP, P = 2, 8, 2, 128, 64, 9, 4
+    q = jnp.zeros((S, qmax, hq, D), q_dtype)
+    pages = jnp.zeros((hkv, NP, ps, D), page_dtype)
+    kw = dict(k_scales=jnp.ones((hkv, NP, ps)),
+              v_scales=jnp.ones((hkv, NP, ps))) if quant else {}
+    seg = jnp.ones((S,), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: ragged_paged_attention(
+        q, k, v, jnp.zeros((S, P), jnp.int32), seg, seg, seg, **kw))(
+            q, pages, pages)
+    (call,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    return str(jaxpr), list(_kernel_eqns(call.params["jaxpr"])), (ps, D)
+
+
+@pytest.mark.parametrize("qmax", [1, 5, 128], ids=["decode", "verify",
+                                                   "chunk"])
+def test_ragged_kernel_feeds_the_mxu_its_pages_as_stored(qmax):
+    """bf16 queries over bf16 pages: no [ps, D] tile is widened to f32 and
+    no query block either; an update's `dot_general`s — the scores, then
+    the two pieces of p against the V tile — take bf16 operands and give an
+    f32 result."""
+    _, eqns, tile = _traced_kernel(jnp.bfloat16, jnp.bfloat16, qmax)
+    rows = -(-qmax * 4 // 8) * 8
+    widened = [e for e in eqns if e.primitive.name == "convert_element_type"
+               and e.outvars[0].aval.dtype == jnp.float32
+               and e.invars[0].aval.shape in (tile, (rows, tile[1]))]
+    assert not widened
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    assert len(dots) == 3 * 2          # (scores, p_hi, p_lo) x 2 kv heads
+    for e in dots:
+        assert [v.aval.dtype for v in e.invars] == [jnp.bfloat16] * 2
+        assert e.outvars[0].aval.dtype == jnp.float32
+    assert [e.invars[0].aval.shape for e in dots] \
+        == [(rows, tile[1]), (rows, tile[0]), (rows, tile[0])] * 2
+    # the two value products read ONE V tile
+    assert dots[1].invars[1] is dots[2].invars[1]
+
+
+# sha256 of `str(make_jaxpr(...))`, recorded at the parent `aa86b38` (jax
+# 0.9.0): the text holds no path and no address.  A later PR that changes
+# the kernel's f32 path on purpose records them again.
+_PARENT_JAXPR = {
+    ("float32", "float32", False): "8cdb0bde321bd50a",
+    ("float32", "bfloat16", False): "d3e6c85521400c8f",
+    ("bfloat16", "float32", False): "678aab9bcc57c204",
+    ("float32", "int8", True): "a160bb9130933e57",
+}
+
+
+@pytest.mark.parametrize("q_dtype,page_dtype,quant", list(_PARENT_JAXPR),
+                         ids=["f32", "q_wider_than_pages",
+                              "pages_wider_than_q", "int8_pages"])
+def test_ragged_kernel_on_other_dtypes_is_the_parents_program(q_dtype,
+                                                              page_dtype,
+                                                              quant):
+    """f32 pages (the parity sweeps, the bit equalities across blockings),
+    a query wider than its pages, and the int8 body trace to the program
+    they traced to before PR 40 — the whole jaxpr, text for text — so their
+    results are the parent's bits: every product on f32 operands."""
+    import hashlib
+    text, eqns, _ = _traced_kernel(jnp.dtype(q_dtype), jnp.dtype(page_dtype),
+                                   5, quant=quant)
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    assert len(dots) == 2 * 2                  # (scores, values) x 2 kv heads
+    for e in dots:
+        assert [v.aval.dtype for v in e.invars] == [jnp.float32] * 2
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == _PARENT_JAXPR[q_dtype, page_dtype, quant]
+
+
+@pytest.mark.parametrize("role", list(_RB_ROLES))
+def test_ragged_bf16_products_equal_the_f32_products_to_f32_rounding(role):
+    """The SAME bf16 values through both paths of the one kernel: as bf16
+    (native products, p in two bf16 pieces) and widened to f32 outside
+    (the f32 products).  A bf16 x bf16 product is exact in f32, so the
+    scores differ by the order of an f32 sum only; p = p_hi + p_lo holds
+    to 2^-17 relative, so an output differs by at most 2^-17 of the largest
+    |v| it averages — 64 x closer than ONE bf16 piece of p (2^-9) would
+    come, which this bound refuses."""
+    from paddle_tpu.ops.pallas.paged_attention import ragged_paged_attention
+    args, kw = _reblocked_case(_RB_ROLES[role], 16, 4, True, jnp.bfloat16,
+                               seed=53)
+    wide = tuple(a.astype(jnp.float32) for a in args[:3]) + args[3:]
+    native = np.asarray(ragged_paged_attention(
+        *args, interpret=True, out_dtype=jnp.float32, role=role, **kw))
+    f32 = np.asarray(ragged_paged_attention(
+        *wide, interpret=True, out_dtype=jnp.float32, role=role, **kw))
+    bound = 2.0 ** -17 * float(np.abs(np.asarray(wide[2])).max())
+    assert np.abs(native - f32).max() <= bound + 2e-6   # + the sums' order
+    assert native.any()
+
+
+@pytest.mark.parametrize("qmax", [1, 5], ids=["decode", "verify"])
+def test_ragged_kernel_as_differential_attention(qmax):
+    """`models/sambay.py`'s use of the kernel: rows `[k_2p | k_2p+1]`, `[v_2p
+    | v_2p+1]` 128 wide, queries `[q | 0]` / `[0 | q]`, the output `a1 - lam
+    a2` a DIFFERENCE of two softmax sums (lam 0.8) — against the plain
+    `diff_attention_pairs` in f32 on the same bf16 values.  Each sum holds
+    to 2^-17 of the largest |v|, the difference to (1 + lam) times that."""
+    from paddle_tpu.models.sambay import diff_attention_pairs
+    from paddle_tpu.ops.pallas.paged_attention import ragged_paged_attention
+    S, nh, nkv, hd, ps, P, lam = 3, 8, 4, 64, 16, 5, 0.8
+    npair, wide = nkv // 2, 2 * hd
+    lr = np.random.default_rng(59)
+    kv_len = np.array([P * ps, 37, 16], np.int32)
+    bf = lambda *shape: jnp.asarray(lr.standard_normal(shape), jnp.bfloat16)
+    q, k, v = bf(S, qmax, nh, hd), bf(S, P * ps, nkv, hd), bf(S, P * ps, nkv, hd)
+    # the store: a slot's pages in order, rows 2 hd wide a K/V pair
+    pages = lambda x: x.reshape(S * P, ps, npair, wide).transpose(2, 0, 1, 3)
+    table = jnp.arange(S * P, dtype=jnp.int32).reshape(S, P)
+    qp = q.reshape(S, qmax, nh // 2, 2, hd)
+    zero = jnp.zeros_like(qp[:, :, :, 0])
+    qz = jnp.stack([jnp.concatenate([qp[:, :, :, 0], zero], -1),
+                    jnp.concatenate([zero, qp[:, :, :, 1]], -1)], 3)
+    o = ragged_paged_attention(
+        qz.reshape(S, qmax, nh, wide), pages(k), pages(v), table,
+        jnp.asarray(kv_len - qmax), jnp.full((S,), qmax, jnp.int32),
+        jnp.asarray(kv_len), sm_scale=1 / math.sqrt(hd), interpret=True,
+        out_dtype=jnp.float32, role="decode", kind="cross")
+    o = np.asarray(o).reshape(S, qmax, nh // 2, 2, wide)
+    got = o[:, :, :, 0] - lam * o[:, :, :, 1]
+    f32 = lambda x: x.astype(jnp.float32)
+    for s in range(S):
+        n = int(kv_len[s])
+        mask = jnp.asarray(np.arange(n)[None]
+                           <= n - qmax + np.arange(qmax)[:, None])
+        a1, a2 = diff_attention_pairs(f32(q[s]), f32(k[s, :n]), f32(v[s, :n]),
+                                      mask, 1 / math.sqrt(hd))
+        bound = (1 + lam) * 2.0 ** -17 * float(np.abs(f32(v[s, :n])).max())
+        assert np.abs(got[s] - np.asarray(a1 - lam * a2)).max() \
+            <= bound + 4e-6
+    assert got.any()
 
 
 def test_grouped_matmul_against_ragged_dot():
