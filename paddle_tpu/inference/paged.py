@@ -124,6 +124,7 @@ from __future__ import annotations
 
 import contextlib
 import hashlib
+import heapq
 import json
 import math
 import time
@@ -186,6 +187,10 @@ class PagePool:
         self.page_size = int(page_size)
         self._free = list(range(self.num_pages - 1, -1, -1))
         self._refs: dict[int, int] = {}
+        # called with a page whose references drop to ONE: the prefix
+        # cache over this pool installs it to learn when a page it holds
+        # has become evictable
+        self._on_sole_ref = None
 
     @property
     def num_free(self) -> int:
@@ -260,8 +265,12 @@ class PagePool:
                     f"PagePool.free: page {p} is not allocated "
                     "(double free or foreign page)")
         for p in pages:
-            self._refs[p] -= 1
-            if self._refs[p] == 0:
+            left = self._refs[p] - 1
+            if left:
+                self._refs[p] = left
+                if left == 1 and self._on_sole_ref is not None:
+                    self._on_sole_ref(p)
+            else:
                 del self._refs[p]
                 self._free.append(p)
 
@@ -310,7 +319,8 @@ def prefix_chain_hashes(tokens, page_size: int) -> list[bytes]:
 
 
 class _CacheEntry:
-    __slots__ = ("key", "parent", "page", "tokens", "tick", "children")
+    __slots__ = ("key", "parent", "page", "tokens", "tick", "children",
+                 "queued")
 
     def __init__(self, key, parent, page, tokens=None):
         self.key = key                # chained SHA-256 digest (None: partial)
@@ -319,6 +329,7 @@ class _CacheEntry:
         self.tokens = tokens          # None for full blocks; bytes for the
         self.tick = 0                 #   partial tail block's token content
         self.children = 0             # cached entries chained under this one
+        self.queued = None            # tick of its live eviction-heap item
 
 
 class PrefixCache:
@@ -339,7 +350,12 @@ class PrefixCache:
     Eviction is LRU over entries that are pure cache (pool refcount 1)
     and leaves of the hash chain (no cached children) — evicting an inner
     block would strand its descendants unreachable while they still hold
-    pages."""
+    pages.  Those entries are kept in a min-heap by last use, so a victim
+    costs O(log entries), not a pass over the whole cache: every
+    evictable entry has one item at its current tick (pushed when it is
+    touched, when its last child goes, and when the pool tells the cache
+    a page of its dropped to one reference), and an item that went stale
+    — a newer tick, a new child or holder — is dropped when it surfaces."""
 
     def __init__(self, pool: PagePool, page_size: int):
         self.pool = pool
@@ -352,6 +368,10 @@ class PrefixCache:
         self._tick = 0
         self.insertions = 0
         self.evictions = 0
+        self.evict_visits = 0         # heap items eviction examined
+        self._by_page: dict[int, _CacheEntry] = {}   # one entry a page
+        self._heap: list = []         # (tick, entry): the eviction index
+        pool._on_sole_ref = self._sole_ref
         # optional ``notify(kind, digests)`` listener (kind "insert" |
         # "evict", digests = full-block chain digests): the fleet router
         # keeps its per-replica cached-chain summary current through this
@@ -360,19 +380,16 @@ class PrefixCache:
         self.notify = None
 
     def __len__(self) -> int:
-        return len(self._full) + sum(len(d) for d in self._partial.values())
+        return len(self._by_page)
 
     def pages(self):
         """Every page the cache holds a reference on (one per entry)."""
-        for e in self._full.values():
-            yield e.page
-        for d in self._partial.values():
-            for e in d.values():
-                yield e.page
+        return iter(self._by_page)
 
     def _touch(self, e: _CacheEntry):
         self._tick += 1
         e.tick = self._tick
+        self._enqueue(e)
 
     def _digest(self, parent: bytes, block) -> bytes:
         return _chain_digest(parent, block)
@@ -447,6 +464,7 @@ class PrefixCache:
                 self.pool.share([pages[i]])
                 e = _CacheEntry(key, parent, int(pages[i]))
                 self._full[key] = e
+                self._by_page[e.page] = e
                 if parent in self._full:
                     self._full[parent].children += 1
                 self.insertions += 1
@@ -465,40 +483,86 @@ class PrefixCache:
                     e = _CacheEntry(None, parent, int(pages[n_full]),
                                     tokens=tb)
                     tails[tb] = e
+                    self._by_page[e.page] = e
                     if parent in self._full:
                         self._full[parent].children += 1
                     self.insertions += 1
                     self._touch(e)
 
     # -- eviction ----------------------------------------------------------
-    def _evictable(self):
-        for d in self._partial.values():
-            for e in d.values():
-                if self.pool.refcount(e.page) == 1:
-                    yield e
-        for e in self._full.values():
-            if e.children == 0 and self.pool.refcount(e.page) == 1:
-                yield e
+    def _can_evict(self, e: _CacheEntry) -> bool:
+        return (e.tokens is not None or e.children == 0) \
+            and self.pool.refcount(e.page) == 1
+
+    def _enqueue(self, e: _CacheEntry):
+        """Give an evictable entry its heap item at its current tick, unless
+        it has one."""
+        if e.queued != e.tick and self._can_evict(e):
+            e.queued = e.tick
+            heapq.heappush(self._heap, (e.tick, e))
+            if len(self._heap) > 2 * len(self._by_page) + 64:
+                self._reindex()         # mostly stale items: start afresh
+
+    def _sole_ref(self, page: int):
+        e = self._by_page.get(page)
+        if e is not None:
+            self._enqueue(e)
+
+    def _entries(self) -> list:
+        return list(self._full.values()) + [
+            e for d in self._partial.values() for e in d.values()]
+
+    def _reindex(self):
+        """Rebuild what the entries determine — children counts, the page
+        map, the eviction heap — from the entries alone (a restore writes
+        the entries directly; a heap gone mostly stale is compacted the
+        same way)."""
+        entries = self._entries()
+        for e in entries:
+            e.children = 0
+        for e in entries:
+            if e.parent in self._full:
+                self._full[e.parent].children += 1
+        self._by_page = {e.page: e for e in entries}
+        self._heap = []
+        for e in entries:
+            e.queued = e.tick if self._can_evict(e) else None
+            if e.queued is not None:
+                self._heap.append((e.tick, e))
+        heapq.heapify(self._heap)
+
+    def check_index(self):
+        """The eviction index agrees with the entries: one page an entry,
+        and every evictable entry holds an item at its current tick."""
+        entries = self._entries()
+        assert self._by_page == {e.page: e for e in entries}, \
+            "prefix cache: page map out of step with its entries"
+        live = {id(e) for t, e in self._heap if t == e.tick == e.queued}
+        stray = [e.page for e in entries
+                 if self._can_evict(e) and id(e) not in live]
+        assert not stray, f"evictable pages missing from the heap: {stray}"
 
     def evict(self, n_pages: int) -> int:
         """Drop up to n_pages LRU cache-only leaf entries, returning their
         pages to the free list; returns how many pages were freed.  Walks
-        chains back-to-front across calls: evicting a leaf makes its
-        parent a leaf for the next pass of the same call."""
-        freed = 0
-        while freed < n_pages:
-            cand = None
-            for e in self._evictable():
-                if cand is None or e.tick < cand.tick:
-                    cand = e
-            if cand is None:
-                break
-            self._drop(cand)
-            freed += 1
-        self.evictions += freed
-        return freed
+        chains back-to-front: evicting a leaf can make its parent a leaf,
+        which then competes by its own tick within the same call."""
+        victims = []
+        while len(victims) < n_pages and self._heap:
+            tick, e = heapq.heappop(self._heap)
+            self.evict_visits += 1
+            if tick != e.tick or e.queued != tick:
+                continue                # superseded by a newer item
+            e.queued = None
+            if self._can_evict(e):
+                self._drop(e)
+                victims.append(e.page)
+        self.pool.free(victims)
+        self.evictions += len(victims)
+        return len(victims)
 
     def _drop(self, e: _CacheEntry):
+        """Unindex e; its page reference is the caller's to free."""
         if e.tokens is None:
             del self._full[e.key]
             if self.notify is not None:
@@ -508,9 +572,12 @@ class PrefixCache:
             del tails[e.tokens]
             if not tails:
                 del self._partial[e.parent]
-        if e.parent in self._full:
-            self._full[e.parent].children -= 1
-        self.pool.free([e.page])
+        del self._by_page[e.page]
+        parent = self._full.get(e.parent)
+        if parent is not None:
+            parent.children -= 1
+            if not parent.children:
+                self._enqueue(parent)
 
 
 class _NgramDraft:
@@ -1208,6 +1275,7 @@ class ServingEngine:
                                        #   them: what the ragged kernel's
                                        #   page loop walked
         self.cache_evictions = 0       # cached pages evicted under pressure
+        self.cache_evict_visits = 0    # eviction-heap items examined for them
         self.cow_copies = 0            # copy-on-write page copies
         self.verify_steps = 0          # speculative verify dispatches
         self.draft_tokens_proposed = 0  # draft tokens sent to verify
@@ -1567,13 +1635,20 @@ class ServingEngine:
         n pages from the prefix cache (LRU leaf-first)."""
         if self.cache is None or n <= 0:
             return 0
-        freed = self.cache.evict(n)
-        self.cache_evictions += freed
+        with self._span("evict", requested=n) as late:
+            freed = late["freed"] = self._evict_cache(n)
         if self.telemetry is not None:
             # recorded even at freed == 0: walking this rung is what the
             # flight-recorder ladder drills assert (admit -> evict ->
             # preempt), whether or not the cache had anything to give back
             self.telemetry.evicted(requested=n, freed=freed)
+        return freed
+
+    def _evict_cache(self, n: int) -> int:
+        visits = self.cache.evict_visits
+        freed = self.cache.evict(n)
+        self.cache_evictions += freed
+        self.cache_evict_visits += self.cache.evict_visits - visits
         return freed
 
     def _register_pages(self, slot, valid: int, with_partial: bool):
@@ -2911,7 +2986,8 @@ class ServingEngine:
                       "prefill_kv_pages_written",
                       "decode_kv_tokens_attended",
                       "decode_kv_pages_attended",
-                      "cache_evictions", "cow_copies", "verify_steps",
+                      "cache_evictions", "cache_evict_visits",
+                      "cow_copies", "verify_steps",
                       "draft_tokens_proposed", "draft_tokens_accepted",
                       "overlap_steps", "quiesces", "fused_sample_steps",
                       "kv_exports", "kv_imports", "kv_pages_exported",
@@ -3380,10 +3456,7 @@ class ServingEngine:
                 e = _CacheEntry(None, parent, int(page), tokens=tb)
                 e.tick = int(tick)
                 c._partial.setdefault(parent, {})[tb] = e
-            for e in list(c._full.values()) + [
-                    e for d in c._partial.values() for e in d.values()]:
-                if e.parent in c._full:
-                    c._full[e.parent].children += 1
+            c._reindex()
 
     def _restore_reprefill(self, meta, reqs):
         """Compact-mode (or geometry-mismatch) restore: requeue every
@@ -3436,6 +3509,9 @@ class ServingEngine:
             "cached_prefix_tokens": self.cache_hit_tokens,
             "cache_hits": self.cache_hits,
             "cache_evictions": self.cache_evictions,
+            # heap items eviction examined: over `cache_evictions` the cost
+            # of picking a victim (1 where no item went stale)
+            "cache_evict_visits": self.cache_evict_visits,
             "cow_copies": self.cow_copies,
             "preemptions": self.preemptions,
             "timeouts": self.timeouts,
@@ -3494,9 +3570,7 @@ class ServingEngine:
         freed.  Pages attached to live requests are untouched."""
         if self.cache is None:
             return 0
-        freed = self.cache.evict(self.pool.num_pages)
-        self.cache_evictions += freed
-        return freed
+        return self._evict_cache(self.pool.num_pages)
 
     def check_invariants(self):
         """Page-refcount accounting must exactly equal what the live page
@@ -3516,6 +3590,7 @@ class ServingEngine:
                     for p in lane.slot.pages:
                         expect[p] = expect.get(p, 0) + 1
         if self.cache is not None:
+            self.cache.check_index()
             for p in self.cache.pages():
                 expect[p] = expect.get(p, 0) + 1
         assert expect == self.pool._refs, (

@@ -7,7 +7,9 @@ Three layers:
     batch — the pool must stay untouched when it raises), and the
     `num_referenced` invariants.
   * `PrefixCache` unit behavior — chained block-hash lookup, partial-tail
-    matching, LRU leaf-first eviction that never strands a chain.
+    matching, LRU leaf-first eviction that never strands a chain, and the
+    eviction heap held to the full scan it replaced (random traffic, and
+    an engine restored from a snapshot).
   * Engine PARITY — the acceptance bar: greedy outputs bit-exact with the
     prefix cache on vs off (and vs `llama_generate`) across staggered
     arrivals, GQA configs, page-boundary prefix lengths (exact multiple of
@@ -194,6 +196,208 @@ class TestPrefixCacheIndex:
         assert cache.evict(5) == 0                   # nothing evictable
         pool.free(pages)
         assert cache.evict(5) == 2
+
+
+class _ScanCache(PrefixCache):
+    """The oracle: eviction as a full pass over every entry for each page,
+    taking the lowest tick among cache-only leaves."""
+
+    def evict(self, n_pages):
+        freed = 0
+        while freed < n_pages:
+            cand = None
+            for e in self._scan():
+                if cand is None or e.tick < cand.tick:
+                    cand = e
+            if cand is None:
+                break
+            self._drop(cand)
+            self.pool.free([cand.page])
+            freed += 1
+        self.evictions += freed
+        return freed
+
+    def _scan(self):
+        for d in self._partial.values():
+            for e in d.values():
+                if self.pool.refcount(e.page) == 1:
+                    yield e
+        for e in self._full.values():
+            if e.children == 0 and self.pool.refcount(e.page) == 1:
+                yield e
+
+
+def _record_victims(cache):
+    """Every page `cache` evicts, in order, and its notify calls."""
+    victims, notes = [], []
+    drop = cache._drop
+    cache._drop = lambda e: (victims.append(e.page), drop(e))
+    cache.notify = lambda kind, digests: notes.append((kind, list(digests)))
+    return victims, notes
+
+
+class _Side:
+    """A pool, a cache over it and the page tables of live sequences."""
+
+    def __init__(self, cache_cls, n_pages, ps):
+        self.pool = PagePool(n_pages, ps)
+        self.cache = cache_cls(self.pool, ps)
+        self.victims, self.notes = _record_victims(self.cache)
+        self.seqs = {}                     # sid -> [tokens, pages]
+
+    def state(self):
+        return (self.victims, self.notes, list(self.pool._free),
+                dict(self.pool._refs), len(self.cache),
+                self.cache.evictions)
+
+    def check(self):
+        expect = {}
+        for _, pages in self.seqs.values():
+            for p in pages:
+                expect[p] = expect.get(p, 0) + 1
+        for p in self.cache.pages():
+            expect[p] = expect.get(p, 0) + 1
+        assert expect == self.pool._refs
+
+    def make_room(self, n):
+        """Evict what n more pages lack; False if the pool still lacks."""
+        if n > self.pool.num_free:
+            self.cache.evict(n - self.pool.num_free)
+        return n <= self.pool.num_free
+
+    def admit(self, sid, tokens):
+        ps = self.pool.page_size
+        shared, partial = self.cache.lookup(tokens)
+        pin = list(shared) + ([partial[0]] if partial else [])
+        self.pool.share(pin)
+        need = -(-len(tokens) // ps) - len(shared)
+        if not self.make_room(need):
+            self.pool.free(pin)
+            return
+        own = self.pool.alloc(need)
+        if partial:                        # copied into its own page
+            self.pool.free([partial[0]])
+        self.seqs[sid] = [tokens, list(shared) + own]
+
+    def grow(self, sid, more):
+        tokens, pages = self.seqs[sid]
+        tokens = np.concatenate([tokens, more]).astype(np.int32)
+        grow = -(-len(tokens) // self.pool.page_size) - len(pages)
+        if grow > 0 and not self.make_room(grow):
+            return
+        if grow > 0:
+            pages += self.pool.alloc(grow)
+        self.seqs[sid][0] = tokens
+
+    def copy_on_write(self, sid, idx):
+        pages = self.seqs[sid][1]
+        if self.pool.refcount(pages[idx]) > 1 and self.make_room(1):
+            new = self.pool.alloc(1)[0]
+            self.pool.free([pages[idx]])
+            pages[idx] = new
+
+    def retire(self, sid, with_partial):
+        tokens, pages = self.seqs.pop(sid)
+        self.cache.register(tokens, pages, with_partial=with_partial)
+        self.pool.free(pages)
+
+
+def _random_traffic(seed, steps=600, n_pages=40, ps=4):
+    """One op sequence from `seed`, applied to the heap cache and to the
+    scan oracle in lockstep; both sides compared after every op."""
+    r = np.random.default_rng(seed)
+    docs = [r.integers(1, 9, int(r.integers(0, 15))).astype(np.int32)
+            for _ in range(4)]
+    sides = [_Side(PrefixCache, n_pages, ps), _Side(_ScanCache, n_pages, ps)]
+    next_sid = 0
+    for _ in range(steps):
+        op = r.choice(["admit", "admit", "grow", "register", "retire",
+                       "retire", "cow", "lookup", "evict", "release"],
+                      p=[.18, .05, .17, .08, .17, .08, .07, .1, .09, .01])
+        live = sorted(sides[0].seqs)
+        sid = live[int(r.integers(len(live)))] if live else None
+        if op == "admit":
+            doc = docs[int(r.integers(len(docs)))]
+            toks = np.concatenate([doc, r.integers(1, 9, int(r.integers(
+                1, 8)))]).astype(np.int32)
+            for s in sides:
+                s.admit(next_sid, toks)
+            next_sid += 1
+        elif op == "grow" and sid is not None:
+            more = r.integers(1, 9, int(r.integers(1, 6)))
+            for s in sides:
+                s.grow(sid, more)
+        elif op == "register" and sid is not None:
+            for s in sides:               # a finished prefill: full blocks
+                s.cache.register(*s.seqs[sid])
+        elif op == "retire" and sid is not None:
+            with_partial = bool(r.integers(2))
+            for s in sides:
+                s.retire(sid, with_partial)
+        elif op == "cow" and sid is not None:
+            idx = int(r.integers(len(sides[0].seqs[sid][1])))
+            for s in sides:
+                s.copy_on_write(sid, idx)
+        elif op == "lookup":
+            doc = docs[int(r.integers(len(docs)))]
+            for s in sides:
+                s.cache.lookup(np.concatenate([doc, [1, 2]]))
+        elif op == "evict":
+            k = int(r.integers(1, 6))
+            assert len({s.cache.evict(k) for s in sides}) == 1
+        elif op == "release":
+            assert len({s.cache.evict(n_pages) for s in sides}) == 1
+        assert sides[0].state() == sides[1].state()
+        for s in sides:
+            s.check()
+        sides[0].cache.check_index()
+    for sid in sorted(sides[0].seqs):
+        for s in sides:
+            s.retire(sid, True)
+    assert len({s.cache.evict(n_pages) for s in sides}) == 1
+    assert sides[0].state() == sides[1].state()
+    assert sides[0].pool.num_free == n_pages
+    return sides[0]
+
+
+class TestEvictionHeapMatchesTheScan:
+    @pytest.mark.parametrize("seed", [0, 1, 2, 3, 4, 5])
+    def test_random_traffic_evicts_as_the_full_scan(self, seed):
+        """Register (with and without tails, over shared chains), lookup,
+        attach, free, copy-on-write, evict(k) and a full release: the same
+        victims in the same order, the same notify calls, the same free
+        list and refcounts as the full scan, after every operation."""
+        side = _random_traffic(seed)
+        full = sum(len(d) for kind, d in side.notes if kind == "evict")
+        # full blocks and partial tails (which notify nothing) among them
+        assert len(side.victims) >= 40
+        assert 0 < full < len(side.victims)
+
+    def test_a_victim_costs_a_few_heap_items(self):
+        side = _random_traffic(7)
+        c = side.cache
+        assert c.evict_visits <= 4 * c.evictions
+
+    def test_index_follows_refcount_and_children(self):
+        """An entry enters the heap when its page drops to the cache's one
+        reference and when its last child goes; a new holder or a new
+        touch leaves a stale item that eviction skips."""
+        pool = PagePool(8, 4)
+        cache = PrefixCache(pool, 4)
+        a = np.arange(1, 9, dtype=np.int32)
+        pa = pool.alloc(2)
+        cache.register(a, pa)
+        assert cache._heap == []                     # held by the sequence
+        pool.free(pa)                                # cache-only now
+        cache.check_index()
+        assert [e.page for _, e in cache._heap] == [pa[1]]   # the leaf
+        pool.share([pa[1]])                          # a holder attaches
+        assert cache.evict(1) == 0 and cache.evict_visits == 1
+        pool.free([pa[1]])
+        cache.lookup(np.concatenate([a, [9]]))       # touches both
+        cache.check_index()
+        assert cache.evict(2) == 2
+        assert pool.num_free == 8 and len(cache) == 0
 
 
 # ---------------------------------------------------------------------------
@@ -406,6 +610,40 @@ class TestPrefixCacheParity:
                         num_pages=64, max_pages_per_seq=12, prefill_chunk=8)
         # 56 tokens / 8-token chunks -> several interleaved steps
         assert eng.steps_run >= 3
+
+    def test_restored_engine_evicts_as_the_original(self):
+        """A full-KV snapshot taken mid-run and restored into a fresh
+        engine: the restore rebuilds the eviction heap, so under the same
+        further traffic the two engines evict the same pages in the same
+        order (and emit the same tokens)."""
+        cfg = llama_config_tiny(vocab=64, hidden=32, layers=2, heads=4, seq=96)
+        params = _params(cfg, seed=10)
+        r = np.random.default_rng(43)
+        system = r.integers(1, 64, (6,)).astype(np.int32)
+        first, later = ([np.concatenate([system, r.integers(
+            1, 64, (int(n),))]).astype(np.int32) for n in r.integers(
+                3, 7, k)] for k in (4, 6))
+        geo = dict(page_size=4, num_pages=14, max_pages_per_seq=5)
+        eng = _mk(cfg, params, **geo)
+        for p in first:
+            eng.submit(p, max_new_tokens=6)
+        for _ in range(3):
+            eng.step()
+        assert eng.num_active == 2 and len(eng.cache)
+        fresh = _mk(cfg, params, **geo)
+        assert fresh.restore(eng.snapshot("full_kv")) == "full_kv"
+        runs = []
+        for e in (eng, fresh):
+            victims, notes = _record_victims(e.cache)
+            rids = [e.submit(p, max_new_tokens=6) for p in later]
+            done = e.run()
+            e.check_invariants()
+            runs.append((victims, notes, list(e.pool._free),
+                         [done[i].output_ids.tolist() for i in rids],
+                         e.stats()["cache_evict_visits"]))
+        assert runs[0][0], "the traffic evicted nothing after the snapshot"
+        assert runs[0] == runs[1]
+        assert eng.cache_evict_visits <= 4 * eng.cache_evictions
 
     def test_cache_off_engine_has_no_cache_state(self):
         cfg = llama_config_tiny(vocab=64, hidden=32, layers=2, heads=4, seq=96)

@@ -26,7 +26,7 @@ from paddle_tpu.models.llama import (build_functional_llama,   # noqa: E402
 from paddle_tpu.observability import Telemetry                 # noqa: E402
 from paddle_tpu.observability.telemetry import ENGINE_PHASES   # noqa: E402
 
-SPAN_ONLY = ("step", "first_token_sync", "provision")
+SPAN_ONLY = ("step", "first_token_sync", "provision", "evict")
 VOCABULARY = {"serve." + n for n in ENGINE_PHASES + SPAN_ONLY}
 
 
@@ -177,6 +177,18 @@ def test_span_closes_on_error_and_skips_the_telemetry_phase(llama, recorder):
         pass
     assert recorder.left[-1] == "serve.provision"
     assert list(tel.utilization_report()["per_phase"]) == ["decode_record"]
+
+
+def test_eviction_runs_under_its_span(llama, recorder):
+    """Reclaiming cached pages is the span `serve.evict`, which says what
+    it was asked for and what it freed."""
+    eng = _engine(llama, num_pages=10, max_pages_per_seq=4)
+    _serve(eng, [13, 21, 9, 17, 12, 19])
+    spans = [a for a in recorder.entered if a.name == "serve.evict"]
+    assert spans and eng.cache_evictions
+    assert all(a.attrs["requested"] >= a.attrs["freed"] >= 0 for a in spans)
+    assert sum(a.attrs["freed"] for a in spans) == eng.cache_evictions
+    assert 1 <= eng.cache_evict_visits <= 4 * eng.cache_evictions
 
 
 # ---------------------------------------------------------------------------
